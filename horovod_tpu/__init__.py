@@ -35,10 +35,6 @@ from typing import Optional
 
 import jax
 
-from .common import jax_compat as _jax_compat
-
-_jax_compat.ensure()  # fill jax.shard_map / lax.axis_size on older jax
-
 from .common import basics as _basics
 from .common.basics import (ccl_built, cuda_built, ddl_built, gloo_built,
                             gloo_enabled, init, is_initialized, mpi_built,

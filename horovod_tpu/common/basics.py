@@ -31,6 +31,36 @@ from .timeline import Timeline
 logger = logging.getLogger("horovod_tpu")
 
 
+# <checkout>/.jax_cache: derived from where this package sits, because
+# the directory is part of every cache key — a path that moves never hits.
+DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def _place_compilation_cache() -> None:
+    """The one rule for JAX's persistent compilation cache: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it stands
+    and nothing here names a directory; where it is not, the cache is
+    :data:`DEFAULT_COMPILATION_CACHE_DIR`. An elastic reset or relaunch
+    re-traces the same programs and TPU compiles run tens of seconds —
+    the cache turns them into reads."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
+    import jax
+
+    if jax.config.jax_compilation_cache_dir \
+            == DEFAULT_COMPILATION_CACHE_DIR:
+        return
+    jax.config.update("jax_compilation_cache_dir",
+                      DEFAULT_COMPILATION_CACHE_DIR)
+    # jax decides whether the cache is in use at the first compile of
+    # the process; anything compiled before init() has already said no.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    compilation_cache.reset_cache()
+
+
 class Context:
     """The live runtime: topology + meshes + eager engine + profiling."""
 
@@ -47,15 +77,18 @@ class Context:
 
         faults_lib.refresh_from_env()
 
-        if config.overlap_xla_flags and not config.force_cpu_devices:
-            # Must land in XLA_FLAGS before the first backend touch (the
-            # topology discovery below initializes devices). The helper
-            # additionally requires positive TPU evidence — unknown
-            # --xla_tpu_* flags ABORT XLA on CPU/GPU-only installs.
+        if config.overlap_xla_flags:
+            # libtpu reads its flags once, when the backend starts (the
+            # topology discovery below).
             from .xla_tuning import enable_overlap_scheduling
 
             enable_overlap_scheduling()
         topo = topo_lib.discover(force_cpu_devices=config.force_cpu_devices)
+        # JAX falls to the CPU without a word when the TPU fails to
+        # initialise; say what this process really runs on.
+        logger.info("devices: platform=%s device_kind=%s count=%d",
+                    topo.platform, topo.devices[0].device_kind,
+                    len(topo.devices))
         if comm is not None:
             # Subset communicator: restrict to the given global rank ids.
             devices = [topo.devices[r] for r in comm]
@@ -70,32 +103,7 @@ class Context:
             config.thread_affinity,
             int(config_lib.runtime_env("LOCAL_SIZE", "1")),
             int(config_lib.runtime_env("LOCAL_RANK", "0")))
-        if config.compilation_cache_dir:
-            # Warm-start XLA compiles from disk: an elastic reset or
-            # relaunch re-traces the same programs, and TPU compiles
-            # run tens of seconds — the cache turns them into reads.
-            import jax
-
-            if jax.config.jax_compilation_cache_dir != \
-                    config.compilation_cache_dir:
-                # jax initializes its persistent cache at most once per
-                # process, at the FIRST compile — if anything compiled
-                # before init() (or a previous Context used another dir),
-                # the config update alone is silently ignored. Reset so
-                # the next compile re-initializes against our dir.
-                # Private API, so best-effort: a jax without it just
-                # keeps the first-compile-wins behavior.
-                try:
-                    from jax._src import compilation_cache as _jax_cc
-
-                    _jax_cc.reset_cache()
-                except Exception:  # noqa: BLE001
-                    logger.warning(
-                        "could not reset jax's persistent compilation "
-                        "cache; if anything compiled before init(), "
-                        "HVD_TPU_COMPILATION_CACHE_DIR may not apply")
-            jax.config.update("jax_compilation_cache_dir",
-                              config.compilation_cache_dir)
+        _place_compilation_cache()
         self.mesh = topo_lib.build_mesh(topo, config.rank_axis)
         self.hier_mesh = None
         if topo.is_homogeneous and topo.cross_size > 1:
@@ -559,37 +567,16 @@ def xla_built() -> bool:
 
 
 def tpu_available() -> bool:
-    """True when a TPU backend is reachable right now. Pre-init this
-    probes in a SUBPROCESS: initializing the in-process JAX backend as a
-    side effect would silently pin the device count/platform before a
-    later init() could configure them (XLA_FLAGS forcing, jax_platforms)."""
+    """True when this process's JAX backend is a TPU. Answers from
+    ``jax.devices()`` itself: a chip belongs to one process, so a probe
+    in a child would take it from the caller — or hang on it. Before
+    ``init()`` this starts the backend, as any first JAX call does."""
     import jax
-    from jax._src import xla_bridge
 
-    if xla_bridge._backends:  # already initialized: answer directly
-        try:
-            return any(d.platform == "tpu" for d in jax.devices())
-        except RuntimeError:
-            return False
-    global _tpu_probe_result
-    if _tpu_probe_result is not None:  # subprocess probe is expensive —
-        return _tpu_probe_result       # the answer can't change in-process
-    import subprocess
-    import sys
-
-    code = ("import jax, sys; "
-            "sys.exit(0 if any(d.platform == 'tpu' for d in jax.devices())"
-            " else 1)")
     try:
-        _tpu_probe_result = subprocess.run(
-            [sys.executable, "-c", code], timeout=120,
-            capture_output=True).returncode == 0
-    except subprocess.TimeoutExpired:
-        _tpu_probe_result = False
-    return _tpu_probe_result
-
-
-_tpu_probe_result: Optional[bool] = None
+        return any(d.platform == "tpu" for d in jax.devices())
+    except RuntimeError:
+        return False
 
 
 # Single source of truth for the query surface the framework shims

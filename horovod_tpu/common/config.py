@@ -109,10 +109,9 @@ class Config:
     # Overlap scheduling (no reference knob — the reference's background
     # thread overlaps implicitly; here overlap=True on the optimizer
     # surfaces selects readiness-ordered buckets + issue-order chaining,
-    # and this knob additionally applies the TPU async-collective /
-    # latency-hiding XLA flags at init (common/xla_tuning.py). Off by
-    # default; applied ONLY with positive TPU evidence (platform env /
-    # libtpu) — XLA aborts on unknown --xla_tpu_* flags elsewhere.
+    # and this knob additionally hands libtpu the async-collective /
+    # latency-hiding compiler flags at init (common/xla_tuning.py). Off
+    # by default.
     overlap_xla_flags: bool = False
     # Topology-aware collective routing (docs/topology.md). `route`
     # names the default WirePlan for the optimizer surfaces: "flat"
@@ -232,11 +231,6 @@ class Config:
     # Host-core pinning: one core id per local rank, comma-separated
     # (reference: HOROVOD_THREAD_AFFINITY, common.cc:140-203).
     thread_affinity: Optional[str] = None
-    # Persistent XLA compilation cache directory (no reference analog —
-    # CUDA kernels ship precompiled; XLA recompiles per process, and an
-    # elastic reset IS a process restart, so warm-starting compiles
-    # from disk directly shortens every reset and relaunch).
-    compilation_cache_dir: Optional[str] = None
     # Unified telemetry (docs/metrics.md). Registry enable/disable is
     # env-only (HVD_TPU_METRICS=0 — read at import so instrumented hot
     # paths can bind no-op singletons before init() ever runs); these
@@ -325,7 +319,6 @@ class Config:
         c.autoscale_log = _env("AUTOSCALE_LOG")
         c.join_mode = _env_bool("JOIN_MODE", False)
         c.thread_affinity = _env("THREAD_AFFINITY")
-        c.compilation_cache_dir = _env("COMPILATION_CACHE_DIR")
         c.metrics_file = _env("METRICS_FILE")
         c.metrics_interval_s = _env_float("METRICS_INTERVAL_S",
                                           cls.metrics_interval_s)
@@ -421,8 +414,6 @@ RUNTIME_KNOBS = {
     "SEQ_PARALLEL":
         "sequence-parallel degree for tools (also a Config field)",
     "SEQ_IMPL": "ring | ulysses attention impl (also a Config field)",
-    "COMPILATION_CACHE_DIR":
-        "persistent XLA cache dir (also a Config field)",
     "METRICS_PORT": "Prometheus endpoint port (also a Config field)",
 }
 

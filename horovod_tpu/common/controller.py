@@ -203,11 +203,8 @@ class JaxKVTransport(KVTransport):
     def set(self, key: str, value: str) -> None:
         from jax._src import distributed as jdist
 
-        client = jdist.global_state.client
-        try:
-            client.key_value_set(key, value, allow_overwrite=True)
-        except TypeError:  # older jaxlib without the kwarg
-            client.key_value_set(key, value)
+        jdist.global_state.client.key_value_set(key, value,
+                                                allow_overwrite=True)
 
     def get(self, key: str, timeout_s: float) -> Optional[str]:
         from jax._src import distributed as jdist

@@ -86,19 +86,16 @@ def _maybe_enable_cpu_collectives() -> None:
 
     XLA's CPU client refuses to compile multiprocess computations
     ("Multiprocess computations aren't implemented on the CPU backend")
-    unless it was created with a collectives implementation, and jax
-    0.4.x never reads the JAX_CPU_COLLECTIVES_IMPLEMENTATION env var —
-    the config knob must be set in-process BEFORE the backend client
-    exists. Without this, every `runner.run(..., np=2)` world on CPU
-    (tests/test_run_api.py) dies at its first allreduce.
+    unless it was created with a collectives implementation, and the
+    default is none: the config knob must be set in-process BEFORE the
+    backend client exists. Without this, every `runner.run(..., np=2)`
+    world on CPU (tests/test_run_api.py) dies at its first allreduce.
     """
     import jax
 
-    impl = os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo")
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", impl)
-    except Exception:  # noqa: BLE001 — older jaxlib without the knob
-        pass
+    jax.config.update(
+        "jax_cpu_collectives_implementation",
+        os.environ.get("JAX_CPU_COLLECTIVES_IMPLEMENTATION", "gloo"))
 
 
 def _maybe_init_distributed() -> None:

@@ -26,6 +26,7 @@ _LIB_PATH = os.path.join(_DIR, "libhvdtpu_native.so")
 _lib: Optional[ctypes.CDLL] = None
 _lib_lock = threading.Lock()
 _build_attempted = False
+_status = "absent"
 
 
 def _build() -> bool:
@@ -41,46 +42,57 @@ def _build() -> bool:
         return False
 
 
+def _stale() -> bool:
+    """No binary, or one older than a source it is built from. The .so
+    is git-ignored, so a checkout carries none and a copied tree may
+    carry one from other sources."""
+    try:
+        built = os.path.getmtime(_LIB_PATH)
+    except OSError:
+        return True
+    return any(os.path.getmtime(os.path.join(_DIR, f)) > built
+               for f in os.listdir(_DIR)
+               if f.endswith(".cc") or f == "Makefile")
+
+
 def load() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _build_attempted
+    """Load the native library, (re)building it first when it is missing
+    or stale; None if unavailable. One build per process at most."""
+    global _lib, _build_attempted, _status
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not os.path.exists(_LIB_PATH):
-            if _build_attempted:
-                return None
-            _build_attempted = True
-            if runtime_env("DISABLE_NATIVE") == "1":
-                return None
-            if not _build():
-                return None
-        try:
-            lib = ctypes.CDLL(_LIB_PATH)
-        except OSError as e:
-            logger.warning("native library load failed: %s", e)
-            return None
-        try:
-            _bind_signatures(lib)
-        except AttributeError:
-            # Stale .so from an older source tree (missing new symbols):
-            # rebuild once, then either bind or fall back to pure Python.
-            if _build_attempted:
-                logger.warning("native library is stale and rebuild "
-                               "already failed; using Python fallbacks")
-                return None
-            _build_attempted = True
-            if not _build():
-                return None
+        # Second pass: a binary that looked fresh but lacks a symbol
+        # this tree binds was built from other sources after all.
+        for rebuild in (_stale(), True):
+            if rebuild:
+                if _build_attempted:
+                    return None
+                _build_attempted = True
+                if runtime_env("DISABLE_NATIVE") == "1" or not _build():
+                    return None
             try:
                 lib = ctypes.CDLL(_LIB_PATH)
                 _bind_signatures(lib)
-            except (OSError, AttributeError) as e:
-                logger.warning("native library unusable after rebuild: %s",
-                               e)
+            except OSError as e:
+                logger.warning("native library load failed: %s", e)
                 return None
-        _lib = lib
-        return _lib
+            except AttributeError:
+                continue
+            _lib = lib
+            _status = "rebuilt" if _build_attempted else "loaded"
+            return _lib
+        logger.warning("native library unusable after rebuild; using "
+                       "Python fallbacks")
+        return None
+
+
+def status() -> str:
+    """How the library came into this process: ``"loaded"`` (a binary
+    newer than its sources was found), ``"rebuilt"`` (``make`` ran
+    first) or ``"absent"`` (Python fallbacks in use)."""
+    load()
+    return _status
 
 
 def _bind_signatures(lib: ctypes.CDLL) -> None:

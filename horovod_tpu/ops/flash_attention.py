@@ -28,6 +28,7 @@ suite which also runs the real kernel bodies in interpret mode.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -37,6 +38,8 @@ from jax.experimental import pallas as pl
 
 from .pallas_kernels import _decide
 from ..common.config import runtime_env
+
+logger = logging.getLogger("horovod_tpu")
 
 _NEG = -1e30  # mask value; NOT -inf (exp(-inf - -inf) = nan)
 _LANE = 128
@@ -336,13 +339,20 @@ def flash_available(seq_len: int, use_pallas: Optional[bool] = None,
     HVD_TPU_FLASH_ATTENTION=0 escape hatch, un-tileable sequence).
     flash_attention_with_lse consults exactly this, so callers (ring
     attention) pre-checking it can rely on a non-None result."""
-    import os
-
     use, _ = _decide(use_pallas)
     if runtime_env("FLASH_ATTENTION", "1") == "0":
         return False
     return bool(use) and _pick_block(seq_len, block_q) is not None \
         and _pick_block(seq_len, block_k) is not None
+
+
+@functools.lru_cache(maxsize=None)  # once per shape, not per trace
+def _warn_untileable(shape, block_q, block_k):
+    logger.warning(
+        "flash_attention: sequence length %d of q%s has no multiple-of-8 "
+        "block <= (%d, %d); this call runs the O(S^2) reference "
+        "attention on the TPU instead of the Pallas kernel",
+        shape[1], tuple(shape), block_q, block_k)
 
 
 def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
@@ -355,9 +365,12 @@ def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
     Returns None when :func:`flash_available` declines, so callers use
     their own reference path."""
     b, s, h, d = q.shape
+    use, interpret = _decide(use_pallas)
     if not flash_available(s, use_pallas, block_q, block_k):
+        if use and not interpret \
+                and runtime_env("FLASH_ATTENTION", "1") != "0":
+            _warn_untileable(q.shape, block_q, block_k)
         return None
-    _, interpret = _decide(use_pallas)
     bq = _pick_block(s, block_q)
     bk = _pick_block(s, block_k)
     if mask is None:

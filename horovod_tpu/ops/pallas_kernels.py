@@ -220,18 +220,81 @@ def adasum_combine(a, b, dot_norms, use_pallas: Optional[bool] = None,
 
 # int8 sublane tile is 32; one scale per (32, 128) = 4096-element block.
 _Q_ROWS = 32
+# Quantization blocks per grid step: the kernels see the (rows, 128)
+# buffer as (nblocks, 32, 128) — a free view, each block is whole
+# (8, 128)/(32, 128) tiles — and take _Q_GROUP blocks at a time, so one
+# step moves the same 512 rows as the other kernels. The per-block
+# scales stay in the vector domain as (group, 1, 1): the TPU lowering
+# refuses a one-element rank-1 SMEM block, and a keepdims reduction over
+# the minor two dims needs no relayout. A ragged last group reads
+# undefined blocks past the end; blocks are independent and the
+# out-of-range writes are dropped.
+_Q_GROUP = _BLOCK_ROWS // _Q_ROWS
+
+
+def _scale_of(absmax):
+    # A product, not ``/ 127.0``: XLA rewrites division by a constant
+    # into this product under jit and Mosaic does not, which would leave
+    # kernel and jnp twin one ulp apart on some blocks.
+    return jnp.maximum(absmax, 1e-30) * (1.0 / 127.0)
+
+
+def _block_scale(xf):
+    return _scale_of(jnp.max(jnp.max(jnp.abs(xf), axis=2, keepdims=True),
+                             axis=1, keepdims=True))
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    xf = x_ref[:].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(xf))
-    scale = jnp.maximum(absmax, 1e-30) / 127.0
-    q_ref[:] = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
-    s_ref[0] = scale
+    xf = x_ref[...].astype(jnp.float32)
+    scale = _block_scale(xf)
+    q_ref[...] = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
+    s_ref[...] = scale
+
+
+def _quant_sr_kernel(x_ref, u_ref, q_ref, s_ref):
+    xf = x_ref[...].astype(jnp.float32)
+    scale = _block_scale(xf)
+    scaled = xf / scale
+    fl = jnp.floor(scaled)
+    q = fl + (u_ref[...] < (scaled - fl)).astype(jnp.float32)
+    q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
+    s_ref[...] = scale
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    o_ref[:] = (q_ref[:].astype(jnp.float32) * s_ref[0]).astype(o_ref.dtype)
+    o_ref[...] = (q_ref[...].astype(jnp.float32)
+                  * s_ref[...]).astype(o_ref.dtype)
+
+
+def _q_specs(nblocks):
+    """(grid, data BlockSpec, scale BlockSpec) over the
+    (nblocks, 32, 128) / (nblocks, 1, 1) views."""
+    group = min(nblocks, _Q_GROUP)
+    data = pl.BlockSpec((group, _Q_ROWS, _LANES), lambda i: (i, 0, 0),
+                        memory_space=pltpu.VMEM)
+    scale = pl.BlockSpec((group, 1, 1), lambda i: (i, 0, 0),
+                         memory_space=pltpu.VMEM)
+    return (pl.cdiv(nblocks, group),), data, scale
+
+
+def _quantize_call(kernel, operands, interpret):
+    """Run a quantize kernel over (rows, 128) operands; returns q as
+    (rows, 128) int8 and one fp32 scale per 32-row block."""
+    rows = operands[0].shape[0]
+    nblocks = rows // _Q_ROWS
+    grid, data, scale = _q_specs(nblocks)
+    q, scales = pl.pallas_call(
+        kernel,
+        grid=grid,
+        in_specs=[data] * len(operands),
+        out_specs=[data, scale],
+        out_shape=[
+            jax.ShapeDtypeStruct((nblocks, _Q_ROWS, _LANES), jnp.int8),
+            jax.ShapeDtypeStruct((nblocks, 1, 1), jnp.float32),
+        ],
+        interpret=interpret,
+    )(*(x.reshape(nblocks, _Q_ROWS, _LANES) for x in operands))
+    return q.reshape(rows, _LANES), scales.reshape(nblocks)
 
 
 def quantize_int8(x, use_pallas: Optional[bool] = None):
@@ -249,38 +312,11 @@ def quantize_int8(x, use_pallas: Optional[bool] = None):
     nblocks = x2.shape[0] // _Q_ROWS
     if not use:
         blocks = x2.reshape(nblocks, _Q_ROWS * _LANES).astype(jnp.float32)
-        absmax = jnp.max(jnp.abs(blocks), axis=1)
-        scales = jnp.maximum(absmax, 1e-30) / 127.0
+        scales = _scale_of(jnp.max(jnp.abs(blocks), axis=1))
         q = jnp.clip(jnp.round(blocks / scales[:, None]), -127, 127)
         return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = pl.pallas_call(
-        _quant_kernel,
-        grid=(nblocks,),
-        in_specs=[pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM)],
-        out_specs=[
-            pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, jnp.int8),
-            jax.ShapeDtypeStruct((nblocks,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2)
+    q, scales = _quantize_call(_quant_kernel, (x2,), interpret)
     return q, scales, n
-
-
-def _quant_sr_kernel(x_ref, u_ref, q_ref, s_ref):
-    xf = x_ref[:].astype(jnp.float32)
-    absmax = jnp.max(jnp.abs(xf))
-    scale = jnp.maximum(absmax, 1e-30) / 127.0
-    scaled = xf / scale
-    fl = jnp.floor(scaled)
-    q = fl + (u_ref[:] < (scaled - fl)).astype(jnp.float32)
-    q_ref[:] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    s_ref[0] = scale
 
 
 def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None):
@@ -313,34 +349,14 @@ def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None):
     u = jax.random.uniform(key, x2.shape, jnp.float32)
     if not use:
         blocks = x2.reshape(nblocks, _Q_ROWS * _LANES).astype(jnp.float32)
-        absmax = jnp.max(jnp.abs(blocks), axis=1)
-        scales = jnp.maximum(absmax, 1e-30) / 127.0
+        scales = _scale_of(jnp.max(jnp.abs(blocks), axis=1))
         scaled = blocks / scales[:, None]
         fl = jnp.floor(scaled)
         ub = u.reshape(nblocks, _Q_ROWS * _LANES)
         q = fl + (ub < (scaled - fl)).astype(jnp.float32)
         q = jnp.clip(q, -127, 127)
         return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = pl.pallas_call(
-        _quant_sr_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(x2.shape, jnp.int8),
-            jax.ShapeDtypeStruct((nblocks,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2, u)
+    q, scales = _quantize_call(_quant_sr_kernel, (x2, u), interpret)
     return q, scales, n
 
 
@@ -353,17 +369,13 @@ def dequantize_int8(q, scales, n, shape, dtype=jnp.float32,
         blocks = q.reshape(nblocks, _Q_ROWS * _LANES).astype(jnp.float32)
         out = (blocks * scales[:, None]).astype(dtype)
         return out.ravel()[:n].reshape(shape)
+    grid, data, scale = _q_specs(nblocks)
     out = pl.pallas_call(
         _dequant_kernel,
-        grid=(nblocks,),
-        in_specs=[
-            pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((_Q_ROWS, _LANES), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct(q.shape, dtype),
+        grid=grid,
+        in_specs=[data, scale],
+        out_specs=data,
+        out_shape=jax.ShapeDtypeStruct((nblocks, _Q_ROWS, _LANES), dtype),
         interpret=interpret,
-    )(q, scales)
+    )(q.reshape(nblocks, _Q_ROWS, _LANES), scales.reshape(nblocks, 1, 1))
     return out.ravel()[:n].reshape(shape)

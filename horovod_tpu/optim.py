@@ -867,7 +867,7 @@ def DistributedOptimizer(optimizer,
     overlap, expressed through XLA scheduling). Composes with
     ``hierarchical``/``quantized_cross`` (each chained bucket runs the
     staged reduction) and reduce-safe ``compression``; same numerics as
-    ``overlap=False``. Pair with the latency-hiding XLA flags
+    ``overlap=False``. Pair with the latency-hiding compiler flags
     (``init(overlap_xla_flags=True)`` / common/xla_tuning.py) on TPU.
     ``bucket_order`` optionally pins a measured leaf permutation
     (``fusion.measured_order``) instead of the reverse-flatten proxy.

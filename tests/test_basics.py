@@ -88,42 +88,114 @@ def test_capability_queries(hvd):
     assert hvd_torch.join is not None
 
 
-def test_compilation_cache_knob(tmp_path, hvd, monkeypatch):
-    """HVD_TPU_COMPILATION_CACHE_DIR warm-starts XLA compiles from disk
-    (elastic resets/relaunches re-trace the same programs): after a
-    jitted collective, the cache directory holds entries."""
+def test_init_says_what_it_runs_on(hvd, caplog):
+    """JAX falls to the CPU silently when a TPU fails to initialise;
+    init() logs platform, device_kind and count once at INFO."""
+    import logging
+
+    hvd.shutdown()
+    try:
+        with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+            hvd.init(log_level="info")
+    finally:
+        hvd.shutdown()
+        hvd.init()
+    said = [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("devices:")]
+    assert said == ["devices: platform=cpu device_kind=cpu count=8"]
+
+
+@pytest.fixture()
+def cache_config(hvd):
+    """Hands back jax's cache directory (and the runtime) as found."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    found = jax.config.jax_compilation_cache_dir
+    yield
+    hvd.shutdown()
+    jax.config.update("jax_compilation_cache_dir", found)
+    compilation_cache.reset_cache()
+    hvd.init()
+
+
+def test_compilation_cache_env_var_stands(tmp_path, hvd, monkeypatch,
+                                          cache_config):
+    """Where JAX_COMPILATION_CACHE_DIR is set, jax's own reading of it
+    stands: init() names no directory, and compiles land there."""
     import glob
 
     import jax
     import numpy as np
+    from jax.experimental.compilation_cache import compilation_cache
 
-    import horovod_tpu as hvd_mod
-
-    cache = str(tmp_path / "xla_cache")
-    monkeypatch.setenv("HVD_TPU_COMPILATION_CACHE_DIR", cache)
+    cache = str(tmp_path / "placed_from_outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", cache)
+    # What jax does with the variable at import, for a process that
+    # imported it long ago.
+    jax.config.update("jax_compilation_cache_dir", cache)
+    compilation_cache.reset_cache()
     # Entry thresholds down so CPU-fast compiles persist in the test.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     try:
-        hvd_mod.shutdown()
-        hvd_mod.init()
+        hvd.shutdown()
+        hvd.init()
         assert jax.config.jax_compilation_cache_dir == cache
-        out = hvd_mod.allreduce(np.ones(12, np.float32), op=hvd_mod.Sum,
-                                name="cc_knob")
+        out = hvd.allreduce(np.ones(12, np.float32), op=hvd.Sum,
+                            name="cc_env")
         jax.block_until_ready(out)
         assert glob.glob(cache + "/*"), "no cache entries written"
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs",
                           1.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                          0)
-        jax.config.update("jax_compilation_cache_dir", None)
-        # Clear the env BEFORE re-init, or Context re-applies the tmp
-        # cache dir and leaks it into the rest of the session.
-        monkeypatch.delenv("HVD_TPU_COMPILATION_CACHE_DIR")
-        hvd_mod.shutdown()
-        hvd_mod.init()
-        assert jax.config.jax_compilation_cache_dir is None
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+def test_compilation_cache_default_is_in_the_checkout(hvd, monkeypatch,
+                                                      cache_config):
+    """Unset, the cache is <checkout>/.jax_cache — derived from the
+    package's location, the same on every init() of every process."""
+    import os
+
+    import jax
+
+    from horovod_tpu.common import basics
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert basics.DEFAULT_COMPILATION_CACHE_DIR == os.path.join(
+        repo, ".jax_cache")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    seen = []
+    for _ in range(2):
+        hvd.shutdown()
+        hvd.init()
+        seen.append(jax.config.jax_compilation_cache_dir)
+    assert seen == [basics.DEFAULT_COMPILATION_CACHE_DIR] * 2
+
+
+def test_no_other_code_names_a_cache_directory():
+    """One rule, one place: nothing else in the program may point jax's
+    cache somewhere (bench.py and two tools each used to)."""
+    import glob
+    import os
+    import re
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    setter = re.compile(r"jax_compilation_cache_dir[\"'],")
+    program = glob.glob(os.path.join(repo, "*.py")) + [
+        os.path.join(root, f)
+        for top in ("horovod_tpu", "tools", "examples")
+        for root, _, files in os.walk(os.path.join(repo, top))
+        for f in files if f.endswith(".py")]
+    assert len(program) > 100
+    hits = []
+    for path in program:
+        with open(path) as fh:
+            if setter.search(fh.read()):
+                hits.append(os.path.relpath(path, repo))
+    assert hits == ["horovod_tpu/common/basics.py"]
 
 
 def test_allgather_object_single_process(hvd):

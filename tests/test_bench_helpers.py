@@ -44,55 +44,70 @@ def test_transformer_model_flops_bert_large_magnitude():
     assert 0.9e12 < got < 1.4e12, got
 
 
-def test_cached_tpu_record_fallthrough(tmp_path, monkeypatch):
-    """The cached-chip-record lookup (ADVICE r4 / code-review r5): a
-    corrupt or stale record in a NEWER round dir must fall through to a
-    valid older one, never shadow it; config-altering flags disable the
-    lookup entirely."""
+def _bench(*argv):
+    """`python bench.py ...` as a user runs it, on a host whose JAX has
+    one CPU device and nothing else."""
+    import os
+    import subprocess
+
+    repo = os.path.dirname(os.path.abspath(bench.__file__))
+    env = {k: v for k, v in os.environ.items()
+           if k != "HVD_TPU_FORCE_CPU_DEVICES"}
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+    return subprocess.run(
+        [sys.executable, os.path.join(repo, "bench.py"), *argv],
+        capture_output=True, text=True, timeout=300, env=env)
+
+
+def test_default_run_without_a_tpu_fails_and_prints_no_metric():
+    """No CPU rung, no cached re-emit: where JAX finds no TPU the
+    default run is one process that exits non-zero with empty stdout."""
+    proc = _bench()
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "no TPU" in proc.stderr
+
+
+def test_cpu_record_only_by_name_and_without_mfu():
     import json
-    import time as _time
 
-    import bench as b
-    from tools.round_dirs import SEARCH_ORDER
+    proc = _bench("--_platform=cpu", "--smoke", "--model", "gpt_tiny",
+                  "--num-warmup", "1", "--num-iters", "1",
+                  "--batches-per-iter", "1")
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert rec["platform"] == "cpu" and rec["value"] > 0
+    assert not [k for k in rec if k.startswith("mfu")
+                or k == "peak_flops_basis"], rec.keys()
+    assert "mfu" not in rec["config_note"]
 
-    newest, older = SEARCH_ORDER[0], SEARCH_ORDER[1]
-    # Point bench at a fake repo root with fake round dirs (scoped to
-    # the module under test — never the process-global os.path), and
-    # pre-seed sys.path so bench's own one-time insert of the fake root
-    # is skipped (monkeypatch would not revert it).
-    monkeypatch.setattr(b, "__file__", str(tmp_path / "bench.py"))
-    monkeypatch.syspath_prepend(str(tmp_path))
-    good = {"platform": "tpu", "value": 123.0,
-            "captured_unix": int(_time.time()) - 3600}
-    for rdir, content in ((newest, "{corrupt"),
-                          (older, json.dumps(good))):
-        d = tmp_path / "results" / rdir
-        d.mkdir(parents=True)
-        (d / "resnet50.json").write_text(content)
 
-    rec = b._cached_tpu_record([], "resnet50")
-    assert rec is not None and rec["value"] == 123.0
-    assert rec["cached"] is True and rec["cached_age_h"] == 1.0
+def test_peak_flops_unknown_device_kind_is_an_error(monkeypatch):
+    import types
 
-    # Config-altering flags (anything but --model) disable the lookup.
-    assert b._cached_tpu_record(["--batch-size", "512"],
-                                "resnet50") is None
-    assert b._cached_tpu_record(["--model", "resnet50"],
-                                "resnet50") is not None
+    import jax
 
-    # A non-TPU record never serves as chip evidence.
-    (tmp_path / "results" / newest / "resnet50.json").write_text(
-        json.dumps({**good, "platform": "cpu"}))
-    rec = b._cached_tpu_record([], "resnet50")
-    assert rec["value"] == 123.0  # fell through to the r04 tpu record
+    def kind(k):
+        monkeypatch.setattr(
+            jax, "devices", lambda: [types.SimpleNamespace(device_kind=k)])
 
-    # Past the 48h cap every record is refused.
-    stale = {**good, "captured_unix": int(_time.time()) - 49 * 3600}
-    (tmp_path / "results" / older / "resnet50.json").write_text(
-        json.dumps(stale))
-    (tmp_path / "results" / newest / "resnet50.json").write_text(
-        "{corrupt")
-    assert b._cached_tpu_record([], "resnet50") is None
+    kind("TPU v5 lite")
+    assert bench._peak_flops() == 197e12
+    kind("TPU v5p")
+    assert bench._peak_flops() == 459e12
+    for unknown in ("cpu", "TPU v9", "NVIDIA H100"):
+        kind(unknown)
+        with pytest.raises(ValueError, match="no published peak"):
+            bench._peak_flops()
+
+
+def test_serve_arm_tp_with_one_device_is_an_error():
+    proc = _bench("--_platform=cpu", "--smoke", "--serve", "--serve-arm",
+                  "tp")
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "--serve-arm tp" in proc.stderr and "JAX has 1" in proc.stderr
 
 
 def test_round_dirs_single_source():

@@ -113,6 +113,37 @@ def test_fallback_off_tpu_and_odd_seq(rng):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_declining_on_a_tpu_is_said_once(rng, monkeypatch, caplog):
+    """Where the kernel is the path (a TPU), an un-tileable S runs the
+    reference — correct, but O(S^2) on the device: one WARNING with the
+    shape, not silence, and not one per trace."""
+    import logging
+
+    from horovod_tpu.ops import flash_attention as fa
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_on_tpu", lambda: True)
+    fa._warn_untileable.cache_clear()
+    q, k, v = _qkv(rng, s=130)
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        for _ in range(2):
+            out = flash_attention(q, k, v)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(reference_attention(q, k, v)),
+        rtol=1e-5, atol=1e-5)
+    said = [r.getMessage() for r in caplog.records
+            if "flash_attention" in r.getMessage()]
+    assert len(said) == 1 and "130" in said[0] and "(2, 130, 2, 128)" \
+        in said[0]
+    # Off the TPU the reference IS the path: nothing to say.
+    monkeypatch.setattr(pk, "_on_tpu", lambda: False)
+    fa._warn_untileable.cache_clear()
+    caplog.clear()
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        flash_attention(q, k, v)
+    assert not caplog.records
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("seed", range(6))
 def test_flash_fuzz_matches_reference(seed):

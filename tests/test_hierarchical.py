@@ -210,6 +210,11 @@ def test_optimizer_quantized_cross(mesh2d, rng):
         l0 = None
         for _ in range(60):
             p, s, l = f(p, s, X[:, None, :], Y[:, None])
+            # One step in flight at a time: dozens of queued 8-device
+            # steps can starve XLA:CPU's in-process rendezvous of
+            # threads, which hangs and then ABORTS the whole pytest
+            # process (seen three times at this line).
+            jax.block_until_ready(l)
             l0 = l0 if l0 is not None else float(l)
         results[name] = (l0, float(l))
     # Both paths train (big drop), and the int8 hop lands on the same

@@ -22,6 +22,23 @@ def test_native_builds_and_loads():
                                        "libhvdtpu_native.so"))
 
 
+def test_stale_binary_is_rebuilt_not_trusted():
+    """A .so older than a source it is built from (a copied tree, a
+    checkout over an old build) is rebuilt before it is loaded."""
+    import subprocess
+    import sys
+
+    ndir = os.path.dirname(native.__file__)
+    lib = os.path.join(ndir, "libhvdtpu_native.so")
+    probe = [sys.executable, "-c",
+             "from horovod_tpu import native; print(native.status())"]
+
+    old = os.path.getmtime(os.path.join(ndir, "wire.cc")) - 10
+    os.utime(lib, (old, old))
+    assert subprocess.run(probe, capture_output=True, text=True,
+                          timeout=120).stdout.strip() == "rebuilt"
+
+
 # -- timeline --------------------------------------------------------------
 
 def test_native_timeline_roundtrip(tmp_path):

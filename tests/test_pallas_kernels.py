@@ -94,6 +94,29 @@ def test_quantize_pallas_matches_fallback(rng):
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q0))
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_quantize_ragged_last_group_matches_fallback(rng, dtype):
+    """More blocks than one grid step takes, and not a multiple of it:
+    the last step's out-of-range blocks must not leak into the result."""
+    import jax
+
+    n = (pk._Q_GROUP + 5) * pk._Q_ROWS * pk._LANES - 7
+    x = jnp.asarray(rng.standard_normal(n) * 5, dtype)
+    key = jax.random.PRNGKey(3)
+    for quantize in (pk.quantize_int8,
+                     lambda v, use_pallas: pk.quantize_int8_stochastic(
+                         v, key, use_pallas=use_pallas)):
+        q1, s1, _ = quantize(x, use_pallas=True)
+        q0, s0, _ = quantize(x, use_pallas=False)
+        assert s1.shape == (pk._Q_GROUP + 5,)
+        np.testing.assert_array_equal(np.asarray(q1), np.asarray(q0))
+        np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    d1 = pk.dequantize_int8(q1, s1, n, x.shape, dtype, use_pallas=True)
+    d0 = pk.dequantize_int8(q1, s1, n, x.shape, dtype, use_pallas=False)
+    np.testing.assert_array_equal(np.asarray(d1, np.float32),
+                                  np.asarray(d0, np.float32))
+
+
 # -- stochastic-rounding quantize kernel (the int8_ef reduce path) ---------
 
 def test_stochastic_quantize_pallas_matches_fallback(rng):
