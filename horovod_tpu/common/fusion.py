@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import metrics as metrics_lib
+from . import scopes
 
 # Leaf-visit orders understood by plan_fusion (besides an explicit
 # permutation): flatten order (the historical default) and reverse
@@ -308,9 +309,11 @@ def fuse(tree, plan: FusionPlan) -> List[jnp.ndarray]:
     (the MemcpyInFusionBuffer analog, collective_operations.h:97-110)."""
     leaves = jax.tree.leaves(tree)
     flats = []
-    for b in plan.buckets:
-        parts = [jnp.ravel(leaves[i]) for i in b.leaf_indices]
-        flats.append(parts[0] if len(parts) == 1 else jnp.concatenate(parts))
+    with jax.named_scope(scopes.PACK):
+        for b in plan.buckets:
+            parts = [jnp.ravel(leaves[i]) for i in b.leaf_indices]
+            flats.append(parts[0] if len(parts) == 1
+                         else jnp.concatenate(parts))
     return flats
 
 
@@ -318,12 +321,14 @@ def unfuse(flats: Sequence[jnp.ndarray], plan: FusionPlan):
     """Split flat buffers back into the original pytree
     (the MemcpyOutFusionBuffer analog)."""
     leaves: List[Any] = [None] * plan.num_leaves
-    for flat, b in zip(flats, plan.buckets):
-        off = 0
-        for i, shape in zip(b.leaf_indices, b.shapes):
-            n = int(np.prod(shape)) if shape else 1
-            leaves[i] = jax.lax.slice_in_dim(flat, off, off + n).reshape(shape)
-            off += n
+    with jax.named_scope(scopes.UNPACK):
+        for flat, b in zip(flats, plan.buckets):
+            off = 0
+            for i, shape in zip(b.leaf_indices, b.shapes):
+                n = int(np.prod(shape)) if shape else 1
+                leaves[i] = jax.lax.slice_in_dim(
+                    flat, off, off + n).reshape(shape)
+                off += n
     return jax.tree.unflatten(plan.treedef, leaves)
 
 

@@ -1,0 +1,35 @@
+"""Names the training step gives its device work, in one place.
+
+``jax.named_scope`` and ``pallas_call(name=)`` exist at trace and compile
+time only: they end up in each HLO instruction's ``op_name`` metadata and
+in a Mosaic kernel's name, which is what a ``jax.profiler`` trace of a
+step shows (docs/timeline.md, "Reading a device trace of a step"). The
+benchmark's ``benchmark/phase_names.json`` and the docs quote these
+strings; ``tests/test_scopes.py`` holds the three together.
+"""
+
+# Scopes: a path component of the ``op_name``, forward and (through the
+# name stack JAX keeps for the transpose) backward.
+REDUCE = "hvd_reduce"        # optim.py core_update: the gradient reduction
+PACK = "pack"                # fusion.fuse:   hvd_reduce/pack
+UNPACK = "unpack"            # fusion.unfuse: hvd_reduce/unpack
+REDUCE_PACK = REDUCE + "/" + PACK
+REDUCE_UNPACK = REDUCE + "/" + UNPACK
+UPDATE = "hvd_update"        # optim.py core_update: the inner optax update
+LM_HEAD = "hvd_lm_head"      # models/gpt.py, models/bert.py: vocabulary matmul
+
+# Pallas kernels: the ``name=`` of each ``pallas_call``.
+FLASH_FWD = "hvd_flash_fwd"
+FLASH_DQ = "hvd_flash_dq"
+FLASH_DKV = "hvd_flash_dkv"
+SCALE = "hvd_scale"
+ADASUM_DOT_NORMS = "hvd_adasum_dot_norms"
+ADASUM_COMBINE = "hvd_adasum_combine"
+INT8_QUANTIZE = "hvd_int8_quantize"
+INT8_QUANTIZE_SR = "hvd_int8_quantize_sr"
+INT8_DEQUANTIZE = "hvd_int8_dequantize"
+
+STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
+FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
+                  INT8_QUANTIZE_SR, INT8_DEQUANTIZE)

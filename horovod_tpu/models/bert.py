@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 # Default attention: the Pallas flash kernel on TPU (O(S) memory,
 # MXU-blocked), the numerically identical jnp reference elsewhere.
+from ..common import scopes  # noqa: E402
 from ..ops.flash_attention import attend as default_attend  # noqa: E402
 
 
@@ -103,10 +104,11 @@ class Bert(nn.Module):
         # bf16 operands + fp32 accumulation: the V x H head matmul at
         # fp32 runs ~4x off the MXU's bf16 peak; accumulating in fp32
         # keeps the softmax stable (the standard LM-head recipe).
-        logits = jax.lax.dot_general(
-            x.astype(self.dtype), emb.embedding.astype(self.dtype),
-            (((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope(scopes.LM_HEAD):
+            logits = jax.lax.dot_general(
+                x.astype(self.dtype), emb.embedding.astype(self.dtype),
+                (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         return logits
 
 

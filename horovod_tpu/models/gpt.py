@@ -20,6 +20,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..common import scopes
 from ..ops.flash_attention import flash_attention
 
 
@@ -489,10 +490,11 @@ class GPT(nn.Module):
         # V x H matmul at fp32 runs ~4x off the MXU's bf16 peak, and
         # fp32 accumulation keeps the softmax stable (standard LM-head
         # recipe).
-        logits = jax.lax.dot_general(
-            x.astype(self.dtype), emb.embedding.astype(self.dtype),
-            (((x.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope(scopes.LM_HEAD):
+            logits = jax.lax.dot_general(
+                x.astype(self.dtype), emb.embedding.astype(self.dtype),
+                (((x.ndim - 1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
         if cache is not None:
             new_cache = {"layers": tuple(new_layers),
                          "pos": cache["pos"] + tokens.shape[1],

@@ -37,6 +37,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from .pallas_kernels import _decide
+from ..common import scopes
 from ..common.config import runtime_env
 
 logger = logging.getLogger("horovod_tpu")
@@ -279,6 +280,7 @@ def _flash_fwd_impl(q, k, v, mask, causal, bq, bk, interpret):
         out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
                    jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32)],
         interpret=interpret,
+        name=scopes.FLASH_FWD,
     )(qt, kt, vt, mask8)
     return jnp.swapaxes(o, 1, 2), lse[..., 0]
 
@@ -312,6 +314,7 @@ def _flash_bwd(causal, bq, bk, interpret, res, cotangents):
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
+        name=scopes.FLASH_DQ,
     )(qt, kt, vt, mask8, dot, lse_l, delta_l, dlse_l)
 
     dk, dv = pl.pallas_call(
@@ -324,6 +327,7 @@ def _flash_bwd(causal, bq, bk, interpret, res, cotangents):
         out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
                    jax.ShapeDtypeStruct(vt.shape, v.dtype)],
         interpret=interpret,
+        name=scopes.FLASH_DKV,
     )(qt, kt, vt, mask8, dot, lse_l, delta_l, dlse_l)
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
             jnp.swapaxes(dv, 1, 2), None)

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import scopes
+
 _LANES = 128
 # Rows per grid step: 512x128 f32 = 256 KiB per operand block in VMEM —
 # deep enough to amortise grid overhead, small enough to double-buffer.
@@ -116,6 +118,7 @@ def scale_buffer(x, scale, out_dtype=None, use_pallas: Optional[bool] = None):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(rows2d.shape, out_dtype),
         interpret=interpret,
+        name=scopes.SCALE,
     )(scale_arr, rows2d)
     return out.ravel()[:n].reshape(x.shape)
 
@@ -167,6 +170,7 @@ def adasum_dot_norms(a, b, use_pallas: Optional[bool] = None):
         out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         out_shape=jax.ShapeDtypeStruct((3,), jnp.float32),
         interpret=interpret,
+        name=scopes.ADASUM_DOT_NORMS,
     )(a2, b2)
 
 
@@ -212,6 +216,7 @@ def adasum_combine(a, b, dot_norms, use_pallas: Optional[bool] = None,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct(a2.shape, a.dtype),
         interpret=interpret,
+        name=scopes.ADASUM_COMBINE,
     )(dot_norms.astype(jnp.float32), a2, b2)
     return out.ravel()[:n].reshape(a.shape)
 
@@ -277,9 +282,10 @@ def _q_specs(nblocks):
     return (pl.cdiv(nblocks, group),), data, scale
 
 
-def _quantize_call(kernel, operands, interpret):
+def _quantize_call(kernel, operands, interpret, name):
     """Run a quantize kernel over (rows, 128) operands; returns q as
-    (rows, 128) int8 and one fp32 scale per 32-row block."""
+    (rows, 128) int8 and one fp32 scale per 32-row block. ``name`` is
+    the kernel's name in a device trace (common/scopes.py)."""
     rows = operands[0].shape[0]
     nblocks = rows // _Q_ROWS
     grid, data, scale = _q_specs(nblocks)
@@ -293,6 +299,7 @@ def _quantize_call(kernel, operands, interpret):
             jax.ShapeDtypeStruct((nblocks, 1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name=name,
     )(*(x.reshape(nblocks, _Q_ROWS, _LANES) for x in operands))
     return q.reshape(rows, _LANES), scales.reshape(nblocks)
 
@@ -315,7 +322,8 @@ def quantize_int8(x, use_pallas: Optional[bool] = None):
         scales = _scale_of(jnp.max(jnp.abs(blocks), axis=1))
         q = jnp.clip(jnp.round(blocks / scales[:, None]), -127, 127)
         return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = _quantize_call(_quant_kernel, (x2,), interpret)
+    q, scales = _quantize_call(_quant_kernel, (x2,), interpret,
+                               scopes.INT8_QUANTIZE)
     return q, scales, n
 
 
@@ -356,7 +364,8 @@ def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None):
         q = fl + (ub < (scaled - fl)).astype(jnp.float32)
         q = jnp.clip(q, -127, 127)
         return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = _quantize_call(_quant_sr_kernel, (x2, u), interpret)
+    q, scales = _quantize_call(_quant_sr_kernel, (x2, u), interpret,
+                               scopes.INT8_QUANTIZE_SR)
     return q, scales, n
 
 
@@ -377,5 +386,6 @@ def dequantize_int8(q, scales, n, shape, dtype=jnp.float32,
         out_specs=data,
         out_shape=jax.ShapeDtypeStruct((nblocks, _Q_ROWS, _LANES), dtype),
         interpret=interpret,
+        name=scopes.INT8_DEQUANTIZE,
     )(q.reshape(nblocks, _Q_ROWS, _LANES), scales.reshape(nblocks, 1, 1))
     return out.ravel()[:n].reshape(shape)

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from .common import fusion as fusion_lib
 from .common import integrity as integrity_lib
 from .common import metrics as metrics_lib
+from .common import scopes
 from .common.integrity import (current_loss_scale, observe_guard)  # noqa: F401 — re-exported API
 from .ops import collectives as C
 from .ops.compression import NoneCompressor
@@ -1087,15 +1088,21 @@ def DistributedOptimizer(optimizer,
                         step=jnp.zeros((), jnp.int32))
 
     def core_update(grads, state, params=None, **extra):
+        # The two scopes are what a device trace of the step is read by
+        # (common/scopes.py): trace-time names, nothing on the hot path.
         if not ef:
-            reduced = reduce_grads(grads)
-            return optimizer.update(reduced, state, params, **extra)
-        reduced, new_res = _reduce_tree_ef(
-            grads, state.residual, state.step, op, axis_name,
-            fusion_threshold_bytes, prescale_factor, postscale_factor,
-            overlap, bucket_order, quantize_min_bucket_bytes, route)
-        updates, new_inner = optimizer.update(reduced, state.inner,
-                                              params, **extra)
+            with jax.named_scope(scopes.REDUCE):
+                reduced = reduce_grads(grads)
+            with jax.named_scope(scopes.UPDATE):
+                return optimizer.update(reduced, state, params, **extra)
+        with jax.named_scope(scopes.REDUCE):
+            reduced, new_res = _reduce_tree_ef(
+                grads, state.residual, state.step, op, axis_name,
+                fusion_threshold_bytes, prescale_factor, postscale_factor,
+                overlap, bucket_order, quantize_min_bucket_bytes, route)
+        with jax.named_scope(scopes.UPDATE):
+            updates, new_inner = optimizer.update(reduced, state.inner,
+                                                  params, **extra)
         return updates, _EFState(new_inner, new_res, state.step + 1)
 
     # Non-finite guard (docs/integrity.md): wraps the WHOLE core —
