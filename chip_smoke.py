@@ -33,7 +33,9 @@ import time
 
 SEED = 0
 BUCKET = 25_000_000                                   # one 100 MB fp32 bucket
-ATTN_SHAPES = ((8, 512, 12, 64), (4, 2048, 12, 64))   # gpt_small b, S, h, d
+# ((b, S, h, d), causal): gpt_small twice, bert_large's unmasked attention
+ATTN_SHAPES = (((8, 512, 12, 64), True), ((4, 2048, 12, 64), True),
+               ((8, 512, 16, 64), False))
 MOSAIC_CALL = 'custom_call_target="tpu_custom_call"'
 
 
@@ -150,9 +152,9 @@ def _bucket_kernels(dtype, n):
     return out
 
 
-def _flash_kernels(shape, dtype):
-    """Flash forward and both backward kernels at one (b, S, h, d),
-    causal, against ``reference_attention`` at full matmul precision.
+def _flash_kernels(shape, dtype, causal=True):
+    """Flash forward and both backward kernels at one (b, S, h, d)
+    against ``reference_attention`` at full matmul precision.
 
     Tolerances are tests/test_flash_attention.py's, taken at the scale
     of the array compared (the tests' arrays are O(1); gradients here
@@ -163,7 +165,8 @@ def _flash_kernels(shape, dtype):
     import jax
     import jax.numpy as jnp
 
-    from horovod_tpu.ops.flash_attention import (flash_attention,
+    from horovod_tpu.ops.flash_attention import (_resolve_blocks,
+                                                 flash_attention,
                                                  reference_attention)
 
     fp32_matmuls = dtype == jnp.float32 and jax.default_backend() != "tpu"
@@ -173,10 +176,10 @@ def _flash_kernels(shape, dtype):
 
     def forward(use, q, k, v):
         if use:
-            return flash_attention(q, k, v, causal=True, use_pallas=True)
+            return flash_attention(q, k, v, causal=causal, use_pallas=True)
         with jax.default_matmul_precision("highest"):
             return reference_attention(
-                *(x.astype(jnp.float32) for x in (q, k, v)), causal=True)
+                *(x.astype(jnp.float32) for x in (q, k, v)), causal=causal)
 
     def backward(use, q, k, v):
         return jax.grad(lambda *a: (forward(use, *a).astype(jnp.float32)
@@ -193,6 +196,9 @@ def _flash_kernels(shape, dtype):
         measured[name] = {"max_abs_err": _excess(x, ref, 0.0),
                           "ref_max": scale}
     _say("flash", shape=list(shape), dtype=jnp.dtype(dtype).name,
+         causal=causal,
+         blocks=_resolve_blocks(shape[1], shape[3], dtype, None, None,
+                                jax.default_backend() != "tpu"),
          fwd_tol=fwd_tol, bwd_tol=bwd_tol, measured=measured)
     return out
 
@@ -206,9 +212,10 @@ def phase_kernels(bucket=BUCKET, attn_shapes=ATTN_SHAPES,
     worst = {}
     for dtype in map(jnp.dtype, dtypes):
         worst[f"bucket.{dtype.name}"] = _bucket_kernels(dtype, bucket)
-        for shape in attn_shapes:
-            worst[f"flash.{dtype.name}.{'x'.join(map(str, shape))}"] = \
-                _flash_kernels(shape, dtype)
+        for shape, causal in attn_shapes:
+            name = "x".join(map(str, shape)) + ("" if causal else ".full")
+            worst[f"flash.{dtype.name}.{name}"] = _flash_kernels(
+                shape, dtype, causal)
     _say("kernels", bucket_elems=bucket, excess_over_tolerance=worst)
     bad = {f"{group}.{k}": v for group, checks in worst.items()
            for k, v in checks.items() if not v <= 0}

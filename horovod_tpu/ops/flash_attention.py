@@ -1,4 +1,4 @@
-"""Flash attention — Pallas TPU kernel for the attention hot op.
+"""Flash attention — Pallas TPU kernels for the attention hot op.
 
 The reference has no attention kernels (it is a collectives framework);
 this belongs to the TPU rebuild's perf mandate: attention is where the
@@ -8,21 +8,50 @@ per-block combine in horovod_tpu/parallel/ring_attention.py) keeps the
 (S, S) logits matrix out of HBM entirely — O(S) memory instead of O(S²),
 with every block matmul MXU-shaped.
 
-Layout: the public API takes (B, S, H, D) as produced by the models'
-fused QKV projection; internally the kernels run on (B, H, S, D) so
-every block's minor-two dims are MXU/VPU-tileable (block_q, D) tiles —
-Mosaic requires the last two block dims be (8k, 128k) or match the
-array, which a (…, H, D) layout with a size-1 head block violates for
-H > 1. Rank-deficient operands ride the same rule via lane/sublane
-broadcast: the key mask crosses as (B, 8, S) and the logsumexp as
-(B, H, S, 128), the trick the stock jax.experimental TPU flash kernel
-uses for l/m/segment-ids. The kernel grid is (B, H, S/block_q); K/V
-live whole in VMEM per (batch, head) and the kernel loops their blocks
-with a carried (m, l, acc) online softmax. Backward is the standard
-two-kernel split (dq over q blocks; dk/dv over kv blocks) against the
-saved logsumexp. Off-TPU (or shapes Pallas can't tile) falls back to
-the plain jnp reference — numerically identical, used by the CPU test
-suite which also runs the real kernel bodies in interpret mode.
+Layout (``_Layout``): the public API takes (B, S, H, D) as produced by
+the models' fused QKV projection, and where the widths allow — D a
+multiple of 128, or D dividing 128 with H a multiple of G = 128 / D, which
+is every model here (two heads of 64) — the kernels read exactly that
+memory, seen as (B, S, H·D): a block is (rows, 128 lanes) = G whole heads
+side by side. Nothing is transposed, padded or widened on the way in or
+out, and HBM holds no lane padding. A head's matmuls run on the whole
+128-lane tile with the other heads' lanes zeroed in one operand: the MXU
+does for 128 lanes what it did for 64, the products are exact, and each
+result lands in its own head's lanes, so the heads' results add up to
+the tile. Other shapes (an odd head count, a width like 80) go one head a
+block on the transposed (B, H, S, D), D being that array's own minor
+size. Mosaic wants a block's last two dims (8k, 128k) or equal to the
+array's; both layouts give it that. Operands cross at the caller's own
+dtype, and o, dq, dk, dv come back in it. The MXU is fed that dtype
+(bf16 tiles straight from the refs; probabilities and ds cast to it
+before their second matmul) and accumulates in fp32; the running max /
+sum, ``exp`` and the saved logsumexp are fp32 whatever the input.
+
+Per-row vectors (logsumexp, and ``delta - dlse`` of the backward) cross
+HBM as (B, H, 1, S) rows and the key mask as (B, 1, S): a block of them
+is a (1, block) lane slice, which is why a compiled block is a multiple
+of 128 or the whole sequence. ``mask=None`` builds no mask operand and
+no ``where``; under ``causal`` only the blocks the diagonal crosses pay
+for the iota / compare / select, blocks under it run bare and blocks
+above it are skipped.
+
+Three kernels, named in common/scopes.py. Forward and dq: grid
+(B, H/G, S/block_q), K/V whole in VMEM per (batch, head group), an
+in-kernel loop over their blocks with carried fp32 state. dk/dv: grid
+(B, H/G, S/block_k, S/block_q) with the q blocks innermost and dk, dv in
+fp32 VMEM scratch, so VMEM holds O(block_q + block_k) rows; it computes
+the scores transposed (k·qᵀ), which makes dv = pᵀ·dO and dk = dsᵀ·q
+plain matmuls and lets the row vectors broadcast along sublanes.
+``block_q`` / ``block_k`` default to a choice from S, D, the dtype and
+a VMEM budget (``_choose_blocks``); passing them caps the choice.
+
+``flash_attention`` (what the models call) has a backward with no lse
+cotangent at all; ``flash_attention_with_lse`` (ring attention's
+blockwise-combine interface) returns the logsumexp as a differentiable
+output and folds its cotangent into the same kernels. Off-TPU (or
+sequences no block tiles) falls back to the plain jnp reference —
+numerically identical, used by the CPU test suite which also runs the
+real kernel bodies in interpret mode.
 """
 
 from __future__ import annotations
@@ -35,8 +64,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .pallas_kernels import _decide
+from ..common import metrics as metrics_lib
 from ..common import scopes
 from ..common.config import runtime_env
 
@@ -44,7 +75,22 @@ logger = logging.getLogger("horovod_tpu")
 
 _NEG = -1e30  # mask value; NOT -inf (exp(-inf - -inf) = nan)
 _LANE = 128
-_SUBLANES = 8
+_BLOCK_TARGET = 512        # swept on the v5e (PERF.md §6, PR 25)
+_WHOLE_SEQ_MAX = 1024      # an S no 128-multiple divides runs as one block
+_VMEM_BUDGET = 40 << 20    # what _choose_blocks lets one call plan for
+_VMEM_FLOOR = 32 << 20     # vmem_limit_bytes is never set below this
+_VMEM_CEIL = 96 << 20
+
+_NT = (((1,), (1,)), ((), ()))   # a · bᵀ
+_NN = (((1,), (0,)), ((), ()))   # a · b
+
+_M_PATHS = metrics_lib.counter(
+    "hvd_tpu_flash_attention_traces_total",
+    "flash-attention kernel paths traced, by what engaged: sequence "
+    "length, head width, dtype, the blocks chosen, key mask operand, "
+    "causal, and whether the lse cotangent is a backward operand",
+    labels=("seq_len", "head_dim", "dtype", "block_q", "block_k",
+            "has_mask", "causal", "dlse"))
 
 
 def _pick_block(s: int, target: int = 128) -> Optional[int]:
@@ -53,6 +99,55 @@ def _pick_block(s: int, target: int = 128) -> Optional[int]:
         if s % b == 0 and b % 8 == 0:
             return b
     return None
+
+
+def _lane_block(s: int, target: int) -> Optional[int]:
+    """The block a COMPILED call uses: the largest multiple-of-128
+    divisor of s that is <= target, else s whole where s is short (a
+    block of the lse / mask rows is a (1, block) lane slice, which
+    Mosaic takes at multiples of 128 or at the array's own size)."""
+    for b in range(min(target, s) // _LANE * _LANE, 0, -_LANE):
+        if s % b == 0:
+            return b
+    if s % 8 == 0 and s <= _WHOLE_SEQ_MAX:
+        return s
+    return None
+
+
+def _vmem_estimate(s: int, d: int, itemsize: int, bq: int, bk: int) -> int:
+    """Bytes of VMEM the hungriest of the three calls plans for: K and V
+    whole (forward, dq) and the q-side / output blocks, each double
+    buffered and lane-padded, plus the fp32 (bq, bk) temporaries."""
+    lanes = -(-d // _LANE) * _LANE
+    resident = 2 * 2 * s * lanes * itemsize
+    blocks = 2 * 4 * max(bq, bk) * lanes * itemsize
+    scores = 6 * bq * bk * 4
+    return resident + blocks + scores
+
+
+def _choose_blocks(s: int, d: int, dtype) -> tuple:
+    """(block_q, block_k) targets from the shape: 512-class blocks, cut
+    down while the plan overruns the VMEM budget."""
+    itemsize = jnp.dtype(dtype).itemsize
+    tq = tk = _BLOCK_TARGET
+    while max(tq, tk) > _LANE \
+            and _vmem_estimate(s, d, itemsize, tq, tk) > _VMEM_BUDGET:
+        if tk >= tq:
+            tk //= 2
+        else:
+            tq //= 2
+    return tq, tk
+
+
+def _resolve_blocks(s, d, dtype, block_q, block_k, interpret):
+    """The (bq, bk) a call runs with, or None where no block tiles s.
+    ``block_q`` / ``block_k`` of None are chosen from the shape; given,
+    they cap the block."""
+    tq, tk = _choose_blocks(s, d, dtype)
+    pick = _pick_block if interpret else _lane_block
+    bq = pick(s, tq if block_q is None else block_q)
+    bk = pick(s, tk if block_k is None else block_k)
+    return (bq, bk) if bq and bk else None
 
 
 def reference_attention(q, k, v, mask=None, causal=False):
@@ -73,343 +168,515 @@ def reference_attention(q, k, v, mask=None, causal=False):
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-# -- forward kernel ---------------------------------------------------------
+# -- kernels ----------------------------------------------------------------
+#
+# Every kernel sees 2-D tiles (rows, lanes): ``lanes`` holds one head of
+# width D, or — packed, see _Layout — G = 128 / D heads side by side. A
+# head's matmuls then run on the whole 128-lane tile with the other
+# heads' lanes zeroed in ONE operand: the contraction (or the output)
+# over 128 lanes costs the MXU what 64 did, the products are exact, and a
+# result lands in its own head's lanes, so the heads' partial results
+# add up to the tile. The row vectors come as (G, 1, rows).
 
-def _fwd_kernel(q_ref, k_ref, v_ref, m_ref, o_ref, lse_ref, *,
-                block_q, block_k, seq_len, causal, scale):
-    q = q_ref[0, 0, :, :].astype(jnp.float32) * scale      # (bq, D)
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _scaled(q_ref, scale):
+    """The q tile times the softmax scale, in q's dtype (exact for the
+    power-of-two scale of head widths 64 and 16)."""
+    q = q_ref[...]
+    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+
+
+def _head(x, g, heads):
+    """Tile ``x`` (rows, lanes) with every lane outside head g zeroed."""
+    if heads == 1:
+        return x
+    width = x.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, x.shape[-1]), 1)
+    return jnp.where((lane >= g * width) & (lane < (g + 1) * width), x,
+                     jnp.zeros_like(x))
+
+
+def _below_diagonal(row0, col0, shape, rows_dim):
+    """rows >= cols over a block whose first row / column are row0 /
+    col0; ``rows_dim`` is the block dimension the q rows run along."""
+    rows = row0 + jax.lax.broadcasted_iota(jnp.int32, shape, rows_dim)
+    cols = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_dim)
+    return rows >= cols
+
+
+def _masked(s, kmask, keep):
+    """Scores with the key mask and the causal select applied, each only
+    where there is one."""
+    if kmask is not None:
+        s = jnp.where(kmask > 0, s, _NEG)
+    if keep is not None:
+        s = jnp.where(keep, s, _NEG)
+    return s
+
+
+def _loop_key_blocks(step, init, qi, block_q, block_k, nk, causal):
+    """Run ``step(j, carry, on_diagonal)`` over a q block's k blocks:
+    first those wholly under the diagonal (no causal select), then the
+    ones it crosses; later ones hold nothing visible and are skipped."""
+    if not causal:
+        return jax.lax.fori_loop(0, nk, lambda j, c: step(j, c, False),
+                                 init)
+    n_bare = jax.lax.div(qi * block_q + 1, block_k)
+    n_visible = jnp.minimum(
+        jax.lax.div((qi + 1) * block_q + block_k - 1, block_k), nk)
+    carry = jax.lax.fori_loop(
+        0, n_bare, lambda j, c: step(j, c, False), init)
+    return jax.lax.fori_loop(
+        n_bare, n_visible, lambda j, c: step(j, c, True), carry)
+
+
+def _key_block(k_ref, v_ref, m_ref, qi, j, block_q, block_k, on_diagonal):
+    """What a step of the forward / dq loop reads for k block j: the K
+    and V tiles (bk, lanes), the key mask (1, bk) or None, and the causal
+    select (bq, bk) or None."""
+    ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+    kmask = None if m_ref is None else m_ref[:, ks]
+    keep = _below_diagonal(qi * block_q, j * block_k, (block_q, block_k),
+                           0) if on_diagonal else None
+    return k_ref[ks, :], v_ref[ks, :], kmask, keep
+
+
+def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
+    q_ref, k_ref, v_ref = refs[:3]
+    m_ref = refs[3] if has_mask else None
+    o_ref, lse_ref = refs[-2:]
+    block_q, lanes = q_ref.shape
+    heads = lse_ref.shape[0]
+    nk = k_ref.shape[0] // block_k
     qi = pl.program_id(2)
-    nk = seq_len // block_k
-    if causal:
-        hi = jax.lax.div(qi * block_q + block_q + block_k - 1, block_k)
-        hi = jnp.minimum(hi, nk)
-    else:
-        hi = nk
+    q = _scaled(q_ref, scale)                               # (bq, lanes)
 
-    def body(j, carry):
-        m, l, acc = carry
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(
-            jnp.float32)                                    # (bk, D)
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(
-            jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kmask = m_ref[0, 0, pl.ds(j * block_k, block_k)] > 0  # (bk,)
-        s = jnp.where(kmask[None, :], s, _NEG)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG)
-        m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + p.sum(axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    def step(j, carry, on_diagonal):
+        k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
+                                       block_k, on_diagonal)
+        out = []
+        for g, (m, l, acc) in enumerate(carry):
+            s = _masked(_dot(q, _head(k, g, heads), _NT), kmask, keep)
+            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)                          # (bq, bk)
+            alpha = jnp.exp(m - m_new)
+            l = l * alpha + p.sum(axis=-1, keepdims=True)
+            acc = acc * alpha + _dot(p.astype(v.dtype),
+                                     _head(v, g, heads), _NN)
+            out.append((m_new, l, acc))
+        return tuple(out)
 
-    m0 = jnp.full((block_q, 1), _NEG, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    a0 = jnp.zeros((block_q, q.shape[-1]), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, hi, body, (m0, l0, a0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0, 0, :, :] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0, 0, :, :] = jnp.broadcast_to(m + jnp.log(l),
-                                           (block_q, _LANE))
+    init = (jnp.full((block_q, 1), _NEG, jnp.float32),
+            jnp.zeros((block_q, 1), jnp.float32),
+            jnp.zeros((block_q, lanes), jnp.float32))
+    carry = _loop_key_blocks(step, (init,) * heads, qi, block_q, block_k,
+                             nk, causal)
+    outs = []
+    for g, (m, l, acc) in enumerate(carry):
+        l = jnp.maximum(l, 1e-30)
+        outs.append(acc / l)            # head g's lanes, 0 in the others
+        lse_ref[g, 0, :] = (m + jnp.log(l))[:, 0]
+    o_ref[...] = sum(outs[1:], outs[0]).astype(o_ref.dtype)
 
 
-# -- backward kernels -------------------------------------------------------
-
-def _dq_kernel(q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
-               dlse_ref, dq_ref, *, block_q, block_k, seq_len, causal,
-               scale):
-    q = q_ref[0, 0, :, :].astype(jnp.float32)
-    do = do_ref[0, 0, :, :].astype(jnp.float32)
-    # lse/delta/dlse blocks are lane-broadcast (bq, 128); every lane
-    # holds the same value — read lane 0 as the (bq, 1) column.
-    lse = lse_ref[0, 0, :, :][:, 0:1]                       # (bq, 1)
-    delta = delta_ref[0, 0, :, :][:, 0:1]
-    # Cotangent of the lse OUTPUT (nonzero when callers combine blocks —
-    # ring attention): lse = logsumexp(s) and dlse/ds = p, so the term
+def _dq_kernel(*refs, block_k, causal, scale, has_mask):
+    q_ref, k_ref, v_ref = refs[:3]
+    m_ref = refs[3] if has_mask else None
+    do_ref, lse_ref, dd_ref, dq_ref = refs[-4:]
+    block_q, lanes = q_ref.shape
+    heads = lse_ref.shape[0]
+    nk = k_ref.shape[0] // block_k
+    qi = pl.program_id(2)
+    q = _scaled(q_ref, scale)
+    do = do_ref[...]
+    # Rows as (bq, 1) columns. dd = delta - dlse, delta_i = rowsum(dO_i *
+    # o_i): lse = logsumexp(s) and dlse/ds = p, so an lse cotangent
     # folds into ds as p * dlse.
-    dlse = dlse_ref[0, 0, :, :][:, 0:1]
-    qi = pl.program_id(2)
-    nk = seq_len // block_k
+    lse = [lse_ref[g, 0, :][:, None] for g in range(heads)]
+    dd = [dd_ref[g, 0, :][:, None] for g in range(heads)]
+
+    def step(j, dq, on_diagonal):
+        k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
+                                       block_k, on_diagonal)
+        for g in range(heads):
+            kg = _head(k, g, heads)
+            s = _masked(_dot(q, kg, _NT), kmask, keep)
+            p = jnp.exp(s - lse[g])                         # (bq, bk)
+            ds = p * (_dot(do, _head(v, g, heads), _NT) - dd[g])
+            dq = dq + _dot(ds.astype(k.dtype), kg, _NN)
+        return dq
+
+    dq = _loop_key_blocks(step, jnp.zeros((block_q, lanes), jnp.float32),
+                          qi, block_q, block_k, nk, causal)
+    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _dkv_kernel(*refs, causal, scale, has_mask):
+    q_ref, k_ref, v_ref = refs[:3]
+    m_ref = refs[3] if has_mask else None
+    do_ref, lse_ref, dd_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs[-7:]
+    block_q = q_ref.shape[0]
+    block_k = k_ref.shape[0]
+    heads = lse_ref.shape[0]
+    ki, qi = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def block(on_diagonal):
+        q = _scaled(q_ref, scale)                           # (bq, lanes)
+        do = do_ref[...]
+        k = k_ref[...]                                      # (bk, lanes)
+        v = v_ref[...]
+        kmask = m_ref[0, :][:, None] if has_mask else None  # (bk, 1)
+        keep = _below_diagonal(qi * block_q, ki * block_k,
+                               (block_k, block_q), 1) if on_diagonal \
+            else None
+        for g in range(heads):
+            qg, dog = _head(q, g, heads), _head(do, g, heads)
+            st = _masked(_dot(k, qg, _NT), kmask, keep)     # (bk, bq) = sᵀ
+            pt = jnp.exp(st - lse_ref[g])                   # rows (1, bq)
+            dv_acc[...] += _dot(pt.astype(do.dtype), dog, _NN)
+            dst = pt * (_dot(v, dog, _NT) - dd_ref[g])
+            # q carries the scale already: dk = scale * dsᵀ·q
+            dk_acc[...] += _dot(dst.astype(q.dtype), qg, _NN)
+
     if causal:
-        hi = jnp.minimum(
-            jax.lax.div(qi * block_q + block_q + block_k - 1, block_k),
-            nk)
+        bare = qi * block_q >= (ki + 1) * block_k - 1
+        visible = (qi + 1) * block_q > ki * block_k
+        pl.when(bare)(lambda: block(False))
+        pl.when(jnp.logical_and(visible, jnp.logical_not(bare)))(
+            lambda: block(True))
     else:
-        hi = nk
+        block(False)
 
-    def body(j, dq):
-        k = k_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(
-            jnp.float32)
-        v = v_ref[0, 0, pl.ds(j * block_k, block_k), :].astype(
-            jnp.float32)
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        kmask = m_ref[0, 0, pl.ds(j * block_k, block_k)] > 0
-        s = jnp.where(kmask[None, :], s, _NEG)
-        if causal:
-            rows = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG)
-        p = jnp.exp(s - lse)                                # (bq, bk)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta + dlse)
-        return dq + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-
-    dq = jax.lax.fori_loop(
-        0, hi, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-    dq_ref[0, 0, :, :] = dq.astype(dq_ref.dtype)
-
-
-def _dkv_kernel(q_ref, k_ref, v_ref, m_ref, do_ref, lse_ref, delta_ref,
-                dlse_ref, dk_ref, dv_ref, *, block_q, block_k, seq_len,
-                causal, scale):
-    ki = pl.program_id(2)
-    k = k_ref[0, 0, :, :].astype(jnp.float32)               # (bk, D)
-    v = v_ref[0, 0, :, :].astype(jnp.float32)
-    # m_ref is the FULL (8, S) sublane-broadcast key mask; this grid
-    # step's K block is bk wide, so slice the matching window.
-    kmask = m_ref[0, 0, pl.ds(ki * block_k, block_k)] > 0   # (bk,)
-    nq = seq_len // block_q
-    lo = jax.lax.div(ki * block_k, block_q) if causal else 0
-
-    def body(i, carry):
-        dk, dv = carry
-        q = q_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(
-            jnp.float32)
-        do = do_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(
-            jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q), :][:, 0:1]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q), :][:, 0:1]
-        dlse = dlse_ref[0, 0, pl.ds(i * block_q, block_q), :][:, 0:1]
-        s = jax.lax.dot_general(q * scale, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(kmask[None, :], s, _NEG)
-        if causal:
-            rows = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG)
-        p = jnp.exp(s - lse)                                # (bq, bk)
-        dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, D)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta + dlse)                        # (bq, bk)
-        dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        return dk, dv
-
-    z = jnp.zeros((block_k, k.shape[-1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo, nq, body, (z, z))
-    dk_ref[0, 0, :, :] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0, :, :] = dv.astype(dv_ref.dtype)
+    @pl.when(qi == pl.num_programs(3) - 1)
+    def _():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # -- pallas_call plumbing ---------------------------------------------------
 
-def _specs(b, s, h, d, bq, bk):
-    """Block specs over the internal (B, H, S, D) layout: every block's
-    minor-two dims are a Mosaic-tileable (rows, lanes) tile. The key
-    mask rides as (B, 8, S) (full-S block, 8 identical sublanes) and
-    lse/delta as (B, H, S, 128) (lane-broadcast)."""
-    q_spec = pl.BlockSpec((1, 1, bq, d), lambda bi, hi, i: (bi, hi, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, s, d), lambda bi, hi, i: (bi, hi, 0, 0))
-    m_spec = pl.BlockSpec((1, _SUBLANES, s), lambda bi, hi, i: (bi, 0, 0))
-    lse_spec = pl.BlockSpec((1, 1, bq, _LANE),
-                            lambda bi, hi, i: (bi, hi, i, 0))
-    lse_full = pl.BlockSpec((1, 1, s, _LANE),
-                            lambda bi, hi, i: (bi, hi, 0, 0))
-    kv_block = pl.BlockSpec((1, 1, bk, d),
-                            lambda bi, hi, j: (bi, hi, j, 0))
-    return q_spec, kv_spec, m_spec, lse_spec, lse_full, kv_block
+class _Layout:
+    """How (B, S, H, D) operands reach the kernels as (rows, lanes) tiles.
+
+    Packed, where the widths allow (D a multiple of 128; or D dividing
+    128 with H a multiple of G = 128 / D): the operands stay where the
+    caller has them, seen as (B, S, H·D), and a block is ``rows`` × 128
+    lanes = G whole heads — nothing is transposed or padded on the way
+    in or out, and HBM holds no lane padding. Otherwise per head: the
+    operands are transposed to (B, H, S, D) (XLA's copies) and a block is
+    ``rows`` × D of one head, D being the array's own minor size."""
+
+    def __init__(self, h, d):
+        if d % _LANE == 0:
+            self.heads, self.packed = 1, True
+        elif _LANE % d == 0 and h % (_LANE // d) == 0:
+            self.heads, self.packed = _LANE // d, True
+        else:
+            self.heads, self.packed = 1, False
+        self.h, self.d = h, d
+        self.lanes = self.heads * d
+        self.groups = h // self.heads      # grid size over the heads
+
+    def to_kernel(self, x):
+        b, s = x.shape[:2]
+        return x.reshape(b, s, self.h * self.d) if self.packed \
+            else jnp.swapaxes(x, 1, 2)
+
+    def from_kernel(self, x):
+        if self.packed:
+            return x.reshape(x.shape[:2] + (self.h, self.d))
+        return jnp.swapaxes(x, 1, 2)
+
+    def rowsum(self, x, y):
+        """sum over D of x * y, in fp32, as (B, H, S)."""
+        prod = x.astype(jnp.float32) * y.astype(jnp.float32)
+        if self.packed:
+            prod = prod.reshape(prod.shape[:2] + (self.h, self.d))
+            return jnp.swapaxes(prod.sum(axis=-1), 1, 2)
+        return prod.sum(axis=-1)
+
+    def tile(self, rows, index):
+        """BlockSpec of a (rows, lanes) tile; ``index(*grid ids)`` gives
+        (batch, head group, row block)."""
+        if self.packed:
+            def at(*ids):
+                b, g, i = index(*ids)
+                return b, i, g
+            return pl.BlockSpec((None, rows, self.lanes), at)
+
+        def at(*ids):
+            b, g, i = index(*ids)
+            return b, g, i, 0
+        return pl.BlockSpec((None, None, rows, self.lanes), at)
+
+    def row(self, rows, index):
+        """BlockSpec of a (G, 1, rows) slice of a (B, H, 1, S) row
+        vector."""
+        def at(*ids):
+            b, g, i = index(*ids)
+            return b, g, 0, i
+        return pl.BlockSpec((None, self.heads, 1, rows), at)
 
 
-def _lanes(x):
-    """(B, H, S) -> lane-broadcast (B, H, S, 128) fp32."""
-    return jnp.broadcast_to(x.astype(jnp.float32)[..., None],
-                            x.shape + (_LANE,))
+def _q_major_specs(layout, s, bq):
+    """Block specs of the forward and dq calls, grid (B, H/G, S/bq): a
+    q-side tile, K / V whole, the (B, 1, S) key mask whole, and a row
+    slice."""
+    q_spec = layout.tile(bq, lambda b, g, i: (b, g, i))
+    kv_spec = layout.tile(s, lambda b, g, i: (b, g, 0))
+    m_spec = pl.BlockSpec((None, 1, s), lambda b, g, i: (b, 0, 0))
+    row_spec = layout.row(bq, lambda b, g, i: (b, g, i))
+    return q_spec, kv_spec, m_spec, row_spec
 
 
-def _sublanes(mask):
-    """(B, S) key mask -> sublane-broadcast (B, 8, S) fp32 (the layout
-    _specs' m_spec blocks over; fwd and bwd must agree)."""
-    b, s = mask.shape
-    return jnp.broadcast_to(mask.astype(jnp.float32)[:, None, :],
-                            (b, _SUBLANES, s))
+def _k_major_specs(layout, bq, bk, causal):
+    """Block specs of the dk/dv call, grid (B, H/G, S/bk, S/bq), q blocks
+    innermost. Under ``causal`` the q blocks above the diagonal are
+    skipped; their index is clamped to the first visible one so that a
+    skipped step fetches nothing new."""
+    def qi(j, i):
+        return jnp.maximum(i, jax.lax.div(j * bk, bq)) if causal else i
 
+    q_spec = layout.tile(bq, lambda b, g, j, i: (b, g, qi(j, i)))
+    kv_spec = layout.tile(bk, lambda b, g, j, i: (b, g, j))
+    m_spec = pl.BlockSpec((None, 1, bk), lambda b, g, j, i: (b, 0, j))
+    row_spec = layout.row(bq, lambda b, g, j, i: (b, g, qi(j, i)))
+    return q_spec, kv_spec, m_spec, row_spec
+
+
+def _compiler_params(s, d, itemsize, bq, bk, inner_arbitrary=False):
+    """Batch, head and the outer block dimension are independent; the
+    dk/dv call's innermost (q block) dimension accumulates. The scoped
+    VMEM limit follows the plan instead of shrinking the blocks."""
+    plan = _vmem_estimate(s, d, itemsize, bq, bk)
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",) * 3
+        + ("arbitrary",) * inner_arbitrary,
+        vmem_limit_bytes=min(max(2 * plan, _VMEM_FLOOR), _VMEM_CEIL))
+
+
+def _forward(q, k, v, mask, causal, bq, bk, interpret):
+    """(o, lse, residuals): o (B, S, H, D) in q's dtype, lse (B, H, S)
+    fp32. The residuals are kept in the kernels' layout so the backward
+    moves nothing twice."""
+    b, s, h, d = q.shape
+    layout = _Layout(h, d)
+    has_mask = mask is not None
+    q_spec, kv_spec, m_spec, row_spec = _q_major_specs(layout, s, bq)
+    qt, kt, vt = (layout.to_kernel(x) for x in (q, k, v))
+    mask3 = mask.astype(jnp.float32)[:, None, :] if has_mask else None
+    ot, lse = pl.pallas_call(
+        functools.partial(_fwd_kernel, block_k=bk, causal=causal,
+                          scale=1.0 / np.sqrt(d), has_mask=has_mask),
+        grid=(b, layout.groups, s // bq),
+        in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask,
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        compiler_params=_compiler_params(s, d, q.dtype.itemsize, bq, bk),
+        interpret=interpret,
+        name=scopes.FLASH_FWD,
+    )(qt, kt, vt, *([mask3] * has_mask))
+    return (layout.from_kernel(ot), lse[:, :, 0, :],
+            (qt, kt, vt, mask3, ot, lse))
+
+
+def _backward(causal, bq, bk, interpret, res, do, dlse):
+    """(dq, dk, dv, None) on (B, S, H, D). ``dlse`` None: the caller has
+    no lse output (flash_attention), so no cotangent of it exists."""
+    qt, kt, vt, mask3, ot, lse = res
+    b, h, _, s = lse.shape
+    # Packed operands are (B, S, H·D), per-head ones (B, H, S, D).
+    d = qt.shape[-1] // h if qt.ndim == 3 else qt.shape[-1]
+    layout = _Layout(h, d)
+    has_mask = mask3 is not None
+    dot = layout.to_kernel(do)
+    # delta_i = rowsum(dO_i * o_i) — one fused elementwise pass in-graph;
+    # with the lse cotangent folded in it is the one row operand both
+    # kernels read beside lse.
+    dd = layout.rowsum(dot, ot)
+    if dlse is not None:
+        dd = dd - dlse.astype(jnp.float32)
+    dd = dd[:, :, None, :]
+    masks = [mask3] * has_mask
+    kw = dict(causal=causal, scale=1.0 / np.sqrt(d), has_mask=has_mask)
+    itemsize = qt.dtype.itemsize
+
+    q_spec, kv_spec, m_spec, row_spec = _q_major_specs(layout, s, bq)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, block_k=bk, **kw),
+        grid=(b, layout.groups, s // bq),
+        in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
+        + [q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        compiler_params=_compiler_params(s, d, itemsize, bq, bk),
+        interpret=interpret,
+        name=scopes.FLASH_DQ,
+    )(qt, kt, vt, *masks, dot, lse, dd)
+
+    q_spec, kv_spec, m_spec, row_spec = _k_major_specs(layout, bq, bk,
+                                                       causal)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, **kw),
+        grid=(b, layout.groups, s // bk, s // bq),
+        in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
+        + [q_spec, row_spec, row_spec],
+        out_specs=[kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
+                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, layout.lanes), jnp.float32)] * 2,
+        compiler_params=_compiler_params(s, d, itemsize, bq, bk,
+                                         inner_arbitrary=True),
+        interpret=interpret,
+        name=scopes.FLASH_DKV,
+    )(qt, kt, vt, *masks, dot, lse, dd)
+    return (layout.from_kernel(dq), layout.from_kernel(dk),
+            layout.from_kernel(dv), None)
+
+
+# Two interfaces over the same kernels: the need differs by caller (is
+# the logsumexp an output?), not by a knob.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash(q, k, v, mask, causal, bq, bk, interpret):
-    """Returns (o, lse). lse (B, H, S) is a first-class differentiable
-    output so blockwise callers (ring attention) can combine partial
-    results; its cotangent folds into the backward kernels' ds."""
-    return _flash_fwd_impl(q, k, v, mask, causal, bq, bk, interpret)
-
-
-def _flash_fwd_impl(q, k, v, mask, causal, bq, bk, interpret):
-    b, s, h, d = q.shape
-    scale = 1.0 / np.sqrt(d)
-    q_spec, kv_spec, m_spec, lse_spec, _, _ = _specs(b, s, h, d, bq, bk)
-    kern = functools.partial(_fwd_kernel, block_q=bq, block_k=bk,
-                             seq_len=s, causal=causal, scale=scale)
-    # (B, S, H, D) API layout -> (B, H, S, D) kernel layout; XLA fuses
-    # these transposes into the surrounding projections.
-    qt, kt, vt = (jnp.swapaxes(x, 1, 2) for x in (q, k, v))
-    mask8 = _sublanes(mask)
-    o, lse = pl.pallas_call(
-        kern,
-        grid=(b, h, s // bq),
-        in_specs=[q_spec, kv_spec, kv_spec, m_spec],
-        out_specs=[q_spec, lse_spec],
-        out_shape=[jax.ShapeDtypeStruct(qt.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b, h, s, _LANE), jnp.float32)],
-        interpret=interpret,
-        name=scopes.FLASH_FWD,
-    )(qt, kt, vt, mask8)
-    return jnp.swapaxes(o, 1, 2), lse[..., 0]
+    """o alone: the backward has no lse cotangent to carry."""
+    return _forward(q, k, v, mask, causal, bq, bk, interpret)[0]
 
 
 def _flash_fwd(q, k, v, mask, causal, bq, bk, interpret):
-    o, lse = _flash_fwd_impl(q, k, v, mask, causal, bq, bk, interpret)
-    return (o, lse), (q, k, v, mask, o, lse)
+    o, _, res = _forward(q, k, v, mask, causal, bq, bk, interpret)
+    return o, res
 
 
-def _flash_bwd(causal, bq, bk, interpret, res, cotangents):
-    do, dlse = cotangents
-    q, k, v, mask, o, lse = res
-    b, s, h, d = q.shape
-    scale = 1.0 / np.sqrt(d)
-    # delta_i = rowsum(do_i * o_i) — cheap elementwise, computed in-graph.
-    delta = jnp.einsum("bshd,bshd->bhs", do.astype(jnp.float32),
-                       o.astype(jnp.float32))
-    q_spec, kv_spec, m_spec, lse_blk, lse_full, kv_block = _specs(
-        b, s, h, d, bq, bk)
-
-    qt, kt, vt, dot = (jnp.swapaxes(x, 1, 2) for x in (q, k, v, do))
-    mask8 = _sublanes(mask)
-    lse_l, delta_l, dlse_l = _lanes(lse), _lanes(delta), _lanes(dlse)
-
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_q=bq, block_k=bk, seq_len=s,
-                          causal=causal, scale=scale),
-        grid=(b, h, s // bq),
-        in_specs=[q_spec, kv_spec, kv_spec, m_spec, q_spec,
-                  lse_blk, lse_blk, lse_blk],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        interpret=interpret,
-        name=scopes.FLASH_DQ,
-    )(qt, kt, vt, mask8, dot, lse_l, delta_l, dlse_l)
-
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=bq, block_k=bk, seq_len=s,
-                          causal=causal, scale=scale),
-        grid=(b, h, s // bk),
-        in_specs=[kv_spec, kv_block, kv_block, m_spec, kv_spec,
-                  lse_full, lse_full, lse_full],
-        out_specs=[kv_block, kv_block],
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, v.dtype)],
-        interpret=interpret,
-        name=scopes.FLASH_DKV,
-    )(qt, kt, vt, mask8, dot, lse_l, delta_l, dlse_l)
-    return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),
-            jnp.swapaxes(dv, 1, 2), None)
+def _flash_bwd(causal, bq, bk, interpret, res, do):
+    return _backward(causal, bq, bk, interpret, res, do, None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_lse(q, k, v, mask, causal, bq, bk, interpret):
+    """(o, lse). lse (B, H, S) is a first-class differentiable output so
+    blockwise callers (ring attention) can combine partial results; its
+    cotangent folds into the backward kernels' ds."""
+    return _forward(q, k, v, mask, causal, bq, bk, interpret)[:2]
+
+
+def _flash_lse_fwd(q, k, v, mask, causal, bq, bk, interpret):
+    o, lse, res = _forward(q, k, v, mask, causal, bq, bk, interpret)
+    return (o, lse), res
+
+
+def _flash_lse_bwd(causal, bq, bk, interpret, res, cotangents):
+    return _backward(causal, bq, bk, interpret, res, *cotangents)
+
+
+_flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+# -- public surface ---------------------------------------------------------
+
 def flash_available(seq_len: int, use_pallas: Optional[bool] = None,
-                    block_q: int = 128, block_k: int = 128) -> bool:
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> bool:
     """THE availability predicate — single source of truth for every
     reason the kernel path can decline (off-TPU without forcing,
     HVD_TPU_FLASH_ATTENTION=0 escape hatch, un-tileable sequence).
     flash_attention_with_lse consults exactly this, so callers (ring
     attention) pre-checking it can rely on a non-None result."""
-    use, _ = _decide(use_pallas)
+    use, interpret = _decide(use_pallas)
     if runtime_env("FLASH_ATTENTION", "1") == "0":
         return False
-    return bool(use) and _pick_block(seq_len, block_q) is not None \
-        and _pick_block(seq_len, block_k) is not None
+    # Whether SOME block tiles seq_len does not depend on D or the dtype
+    # (they only shrink the choice, never below one lane tile).
+    return bool(use) and _resolve_blocks(
+        seq_len, _LANE, jnp.float32, block_q, block_k,
+        interpret) is not None
 
 
 @functools.lru_cache(maxsize=None)  # once per shape, not per trace
 def _warn_untileable(shape, block_q, block_k):
     logger.warning(
-        "flash_attention: sequence length %d of q%s has no multiple-of-8 "
-        "block <= (%d, %d); this call runs the O(S^2) reference "
-        "attention on the TPU instead of the Pallas kernel",
-        shape[1], tuple(shape), block_q, block_k)
+        "flash_attention: sequence length %d of q%s has no block the "
+        "kernels can tile (a multiple of 128, or the whole of a short "
+        "sequence a multiple of 8; block_q=%s, block_k=%s); this call "
+        "runs the O(S^2) reference attention on the TPU instead of the "
+        "Pallas kernel", shape[1], tuple(shape), block_q, block_k)
 
 
-def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
-                             use_pallas: Optional[bool] = None,
-                             block_q: int = 128, block_k: int = 128):
-    """Like :func:`flash_attention` but also returns the per-row
-    logsumexp (B, H, S) — the blockwise-combination interface ring
-    attention stitches partial results with. Both outputs are
-    differentiable (the lse cotangent folds into the backward kernels).
-    Returns None when :func:`flash_available` declines, so callers use
-    their own reference path."""
-    b, s, h, d = q.shape
+@functools.lru_cache(maxsize=None)  # once per distinct call shape
+def _say_path(shape, dtype, bq, bk, has_mask, causal, dlse):
+    logger.info(
+        "flash_attention: q%s %s runs the Pallas kernels with "
+        "block_q=%d block_k=%d has_mask=%s causal=%s dlse_operand=%s",
+        tuple(shape), dtype, bq, bk, has_mask, causal, dlse)
+
+
+def _engage(q, mask, causal, use_pallas, block_q, block_k, dlse):
+    """(bq, bk, interpret) of the kernel path for this call, or None
+    where flash_available declines; says which path engaged."""
+    _, s, _, d = q.shape
     use, interpret = _decide(use_pallas)
     if not flash_available(s, use_pallas, block_q, block_k):
         if use and not interpret \
                 and runtime_env("FLASH_ATTENTION", "1") != "0":
             _warn_untileable(q.shape, block_q, block_k)
         return None
-    bq = _pick_block(s, block_q)
-    bk = _pick_block(s, block_k)
-    if mask is None:
-        mask = jnp.ones((b, s), jnp.float32)
-    mask = mask.astype(jnp.float32)
-    if d % _LANE != 0:
-        # Pad head_dim to the lane width; zero columns contribute zero
-        # to every dot product and are sliced off the output. The
-        # kernel derives its scale from the PADDED d, so fold the
-        # correction into q.
-        pad = _LANE - d % _LANE
-        qp = jnp.pad(q, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        kp = jnp.pad(k, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        vp = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, pad)))
-        corr = np.sqrt((d + pad) / d).astype(np.float32)
-        o, lse = _flash(qp * corr, kp, vp, mask, causal, bq, bk,
-                        interpret)
-        return o[..., :d], lse
-    return _flash(q, k, v, mask, causal, bq, bk, interpret)
+    bq, bk = _resolve_blocks(s, d, q.dtype, block_q, block_k, interpret)
+    has_mask = mask is not None
+    _say_path(q.shape, q.dtype.name, bq, bk, has_mask, causal, dlse)
+    _M_PATHS.labels(seq_len=str(s), head_dim=str(d), dtype=q.dtype.name,
+                    block_q=str(bq), block_k=str(bk),
+                    has_mask=str(has_mask).lower(),
+                    causal=str(bool(causal)).lower(),
+                    dlse=str(dlse).lower()).inc()
+    return bq, bk, interpret
+
+
+def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
+                             use_pallas: Optional[bool] = None,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None):
+    """Like :func:`flash_attention` but also returns the per-row
+    logsumexp (B, H, S) — the blockwise-combination interface ring
+    attention stitches partial results with. Both outputs are
+    differentiable (the lse cotangent folds into the backward kernels).
+    Returns None when :func:`flash_available` declines, so callers use
+    their own reference path."""
+    path = _engage(q, mask, causal, use_pallas, block_q, block_k, True)
+    if path is None:
+        return None
+    return _flash_lse(q, k, v, mask, causal, *path)
 
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     use_pallas: Optional[bool] = None,
-                    block_q: int = 128, block_k: int = 128):
-    """Blockwise online-softmax attention on (B, S, H, D).
+                    block_q: Optional[int] = None,
+                    block_k: Optional[int] = None):
+    """Blockwise online-softmax attention on (B, S, H, D), returned in
+    q's dtype.
 
     ``mask``: optional (B, S) key mask (1 = attend). ``use_pallas=None``
     auto-selects the Pallas kernel on TPU with a jnp fallback elsewhere;
     ``True`` forces the kernel (interpret mode off-TPU — the test path).
-    Differentiable via the standard flash backward kernels."""
-    out = flash_attention_with_lse(q, k, v, mask, causal, use_pallas,
-                                   block_q, block_k)
-    if out is None:
+    ``block_q`` / ``block_k``: None lets the code choose from the shape;
+    a number caps the block. Differentiable via the flash backward
+    kernels."""
+    path = _engage(q, mask, causal, use_pallas, block_q, block_k, False)
+    if path is None:
         return reference_attention(q, k, v, mask, causal)
-    return out[0]
+    return _flash(q, k, v, mask, causal, *path)
 
 
 def attend(q, k, v, mask=None):

@@ -26,7 +26,7 @@ def test_kernels_phase_tiny(capsys):
     # One dtype: tests/test_pallas_kernels.py has both, and every case
     # here is a compile.
     worst = chip_smoke.phase_kernels(
-        bucket=18 * 4096 - 5, attn_shapes=((1, 128, 2, 64),),
+        bucket=18 * 4096 - 5, attn_shapes=(((1, 128, 2, 64), True),),
         dtypes=("float32",))
     assert set(worst) == {"bucket.float32", "flash.float32.1x128x2x64"}
     assert worst["bucket.float32"][

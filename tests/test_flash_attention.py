@@ -1,106 +1,247 @@
-"""Flash-attention Pallas kernel vs the jnp reference — forward AND
+"""Flash-attention Pallas kernels vs the jnp reference — forward AND
 backward (custom-VJP kernels), run in interpret mode on CPU so the real
-kernel bodies execute (same tier as tests/test_pallas_kernels.py)."""
+kernel bodies execute (same tier as tests/test_pallas_kernels.py).
+
+The parity cases run over what the kernels adapt to: the blocks (chosen
+from the shape, or given — also with block_q != block_k, so that the
+causal diagonal crosses blocks unevenly), the mask (none, all ones, a
+real key mask), the dtype and the head width (64 as the models have it,
+unpadded; 128)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops.flash_attention import (flash_attention,
+                                             flash_attention_with_lse,
                                              reference_attention)
 
 B, S, H, D = 2, 256, 2, 128
 
-
-def _qkv(rng, d=D, s=S, dtype=np.float32):
-    return (rng.standard_normal((B, s, H, d)).astype(dtype),
-            rng.standard_normal((B, s, H, d)).astype(dtype),
-            rng.standard_normal((B, s, H, d)).astype(dtype))
-
-
-def test_forward_matches_reference(rng):
-    q, k, v = _qkv(rng)
-    out = flash_attention(q, k, v, use_pallas=True)
-    ref = reference_attention(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+# None: the blocks the code chooses (one 256 block at S256); pairs: given,
+# equal and unequal.
+BLOCKS = [None, (64, 64), (64, 32), (32, 128)]
+BLOCK_IDS = ["chosen", "bq64_bk64", "bq64_bk32", "bq32_bk128"]
+MASKS = ["none", "ones", "keys"]
 
 
-def test_forward_causal(rng):
-    q, k, v = _qkv(rng)
-    out = flash_attention(q, k, v, causal=True, use_pallas=True)
-    ref = reference_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+def _qkv(rng, d=D, s=S, dtype=np.float32, h=H):
+    return tuple(jnp.asarray(rng.standard_normal((B, s, h, d)), dtype)
+                 for _ in range(3))
 
 
-def test_forward_key_mask(rng):
-    q, k, v = _qkv(rng)
-    mask = (rng.random((B, S)) > 0.3).astype(np.float32)
+def _mask(rng, kind, s=S, keep=0.7):
+    if kind == "none":
+        return None
+    if kind == "ones":
+        return np.ones((B, s), np.float32)
+    mask = (rng.random((B, s)) < keep).astype(np.float32)
     mask[:, 0] = 1.0  # at least one visible key per batch
-    out = flash_attention(q, k, v, mask=mask, use_pallas=True)
-    ref = reference_attention(q, k, v, mask=mask)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+    return mask
 
 
-def test_forward_padded_head_dim(rng):
-    # D=64 (BERT-large) pads to the 128-lane width inside the wrapper.
-    q, k, v = _qkv(rng, d=64)
-    out = flash_attention(q, k, v, use_pallas=True)
-    ref = reference_attention(q, k, v)
-    assert out.shape == (B, S, H, 64)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+def _kw(blocks):
+    return {} if blocks is None else dict(block_q=blocks[0],
+                                          block_k=blocks[1])
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_backward_matches_reference(rng, causal):
+def _grads(fn, q, k, v):
+    """Gradients of sum(fn(q, k, v) ** 2), taken in fp32."""
+    return jax.grad(lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum(),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masking", MASKS)
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
+def test_forward_matches_reference(rng, blocks, masking, causal):
     q, k, v = _qkv(rng)
-    mask = (rng.random((B, S)) > 0.2).astype(np.float32)
-    mask[:, 0] = 1.0
+    mask = _mask(rng, masking)
+    out = flash_attention(q, k, v, mask=mask, causal=causal,
+                          use_pallas=True, **_kw(blocks))
+    ref = reference_attention(q, k, v, mask=mask, causal=causal)
+    assert out.dtype == q.dtype and out.shape == q.shape
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
 
-    def loss_flash(q, k, v):
-        return (flash_attention(q, k, v, mask=mask, causal=causal,
-                                use_pallas=True) ** 2).sum()
 
-    def loss_ref(q, k, v):
-        return (reference_attention(q, k, v, mask=mask,
-                                    causal=causal) ** 2).sum()
-
-    g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masking", ["none", "keys"])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
+def test_backward_matches_reference(rng, blocks, masking, causal):
+    q, k, v = _qkv(rng)
+    mask = _mask(rng, masking, keep=0.8)
+    g_flash = _grads(lambda *a: flash_attention(
+        *a, mask=mask, causal=causal, use_pallas=True, **_kw(blocks)),
+        q, k, v)
+    g_ref = _grads(lambda *a: reference_attention(
+        *a, mask=mask, causal=causal), q, k, v)
+    for gf, gr, x, name in zip(g_flash, g_ref, (q, k, v), "qkv"):
+        assert gf.dtype == x.dtype, f"d{name} came back {gf.dtype}"
         np.testing.assert_allclose(
             np.asarray(gf), np.asarray(gr), rtol=5e-3, atol=5e-3,
             err_msg=f"d{name} mismatch (causal={causal})")
 
 
-def test_backward_padded_head_dim(rng):
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
+def test_no_mask_is_an_all_ones_mask_bitwise(rng, blocks, causal):
+    """``mask=None`` builds no mask operand and no select; it must give
+    what a mask of ones gives, to the bit, forward and backward."""
     q, k, v = _qkv(rng, d=64)
+    ones = _mask(rng, "ones")
 
-    def loss(fn):
-        return lambda q, k, v: (fn(q, k, v) ** 2).sum()
+    def run(mask):
+        fn = lambda *a: flash_attention(  # noqa: E731
+            *a, mask=mask, causal=causal, use_pallas=True, **_kw(blocks))
+        return (fn(q, k, v),) + _grads(fn, q, k, v)
 
-    g_flash = jax.grad(
-        loss(lambda q, k, v: flash_attention(q, k, v, use_pallas=True)),
-        argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss(reference_attention),
-                     argnums=(0, 1, 2))(q, k, v)
+    for bare, masked in zip(run(None), run(ones)):
+        np.testing.assert_array_equal(np.asarray(bare), np.asarray(masked))
+
+
+# Head width: 64 is what every model of the benchmark has (it used to be
+# padded to the 128 lanes inside the wrapper); 128 is a whole lane tile;
+# 16 is narrower than anything the MXU likes. None is a multiple of 128
+# but the second.
+@pytest.mark.parametrize("d", [64, 128, 16])
+def test_forward_padded_head_dim(rng, d):
+    q, k, v = _qkv(rng, d=d)
+    out = flash_attention(q, k, v, use_pallas=True)
+    ref = reference_attention(q, k, v)
+    assert out.shape == (B, S, H, d) and out.dtype == q.dtype
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("d", [64, 128, 16])
+def test_backward_padded_head_dim(rng, d):
+    q, k, v = _qkv(rng, d=d)
+    g_flash = _grads(lambda *a: flash_attention(*a, causal=True,
+                                                use_pallas=True), q, k, v)
+    g_ref = _grads(lambda *a: reference_attention(*a, causal=True),
+                   q, k, v)
     for gf, gr in zip(g_flash, g_ref):
+        assert gf.shape == (B, S, H, d) and gf.dtype == q.dtype
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=5e-3, atol=5e-3)
 
 
-def test_bf16_inputs(rng):
-    q, k, v = _qkv(rng, dtype=np.float32)
+# (H, D) -> how the operands reach the kernels: whole heads side by side
+# on the 128 lanes where the widths allow (nothing transposed), else one
+# head a block on the transposed (B, H, S, D).
+@pytest.mark.parametrize("h, d, packed, heads", [
+    (2, 64, True, 2), (4, 32, True, 4), (1, 128, True, 1),
+    (3, 64, False, 1), (2, 16, False, 1), (2, 80, False, 1)])
+def test_heads_side_by_side_on_the_lanes(rng, h, d, packed, heads):
+    layout = fa._Layout(h, d)
+    assert (layout.packed, layout.heads) == (packed, heads)
+    assert layout.groups * layout.heads == h
+    q, k, v = _qkv(rng, d=d, h=h, s=128)
+    mask = _mask(rng, "keys", s=128)
+    w = jnp.asarray(rng.standard_normal((B, h, 128)), jnp.float32)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o ** 2).sum() + (w * lse).sum()
+        return f
+
+    flash = lambda *a: flash_attention_with_lse(  # noqa: E731
+        *a, mask=mask, causal=True, use_pallas=True, block_q=64,
+        block_k=32)
+    ref = lambda *a: _reference_with_lse(*a, mask, True)  # noqa: E731
+    for got, want in zip(flash(q, k, v), ref(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=5e-3, atol=5e-3,
+                                   err_msg=f"d{name} at H={h} D={d}")
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_bf16_inputs(rng, d, causal):
+    """bf16 in: bf16 tiles feed the matmuls, fp32 accumulates, and the
+    output and every gradient come back bf16 (q's path used to come back
+    promoted to fp32)."""
+    q, k, v = _qkv(rng, d=d)
     qb, kb, vb = (x.astype(jnp.bfloat16) for x in (q, k, v))
-    out = flash_attention(qb, kb, vb, use_pallas=True)
+    mask = _mask(rng, "keys")
+    out = flash_attention(qb, kb, vb, mask=mask, causal=causal,
+                          use_pallas=True, block_q=64, block_k=128)
     assert out.dtype == jnp.bfloat16
-    ref = reference_attention(q, k, v)
+    # The reference sees the same bf16-rounded inputs, in fp32.
+    qf, kf, vf = (x.astype(jnp.float32) for x in (qb, kb, vb))
+    ref = reference_attention(qf, kf, vf, mask=mask, causal=causal)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref), rtol=2e-2, atol=2e-2)
+    g_flash = _grads(lambda *a: flash_attention(
+        *a, mask=mask, causal=causal, use_pallas=True, block_q=64,
+        block_k=128), qb, kb, vb)
+    g_ref = _grads(lambda *a: reference_attention(
+        *a, mask=mask, causal=causal), qf, kf, vf)
+    for gf, gr in zip(g_flash, g_ref):
+        assert gf.dtype == jnp.bfloat16
+        scale = float(np.abs(np.asarray(gr)).max())
+        np.testing.assert_allclose(np.asarray(gf, np.float32),
+                                   np.asarray(gr), rtol=3e-2,
+                                   atol=3e-2 * scale)
+
+
+def _reference_with_lse(q, k, v, mask, causal):
+    """reference_attention beside the logsumexp of its own logits."""
+    d, s = q.shape[-1], q.shape[1]
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    if mask is not None:
+        logits = jnp.where(mask[:, None, None, :] > 0, logits, fa._NEG)
+    if causal:
+        tri = np.tril(np.ones((s, s), bool))
+        logits = jnp.where(tri[None, None], logits, fa._NEG)
+    return (reference_attention(q, k, v, mask=mask, causal=causal),
+            jax.scipy.special.logsumexp(logits, axis=-1))
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("masking", ["none", "keys"])
+@pytest.mark.parametrize("blocks", [None, (64, 32)],
+                         ids=["chosen", "bq64_bk32"])
+def test_lse_output_and_its_cotangent(rng, blocks, masking, causal):
+    """flash_attention_with_lse: the logsumexp is a differentiable
+    output (ring attention combines blocks with it), so a loss that uses
+    it — a non-zero lse cotangent — must match the reference's
+    gradients, not only one through o."""
+    q, k, v = _qkv(rng, d=64)
+    mask = _mask(rng, masking)
+    w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o ** 2).sum() + (w * lse).sum()
+        return f
+
+    flash = lambda *a: flash_attention_with_lse(  # noqa: E731
+        *a, mask=mask, causal=causal, use_pallas=True, **_kw(blocks))
+    ref = lambda *a: _reference_with_lse(*a, mask, causal)  # noqa: E731
+    o, lse = flash(q, k, v)
+    o_ref, lse_ref = ref(q, k, v)
+    assert lse.shape == (B, H, S) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
+                               rtol=2e-4, atol=2e-4)
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
+                                   rtol=5e-3, atol=5e-3,
+                                   err_msg=f"d{name} with an lse cotangent")
 
 
 def test_fallback_off_tpu_and_odd_seq(rng):
@@ -111,6 +252,82 @@ def test_fallback_off_tpu_and_odd_seq(rng):
     ref = reference_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+    assert flash_attention_with_lse(q, k, v) is None
+
+
+# (S, D, dtype, block_q, block_k, compiled) -> (bq, bk) or None
+@pytest.mark.parametrize("case, want", [
+    ((512, 64, jnp.bfloat16, None, None, True), (512, 512)),
+    ((2048, 64, jnp.bfloat16, None, None, True), (512, 512)),
+    ((4096, 64, jnp.float32, None, None, True), (512, 512)),
+    ((384, 64, jnp.bfloat16, None, None, True), (384, 384)),
+    ((640, 64, jnp.bfloat16, None, None, True), (128, 128)),
+    ((200, 64, jnp.bfloat16, None, None, True), (200, 200)),  # whole, short
+    ((2056, 64, jnp.bfloat16, None, None, True), None),   # 8 x 257: no tile
+    ((130, 128, jnp.float32, None, None, True), None),
+    ((512, 64, jnp.bfloat16, 256, 128, True), (256, 128)),    # caps
+    ((512, 64, jnp.bfloat16, 200, 1024, True), (128, 512)),
+    ((32, 16, jnp.float32, 8, 8, False), (8, 8)),             # the tests'
+    ((256, 128, jnp.float32, None, None, False), (256, 256)),
+    ((24, 8, jnp.float32, 16, 16, False), (8, 8)),
+])
+def test_blocks_chosen_from_the_shape(case, want):
+    """block_q / block_k of None are chosen by the code; numbers cap the
+    choice. A compiled call takes multiples of 128 (or a short sequence
+    whole): a block of the lse row is a lane slice."""
+    s, d, dtype, block_q, block_k, compiled = case
+    assert fa._resolve_blocks(s, d, dtype, block_q, block_k,
+                              interpret=not compiled) == want
+
+
+def test_blocks_shrink_to_the_vmem_budget():
+    """The choice is 512-class until the plan (K and V whole, the
+    blocks, the fp32 score temporaries) overruns the budget; then the
+    score tile gives way, never below one lane tile."""
+    assert fa._choose_blocks(2048, 64, jnp.bfloat16) == (512, 512)
+    assert fa._choose_blocks(4096, 128, jnp.float32) == (512, 512)
+    tq, tk = fa._choose_blocks(32768, 128, jnp.float32)
+    assert 128 <= tq <= 512 and 128 <= tk <= 512
+    assert fa._vmem_estimate(32768, 128, 4, tq, tk) \
+        < fa._vmem_estimate(32768, 128, 4, 512, 512)
+    assert fa._choose_blocks(1 << 20, 128, jnp.float32) == (128, 128)
+
+
+def test_the_path_that_engaged_is_said_once_and_counted(rng, caplog):
+    """One INFO line per distinct call shape at trace time, and the same
+    fields as a labelled counter in hvd.metrics(): a run's record can
+    say which path its step compiled."""
+    import logging
+
+    from horovod_tpu.common import metrics
+
+    def count(**labels):
+        fam = metrics.snapshot()["hvd_tpu_flash_attention_traces_total"]
+        return sum(s["value"] for s in fam["samples"]
+                   if all(s["labels"].get(k) == v
+                          for k, v in labels.items()))
+
+    fa._say_path.cache_clear()
+    q, k, v = _qkv(rng, s=64, d=16)
+    labels = dict(seq_len="64", head_dim="16", dtype="float32",
+                  block_q="32", block_k="16", has_mask="false",
+                  causal="true")
+    before = count(**labels, dlse="false"), count(**labels, dlse="true")
+    with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+        for _ in range(2):
+            flash_attention(q, k, v, causal=True, use_pallas=True,
+                            block_q=32, block_k=16)
+        flash_attention_with_lse(q, k, v, causal=True, use_pallas=True,
+                                 block_q=32, block_k=16)
+    said = [r.getMessage() for r in caplog.records
+            if "flash_attention" in r.getMessage()]
+    assert len(said) == 2, said
+    assert "block_q=32 block_k=16" in said[0] \
+        and "(2, 64, 2, 16)" in said[0] and "float32" in said[0] \
+        and "has_mask=False causal=True dlse_operand=False" in said[0]
+    assert "dlse_operand=True" in said[1]
+    assert count(**labels, dlse="false") == before[0] + 2
+    assert count(**labels, dlse="true") == before[1] + 1
 
 
 def test_declining_on_a_tpu_is_said_once(rng, monkeypatch, caplog):
@@ -119,7 +336,6 @@ def test_declining_on_a_tpu_is_said_once(rng, monkeypatch, caplog):
     shape, not silence, and not one per trace."""
     import logging
 
-    from horovod_tpu.ops import flash_attention as fa
     from horovod_tpu.ops import pallas_kernels as pk
 
     monkeypatch.setattr(pk, "_on_tpu", lambda: True)

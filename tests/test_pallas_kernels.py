@@ -339,45 +339,58 @@ def test_flash_block_specs_obey_mosaic_tiling_rule():
     window: every BlockSpec's minor-two dims must be (multiple of 8,
     multiple of 128) OR equal the array dims. CPU interpret mode never
     checks this, so the rule is asserted statically here for every
-    benchmark shape (BERT/GPT S=512, GPT-2k, microbench S in {1k, 2k,
-    4k}, and the S=512 block sweep) against the exact spec/array pairs
-    each pallas_call binds."""
-    from horovod_tpu.ops.flash_attention import (_LANE, _SUBLANES,
-                                                 _pick_block, _specs)
+    benchmark shape (BERT/GPT S=512, GPT-2k and 4k, microbench S in
+    {1k, 2k, 4k}, the S=512 block sweep, short ring-attention blocks)
+    against the exact spec/array pairs each pallas_call binds, at the
+    blocks a COMPILED call resolves (tests/test_tpu_compile.py asks the
+    chip's compiler itself for the main shapes)."""
+    from horovod_tpu.ops.flash_attention import (_k_major_specs, _Layout,
+                                                 _q_major_specs,
+                                                 _resolve_blocks)
 
     def ok(block, array):
-        if len(block) < 2:
-            return True
-        last = block[-1] == array[-1] or block[-1] % 128 == 0
-        sub = block[-2] == array[-2] or block[-2] % 8 == 0
-        return last and sub
+        # A squeezed (None) dimension is not one of the block's minor two.
+        dims = [(b, a) for b, a in zip(block, array) if b is not None]
+        (sub, sub_a), (last, last_a) = dims[-2:]
+        return (last == last_a or last % 128 == 0) \
+            and (sub == sub_a or sub % 8 == 0)
 
     configs = [
         # (b, s, h, d, block_q, block_k)
-        (8, 512, 16, 64, 128, 128),    # bert_large bench
-        (8, 512, 12, 64, 128, 128),    # gpt_small bench
-        (4, 2048, 12, 64, 128, 128),   # gpt_2k long-context leg
+        (8, 512, 16, 64, None, None),  # bert_large bench
+        (32, 512, 12, 64, None, None),  # gpt_small bench
+        (8, 2048, 12, 64, None, None),  # gpt_2k long-context leg
+        (4, 4096, 12, 64, None, None),
         (4, 1024, 8, 64, 128, 128),    # microbench
         (4, 4096, 8, 64, 128, 128),
         (4, 512, 8, 64, 256, 128),     # S=512 block sweep entries
         (4, 512, 8, 64, 256, 256),
         (4, 512, 8, 64, 512, 512),
+        (2, 200, 4, 64, None, None),   # short S no 128 divides: whole
+        (2, 384, 4, 128, None, None),  # a head fills the lanes
+        (2, 640, 3, 64, None, None),   # odd head count: one head a block
+        (2, 512, 25, 64, None, None),  # gpt2-xl's 25 heads of 64
+        (2, 256, 8, 32, None, None),   # four heads a block
+        (2, 256, 4, 80, None, None),   # a width that does not divide 128
     ]
     for b, s, h, d, cbq, cbk in configs:
-        d_pad = d if d % _LANE == 0 else d + (_LANE - d % _LANE)
-        bq, bk = _pick_block(s, cbq), _pick_block(s, cbk)
-        assert bq and bk, (s, cbq, cbk)
-        q_spec, kv_spec, m_spec, lse_blk, lse_full, kv_block = _specs(
-            b, s, h, d_pad, bq, bk)
-        qshape = (b, h, s, d_pad)
-        mshape = (b, _SUBLANES, s)
-        lshape = (b, h, s, _LANE)
+        blocks = _resolve_blocks(s, d, jnp.bfloat16, cbq, cbk,
+                                 interpret=False)
+        assert blocks, (s, cbq, cbk)
+        bq, bk = blocks
+        assert s % bq == 0 and s % bk == 0
+        layout = _Layout(h, d)
+        assert layout.packed == (h % (128 // d) == 0 if 128 % d == 0
+                                 else d % 128 == 0)
+        assert layout.groups * layout.heads == h
+        qkv = (b, s, h * d) if layout.packed else (b, h, s, d)
+        rows, mask = (b, h, 1, s), (b, 1, s)
         # (spec, array) pairs exactly as the three pallas_calls bind
-        # them: fwd ins/outs, dq ins/outs, dkv ins/outs.
-        pairs = [
-            (q_spec, qshape), (kv_spec, qshape), (m_spec, mshape),
-            (lse_blk, lshape), (lse_full, lshape), (kv_block, qshape),
-        ]
+        # them: forward and dq (q blocks outermost), then dk/dv.
+        pairs = list(zip(_q_major_specs(layout, s, bq),
+                         (qkv, qkv, mask, rows)))
+        pairs += zip(_k_major_specs(layout, bq, bk, True),
+                     (qkv, qkv, mask, rows))
         for spec, array in pairs:
             assert ok(spec.block_shape, array), (
                 f"Mosaic-untileable block {spec.block_shape} over "

@@ -10,6 +10,7 @@ say nothing about results — chip_smoke.py's ``kernels`` phase does that.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,7 +20,15 @@ from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import pallas_kernels as pk
 
 BUCKET = 25_000_000  # one 100 MB fp32 gradient bucket
-FLASH_SHAPES = [(8, 512, 12, 64), (4, 2048, 12, 64)]  # gpt_small b/S/h/d
+# (b, S, h, d), causal: gpt_small at three lengths (S4096 is what the
+# dk/dv kernel could not hold in VMEM before it was tiled on its q side),
+# bert_large's unmasked attention.
+FLASH_SHAPES = {"b8_s512": ((8, 512, 12, 64), True),
+                "b4_s2048": ((4, 2048, 12, 64), True),
+                "b4_s4096": ((4, 4096, 12, 64), True),
+                "bert_b8_s512": ((8, 512, 16, 64), False),
+                # an odd head count: one head a block, on (B, H, S, D)
+                "b2_s512_h3": ((2, 512, 3, 64), True)}
 
 
 @pytest.fixture(scope="module")
@@ -101,19 +110,57 @@ def test_bucket_kernel_compiles_for_v5e(v5e, on_tpu, kernel, dtype):
     assert "tpu_custom_call" in _compile(fn, v5e, *specs)
 
 
-@pytest.mark.parametrize("shape", FLASH_SHAPES,
-                         ids=["b8_s512", "b4_s2048"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "fp32"])
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
-def test_flash_attention_compiles_for_v5e(v5e, on_tpu, direction, shape):
+def test_flash_attention_compiles_for_v5e(v5e, on_tpu, direction, shape,
+                                          dtype):
+    (b, s, h, d), causal = FLASH_SHAPES[shape]
+
     def fwd(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True)
+        return fa.flash_attention(q, k, v, causal=causal)
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
     hlo = _compile(fwd if direction == "fwd" else bwd, v5e,
-                   *[(shape, jnp.bfloat16)] * 3)
+                   *[((b, s, h, d), dtype)] * 3)
     # bwd recomputes nothing: fwd kernel for the residuals, then dq
-    # and dk/dv.
-    assert hlo.count("tpu_custom_call") >= (1 if direction == "fwd" else 3)
+    # and dk/dv — one Mosaic call each, under its own name.
+    kernels = ["hvd_flash_fwd"] + ["hvd_flash_dq", "hvd_flash_dkv"] * (
+        direction == "bwd")
+    assert hlo.count("tpu_custom_call") >= len(kernels)
+    for name in kernels:
+        assert name in hlo
+    # Operands cross at the caller's head width and dtype: nothing is
+    # padded to the 128 lanes on the way in, and no per-row vector (lse,
+    # delta, the lse cotangent) is broadcast to (B, H, S, 128).
+    assert " pad(" not in hlo
+    assert not re.search(rf"\[{b},{h},{s},128\]", hlo)
+    if h % 2 == 0:
+        # Two heads of 64 fill the 128 lanes: the kernels read the
+        # caller's (B, S, H*D) as it lies, nothing is transposed.
+        assert re.search(rf"custom-call\([^)]*\).*"
+                         rf"operand_layout_constraints=\{{\w+\[{b},{s},"
+                         rf"{h * d}\]", hlo)
+        assert not re.search(rf"\[{b},{h},{s},{d}\]", hlo)
+
+
+def test_flash_with_lse_backward_compiles_for_v5e(v5e, on_tpu):
+    """Ring attention's interface: a key mask operand, the lse as an
+    output and its cotangent folded into the one row operand — on a
+    short local block."""
+    b, s, h, d = 2, 256, 4, 64
+
+    def loss(q, k, v, mask):
+        o, lse = fa.flash_attention_with_lse(q, k, v, mask=mask,
+                                             causal=True)
+        return o.astype(jnp.float32).sum() + lse.sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e,
+                   *[((b, s, h, d), jnp.bfloat16)] * 3,
+                   ((b, s), jnp.float32))
+    assert hlo.count("tpu_custom_call") >= 3
+    assert not re.search(rf"\[{b},{h},{s},128\]", hlo)
