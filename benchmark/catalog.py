@@ -75,6 +75,11 @@ class Catalog:
     def traffic(self, name):
         return self._data("traffic", name)
 
+    def names(self, cell):
+        """The files of kernel and scope names the cell's own file lists
+        under ``"names"`` (``hlo_counts.load_names``); most list none."""
+        return [self._data("names", name) for name in cell.get("names", ())]
+
     def module(self, kind, name):
         """``<home>/<kind>/<name>.py`` as a module; names may hold ``-``
         and ``.``, so it is loaded by path."""

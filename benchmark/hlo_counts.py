@@ -2,7 +2,7 @@
 the bytes per chip they move, and Mosaic (Pallas) kernel calls.
 
 Counts, not times: they repeat exactly and a CPU rehearsal can print
-them. The names matched are data (``trace_names.json``).
+them. The names matched are data, read by ``load_names``.
 """
 
 from __future__ import annotations
@@ -11,8 +11,11 @@ import json
 import os
 import re
 
-_NAMES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                      "trace_names.json")
+_HERE = os.path.dirname(os.path.abspath(__file__))
+# What a cell's own file of names may add to: the lists a later PR's kernel
+# or scope name belongs in, and the phases its parts make up.
+_EXTENDED = ("flash_kernels", "not_flash_kernels", "dense_markers",
+             "program_scopes")
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8,
@@ -23,9 +26,33 @@ _SHAPE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
 _INSTR = re.compile(r"=\s*(\(?[^=]*?)\s([a-z][a-z\-]*)\(")
 
 
-def load_names() -> dict:
-    with open(_NAMES) as f:
-        return json.load(f)
+def load_names(more=()) -> dict:
+    """``trace_names.json`` (classes, planes, lines) with
+    ``phase_names.json`` (kernels, markers, phases) laid over it: the names
+    of every cell. ``more``: the files of names a cell's own file lists
+    (``"names"``, found under ``names/``), laid over both in the order
+    given, for that cell alone: a later PR brings its kernels and scopes
+    with its cell and cannot move a millisecond in a cell that is there.
+    Such a file extends the lists of ``_EXTENDED``, its ``dense_markers``
+    going before those already there so that a more specific scope wins,
+    and gives ``phases`` new keys. Anything else in it but a ``comment``
+    is an error, as is a phase that exists."""
+    names = {}
+    for name in ("trace_names.json", "phase_names.json"):
+        with open(os.path.join(_HERE, name)) as f:
+            names.update(json.load(f))
+    for extra in more:
+        extra = {k: v for k, v in extra.items() if k != "comment"}
+        phases = extra.pop("phases", {})
+        unknown = sorted(set(extra) - set(_EXTENDED)) \
+            + sorted(set(phases) & set(names["phases"]))
+        if unknown:
+            raise ValueError(f"a cell's file of names may not set {unknown}")
+        for key, entries in extra.items():
+            names[key] = entries + names[key] if key == "dense_markers" \
+                else names[key] + entries
+        names["phases"] = {**names["phases"], **phases}
+    return names
 
 
 def _shape_bytes(text: str) -> list:

@@ -8,11 +8,11 @@ replicated, batch rows split over ``hvd.rank_axis()``), batches from the
 benchmark's stream through ``hvd.infeed_pipeline(mode="double")``.
 
 Set-up, in order: ``hvd.init``; the weights made on the device in one
-jitted call from ``--seed``; the plain reference's steps from a copy of
-them (now, while little else is on the chip); the optimizer state; the
-cell's one step shape compiled or read from the persistent cache (a
-loaded program reserves its scratch); the system's first steps, which
-are compared with the reference's and are the warm-up. Then the window: a closed loop with one step
+jitted call from ``--seed``; the plain reference (now, while little else
+is on the chip); the optimizer state; the cell's one step shape compiled
+or read from the persistent cache (a loaded program reserves its
+scratch); the system's first steps, which are compared with the
+reference's and are the warm-up. Then the window: a closed loop with one step
 in flight — dispatch step i, then block on step i-1's loss — that ends at
 the first completion after ``--seconds``.
 
@@ -28,6 +28,24 @@ steps: at a learning rate of 1e-4 a layer left out of the update moves
 the loss by less than the bf16 step's own rounding (PR 22 tried it on
 the chip). After the window: every loss finite; across chips, each device got
 its share of the batch rows and the parameters are equal on all.
+
+Two ways to get the reference's three numbers, and the cell's
+``check_steps`` decides which (the ``check`` note's ``mode``). With 2 or
+more, ``trainer``: a plain AdamW trainer takes ``check_steps`` steps from
+a copy of the weights. With the system's weights, the copy, the
+trainer's own start, its two moments, the gradient accumulator and one
+microbatch's gradients that is 28 bytes a parameter on one chip, whatever
+the number of chips: a ceiling of about 500M parameters on a 16 GB chip.
+With 1, ``first_step``, which a model past that ceiling needs and any
+model can take: one jitted call reads the system's own parameter buffers
+in place, accumulates the first batch's gradients, and works out from
+them, a leaf at a time, what the trainer's first step would leave: the
+sum of Adam's second moments and each module's movement (``optax.adamw``
+itself, ``init`` and ``update`` on one leaf; equal to the trainer's
+after one step to 1e-6, ``tests/benchmark``). 12 bytes a parameter, and
+the system's ``start`` is kept on the host. One step carries no optimizer
+state to a next one, so this way does not see it; the cells with two
+checked steps run the same ``optim.py`` and do.
 
 The objective, for the reference as for the system: each chip's rows form
 a group; the loss is the mean over groups of the group's mean
@@ -109,31 +127,76 @@ def _module_moves(after, before):
         for name in after}
 
 
-def _reference_steps(reference, config, params, batches, groups, micro,
-                     learning_rate):
-    """The plain trainer: ``len(batches)`` AdamW steps from ``params`` on
-    one device. Returns ``(losses, nu_sum, moves)``."""
+def _module_moves_from_host(after, before):
+    """``_module_moves`` where ``before`` is on the host (numpy): a leaf
+    at a time goes back to the device, and no more than one module's
+    leaves are there at once."""
     import jax
     import jax.numpy as jnp
-    import numpy as np
-    import optax
 
-    tx = optax.adamw(learning_rate)
+    squares = jax.jit(lambda a, b: jnp.sum((a - b) ** 2))
+    return {name: float(jnp.sqrt(sum(
+        squares(a, b) for a, b in zip(
+            jax.tree.leaves(after[name]), jax.tree.leaves(before[name])))))
+        for name in after}
+
+
+def _microbatches(batch, groups, micro):
+    """``(tokens, weights)`` of one batch in microbatches of ``micro``
+    rows. The weights are the objective's: each chip's rows form a group,
+    and a scored position weighs 1 / (groups x the group's scored
+    positions)."""
+    import numpy as np
+
+    tokens = batch["tokens"]
+    rows, seq_len = tokens.shape[0], tokens.shape[1] - 1
+    scored = batch.get("scored", np.ones((rows, seq_len), np.float32))
+    per_group = scored.reshape(groups, -1).sum(1)
+    weights = (scored.reshape(groups, -1)
+               / (groups * np.maximum(per_group, 1.0))[:, None])
+    return (tokens.reshape(rows // micro, micro, -1),
+            weights.astype(np.float32).reshape(rows // micro, micro, -1))
+
+
+def _accumulated_gradients(reference, config):
+    """``(p, tokens, weights) -> (loss, gradients)`` of the plain
+    reference over one batch, a microbatch at a time under ``lax.scan``:
+    the accumulator and one microbatch's gradients are all it holds."""
+    import jax
+    import jax.numpy as jnp
 
     def micro_loss(p, tokens, weights):
         with jax.default_matmul_precision("highest"):
             return (reference.token_losses(p, {"tokens": tokens}, config)
                     * weights).sum()
 
-    @functools.partial(jax.jit, donate_argnums=(0, 1))
-    def step(p, state, tokens, weights):
+    def accumulate(p, tokens, weights):
         def body(carry, xs):
             loss, grads = jax.value_and_grad(micro_loss)(p, *xs)
             return (carry[0] + loss,
                     jax.tree.map(jnp.add, carry[1], grads)), None
 
         zero = (jnp.zeros((), jnp.float32), jax.tree.map(jnp.zeros_like, p))
-        (loss, grads), _ = jax.lax.scan(body, zero, (tokens, weights))
+        return jax.lax.scan(body, zero, (tokens, weights))[0]
+
+    return accumulate
+
+
+def _reference_steps(reference, config, params, batches, groups, micro,
+                     learning_rate):
+    """``check_steps`` of 2 or more. The plain trainer: ``len(batches)``
+    AdamW steps from ``params`` (a copy: the step donates it) on one
+    device. Returns ``(losses, nu_sum, moves)``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    tx = optax.adamw(learning_rate)
+    accumulate = _accumulated_gradients(reference, config)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(p, state, tokens, weights):
+        loss, grads = accumulate(p, tokens, weights)
         updates, state = tx.update(grads, state, p)
         return optax.apply_updates(p, updates), state, loss
 
@@ -141,19 +204,63 @@ def _reference_steps(reference, config, params, batches, groups, micro,
     start = jax.tree.map(jnp.copy, params)      # the step donates
     losses = []
     for batch in batches:
-        tokens = batch["tokens"]
-        rows, seq_len = tokens.shape[0], tokens.shape[1] - 1
-        scored = batch.get("scored", np.ones((rows, seq_len), np.float32))
-        per_group = scored.reshape(groups, -1).sum(1)
-        weights = (scored.reshape(groups, -1)
-                   / (groups * np.maximum(per_group, 1.0))[:, None])
-        params, state, loss = step(
-            params, state, tokens.reshape(rows // micro, micro, -1),
-            weights.astype(np.float32).reshape(rows // micro, micro, -1))
+        params, state, loss = step(params, state,
+                                   *_microbatches(batch, groups, micro))
         losses.append(float(loss))
     moves = jax.jit(_module_moves)(params, start)
     return (losses, float(jax.jit(_adam_nu_sum)(state)),
             {k: float(v) for k, v in moves.items()})
+
+
+def _reference_first_step(reference, config, params, batch, groups, micro,
+                          learning_rate):
+    """``check_steps`` of 1. What the plain trainer's first step from
+    ``params`` on ``batch`` would leave, without taking it: ``params`` are
+    the system's own buffers, read and neither copied nor donated. One
+    jitted call accumulates the gradients and reads them a leaf at a
+    time, so no second tree is held: the leaf's ``optax.adamw`` state
+    after ``init`` and one ``update`` gives its part of the sum of Adam's
+    second moments, and the leaf's update, applied, how far it moves.
+    Only scalars leave the call. Returns ``(losses, nu_sum, moves)``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    tx = optax.adamw(learning_rate)
+    accumulate = _accumulated_gradients(reference, config)
+
+    @jax.jit
+    def first_step(p, tokens, weights):
+        loss, grads = accumulate(p, tokens, weights)
+        nu, moves = 0.0, {}
+        for name in p:
+            squares = 0.0
+            for leaf, grad in zip(jax.tree.leaves(p[name]),
+                                  jax.tree.leaves(grads[name])):
+                updates, state = tx.update(grad, tx.init(leaf), leaf)
+                nu += _adam_nu_sum(state)
+                # As ``_module_moves`` reads it from a trainer: after
+                # less before, rounded as the parameter is.
+                squares += jnp.sum(
+                    (optax.apply_updates(leaf, updates) - leaf) ** 2)
+            moves[name] = jnp.sqrt(squares)
+        return loss, nu, moves
+
+    loss, nu, moves = first_step(params, *_microbatches(batch, groups, micro))
+    return ([float(loss)], float(nu),
+            {k: float(v) for k, v in moves.items()})
+
+
+def _gaps(losses, nu, moves, ref_losses, ref_nu, ref_moves):
+    """The three numbers ``correct`` compares: ``(loss, gradient scale,
+    worst module's movement, that module)``, each gap a share of the
+    reference's number."""
+    loss_err = max(abs(a - b) / abs(b) for a, b in zip(losses, ref_losses))
+    nu_err = abs((nu / ref_nu) ** 0.5 - 1.0)
+    move_errs = {k: abs(float(v) / ref_moves[k] - 1.0)
+                 for k, v in moves.items()}
+    worst = max(move_errs, key=move_errs.get)
+    return loss_err, nu_err, move_errs[worst], worst
 
 
 def _replica_checksums(hvd, params):
@@ -219,11 +326,12 @@ def run(run):
     traffic = run.catalog.traffic(preset["traffic"])
     family = run.catalog.module("families", config["family"])
     reference = run.catalog.module("reference", config["family"])
-    names = hlo_counts.load_names()
+    names = hlo_counts.load_names(run.catalog.names(cell))
     chips, seed = cell["chips"], run.seed
     batch_rows, seq_len = traffic["batch"], traffic["seq_len"]
     if batch_rows % chips:
         raise ValueError(f"batch {batch_rows} does not split over {chips}")
+    mode = "first_step" if cell["check_steps"] == 1 else "trainer"
 
     # Every program of a run, small ones too, is read from the persistent
     # cache by the next run.
@@ -257,20 +365,38 @@ def run(run):
             jax.random.PRNGKey(seed)))
     weights_s = time.perf_counter() - t0
 
-    # -- the reference's steps, while only the weights are on the chip: the
-    # step program reserves its scratch when it is loaded ---------------
+    # -- the reference, while only the weights are on the chip: the step
+    # program reserves its scratch when it is loaded ------------------------
     def stream():
         return token_stream(seed, traffic, config["vocab_size"])
 
     first = list(itertools.islice(stream(), cell["check_steps"]))
+    micro = min(cell["reference_microbatch"], batch_rows // chips)
+    in_use_before = device_lib.memory_stats()[0].get("bytes_in_use", 0)
     t0 = time.perf_counter()
-    ref_losses, ref_nu, ref_moves = _reference_steps(
-        reference, config,
-        jax.tree.map(lambda x: jnp.copy(x.addressable_data(0)), params),
-        first, chips, min(cell["reference_microbatch"], batch_rows // chips),
-        opt["learning_rate"])
+    if mode == "first_step":
+        ref_losses, ref_nu, ref_moves = _reference_first_step(
+            reference, config,
+            jax.tree.map(lambda x: x.addressable_data(0), params),
+            first[0], chips, micro, opt["learning_rate"])
+    else:
+        ref_losses, ref_nu, ref_moves = _reference_steps(
+            reference, config,
+            jax.tree.map(lambda x: jnp.copy(x.addressable_data(0)), params),
+            first, chips, micro, opt["learning_rate"])
     reference_s = time.perf_counter() - t0
+    # What the reference added to the chip, as the allocator saw it: live
+    # buffers are "in use", a running program's temporaries "reserved" (PR
+    # 26's chip runs: 3.3 GB reserved against 0.4 GB in use at 6 x S4096).
+    # Two peaks of the process so far, which need not fall together.
     stats_after_reference = device_lib.memory_stats()[0]
+    reference_peaks = {
+        "reference_peak_in_use_bytes": max(
+            0, stats_after_reference.get("peak_bytes_in_use", 0)
+            - in_use_before),
+        "reference_peak_reserved_bytes":
+            stats_after_reference.get("peak_bytes_reserved", 0)}
+    n_params = sum(x.size for x in jax.tree.leaves(params))
 
     # -- set-up: optimizer state, the step program ---------------------------
     t0 = time.perf_counter()
@@ -329,7 +455,10 @@ def run(run):
     feed = hvd.infeed_pipeline(stream(), mode="double", sharding=rows)
     try:
         losses, shard_rows = [], None
-        start = jax.tree.map(jnp.copy, state[0])    # the step donates
+        if mode == "first_step":    # no second copy on the chip
+            start = jax.tree.map(np.asarray, state[0])
+        else:
+            start = jax.tree.map(jnp.copy, state[0])    # the step donates
         for _ in first:
             batch = next(feed)
             if shard_rows is None:
@@ -339,30 +468,31 @@ def run(run):
             *state, loss = compiled(*state, batch)
             losses.append(float(loss))
         nu = float(jax.jit(_adam_nu_sum)(state[1]))
-        moves = jax.jit(_module_moves)(state[0], start)
+        if mode == "first_step":
+            moves = _module_moves_from_host(state[0], start)
+        else:
+            moves = jax.jit(_module_moves)(state[0], start)
         del start
         tol = preset["tolerance"]
-        loss_err = max(abs(a - b) / abs(b)
-                       for a, b in zip(losses, ref_losses))
-        nu_err = abs((nu / ref_nu) ** 0.5 - 1.0)
-        move_errs = {k: abs(float(v) / ref_moves[k] - 1.0)
-                     for k, v in moves.items()}
-        worst_module = max(move_errs, key=move_errs.get)
+        loss_err, nu_err, move_err, worst_module = _gaps(
+            losses, nu, moves, ref_losses, ref_nu, ref_moves)
         spread = (len(shard_rows) == chips
                   and all(r * chips == batch_rows for _, r in shard_rows))
         checks = {"losses_match_reference": loss_err <= tol["loss_rtol"],
                   "gradient_scale_matches_reference":
                       nu_err <= tol["grad_scale_rtol"],
                   "every_module_moved_as_the_reference":
-                      move_errs[worst_module] <= tol["module_move_rtol"],
+                      move_err <= tol["module_move_rtol"],
                   "batch_rows_spread_over_chips": spread}
-        run.say("check", system_losses=losses, reference_losses=ref_losses,
+        run.say("check", mode=mode, system_losses=losses,
+                reference_losses=ref_losses,
                 loss_rel_err=loss_err, loss_rtol=tol["loss_rtol"],
                 grad_scale_rel_err=nu_err,
                 grad_scale_rtol=tol["grad_scale_rtol"],
-                module_move_rel_err=move_errs[worst_module],
+                module_move_rel_err=move_err,
                 module_move_worst=worst_module,
                 module_move_rtol=tol["module_move_rtol"],
+                **reference_peaks, parameters=n_params,
                 batch_shards=shard_rows,
                 check_s=time.perf_counter() - t_check)
 
@@ -461,5 +591,6 @@ def run(run):
         "memory": {"alloc_peak_bytes": int(alloc_peak),
                    "step_hbm_bytes": int(step_hbm)},
         "trace": traced,
+        "names": names,
         "breakdown": traced.get("breakdown"),
     }

@@ -53,22 +53,13 @@ import glob
 import json
 import os
 
+from benchmark.hlo_counts import load_names
 from benchmark.trace_reduce import (classify, load_xplane, short_name,
                                     subtract, total, union)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(_HERE)
 CONSUMER_DEPTH = 8      # how far ``candidates`` follows nameless neighbours
-
-
-def load_names() -> dict:
-    """``trace_names.json`` (classes, planes, lines) with
-    ``phase_names.json`` (kernels, markers, phases) laid over it."""
-    names = {}
-    for name in ("trace_names.json", "phase_names.json"):
-        with open(os.path.join(_HERE, name)) as f:
-            names.update(json.load(f))
-    return names
 
 
 # -- protocol buffers, from the wire ---------------------------------------
@@ -369,14 +360,16 @@ def newest_trace(root: str = ROOT):
 def phases(record: dict, root: str = ROOT):
     """The run's phases, ``{"ms": {phase: ms a step}, "named", "kind"}``, or
     ``None`` where the run was not traced or left no file. Computed once
-    a record; the first call prints the ``phases`` note."""
+    a record; the first call prints the ``phases`` note. The names are the
+    record's, which the job read for its cell; a record without any is
+    read by the names of every cell."""
     trace = record.get("trace")
     if not trace or not trace.get("steps"):
         return None
     if "phases" not in record:
         record["phases"] = None
         path = newest_trace(root)
-        names = load_names()
+        names = record.get("names") or load_names()
         reduced = {}
         if path:
             reduced = reduce_phases(read_trace(path, names), names)

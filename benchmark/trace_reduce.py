@@ -11,9 +11,10 @@ time is the UNION of the intervals in which an operation ran — durations
 summed would count nested or overlapping events twice (the repo's only
 banked reduction reported a busy share of 2.8 that way).
 
-Device events fall into three classes by the name lists in
-``trace_names.json``: collective, flash kernel, and every other
-operation ("dense"). Per device:
+Device events fall into three classes by the name lists of
+``hlo_counts.load_names``: collective, flash kernel (a Mosaic call, unless
+it is named as one of ``not_flash_kernels``), and every other operation
+("dense"). Per device:
 
     flash      = union(flash events)
     dense      = union(other events) - flash
@@ -121,7 +122,11 @@ def short_name(name: str) -> str:
 
 
 def classify(name: str, text: str, names: dict) -> str:
-    """``"collective"``, ``"flash"`` or ``"dense"``."""
+    """``"collective"``, ``"flash"`` or ``"dense"``. A Mosaic call is a
+    flash kernel unless its own name (never an operand's) or its
+    ``op_name`` holds an entry of ``not_flash_kernels``: a wire's or a
+    grouped matmul's kernel is dense work, so that ``flash_ms`` and
+    ``flash_roofline_pct`` stay the attention kernels'."""
     bare = short_name(name).lstrip("%")
     if any(bare == op or bare.startswith(op + ".")
            or bare.startswith(op + "-start") or bare.startswith(op + "-done")
@@ -129,6 +134,9 @@ def classify(name: str, text: str, names: dict) -> str:
         return "collective"
     if any(marker in name or marker in text
            for marker in names["flash_kernel_markers"]):
+        if any(kernel in bare or kernel in text
+               for kernel in names["not_flash_kernels"]):
+            return "dense"
         return "flash"
     return "dense"
 

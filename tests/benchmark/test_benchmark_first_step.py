@@ -1,0 +1,296 @@
+"""The two ways the job decides ``correct`` (a trainer's ``check_steps``
+steps, or with ``check_steps`` 1 one reference step on the system's own
+parameters) give the same reference numbers after one step, both see the
+faults they are there to catch, and a cell's own file of names is read
+for that cell alone."""
+
+import json
+import os
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+from benchmark import hlo_counts, phase_reduce, trace_reduce
+from benchmark.catalog import ROOT, Catalog
+from benchmark.jobs import train_lm
+from benchmark.stream import token_stream
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from low_precision import matmul_operands_in  # noqa: E402
+
+FAULTY_RUN = os.path.join(ROOT, "tests", "benchmark", "faulty_run.py")
+FIRST_STEP = ("--set", "check_steps=1")
+
+
+# -- the two references agree ------------------------------------------------
+
+@pytest.mark.parametrize("config_name, traffic_name, groups", [
+    ("gpt-tiny", "lm-tiny", 1),
+    ("bert-tiny", "mlm-tiny", 2),
+])
+def test_first_step_reads_what_the_trainer_leaves_after_its_first_step(
+        config_name, traffic_name, groups):
+    import jax
+    import jax.numpy as jnp
+
+    cat = Catalog()
+    config, traffic = cat.config(config_name), cat.traffic(traffic_name)
+    family = cat.module("families", config["family"])
+    reference = cat.module("reference", config["family"])
+    params = family.build(config).init(
+        jax.random.PRNGKey(11),
+        jnp.zeros((1, traffic["seq_len"]), jnp.int32))["params"]
+    batch = next(token_stream(11, traffic, config["vocab_size"]))
+    before = jax.tree.map(np.array, params)
+
+    first = train_lm._reference_first_step(
+        reference, config, params, batch, groups, 2, 1e-4)
+    # ... which read the parameters in place and left them as they were.
+    assert all(np.array_equal(a, b) for a, b in zip(
+        jax.tree.leaves(before), jax.tree.leaves(params)))
+    trainer = train_lm._reference_steps(
+        reference, config, jax.tree.map(jnp.copy, params), [batch], groups,
+        2, 1e-4)
+
+    assert first[0] == pytest.approx(trainer[0], rel=1e-6)
+    assert first[1] == pytest.approx(trainer[1], rel=1e-6)
+    assert set(first[2]) == set(trainer[2]) == set(params)
+    for name, move in trainer[2].items():
+        assert move > 0
+        assert first[2][name] == pytest.approx(move, rel=1e-6)
+
+
+def test_moves_from_a_start_on_the_host_are_the_moves():
+    import jax
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(3)
+    before = {"a": {"w": rng.normal(size=(5, 3)).astype(np.float32)},
+              "b": {"w": rng.normal(size=(7,)).astype(np.float32),
+                    "v": rng.normal(size=(2, 2)).astype(np.float32)}}
+    after = jax.tree.map(lambda x: jnp.asarray(x) + 0.5, before)
+    host = train_lm._module_moves_from_host(after, before)
+    device = jax.jit(train_lm._module_moves)(
+        after, jax.tree.map(jnp.asarray, before))
+    assert host == pytest.approx({"a": 0.5 * 15 ** 0.5, "b": 0.5 * 11 ** 0.5})
+    assert host == pytest.approx({k: float(v) for k, v in device.items()})
+
+
+# -- the control: the reference in the precision below ------------------------
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_the_reference_in_the_precision_below_is_not_correct(seed):
+    """The control stands in for the system: it is the plain reference
+    with its operands rounded, not the system's step run in float8. On the
+    chip at ``gpt2s-s4096``'s own size the number that says "not correct"
+    is sqrt(sum nu) (3.5e-2 against the limit; ``PERF.md``), so that is
+    what is held here, on the cell's tiny preset against the cell's own
+    limit: with bfloat16's mantissa (what the system computes in) inside
+    it, with float8's outside. The movement cannot decide, and is inside
+    its limit in both: Adam's first step moves every weight by the
+    learning rate whatever its gradient's size. The loss over 128 tokens
+    of a vocabulary of 128 is not held to the limit of 24,576 of 50,257."""
+    import jax
+    import jax.numpy as jnp
+
+    cat = Catalog()
+    cell = cat.cell("gpt2s-s4096")
+    tol = cell["tolerance"]
+    config = cat.config(cell["rehearsal"]["config"])
+    traffic = cat.traffic(cell["rehearsal"]["traffic"])
+    family = cat.module("families", config["family"])
+    reference = cat.module("reference", config["family"])
+    params = family.build(config).init(
+        jax.random.PRNGKey(seed),
+        jnp.zeros((1, traffic["seq_len"]), jnp.int32))["params"]
+    batch = next(token_stream(seed, traffic, config["vocab_size"]))
+
+    def numbers():
+        return train_lm._reference_first_step(
+            reference, config, params, batch, 1, 2, 1e-4)
+
+    plain = numbers()
+    gaps = {}
+    for precision in ("bfloat16", "float8_e4m3"):
+        with matmul_operands_in(precision):
+            gaps[precision] = train_lm._gaps(*numbers(), *plain)[:3]
+    assert numbers() == plain           # the rounding is gone again
+    for precision in gaps:
+        assert gaps[precision][2] <= tol["module_move_rtol"]
+    assert gaps["bfloat16"][1] <= tol["grad_scale_rtol"]
+    assert gaps["float8_e4m3"][1] > tol["grad_scale_rtol"]
+    assert gaps["float8_e4m3"][0] > gaps["bfloat16"][0]
+
+
+# -- both ways see the faults ------------------------------------------------
+
+def _faulty(*args):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("XLA_FLAGS", "HVD_TPU_FORCE_CPU_DEVICES")}
+    env["JAX_PLATFORMS"] = "cpu"
+    return subprocess.run(
+        [sys.executable, FAULTY_RUN, *args, "--seed", "7", "--seconds",
+         "0.5", "--trace", "0", "--rehearse-cpu"],
+        capture_output=True, text=True, timeout=600, env=env, cwd=ROOT)
+
+
+@pytest.mark.parametrize("mode", ["trainer", "first_step"])
+@pytest.mark.parametrize("fault, failing", [
+    ("none", None),
+    ("sum_not_mean", "gradient_scale_matches_reference"),
+    ("module_left_out", "every_module_moved_as_the_reference"),
+    ("scaled_gradients", "gradient_scale_matches_reference"),
+])
+def test_a_fault_in_the_timed_path_is_not_correct(mode, fault, failing):
+    """The data-parallel cell's tiny preset on two virtual devices, the
+    system's optimizer broken underneath; the reference is not touched."""
+    out = _faulty("--fault", fault, "--set", "chips=2",
+                  *(FIRST_STEP if mode == "first_step" else ()),
+                  "--workload", "bert-large-s512-dp4")
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(line) for line in out.stdout.splitlines()]
+    notes = {line["note"]: line for line in lines[:-1]}
+    assert notes["check"]["mode"] == mode
+    assert lines[-1]["device"]["count"] == 2
+    assert lines[-1]["correct"] is (failing is None)
+    wrong = [k for k, ok in notes["window"]["checks"].items() if not ok]
+    assert wrong == ([failing] if failing else [])
+    if fault == "sum_not_mean":     # off by n - 1
+        assert notes["check"]["grad_scale_rel_err"] == pytest.approx(
+            1.0, abs=0.01)
+    if fault == "scaled_gradients":
+        assert notes["check"]["grad_scale_rel_err"] == pytest.approx(
+            0.05, abs=0.005)
+
+
+def test_the_new_cell_is_checked_by_one_reference_step():
+    out = _faulty("--workload", "gpt2s-s4096")
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(line) for line in out.stdout.splitlines()]
+    assert set(lines[-1]) == {"correct", "attempted", "failed", "metrics",
+                              "device"}
+    assert lines[-1]["correct"] is True
+    check = next(line for line in lines if line["note"] == "check")
+    assert check["mode"] == "first_step"
+    assert len(check["system_losses"]) == len(check["reference_losses"]) == 1
+    assert {"reference_peak_in_use_bytes", "reference_peak_reserved_bytes",
+            "parameters"} <= set(check)
+
+
+@pytest.mark.parametrize("fault, failing", [
+    ("learning_rate_off", "every_module_moved_as_the_reference"),
+    ("row_left_out", "gradient_scale_matches_reference"),
+])
+def test_the_new_cell_sees_what_its_limits_are_held_against(fault, failing):
+    """A learning rate 5% off moves every module 5% further, and nothing
+    else sees it; a row of the batch left out (fed twice in another's
+    place) is seen by the gradients' size before the loss."""
+    out = _faulty("--fault", fault, "--workload", "gpt2s-s4096")
+    assert out.returncode == 0, out.stderr[-3000:]
+    lines = [json.loads(line) for line in out.stdout.splitlines()]
+    notes = {line["note"]: line for line in lines[:-1]}
+    assert lines[-1]["correct"] is False
+    assert not notes["window"]["checks"][failing]
+    if fault == "learning_rate_off":
+        assert notes["check"]["module_move_rel_err"] == pytest.approx(
+            0.05, abs=0.002)
+        assert [k for k, ok in notes["window"]["checks"].items()
+                if not ok] == [failing]
+
+
+# -- a cell's own file of names -----------------------------------------------
+
+MORE_NAMES = {
+    "comment": "a later PR's grouped matmul and its scope",
+    "not_flash_kernels": ["hvd_grouped_matmul"],
+    "flash_kernels": [["hvd_flash_fwd_gqa", "flash_fwd"]],
+    "dense_markers": [["hvd_update/experts", "expert_update"],
+                      ["hvd_moe_dispatch", "moe_dispatch"]],
+    "program_scopes": ["hvd_moe_dispatch"],
+    "phases": {"moe": ["moe_dispatch", "expert_update"]},
+}
+
+
+def test_a_file_of_names_extends_the_lists_and_adds_phases():
+    plain = hlo_counts.load_names()
+    names = hlo_counts.load_names(
+        [{"not_flash_kernels": ["hvd_fp8_quantize"]}, MORE_NAMES])
+    assert names["not_flash_kernels"] == plain["not_flash_kernels"] + [
+        "hvd_fp8_quantize", "hvd_grouped_matmul"]       # in the order given
+    assert names["flash_kernels"] == plain["flash_kernels"] + [
+        ["hvd_flash_fwd_gqa", "flash_fwd"]]
+    # A file's markers go first: 'hvd_update/experts' before 'hvd_update'.
+    assert names["dense_markers"] == \
+        MORE_NAMES["dense_markers"] + plain["dense_markers"]
+    assert names["program_scopes"] == plain["program_scopes"] + [
+        "hvd_moe_dispatch"]
+    assert names["phases"] == {**plain["phases"], **MORE_NAMES["phases"]}
+    assert {k: v for k, v in names.items() if k not in MORE_NAMES} == \
+        {k: v for k, v in plain.items() if k not in MORE_NAMES}
+    assert hlo_counts.load_names() == plain         # and nothing stays
+
+
+def test_a_named_mosaic_call_that_is_no_flash_kernel_is_dense():
+    call = ('%{}.4 = f32[8]{{0}} custom-call(%hvd_flash_fwd.2), '
+            'custom_call_target="tpu_custom_call"')
+    plain = hlo_counts.load_names()
+    assert trace_reduce.classify(
+        call.format("hvd_grouped_matmul"), "", plain) == "flash"
+    assert trace_reduce.classify(
+        call.format("hvd_int8_quantize_sr"), "", plain) == "dense"
+    names = hlo_counts.load_names([MORE_NAMES])
+    assert trace_reduce.classify(
+        call.format("hvd_grouped_matmul"), "", names) == "dense"
+    # By its own name, never an operand's; and the phases follow.
+    assert trace_reduce.classify(
+        call.format("hvd_flash_fwd_gqa"), "", names) == "flash"
+    events = [
+        [call.format("hvd_grouped_matmul"), 0.0, 4e3, "",
+         "jit(step)/jvp(GPT)/hvd_moe_dispatch/hvd_grouped_matmul", 1],
+        [call.format("hvd_flash_fwd_gqa"), 4e3, 2e3, "", "", 1],
+        ["%fusion.1 = f32[8]{0} fusion(%p.1), kind=kLoop", 6e3, 1e3, "",
+         "jit(step)/hvd_update/experts/mul", 1]]
+    seconds = phase_reduce.reduce_phases(
+        {"devices": {"/device:TPU:0": events}, "hlo": {}}, names)["seconds"]
+    assert {p: s for p, s in seconds.items() if s} == pytest.approx(
+        {"moe_dispatch": 4e-6, "flash_fwd": 2e-6, "expert_update": 1e-6})
+
+
+@pytest.mark.parametrize("more", [
+    {"flash_default": "flash_fwd"},
+    {"collective_opcodes": ["all-reduce"]},
+    {"phases": {"optimizer": ["fwd"]}},
+])
+def test_a_file_of_names_may_not_change_what_is_there(more):
+    with pytest.raises(ValueError, match="may not set"):
+        hlo_counts.load_names([more])
+
+
+def test_names_are_read_for_the_cell_that_lists_them_and_no_other(tmp_path):
+    """No cell of today lists any, so each is reduced by the names of
+    every cell whatever files a later PR adds; a cell that lists one gets
+    it, through the record, and the cell beside it does not."""
+    cat = Catalog()
+    for entry in cat.index["workloads"]:
+        assert cat.names(cat.cell(entry["name"])) == []
+    assert not os.path.exists(os.path.join(ROOT, "benchmark", "names"))
+    assert phase_reduce.load_names is hlo_counts.load_names
+
+    (tmp_path / "names").mkdir()
+    (tmp_path / "names" / "moe.json").write_text(json.dumps(MORE_NAMES))
+    cat.home = str(tmp_path)
+    assert cat.names({"names": ["moe"]}) == [MORE_NAMES]
+    assert cat.names({}) == []
+    with pytest.raises(LookupError):
+        cat.names({"names": ["absent"]})
+
+    with open(os.path.join(ROOT, "tests", "benchmark", "fixtures",
+                           "phases_small.json")) as f:
+        fixture = json.load(f)
+    plain = phase_reduce.reduce_phases(fixture, hlo_counts.load_names())
+    with_more = phase_reduce.reduce_phases(
+        fixture, hlo_counts.load_names([MORE_NAMES]))
+    assert {p: s for p, s in with_more["seconds"].items()
+            if p in plain["seconds"]} == plain["seconds"]

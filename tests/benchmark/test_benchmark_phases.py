@@ -105,7 +105,7 @@ def test_a_dense_event_goes_to_the_first_marker_it_holds(op_name, want):
      "custom_call_target=\"tpu_custom_call\"", "", "other_kernel"),
     ("%hvd_int8_dequantize.2 = f32[8]{0} custom-call(%p.1), "
      "custom_call_target=\"tpu_custom_call\"",
-     "jit(step)/hvd_reduce/hvd_int8_dequantize/pallas_call", "other_kernel"),
+     "jit(step)/hvd_reduce/hvd_int8_dequantize/pallas_call", "bucket_other"),
 ])
 def test_a_flash_event_goes_to_the_kernel_it_is_named_for(name, op_name,
                                                           want):
