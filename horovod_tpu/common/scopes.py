@@ -16,7 +16,10 @@ UNPACK = "unpack"            # fusion.unfuse: hvd_reduce/unpack
 REDUCE_PACK = REDUCE + "/" + PACK
 REDUCE_UNPACK = REDUCE + "/" + UNPACK
 UPDATE = "hvd_update"        # optim.py core_update: the inner optax update
-LM_HEAD = "hvd_lm_head"      # models/gpt.py, models/bert.py: vocabulary matmul
+LM_HEAD = "hvd_lm_head"      # models/gpt.py, bert.py, looplm.py: vocabulary matmul
+# models/looplm.py: the exit gate, the exit distribution, its entropy and
+# the weighting of the exits' losses
+LOOP_EXIT = "hvd_loop_exit"
 
 # Pallas kernels: the ``name=`` of each ``pallas_call``.
 FLASH_FWD = "hvd_flash_fwd"
@@ -30,6 +33,7 @@ INT8_QUANTIZE_SR = "hvd_int8_quantize_sr"
 INT8_DEQUANTIZE = "hvd_int8_dequantize"
 
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
+LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import functools
 import logging
+import math
 from typing import Optional
 
 import jax
@@ -183,11 +184,27 @@ def _dot(a, b, dims):
                                preferred_element_type=jnp.float32)
 
 
+def _scale_folds_into_q(scale: float) -> bool:
+    """Whether ``q * scale`` is exact in q's own dtype: a power of two
+    (head widths 64 and 16). Width 128's 2^-3.5 is none, and would round
+    a bf16 q a second time."""
+    return math.frexp(scale)[0] == 0.5
+
+
 def _scaled(q_ref, scale):
-    """The q tile times the softmax scale, in q's dtype (exact for the
-    power-of-two scale of head widths 64 and 16)."""
+    """``(q tile, on_scores)``: the softmax scale goes into the q tile
+    where that is exact, and ``on_scores`` is None; else the tile is as
+    it came and the fp32 scores take the scale (``_scores``)."""
     q = q_ref[...]
-    return (q.astype(jnp.float32) * scale).astype(q.dtype)
+    if _scale_folds_into_q(scale):
+        return (q.astype(jnp.float32) * scale).astype(q.dtype), None
+    return q, scale
+
+
+def _scores(a, b, on_scores):
+    """a · bᵀ in fp32, times the scale where ``_scaled`` left it out."""
+    s = _dot(a, b, _NT)
+    return s if on_scores is None else s * on_scores
 
 
 def _head(x, g, heads):
@@ -253,14 +270,15 @@ def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
     heads = lse_ref.shape[0]
     nk = k_ref.shape[0] // block_k
     qi = pl.program_id(2)
-    q = _scaled(q_ref, scale)                               # (bq, lanes)
+    q, on_scores = _scaled(q_ref, scale)                    # (bq, lanes)
 
     def step(j, carry, on_diagonal):
         k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
                                        block_k, on_diagonal)
         out = []
         for g, (m, l, acc) in enumerate(carry):
-            s = _masked(_dot(q, _head(k, g, heads), _NT), kmask, keep)
+            s = _masked(_scores(q, _head(k, g, heads), on_scores), kmask,
+                        keep)
             m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
             p = jnp.exp(s - m_new)                          # (bq, bk)
             alpha = jnp.exp(m - m_new)
@@ -291,7 +309,7 @@ def _dq_kernel(*refs, block_k, causal, scale, has_mask):
     heads = lse_ref.shape[0]
     nk = k_ref.shape[0] // block_k
     qi = pl.program_id(2)
-    q = _scaled(q_ref, scale)
+    q, on_scores = _scaled(q_ref, scale)
     do = do_ref[...]
     # Rows as (bq, 1) columns. dd = delta - dlse, delta_i = rowsum(dO_i *
     # o_i): lse = logsumexp(s) and dlse/ds = p, so an lse cotangent
@@ -304,7 +322,7 @@ def _dq_kernel(*refs, block_k, causal, scale, has_mask):
                                        block_k, on_diagonal)
         for g in range(heads):
             kg = _head(k, g, heads)
-            s = _masked(_dot(q, kg, _NT), kmask, keep)
+            s = _masked(_scores(q, kg, on_scores), kmask, keep)
             p = jnp.exp(s - lse[g])                         # (bq, bk)
             ds = p * (_dot(do, _head(v, g, heads), _NT) - dd[g])
             dq = dq + _dot(ds.astype(k.dtype), kg, _NN)
@@ -330,7 +348,7 @@ def _dkv_kernel(*refs, causal, scale, has_mask):
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def block(on_diagonal):
-        q = _scaled(q_ref, scale)                           # (bq, lanes)
+        q, on_scores = _scaled(q_ref, scale)                # (bq, lanes)
         do = do_ref[...]
         k = k_ref[...]                                      # (bk, lanes)
         v = v_ref[...]
@@ -340,11 +358,13 @@ def _dkv_kernel(*refs, causal, scale, has_mask):
             else None
         for g in range(heads):
             qg, dog = _head(q, g, heads), _head(do, g, heads)
-            st = _masked(_dot(k, qg, _NT), kmask, keep)     # (bk, bq) = sᵀ
+            st = _masked(_scores(k, qg, on_scores), kmask,
+                         keep)                              # (bk, bq) = sᵀ
             pt = jnp.exp(st - lse_ref[g])                   # rows (1, bq)
             dv_acc[...] += _dot(pt.astype(do.dtype), dog, _NN)
             dst = pt * (_dot(v, dog, _NT) - dd_ref[g])
-            # q carries the scale already: dk = scale * dsᵀ·q
+            # dk = scale * dsᵀ·q: q carries the scale already, or the
+            # accumulator takes it when it is written out
             dk_acc[...] += _dot(dst.astype(q.dtype), qg, _NN)
 
     if causal:
@@ -358,7 +378,10 @@ def _dkv_kernel(*refs, causal, scale, has_mask):
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dk = dk_acc[...]
+        if not _scale_folds_into_q(scale):
+            dk = dk * scale
+        dk_ref[...] = dk.astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 

@@ -1,0 +1,278 @@
+"""Family ``ouro`` (the looped decoder of ``ByteDance/Ouro-2.6B``) on the
+CPU at its tiny preset: the system against the plain reference on seeded
+weights, the configuration's file against the published widths, the
+family's counts by hand, and the two "of which" readers on a hand-made
+trace. Nothing here touches a device."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import hlo_counts, of_which, phase_reduce
+from benchmark.catalog import Catalog
+from benchmark.jobs import train_lm
+from benchmark.stream import token_stream
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+from low_precision import matmul_operands_in  # noqa: E402
+
+CAT = Catalog()
+FAMILY = CAT.module("families", "ouro")
+REFERENCE = CAT.module("reference", "ouro")
+TINY = CAT.config("ouro-tiny")
+# (loss, logits and distribution, gradients): the arithmetic of the two
+# agrees to fp32 rounding; in bf16 the system's operands are rounded.
+TOLERANCE = {"float32": (1e-5, 2e-4, 2e-4), "bfloat16": (2e-3, 3e-2, 1e-1)}
+
+
+def _close(got, want, tol):
+    """``|got - want| <= tol |want|``, the arrays whole (a bf16 logit by
+    itself can be off by more than a share of itself)."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return np.linalg.norm(got - want) <= tol * np.linalg.norm(want)
+
+
+@pytest.fixture(scope="module", params=sorted(TOLERANCE))
+def pair(request):
+    """The system's model in one compute dtype, its seeded parameters and
+    a batch; the reference reads the same tree."""
+    from horovod_tpu.models import LoopLM
+
+    model = FAMILY.build(TINY)
+    assert isinstance(model, LoopLM) and model.dtype == jnp.bfloat16
+    model = model.clone(dtype=jnp.dtype(request.param))
+    tokens = jax.random.randint(jax.random.PRNGKey(4), (3, 33), 0,
+                                TINY["vocab_size"])
+    params = model.init(jax.random.PRNGKey(5), tokens[:, :-1])["params"]
+    return model, params, tokens, TOLERANCE[request.param]
+
+
+def test_every_exits_logits_and_the_exit_distribution(pair):
+    from horovod_tpu.models import exit_log_distribution
+
+    model, params, tokens, (_, tol, _) = pair
+    logits, gates = model.apply({"params": params}, tokens[:, :-1])
+    want = REFERENCE.exit_logits(params, tokens[:, :-1], TINY)
+    assert logits.shape == want.shape == (3, 3, 32, TINY["vocab_size"])
+    assert _close(logits, want, tol)
+    p = jnp.exp(exit_log_distribution(gates))
+    want_p = REFERENCE.exit_distribution(
+        REFERENCE.exits(params, tokens[:, :-1], TINY)[1])
+    assert np.allclose(want_p.sum(0), 1.0, atol=1e-6)
+    assert _close(p, want_p, tol)
+
+
+def test_the_loss_a_position_and_its_mean(pair):
+    from horovod_tpu.models import looplm_loss
+
+    model, params, tokens, (tol, _, _) = pair
+    beta = TINY["exit_entropy_beta"]
+    want = REFERENCE.token_losses(params, {"tokens": tokens}, TINY)
+    assert want.shape == (3, 32) and want.dtype == jnp.float32
+    assert float(looplm_loss(model, params, tokens, beta)) \
+        == pytest.approx(float(want.mean()), rel=tol)
+    # The family's loss is that mean, with the configuration's beta.
+    built = FAMILY.build(TINY)
+    assert float(FAMILY.loss(built, params, {"tokens": tokens})) \
+        == pytest.approx(float(want.mean()), rel=TOLERANCE["bfloat16"][0])
+    # One position at a time, through the weights.
+    for b, s in ((0, 0), (1, 17), (2, 31)):
+        one = jnp.zeros((3, 32)).at[b, s].set(2.0)
+        assert float(looplm_loss(model, params, tokens, beta, one)) \
+            == pytest.approx(float(want[b, s]), rel=10 * tol)
+
+
+def test_every_gradient(pair):
+    from horovod_tpu.models import looplm_loss
+
+    model, params, tokens, (_, _, tol) = pair
+    got = jax.grad(lambda p: looplm_loss(
+        model, p, tokens, TINY["exit_entropy_beta"]))(params)
+    want = jax.grad(lambda p: REFERENCE.token_losses(
+        p, {"tokens": tokens}, TINY).mean())(params)
+    flat = jax.tree_util.tree_leaves_with_path(got)
+    assert len(flat) == 2 * 11 + 5
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        assert float(jnp.abs(w).max()) > 0, path
+        assert _close(g, w, tol), path
+
+
+# -- the control: the reference in the precision below ------------------------
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4])
+def test_the_reference_in_the_precision_below_is_not_correct_here_either(seed):
+    """As for ``gpt2s-s4096``: the plain reference with float8's mantissa
+    in its matmul operands, in the program's place, on the cell's tiny
+    preset against the cell's own limits: not correct, by one of them and
+    not by each (which one changes with the seed at this size: 128 tokens
+    of a vocabulary of 256); with bfloat16's mantissa (what the system
+    computes in) inside the two that a few tokens do not swing. On the
+    chip at the cell's size the control reads 9.0e-3 to 2.3e-2 in
+    sqrt(sum nu) against 6e-3 on every seed tried (``PERF.md``)."""
+    cell = CAT.cell("ouro-2.6b-l8-s2048")
+    tol = cell["tolerance"]
+    traffic = CAT.traffic(cell["rehearsal"]["traffic"])
+    params = FAMILY.build(TINY).init(
+        jax.random.PRNGKey(seed),
+        jnp.zeros((1, traffic["seq_len"]), jnp.int32))["params"]
+    batch = next(token_stream(seed, traffic, TINY["vocab_size"]))
+
+    def numbers():
+        return train_lm._reference_first_step(
+            REFERENCE, TINY, params, batch, 1, 2, 1e-4)
+
+    plain = numbers()
+    gaps = {}
+    for precision in ("bfloat16", "float8_e4m3"):
+        with matmul_operands_in(precision):
+            gaps[precision] = train_lm._gaps(*numbers(), *plain)[:3]
+    limits = (tol["loss_rtol"], tol["grad_scale_rtol"],
+              tol["module_move_rtol"])
+    assert any(gap > limit for gap, limit in zip(gaps["float8_e4m3"],
+                                                 limits))
+    assert all(gap <= limit for gap, limit in zip(gaps["bfloat16"][1:],
+                                                  limits[1:]))
+    assert gaps["float8_e4m3"][0] > 3 * gaps["bfloat16"][0]
+    assert set(plain[2]) == set(params)     # every top-level module moved
+    assert all(move > 0 for move in plain[2].values())
+
+
+# -- the configuration and the family's counts --------------------------------
+
+def test_the_configuration_keeps_every_published_width():
+    config = CAT.config("ouro-2.6b-l8")
+    published = {"hidden_size": 2048, "num_attention_heads": 16,
+                 "num_key_value_heads": 16, "head_dim": 128,
+                 "intermediate_size": 5632, "vocab_size": 49152,
+                 "total_ut_steps": 4, "tie_word_embeddings": False,
+                 "hidden_act": "silu", "rms_norm_eps": 1e-6,
+                 "rope_theta": 1000000, "early_exit_threshold": 1,
+                 "max_position_embeddings": 65536}
+    assert {k: config[k] for k in published} == published
+    assert config["reduced"] == ["num_hidden_layers"]
+    assert config["num_hidden_layers"] == 8
+    assert config["published"] == {"num_hidden_layers": 48}
+    assert len(config["layer_types"]) == 48     # copied whole
+    assert set(config["layer_types"]) == {"full_attention"}
+    assert "six pipeline stages of eight" in config["deployment"]
+    assert {"exit_entropy_beta", "biases", "carried_state", "initialization",
+            "compute"} <= set(config["assumed"])
+    assert config["exit_entropy_beta"] == 0.05
+
+
+def test_the_family_builds_the_published_model_cut_in_depth():
+    from horovod_tpu.models import LoopLM
+
+    assert FAMILY.build(CAT.config("ouro-2.6b-l8")) == LoopLM(num_layers=8)
+    assert LoopLM().num_layers == 48 and LoopLM().passes == 4
+
+
+def test_train_flops_per_token_by_hand():
+    config = CAT.config("ouro-2.6b-l8")
+    # A layer's matmuls: 4 x 2048 x 2048 + 3 x 2048 x 5632 = 51,380,224
+    # weights, met 4 x 8 = 32 times; the head's 49152 x 2048 =
+    # 100,663,296 at each of 4 exits: 2,046,820,352 in all, x 6.
+    assert 4 * 2048 * 2048 + 3 * 2048 * 5632 == 51_380_224
+    assert 6 * (32 * 51_380_224 + 4 * 100_663_296) == 12_280_922_112
+    # Causal attention: 6 x 32 x S x (16 x 128).
+    assert FAMILY.train_flops_per_token(config, 2048) \
+        == 12_280_922_112 + 805_306_368 == 13_086_228_480
+    assert FAMILY.train_flops_per_token(config, 512) \
+        == 12_280_922_112 + 201_326_592
+    assert FAMILY.attention_calls(config, 2, 2048) == {
+        "calls": 32, "batch": 2, "heads": 16, "seq_len": 2048,
+        "head_dim": 128, "causal": True}
+
+
+# -- the two "of which" readers, on a hand-made trace ------------------------
+
+LOOP = "jit(step)/jvp(LoopLM)/while/body/closed_call/LoopLM.one_pass/"
+BACK = "jit(step)/transpose(jvp(LoopLM))/while/body/closed_call/LoopLM.one_pass/"
+FUSION = "%fusion.{} = f32[8]{{0}} fusion(%p.1), kind=kLoop"
+KERNEL = ('%hvd_flash_fwd.{} = bf16[8]{{0}} custom-call(%p.1), '
+          'custom_call_target="tpu_custom_call"')
+# (instruction, microseconds, op_name), one after the other on one device.
+EVENTS = [
+    (FUSION, 100, LOOP + "layer0/q/dot_general"),
+    (FUSION, 10, LOOP + "exit_gate/hvd_loop_exit/mul"),
+    (FUSION, 15, "jit(step)/jvp(hvd_loop_exit)/mul"),
+    (FUSION, 10, "jit(step)/transpose(jvp(hvd_loop_exit))/mul"),
+    (FUSION, 60, BACK + "checkpoint/rematted_computation/lm_head/"
+                        "hvd_lm_head/dot_general"),
+    (FUSION, 105, BACK + "checkpoint/lm_head/hvd_lm_head/dot_general"),
+    (FUSION, 80, BACK + "checkpoint/rematted_computation/layer1/q/"
+                        "dot_general"),
+    # The flash forward run again is a kernel's time, not dense time.
+    (KERNEL, 40, BACK + "checkpoint/rematted_computation/layer1/"
+                        "hvd_flash_fwd/pallas_call"),
+    (FUSION, 180, BACK + "checkpoint/layer1/q/dot_general"),
+    (FUSION, 7, BACK + "exit_gate/hvd_loop_exit/mul"),
+    (FUSION, 43, "jit(step)/hvd_update/mul"),
+]
+
+
+def _trace(events):
+    out, start = [], 0.0
+    for i, (name, us, op_name) in enumerate(events):
+        out.append([name.format(i), start, us * 1e3, "", op_name, 1])
+        start += us * 1e3
+    return {"devices": {"/device:TPU:0": out}, "hlo": {}}
+
+
+def _record(events, steps=1):
+    return {"trace": {"steps": steps},
+            "of_which_trace": of_which._without_loops(_trace(events))}
+
+
+@pytest.mark.parametrize("metric, want_us", [("loop_exit_ms", 42.0),
+                                             ("recompute_ms", 140.0)])
+def test_a_reader_sums_the_dense_time_under_its_marker(metric, want_us):
+    read = CAT.module("layer_metrics", metric).read
+    assert read(_record(EVENTS)) == pytest.approx(want_us / 1e3)
+    assert read(_record(EVENTS, steps=2)) == pytest.approx(want_us / 2e3)
+    assert read(_record(EVENTS[:1] + EVENTS[8:9] + EVENTS[10:])) is None
+    assert read({"trace": {}}) is None and read({}) is None
+    assert read({"trace": {"steps": 3}, "of_which_trace": None}) is None
+
+
+def test_a_loop_is_read_through_the_events_of_its_body():
+    """The trace shows a ``while`` as one event over its body's. However
+    it comes to be named, the reading is of the body's own events."""
+    trace = _trace(EVENTS)
+    line = trace["devices"]["/device:TPU:0"]
+    line.append(["%while.10 = (s32[], f32[8]{0}) while(%tuple.4), "
+                 "condition=%cond, body=%body", line[4][1], 432e3, "",
+                 BACK + "checkpoint/rematted_computation/layer1/mul", 1])
+    kept = of_which._without_loops(trace)["devices"]["/device:TPU:0"]
+    assert len(kept) == len(EVENTS) and len(line) == len(EVENTS) + 1
+    record = {"trace": {"steps": 1},
+              "of_which_trace": of_which._without_loops(trace)}
+    assert of_which.per_step_ms(record, "rematted_computation") \
+        == pytest.approx(0.140)
+
+
+def test_the_readings_lie_inside_the_cells_partition_and_leave_it_alone():
+    names = hlo_counts.load_names()
+    before = phase_reduce.reduce_phases(_trace(EVENTS), names)["seconds"]
+    us = {p: round(s * 1e6, 6) for p, s in before.items() if s}
+    assert us == {"flash_fwd": 40.0, "lm_head": 165.0, "fwd": 125.0,
+                  "bwd": 277.0, "optimizer_update": 43.0}
+    for metric in ("loop_exit_ms", "recompute_ms"):
+        CAT.module("layer_metrics", metric).read(_record(EVENTS))
+    assert hlo_counts.load_names() == names
+    assert phase_reduce.reduce_phases(_trace(EVENTS), names)["seconds"] \
+        == before
+    assert of_which.per_step_ms(_record(EVENTS), "hvd_lm_head") \
+        == pytest.approx(0.165)
+
+
+def test_the_cell_reports_the_two_readings_and_no_other_cell_does():
+    for entry in CAT.index["workloads"]:
+        names = {m["name"] for m in CAT.metrics("per_layer", entry["name"])}
+        assert ({"loop_exit_ms", "recompute_ms"} <= names) \
+            == (entry["name"] == "ouro-2.6b-l8-s2048")
+        assert len(names) >= 21

@@ -161,8 +161,8 @@ def _constants():
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 16
-    assert set(scopes.STEP_SCOPES + scopes.FLASH_KERNELS
+    assert len(values) == len(set(values)) == 17
+    assert set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.FLASH_KERNELS
                + scopes.BUCKET_KERNELS) <= set(values)
 
 
@@ -182,7 +182,7 @@ def test_the_docs_list_the_same_names():
     with open(os.path.join(ROOT, "docs", "timeline.md")) as f:
         docs = f.read()
     listed = set(re.findall(r"`(hvd_[a-z0-9_/]+)`", docs))
-    want = set(scopes.STEP_SCOPES + scopes.FLASH_KERNELS
+    want = set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.FLASH_KERNELS
                + scopes.BUCKET_KERNELS)
     assert want <= listed
     assert {n for n in listed if not n.startswith("hvd_tpu")} <= want

@@ -22,13 +22,16 @@ from horovod_tpu.ops import pallas_kernels as pk
 BUCKET = 25_000_000  # one 100 MB fp32 gradient bucket
 # (b, S, h, d), causal: gpt_small at three lengths (S4096 is what the
 # dk/dv kernel could not hold in VMEM before it was tiled on its q side),
-# bert_large's unmasked attention.
+# bert_large's unmasked attention, the looped model's heads of 128.
 FLASH_SHAPES = {"b8_s512": ((8, 512, 12, 64), True),
                 "b4_s2048": ((4, 2048, 12, 64), True),
                 "b4_s4096": ((4, 4096, 12, 64), True),
                 "bert_b8_s512": ((8, 512, 16, 64), False),
                 # an odd head count: one head a block, on (B, H, S, D)
-                "b2_s512_h3": ((2, 512, 3, 64), True)}
+                "b2_s512_h3": ((2, 512, 3, 64), True),
+                # Ouro-2.6B: one head of 128 fills a 128-lane block, and
+                # the softmax scale (no power of two) rides on the scores
+                "ouro_b2_s2048_d128": ((2, 2048, 16, 128), True)}
 
 
 @pytest.fixture(scope="module")
