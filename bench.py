@@ -58,11 +58,6 @@ def main():
                             "vgg16", "vgg19", "inception3",
                             "vit_base", "bert_large", "bert_base",
                             "gpt_small", "gpt_medium", "gpt_tiny"])
-    p.add_argument("--overlap", action="store_true",
-                   help="readiness-ordered gradient buckets + issue-"
-                        "order chaining on the DistributedOptimizer "
-                        "(overlap=True; pairs with the latency-hiding "
-                        "XLA flags, HVD_TPU_OVERLAP_XLA_FLAGS=1)")
     p.add_argument("--mesh-shape", default="",
                    help="train over a simulated RxC (or RxMxC) device "
                         "mesh with the topology-aware collective router "
@@ -339,10 +334,7 @@ def main():
 
     import horovod_tpu as hvd
 
-    # --overlap's A/B depends on the latency-hiding/async-collective
-    # flags: the barrier chain alone fixes issue ORDER; concurrency is
-    # the scheduler's job (docs/overlap.md).
-    hvd.init(overlap_xla_flags=args.overlap)
+    hvd.init()
     platform = jax.devices()[0].platform
     n = hvd.size()
     _log(f"initialized: platform={platform} "
@@ -588,8 +580,7 @@ def _shard_decision(args, params, n) -> bool:
     (hvd.ShardedOptimizer; docs/performance.md). 'auto' consults the
     hvd.should_shard_update heuristic — replicated params at least
     HVD_TPU_AUTO_SHARD_THRESHOLD bytes and n > 1; incompatible arms
-    (single rank, Adasum routing, overlap scheduling — the sharded
-    surface has no bucket chaining) log and fall back to replicated."""
+    (single rank, Adasum routing) log and fall back to replicated."""
     import horovod_tpu as hvd
 
     if args.shard_update == "off":
@@ -599,8 +590,6 @@ def _shard_decision(args, params, n) -> bool:
         why = "single-rank world"
     elif args.route.startswith("adasum") and args.mesh_shape:
         why = "Adasum routing (sharded update reduces SUM/AVERAGE only)"
-    elif args.overlap:
-        why = "--overlap (no bucket chaining on the sharded surface)"
     if why is not None:
         if args.shard_update == "on":
             _log(f"--shard-update on ignored: {why}")
@@ -635,11 +624,6 @@ def _zero_stage_decision(args, params, n) -> int:
         why = "single-rank world"
     elif args.route.startswith("adasum") and args.mesh_shape:
         why = "Adasum routing (sharded update reduces SUM/AVERAGE only)"
-    elif stage == 1 and args.overlap:
-        # Same guard the legacy heuristic enforces: ShardedOptimizer
-        # has no bucket chaining, so running it would stamp an overlap
-        # arm that never overlapped (stages 2/3 chain internally).
-        why = "--overlap (no bucket chaining on the ZeRO-1 surface)"
     elif stage >= 2 and not args.model.startswith("gpt"):
         why = f"stage {stage} is wired for gpt_* models only here"
     elif stage >= 3 and args.moe:
@@ -678,7 +662,7 @@ def _make_tx(args, params, n, inner):
             **({"route": rt["plan"]} if rt else {}))
     else:
         tx = hvd.DistributedOptimizer(
-            inner, axis_name=hvd.rank_axis(), overlap=args.overlap,
+            inner, axis_name=hvd.rank_axis(),
             compression=args.compression,
             nonfinite_policy=_guard_policy(args),
             accum_steps=args.accum, remat_policy=args.remat_policy,
@@ -1159,7 +1143,6 @@ def _run_benchmark_inner(args, n):
         else "window_single_fetch",
         "steps_timed": total_batches,
         "remat": bool(args.remat) if is_gpt else None,
-        "overlap": bool(args.overlap),
         "compression": args.compression,
         "guard": args.guard,
         "mesh_shape": args.mesh_shape or None,

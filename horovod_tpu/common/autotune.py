@@ -7,9 +7,12 @@ bytes-per-second score with a Gaussian-process surrogate and
 expected-improvement acquisition, logging samples to HOROVOD_AUTOTUNE_LOG
 as CSV.
 
-TPU-native version: the tunables that matter under XLA are the fusion
-bucket threshold (collective launch count vs overlap granularity) and the
-hierarchical toggle; cycle time has no analog (no background thread). The
+TPU-native version: the tunables under XLA are the fusion bucket
+threshold and the hierarchical toggle; cycle time has no analog (no
+background thread). The threshold shapes the paths that reduce flat
+buckets only (``route``, ``hierarchical``, Adasum, ``int8_ef``, ZeRO's
+shards): since PR 28 the default data-parallel step reduces each
+gradient where it lies and never reads it (``optim._reduce_tree``). The
 same GP+EI machinery is implemented in NumPy over a log-spaced candidate
 grid — no LBFGS needed since the candidate space is small and discrete.
 """
@@ -37,8 +40,6 @@ _M_THRESHOLD = metrics_lib.gauge(
     "current fusion threshold the autotuner is running")
 _M_HIER = metrics_lib.gauge(
     "hvd_tpu_autotune_hierarchical", "current hierarchical toggle (0/1)")
-_M_OVERLAP = metrics_lib.gauge(
-    "hvd_tpu_autotune_overlap", "current overlap toggle (0/1)")
 _M_COMP_IDX = metrics_lib.gauge(
     "hvd_tpu_autotune_compression_index",
     "index of the current compression candidate "
@@ -76,7 +77,7 @@ _M_CONVERGED = metrics_lib.gauge(
 _M_SAMPLES = metrics_lib.counter(
     "hvd_tpu_autotune_samples_total",
     "scored samples per configuration (config = threshold|hierarchical"
-    "|overlap|compression|route|accum|remat|shard|moe_wire|pp_wire"
+    "|compression|route|accum|remat|shard|moe_wire|pp_wire"
     "|seq_wire)",
     labels=("config",))
 
@@ -94,14 +95,13 @@ class TunedPoint(NamedTuple):
 
     threshold: int
     hierarchical: bool
-    overlap: bool
     compression: str
     route: str
     accum: int        # gradient-accumulation microbatch count
     remat: str        # remat-policy name ("none"/"dots"/...)
     shard: int        # ZeRO stage (0 = replicated; 1/2/3 = docs/zero.md)
     # MoE dispatch wire format ("none"/"bf16"/"int8" — docs/moe.md);
-    # defaulted so pre-existing 8-positional constructions keep working.
+    # defaulted so pre-existing 7-positional constructions keep working.
     moe_wire: str = "none"
     # Pipeline stage-boundary send wire ("none"/"bf16"/"int8" —
     # docs/pipeline.md); defaulted for the same compatibility reason.
@@ -207,7 +207,6 @@ class Autotuner:
                  steps_per_sample: int = 10,
                  log_file: Optional[str] = None,
                  tune_hierarchical: bool = False,
-                 tune_overlap: bool = False,
                  tune_compression: bool = False,
                  compression_candidates: Sequence[str] = (
                      "none", "bf16", "int8_ef"),
@@ -235,20 +234,16 @@ class Autotuner:
         self.warmup = warmup_samples
         self.steps_per_sample = steps_per_sample
         self.log_file = log_file
-        # Joint (threshold, hierarchical, overlap, compression) space
-        # when asked — the reference's ParameterManager tunes the
-        # hierarchical toggle alongside the fusion threshold
-        # (parameter_manager.cc); the overlap toggle (readiness-ordered
-        # buckets + issue chaining, common/overlap.py) and the
-        # compression axis (reduction wire format: none / bf16 cast /
+        # Joint (threshold, hierarchical, compression) space when asked
+        # — the reference's ParameterManager tunes the hierarchical
+        # toggle alongside the fusion threshold (parameter_manager.cc);
+        # the compression axis (reduction wire format: none / bf16 cast /
         # int8_ef quantized allreduce — whether 4x fewer wire bytes beat
         # the quantize/dequant overhead is topology- and model-
-        # dependent, so measured, not guessed) are this rebuild's
-        # additions. Points are always internal 4-tuples (threshold,
-        # hierarchical, overlap, compression_index); untuned axes stay
-        # pinned at 0.
+        # dependent, so measured, not guessed) is this rebuild's
+        # addition. Points are internal index tuples in TunedPoint's
+        # field order; untuned axes stay pinned at 0.
         self.tune_hierarchical = tune_hierarchical
-        self.tune_overlap = tune_overlap
         self.tune_compression = tune_compression
         # Routing/reduction-mode axis (docs/topology.md): which WirePlan
         # (and whether Adasum replaces SUM on the slow axis) the step
@@ -311,7 +306,6 @@ class Autotuner:
         self.accum_gate = accum_gate
         self._accum_pruned = False
         hs = (0, 1) if tune_hierarchical else (0,)
-        ovs = (0, 1) if tune_overlap else (0,)
         cs = tuple(range(len(self.compression_candidates)))
         rs = tuple(range(len(self.route_candidates)))
         accs = tuple(range(len(self.accum_candidates)))
@@ -321,9 +315,9 @@ class Autotuner:
         pws = tuple(range(len(self.pp_wire_candidates)))
         sws = tuple(range(len(self.seq_wire_candidates)))
         self._space: List[Tuple[int, ...]] = [
-            (t, h, o, c, rt, a, m, s, mw, pw, sw)
+            (t, h, c, rt, a, m, s, mw, pw, sw)
             for t in self.candidates
-            for h in hs for o in ovs for c in cs for rt in rs
+            for h in hs for c in cs for rt in rs
             for a in accs for m in rms for s in shs for mw in mws
             for pw in pws for sw in sws]
         self._steps = 0
@@ -342,8 +336,6 @@ class Autotuner:
         cols = ["threshold_bytes"]
         if tune_hierarchical:
             cols.append("hierarchical")
-        if tune_overlap:
-            cols.append("overlap")
         if tune_compression:
             cols.append("compression")
         if tune_route:
@@ -381,11 +373,6 @@ class Autotuner:
             return bool(self._cur[1])
 
     @property
-    def current_overlap(self) -> bool:
-        with self._tlock:
-            return bool(self._cur[2])
-
-    @property
     def current_point(self) -> Tuple[int, bool]:
         """Atomic (threshold, hierarchical) snapshot — readers that need
         both must not take them in two lock acquisitions (a concurrent
@@ -395,85 +382,62 @@ class Autotuner:
             return self._cur[0], bool(self._cur[1])
 
     @property
-    def current_triple(self) -> Tuple[int, bool, bool]:
-        """Atomic (threshold, hierarchical, overlap) snapshot."""
-        with self._tlock:
-            return self._cur[0], bool(self._cur[1]), bool(self._cur[2])
-
-    @property
     def current_compression(self) -> str:
         with self._tlock:
-            return self.compression_candidates[self._cur[3]]
+            return self.compression_candidates[self._cur[2]]
 
     @property
     def current_route(self) -> str:
         with self._tlock:
-            return self.route_candidates[self._cur[4]]
-
-    @property
-    def current_quad(self) -> Tuple[int, bool, bool, str]:
-        """Atomic (threshold, hierarchical, overlap, compression)
-        snapshot."""
-        return self.current_quint[:4]
-
-    @property
-    def current_quint(self) -> Tuple[int, bool, bool, str, str]:
-        """Atomic (threshold, hierarchical, overlap, compression,
-        route) snapshot — the historical 5-axis point (the MFU axes
-        are on :attr:`current_full`)."""
-        with self._tlock:
-            return (self._cur[0], bool(self._cur[1]), bool(self._cur[2]),
-                    self.compression_candidates[self._cur[3]],
-                    self.route_candidates[self._cur[4]])
+            return self.route_candidates[self._cur[3]]
 
     @property
     def current_accum(self) -> int:
         with self._tlock:
-            return self.accum_candidates[self._cur[5]]
+            return self.accum_candidates[self._cur[4]]
 
     @property
     def current_remat(self) -> str:
         with self._tlock:
-            return self.remat_candidates[self._cur[6]]
+            return self.remat_candidates[self._cur[5]]
 
     @property
     def current_shard(self) -> int:
         with self._tlock:
-            return self.shard_candidates[self._cur[7]]
+            return self.shard_candidates[self._cur[6]]
 
     @property
     def current_moe_wire(self) -> str:
         with self._tlock:
-            return self.moe_wire_candidates[self._cur[8]]
+            return self.moe_wire_candidates[self._cur[7]]
 
     @property
     def current_pp_wire(self) -> str:
         with self._tlock:
-            return self.pp_wire_candidates[self._cur[9]]
+            return self.pp_wire_candidates[self._cur[8]]
 
     @property
     def current_seq_wire(self) -> str:
         with self._tlock:
-            return self.seq_wire_candidates[self._cur[10]]
+            return self.seq_wire_candidates[self._cur[9]]
 
     @property
     def current_full(self) -> TunedPoint:
-        """Atomic snapshot of the FULL tuned point (all 11 axes)."""
+        """Atomic snapshot of the FULL tuned point (all 10 axes)."""
         with self._tlock:
             return self._point_of(self._cur)
 
     def _point_of(self, cur: Tuple[int, ...]) -> TunedPoint:
         return TunedPoint(
             threshold=cur[0], hierarchical=bool(cur[1]),
-            overlap=bool(cur[2]),
-            compression=self.compression_candidates[cur[3]],
-            route=self.route_candidates[cur[4]],
-            accum=self.accum_candidates[cur[5]],
-            remat=self.remat_candidates[cur[6]],
-            shard=self.shard_candidates[cur[7]],
-            moe_wire=self.moe_wire_candidates[cur[8]],
-            pp_wire=self.pp_wire_candidates[cur[9]],
-            seq_wire=self.seq_wire_candidates[cur[10]])
+            compression=self.compression_candidates[cur[2]],
+            route=self.route_candidates[cur[3]],
+            accum=self.accum_candidates[cur[4]],
+            remat=self.remat_candidates[cur[5]],
+            shard=self.shard_candidates[cur[6]],
+            moe_wire=self.moe_wire_candidates[cur[7]],
+            pp_wire=self.pp_wire_candidates[cur[8]],
+            seq_wire=self.seq_wire_candidates[cur[9]])
 
     @property
     def done(self) -> bool:
@@ -505,30 +469,11 @@ class Autotuner:
                    seconds: float) -> Tuple[int, bool]:
         """Like feed() but returns the full (threshold, hierarchical)
         point under ONE lock acquisition."""
-        return self.feed_triple(nbytes, seconds)[:2]
-
-    def feed_triple(self, nbytes: float,
-                    seconds: float) -> Tuple[int, bool, bool]:
-        """Like feed() but returns the full (threshold, hierarchical,
-        overlap) point under ONE lock acquisition."""
-        return self.feed_quad(nbytes, seconds)[:3]
-
-    def feed_quad(self, nbytes: float,
-                  seconds: float) -> Tuple[int, bool, bool, str]:
-        """Like feed() but returns the full (threshold, hierarchical,
-        overlap, compression) point under ONE lock acquisition."""
-        return self.feed_quint(nbytes, seconds)[:4]
-
-    def feed_quint(self, nbytes: float,
-                   seconds: float) -> Tuple[int, bool, bool, str, str]:
-        """Like feed() but returns the historical 5-axis (threshold,
-        hierarchical, overlap, compression, route) point under ONE
-        lock acquisition."""
-        return tuple(self.feed_full(nbytes, seconds)[:5])
+        return tuple(self.feed_full(nbytes, seconds)[:2])
 
     def feed_full(self, nbytes: float, seconds: float) -> TunedPoint:
         """Atomic record + (if a sample completed) suggest, returning
-        the FULL 8-axis :class:`TunedPoint` under one lock acquisition
+        the FULL :class:`TunedPoint` under one lock acquisition
         — the call AutotunedStepper uses."""
         with self._tlock:
             self.record(nbytes, seconds)
@@ -537,29 +482,28 @@ class Autotuner:
             return self._point_of(self._cur)
 
     def _config_label(self, point: Tuple[int, ...]) -> str:
-        return (f"{point[0]}|{int(point[1])}|{int(point[2])}"
-                f"|{self.compression_candidates[point[3]]}"
-                f"|{self.route_candidates[point[4]]}"
-                f"|{self.accum_candidates[point[5]]}"
-                f"|{self.remat_candidates[point[6]]}|{int(point[7])}"
-                f"|{self.moe_wire_candidates[point[8]]}"
-                f"|{self.pp_wire_candidates[point[9]]}"
-                f"|{self.seq_wire_candidates[point[10]]}")
+        return (f"{point[0]}|{int(point[1])}"
+                f"|{self.compression_candidates[point[2]]}"
+                f"|{self.route_candidates[point[3]]}"
+                f"|{self.accum_candidates[point[4]]}"
+                f"|{self.remat_candidates[point[5]]}|{int(point[6])}"
+                f"|{self.moe_wire_candidates[point[7]]}"
+                f"|{self.pp_wire_candidates[point[8]]}"
+                f"|{self.seq_wire_candidates[point[9]]}")
 
     def _publish_metrics(self) -> None:
         """Mirror the live point into the metrics registry (called with
         the tuner lock held or from __init__ before threads exist)."""
         _M_THRESHOLD.set(self._cur[0])
         _M_HIER.set(self._cur[1])
-        _M_OVERLAP.set(self._cur[2])
-        _M_COMP_IDX.set(self._cur[3])
-        _M_ROUTE_IDX.set(self._cur[4])
-        _M_ACCUM.set(self.accum_candidates[self._cur[5]])
-        _M_REMAT_IDX.set(self._cur[6])
-        _M_SHARD.set(self.shard_candidates[self._cur[7]])
-        _M_MOE_WIRE_IDX.set(self._cur[8])
-        _M_PP_WIRE_IDX.set(self._cur[9])
-        _M_SEQ_WIRE_IDX.set(self._cur[10])
+        _M_COMP_IDX.set(self._cur[2])
+        _M_ROUTE_IDX.set(self._cur[3])
+        _M_ACCUM.set(self.accum_candidates[self._cur[4]])
+        _M_REMAT_IDX.set(self._cur[5])
+        _M_SHARD.set(self.shard_candidates[self._cur[6]])
+        _M_MOE_WIRE_IDX.set(self._cur[7])
+        _M_PP_WIRE_IDX.set(self._cur[8])
+        _M_SEQ_WIRE_IDX.set(self._cur[9])
         _M_CONVERGED.set(1.0 if self._done else 0.0)
 
     def _row(self, point: Tuple[int, ...]) -> List:
@@ -569,24 +513,22 @@ class Autotuner:
         row: List = [point[0]]
         if self.tune_hierarchical:
             row.append(point[1])
-        if self.tune_overlap:
-            row.append(point[2])
         if self.tune_compression:
-            row.append(self.compression_candidates[point[3]])
+            row.append(self.compression_candidates[point[2]])
         if self.tune_route:
-            row.append(self.route_candidates[point[4]])
+            row.append(self.route_candidates[point[3]])
         if self.tune_accum:
-            row.append(self.accum_candidates[point[5]])
+            row.append(self.accum_candidates[point[4]])
         if self.tune_remat:
-            row.append(self.remat_candidates[point[6]])
+            row.append(self.remat_candidates[point[5]])
         if self.tune_shard:
-            row.append(self.shard_candidates[point[7]])
+            row.append(self.shard_candidates[point[6]])
         if self.tune_moe_wire:
-            row.append(self.moe_wire_candidates[point[8]])
+            row.append(self.moe_wire_candidates[point[7]])
         if self.tune_pp_wire:
-            row.append(self.pp_wire_candidates[point[9]])
+            row.append(self.pp_wire_candidates[point[8]])
         if self.tune_seq_wire:
-            row.append(self.seq_wire_candidates[point[10]])
+            row.append(self.seq_wire_candidates[point[9]])
         return row
 
     def _log(self, point: Tuple[int, ...], score: float) -> None:
@@ -611,10 +553,10 @@ class Autotuner:
         # as log2(k) — neighboring microbatch counts genuinely are
         # neighboring configurations.
         return [math.log2(point[0]), 2.0 * point[1], 2.0 * point[2],
-                2.0 * point[3], 2.0 * point[4],
-                math.log2(max(self.accum_candidates[point[5]], 1)),
-                2.0 * point[6], 2.0 * point[7], 2.0 * point[8],
-                2.0 * point[9], 2.0 * point[10]]
+                2.0 * point[3],
+                math.log2(max(self.accum_candidates[point[4]], 1)),
+                2.0 * point[5], 2.0 * point[6], 2.0 * point[7],
+                2.0 * point[8], 2.0 * point[9]]
 
     def _maybe_prune_accum(self) -> None:
         """One-shot accumulation-space pruning, decided at the FIRST
@@ -636,7 +578,7 @@ class Autotuner:
             return
         before = len(self._space)
         self._space = [p for p in self._space
-                       if p[5] == 0 or p in self._samples]
+                       if p[4] == 0 or p in self._samples]
         logger.info(
             "autotune: step is compute-bound (StepTimer phases) — "
             "pruned %d accumulation candidates from the search space",
@@ -690,25 +632,23 @@ class Autotuner:
                     "autotune converged: fusion threshold %d MiB"
                     + (", hierarchical=%s" % bool(best[1])
                        if self.tune_hierarchical else "")
-                    + (", overlap=%s" % bool(best[2])
-                       if self.tune_overlap else "")
                     + (", compression=%s"
-                       % self.compression_candidates[best[3]]
+                       % self.compression_candidates[best[2]]
                        if self.tune_compression else "")
-                    + (", route=%s" % self.route_candidates[best[4]]
+                    + (", route=%s" % self.route_candidates[best[3]]
                        if self.tune_route else "")
-                    + (", accum=%d" % self.accum_candidates[best[5]]
+                    + (", accum=%d" % self.accum_candidates[best[4]]
                        if self.tune_accum else "")
-                    + (", remat=%s" % self.remat_candidates[best[6]]
+                    + (", remat=%s" % self.remat_candidates[best[5]]
                        if self.tune_remat else "")
-                    + (", zero_stage=%s" % self.shard_candidates[best[7]]
+                    + (", zero_stage=%s" % self.shard_candidates[best[6]]
                        if self.tune_shard else "")
-                    + (", moe_wire=%s" % self.moe_wire_candidates[best[8]]
+                    + (", moe_wire=%s" % self.moe_wire_candidates[best[7]]
                        if self.tune_moe_wire else "")
-                    + (", pp_wire=%s" % self.pp_wire_candidates[best[9]]
+                    + (", pp_wire=%s" % self.pp_wire_candidates[best[8]]
                        if self.tune_pp_wire else "")
                     + (", seq_wire=%s"
-                       % self.seq_wire_candidates[best[10]]
+                       % self.seq_wire_candidates[best[9]]
                        if self.tune_seq_wire else ""),
                     best[0] // _MB)
                 return best[0]

@@ -77,12 +77,6 @@ class Context:
 
         faults_lib.refresh_from_env()
 
-        if config.overlap_xla_flags:
-            # libtpu reads its flags once, when the backend starts (the
-            # topology discovery below).
-            from .xla_tuning import enable_overlap_scheduling
-
-            enable_overlap_scheduling()
         topo = topo_lib.discover(force_cpu_devices=config.force_cpu_devices)
         # JAX falls to the CPU without a word when the TPU fails to
         # initialise; say what this process really runs on.

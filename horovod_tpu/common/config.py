@@ -106,12 +106,6 @@ class Config:
     autotune_log: Optional[str] = None
     autotune_warmup_samples: int = 3
     autotune_steps_per_sample: int = 10
-    # Overlap scheduling (no reference knob: the reference's background
-    # thread overlaps implicitly). hvd.spmd_step asks the compiler for
-    # asynchronous all-reduces itself, per program, across TPU chips
-    # (common/xla_tuning.py); this knob hands libtpu the same flags at
-    # init, for programs spmd_step does not build. Off by default.
-    overlap_xla_flags: bool = False
     # Topology-aware collective routing (docs/topology.md). `route`
     # names the default WirePlan for the optimizer surfaces: "flat"
     # (1-D axis), "staged" (RS local -> reduce cross -> AG local),
@@ -285,7 +279,6 @@ class Config:
             "AUTOTUNE_WARMUP_SAMPLES", cls.autotune_warmup_samples)
         c.autotune_steps_per_sample = _env_int(
             "AUTOTUNE_STEPS_PER_SAMPLE", cls.autotune_steps_per_sample)
-        c.overlap_xla_flags = _env_bool("OVERLAP_XLA_FLAGS", False)
         c.route = _env("ROUTE")
         c.mesh_shape = _env("MESH_SHAPE")
         c.parallel = _env("PARALLEL")

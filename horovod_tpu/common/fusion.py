@@ -11,8 +11,8 @@ pure data-movement that XLA fuses/elides where possible, and each bucket
 becomes a single large AllReduce on the wire — the exact latency win fusion
 buys the reference, with no hand-managed buffer.
 
-Bucket *plans* are deterministic functions of (shapes, dtypes, threshold,
-order) so every rank computes the identical plan without negotiation — the
+Bucket *plans* are deterministic functions of (shapes, dtypes, threshold)
+so every rank computes the identical plan without negotiation — the
 property the reference's coordinator exists to enforce (controller.cc:63-358)
 falls out for free in SPMD.
 
@@ -23,21 +23,12 @@ than these buckets' copies in and out (PERF.md, PR 28). The buckets stay
 for what needs a flat buffer by construction: block-scaled int8, the mesh
 router's and the staged pipeline's per-axis shards, Adasum's per-bucket
 dot products, ZeRO's positional shards, the eager engine.
-
-``order`` is the readiness lever for those paths: leaves are visited in
-reverse-VJP completion order so each bucket *closes* — and its collective
-can be issued — as early as possible during backprop, instead of waiting on
-a bucket that mixes early- and late-ready gradients. ``"reverse"`` (reverse
-flatten order) is the default proxy for completion order — backprop produces
-the LAST layer's gradients first, and flatten order tracks layer order for
-the standard nested-dict parameter trees; a measured order from a timeline
-trace plugs in via :func:`measured_order`.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
+from typing import Any, Callable, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -45,12 +36,6 @@ import numpy as np
 
 from . import metrics as metrics_lib
 from . import scopes
-
-# Leaf-visit orders understood by plan_fusion (besides an explicit
-# permutation): flatten order (the historical default) and reverse
-# flatten order (the readiness proxy used by overlap=True).
-ORDER_FLATTEN = "flatten"
-ORDER_REVERSE = "reverse"
 
 # Telemetry (docs/metrics.md): plan/assign run at trace time (host
 # Python), so these record per compiled program, not per step. Guarded
@@ -91,11 +76,6 @@ class FusionPlan:
     buckets: Tuple[Bucket, ...]
     treedef: Any
     num_leaves: int
-    # The leaf-visit order the plan was built with ("flatten"/"reverse"/
-    # "explicit"). Buckets are emitted in closing order, so under
-    # "reverse" bucket 0 covers the LAST leaves — the first gradients
-    # backprop completes.
-    order: str = ORDER_FLATTEN
     # Per-bucket wire format for quantized reduction, parallel to
     # ``buckets`` ("int8"/"bf16"/"none"); None until
     # :func:`assign_wire_dtypes` stamps the plan. Part of the plan (not
@@ -104,52 +84,9 @@ class FusionPlan:
     wire_dtypes: Optional[Tuple[str, ...]] = None
 
 
-def _resolve_order(num_leaves: int,
-                   order: Union[str, Sequence[int], None]) -> List[int]:
-    """Leaf-visit permutation from an order spec. Explicit permutations
-    must cover every leaf exactly once — a silent subset would bucket
-    leaves under the wrong readiness rank on some trees only."""
-    if order is None or order == ORDER_FLATTEN:
-        return list(range(num_leaves))
-    if order == ORDER_REVERSE:
-        return list(range(num_leaves - 1, -1, -1))
-    perm = [int(i) for i in order]
-    if sorted(perm) != list(range(num_leaves)):
-        raise ValueError(
-            f"order must be '{ORDER_FLATTEN}', '{ORDER_REVERSE}', or a "
-            f"permutation of range({num_leaves}); got {order!r}")
-    return perm
-
-
-def measured_order(tree, ready_names: Sequence[str]) -> List[int]:
-    """Leaf permutation from a MEASURED readiness order (the
-    timeline-trace hook): ``ready_names`` lists leaf path names
-    (``jax.tree_util.keystr`` form, e.g. ``"['layer0']['w']"``) earliest-
-    ready first — see :func:`common.timeline.readiness_order_from_trace`.
-    Matched leaves come first in measured order; unmeasured leaves follow
-    in reverse flatten order (the proxy). Deterministic given the same
-    (tree, ready_names) on every rank — ship the measured list with the
-    job config, never measure per-rank."""
-    paths_leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
-    names = [jax.tree_util.keystr(p) for p, _ in paths_leaves]
-    index = {n: i for i, n in enumerate(names)}
-    seen = set()
-    perm: List[int] = []
-    for n in ready_names:
-        i = index.get(n)
-        if i is not None and i not in seen:
-            perm.append(i)
-            seen.add(i)
-    for i in range(len(names) - 1, -1, -1):
-        if i not in seen:
-            perm.append(i)
-    return perm
-
-
 def plan_fusion(tree, threshold_bytes: int,
-                order: Union[str, Sequence[int], None] = ORDER_FLATTEN,
                 _telemetry: bool = True) -> FusionPlan:
-    """Greedy same-dtype bucketing in ``order`` (reference fuses in
+    """Greedy same-dtype bucketing in flatten order (reference fuses in
     response order up to the threshold, controller.cc:686-809).
 
     The bucket-id assignment runs in the native planner
@@ -157,44 +94,33 @@ def plan_fusion(tree, threshold_bytes: int,
     for 100k-leaf LLM trees the O(n) pass stays off the Python profile.
     The Python fallback implements byte-identical semantics (same
     per-dtype running bucket, same byte threshold) so plans never diverge
-    across ranks with mixed availability. Leaf permutation happens on the
-    Python side, so both paths see the same visit sequence.
+    across ranks with mixed availability.
 
-    Under a readiness order (``"reverse"`` or explicit) buckets are
-    returned in CLOSING order — sorted by the visit position of each
-    bucket's LAST leaf, the moment all of its gradients exist — so the
-    earliest-closing bucket (backprop's first-finished gradients) is
-    bucket 0 and issuing collectives in bucket order IS issuing them in
-    readiness order, including for mixed-dtype trees where a bucket
-    opened early keeps absorbing its dtype's leaves and closes late.
-    The default ``"flatten"`` order keeps the historical bucket-id
-    emission: sharded optimizer state (ZeRO-1/FSDP) is positionally
-    indexed by ``plan.buckets``, so the default layout must stay stable
-    across releases.
+    Buckets are emitted in bucket-id (opening) order: sharded optimizer
+    state (ZeRO-1/FSDP) is positionally indexed by ``plan.buckets``, so
+    the plan is a checkpoint layout and must stay stable across
+    releases.
     """
     leaves, treedef = jax.tree.flatten(tree)
     leaves = [l if hasattr(l, "dtype") else jnp.asarray(l) for l in leaves]
     elem_counts = [int(np.prod(l.shape)) if l.shape else 1 for l in leaves]
     itemsizes = [np.dtype(l.dtype).itemsize for l in leaves]
     dtype_strs = [str(l.dtype) for l in leaves]
-    visit = _resolve_order(len(leaves), order)
     dtype_codes = {}
-    for i in visit:
-        dtype_codes.setdefault(dtype_strs[i], len(dtype_codes))
+    for d in dtype_strs:
+        dtype_codes.setdefault(d, len(dtype_codes))
 
     from ..native import plan_fusion_native
 
     bucket_ids = plan_fusion_native(
-        [elem_counts[i] for i in visit],
-        [dtype_codes[dtype_strs[i]] for i in visit],
-        [itemsizes[i] for i in visit],
+        elem_counts, [dtype_codes[d] for d in dtype_strs], itemsizes,
         threshold_bytes)
     if bucket_ids is None:
         # Python fallback — mirror of fusion_planner.cc.
         open_buckets = {}  # dtype -> [bucket_id, bytes_used]
         next_bucket = 0
         bucket_ids = []
-        for i in visit:
+        for i in range(len(leaves)):
             nbytes = elem_counts[i] * itemsizes[i]
             o = open_buckets.get(dtype_strs[i])
             if o is None:
@@ -210,30 +136,15 @@ def plan_fusion(tree, threshold_bytes: int,
             bucket_ids.append(o[0])
 
     by_bucket = {}
-    close_pos = {}
-    for pos, b in enumerate(bucket_ids):
-        by_bucket.setdefault(b, []).append(visit[pos])
-        close_pos[b] = pos  # last visit position = when the bucket closes
-    # Readiness orders emit in CLOSING order, not bucket-id (opening)
-    # order: with interleaved dtypes a bucket opened early can close
-    # late (it keeps absorbing leaves of its dtype), and issuing by
-    # opening order would pin an early-ready bucket's collective behind
-    # it. The historical "flatten" order keeps id-order emission — the
-    # ZeRO-1/FSDP sharded-state layout is positionally indexed by
-    # plan.buckets, and reordering the default plan would silently
-    # misalign pre-existing sharded checkpoints on mixed-dtype trees.
-    readiness = not (order is None or order == ORDER_FLATTEN)
-    key = (lambda kv: (close_pos[kv[0]], kv[0])) if readiness \
-        else (lambda kv: kv[0])
+    for i, b in enumerate(bucket_ids):
+        by_bucket.setdefault(b, []).append(i)
     buckets = [
         Bucket(tuple(idxs),
                tuple(tuple(leaves[i].shape) for i in idxs),
                leaves[idxs[0]].dtype,
                sum(elem_counts[i] for i in idxs))
-        for b, idxs in sorted(by_bucket.items(), key=key)
+        for b, idxs in sorted(by_bucket.items())
     ]
-    order_tag = order if isinstance(order, str) and order in (
-        ORDER_FLATTEN, ORDER_REVERSE) else "explicit"
     # ``_telemetry=False`` suppresses the metric bumps for plans built
     # purely to PRICE an already-planned program (the eager engine's
     # byte accounting) — otherwise every grouped signature counts twice.
@@ -245,8 +156,7 @@ def plan_fusion(tree, threshold_bytes: int,
                          * np.dtype(b.dtype).itemsize / threshold_bytes)
                      for b in buckets]
             _M_FILL.set(sum(fills) / len(fills))
-    return FusionPlan(tuple(buckets), treedef, len(leaves),
-                      order=order_tag)
+    return FusionPlan(tuple(buckets), treedef, len(leaves))
 
 
 # Wire formats a bucket can ride in a quantized reduction.

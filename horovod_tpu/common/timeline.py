@@ -43,38 +43,6 @@ UNFUSE = "MEMCPY_OUT_FUSION_BUFFER"
 RECOVERY = "RECOVERY"
 
 
-def readiness_order_from_trace(filename: str,
-                               activity: Optional[str] = None):
-    """Tensor names from a chrome-trace file, earliest first event first —
-    the measured-order hook for readiness bucketing
-    (:func:`common.fusion.measured_order` consumes the list).
-
-    A traced training step records one event stream per tensor (the
-    ``cat``/``tid`` fields carry the tensor name); the first timestamp a
-    tensor appears at is its observed readiness. ``activity`` optionally
-    restricts to one activity name (e.g. ``XLA_ALLREDUCE``) so queue-time
-    noise from other phases doesn't reorder the list. Measure ONCE, ship
-    the resulting list with the job config — per-rank measurement would
-    produce diverged bucket plans.
-    """
-    with open(filename) as f:
-        data = json.load(f)
-    events = data.get("traceEvents", []) if isinstance(data, dict) else data
-    first = {}
-    for e in events:
-        if not isinstance(e, dict) or e.get("ph") not in ("B", "X", "i"):
-            continue
-        if activity is not None and e.get("name") != activity:
-            continue
-        name = e.get("cat") or e.get("tid")
-        if not name or name == "marker":
-            continue
-        ts = float(e.get("ts", 0.0))
-        if name not in first or ts < first[name]:
-            first[name] = ts
-    return sorted(first, key=lambda n: (first[n], n))
-
-
 class Timeline:
     """Writes chrome-trace JSON events; safe to call from any thread.
 

@@ -32,22 +32,20 @@ and measured on a v5e (PERF.md, PR 28):
 
 ``hvd.spmd_step`` passes the set per program
 (:func:`step_compiler_options`), for a TPU mesh of more than one device
-only. The same tuple can be merged into ``LIBTPU_INIT_ARGS``, the
-variable libtpu itself reads, for programs ``spmd_step`` does not build
-(:func:`enable_overlap_scheduling`; ``HVD_TPU_OVERLAP_XLA_FLAGS=1`` has
-``hvd.init()`` do it): user-set values always win and re-applying is a
-no-op. The flags never go into ``XLA_FLAGS``: jaxlib parses that
-variable on every backend and aborts the process on a name it does not
-know, which is every one of these. libtpu reads ``LIBTPU_INIT_ARGS``
-once, at backend initialization, and it too aborts on an unknown name;
-as a compile option an unknown name is an ordinary error.
+only, and that is the only route: as a compile option an unknown name
+is an ordinary error, where libtpu aborts the process on an unknown
+name in ``LIBTPU_INIT_ARGS`` (which it reads once, at backend
+initialization) and jaxlib does the same for ``XLA_FLAGS`` on every
+backend. A flag the user has set in ``LIBTPU_INIT_ARGS`` is left to
+that value.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Mapping, MutableMapping, Optional, Tuple
+from typing import Mapping, Optional, Tuple
 
+# Read, never written: a flag the user set there keeps the user's value.
 LIBTPU_ENV = "LIBTPU_INIT_ARGS"
 
 # (flag, value) pairs: what ISSUE 28's chip runs kept. Each of the first
@@ -72,36 +70,6 @@ def flag_name(token: str) -> str:
     return token.split("=", 1)[0]
 
 
-def merge_flags(existing: str,
-                flags: Tuple[Tuple[str, str], ...]) -> str:
-    """Append each flag not already present (by NAME — a user-set value
-    for the same flag wins regardless of what it is). Existing tokens
-    keep their order; merged output is stable under re-merging."""
-    tokens = existing.split()
-    present = {flag_name(t) for t in tokens}
-    additions = [f"{name}={value}" for name, value in flags
-                 if name not in present]
-    return " ".join(tokens + additions)
-
-
-def enable_overlap_scheduling(
-        env: Optional[MutableMapping[str, str]] = None,
-        extra_flags: Tuple[Tuple[str, str], ...] = ()) -> str:
-    """Merge the TPU overlap flag set (plus ``extra_flags``) into
-    ``env['LIBTPU_INIT_ARGS']`` and return the resulting string.
-
-    Safe to call repeatedly — a second call changes nothing — and safe
-    to call with user flags already present: only flags the user has NOT
-    set are appended.
-    """
-    if env is None:
-        env = os.environ
-    merged = merge_flags(env.get(LIBTPU_ENV, ""),
-                         TPU_OVERLAP_FLAGS + tuple(extra_flags))
-    env[LIBTPU_ENV] = merged
-    return merged
-
-
 def step_compiler_options(devices, env: Optional[Mapping[str, str]] = None
                           ) -> Optional[dict]:
     """The overlap set as ``jax.jit(compiler_options=...)`` for ONE
@@ -119,11 +87,3 @@ def step_compiler_options(devices, env: Optional[Mapping[str, str]] = None
     return {name.lstrip("-"): value for name, value in TPU_OVERLAP_FLAGS
             if name not in present} or None
 
-
-def overlap_flags_active(env: Optional[Mapping[str, str]] = None) -> bool:
-    """True iff every overlap flag is present in ``LIBTPU_INIT_ARGS`` (by
-    name — the user may have pinned different values)."""
-    if env is None:
-        env = os.environ
-    present = {flag_name(t) for t in env.get(LIBTPU_ENV, "").split()}
-    return all(name in present for name, _ in TPU_OVERLAP_FLAGS)

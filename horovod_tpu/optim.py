@@ -260,28 +260,21 @@ def _reduce_tree(grads, op: C.ReduceOp, axis_name: str, compression,
                  fusion_threshold: int, prescale: float = 1.0,
                  postscale: float = 1.0, hierarchical: bool = False,
                  local_axis: str = "local", cross_axis: str = "cross",
-                 quantized_cross: bool = False,
-                 overlap: Optional[bool] = None,
-                 bucket_order=None, route=None):
-    """Fused (bucketed) allreduce of a gradient pytree over the mesh axis.
+                 quantized_cross: bool = False, route=None):
+    """Allreduce of a gradient pytree over the mesh axis, in one of two
+    shapes chosen from what the trace shows.
 
-    ``overlap`` decides the program's shape. ``None`` (the default)
-    observes: where the flat rank axis is bound over more than one rank,
-    the op is SUM/AVERAGE and the compressor acts on each element alone
-    (identity, a cast), every leaf is reduced WHERE IT LIES — no flat
-    bucket, so no leaf waits for a ``concatenate`` of its bucket's last
-    leaf, and the compiler may run each all-reduce under the rest of
-    the backward (common/xla_tuning.py). The same numbers are summed in
-    the same precision: bitwise the flat buckets' result. ``False`` is
-    the flat-bucket program in flatten order (what every path was before
-    ISSUE 28; the equivalence tests' twin). ``True`` asks for the
-    overlapped form explicitly; on paths that need a flat buffer by
-    construction (``route``, ``hierarchical``, Adasum, a compressor that
-    is not per-element) that is readiness-ordered buckets (reverse
-    flatten, or ``bucket_order`` from ``fusion.measured_order``) issued
-    through an ``optimization_barrier`` chain (common/overlap.py), and
-    ``None`` leaves those paths as they were: bucket composition is part
-    of their numerics (per-bucket dots, block boundaries).
+    Where the flat rank axis is bound over more than one rank, the op is
+    SUM/AVERAGE and the compressor acts on each element alone (identity,
+    a cast), every leaf is reduced WHERE IT LIES: no leaf waits for a
+    ``concatenate`` of its bucket's last leaf, and the compiler may run
+    each all-reduce under the rest of the backward
+    (common/xla_tuning.py). The same numbers are summed in the same
+    precision, so this is bitwise the flat buckets' result. Every other
+    path goes through flat buckets in flatten order
+    (``fusion.fused_apply``), because bucket composition is part of its
+    numerics: block-scaled payloads, the router's and the staged
+    pipeline's per-axis shards, Adasum's per-bucket dots.
 
     ``route`` (a :class:`~.ops.collectives.WirePlan`) sends every bucket
     through the topology-aware router (``collectives.mesh_allreduce``):
@@ -361,17 +354,10 @@ def _reduce_tree(grads, op: C.ReduceOp, axis_name: str, compression,
         return compression.decompress(w, ctx)
 
     fn = one if bound else identity_with_scales
-    # What the code can see decides the program's shape, never a flag: a
-    # linear op over the flat rank axis with a per-element wire sums the
-    # same numbers leaf by leaf as over their concatenation. Everything
-    # else needs its flat buffer (block-scaled payloads, the router's and
-    # the staged pipeline's per-axis shards, Adasum's per-bucket dots).
     in_place = (route is None and not hierarchical
                 and op in (C.ReduceOp.SUM, C.ReduceOp.AVERAGE)
                 and getattr(compression, "per_element", False))
-    if overlap is None:
-        overlap = in_place and bound and jax.lax.axis_size(axis_name) > 1
-    if overlap and bound and in_place:
+    if in_place and bound and jax.lax.axis_size(axis_name) > 1:
         # Nothing ties a leaf to a concatenate that waits for the last
         # leaf of a bucket, and its 1/n fuses into the update that reads
         # it. When each all-reduce starts is the compiler's business
@@ -379,14 +365,6 @@ def _reduce_tree(grads, op: C.ReduceOp, axis_name: str, compression,
         # bought nothing on the chip and cost memory under asynchronous
         # all-reduces (PERF.md, PR 28).
         return jax.tree.map(lambda g: fn(jnp.asarray(g)), grads)
-    if overlap and bound:
-        from .common import overlap as overlap_lib
-
-        order = bucket_order if bucket_order is not None \
-            else fusion_lib.ORDER_REVERSE
-        return overlap_lib.fused_apply_overlapped(grads, fn,
-                                                  fusion_threshold,
-                                                  order=order)
     return fusion_lib.fused_apply(grads, fn, fusion_threshold)
 
 
@@ -486,25 +464,22 @@ def _ef_key(step, bucket_index: int):
 
 def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
                     fusion_threshold: int, prescale: float = 1.0,
-                    postscale: float = 1.0, overlap: bool = False,
-                    bucket_order=None,
+                    postscale: float = 1.0,
                     quantize_min_bytes: Optional[int] = None,
                     route=None):
     """Fused QUANTIZED allreduce of a gradient pytree with error
     feedback. Returns ``(reduced_tree, new_residual_tree)``.
 
-    Buckets are planned exactly like :func:`_reduce_tree` (same
-    threshold; reverse/readiness order under ``overlap``) and then
-    stamped with per-bucket wire decisions
-    (``fusion.assign_wire_dtypes``): large float buckets go through
+    Buckets are planned like :func:`_reduce_tree`'s flat path (same
+    threshold, flatten order) and then stamped with per-bucket wire
+    decisions (``fusion.assign_wire_dtypes``): large float buckets go
+    through
     ``collectives.quantized_allreduce`` with this step's corrected
     gradient ``g + residual`` and a per-(step, bucket) stochastic-
     rounding key; their returned local quantization error becomes the
     next residual. Small float buckets ride a bf16 cast (no residual —
     bf16 keeps fp32's exponent range and the cast error is far below the
-    int8 rounding floor); integer buckets ride untouched. ``overlap``
-    chains the per-bucket collectives in issue order (common/overlap.py)
-    exactly like the unquantized path.
+    int8 rounding floor); integer buckets ride untouched.
 
     ``route`` (a WirePlan) sends each bucket through the mesh router
     instead of the flat axis: int8-eligible buckets run
@@ -525,11 +500,8 @@ def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
         route = None  # flat mesh is live — reduce flat (see _reduce_tree)
     bound = _axes_bound(*(route.axis_names if route is not None
                           else (axis_name,)))
-    order = (bucket_order if bucket_order is not None
-             else (fusion_lib.ORDER_REVERSE if overlap
-                   else fusion_lib.ORDER_FLATTEN))
-    plan = fusion_lib.plan_fusion(grads, fusion_threshold, order=order)
-    plan = fusion_lib.assign_wire_dtypes(plan, qmin)
+    plan = fusion_lib.assign_wire_dtypes(
+        fusion_lib.plan_fusion(grads, fusion_threshold), qmin)
     g_flats = fusion_lib.fuse(grads, plan)
     r_flats = fusion_lib.fuse(residual, plan)
     reducible = (C.ReduceOp.SUM, C.ReduceOp.AVERAGE, C.ReduceOp.ADASUM)
@@ -584,15 +556,7 @@ def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
             return C._apply_scale(w, postscale), r
         return C.allreduce(g, op, axis_name, prescale, postscale), r
 
-    outs = []
-    token = None
-    for i, (g, r) in enumerate(zip(g_flats, r_flats)):
-        if overlap and bound and token is not None:
-            g, token = jax.lax.optimization_barrier((g, token))
-        y, res = one(i, g, r)
-        outs.append((y, res))
-        if overlap and bound:
-            token = y
+    outs = [one(i, g, r) for i, (g, r) in enumerate(zip(g_flats, r_flats))]
     reduced = fusion_lib.unfuse([y for y, _ in outs], plan)
     new_residual = fusion_lib.unfuse([res for _, res in outs], plan)
     return reduced, new_residual
@@ -840,8 +804,6 @@ def DistributedOptimizer(optimizer,
                          local_axis: str = "local",
                          cross_axis: str = "cross",
                          quantized_cross: bool = False,
-                         overlap: Optional[bool] = None,
-                         bucket_order=None,
                          quantize_min_bucket_bytes: Optional[int] = None,
                          nonfinite_policy: Optional[str] = None,
                          route=None,
@@ -883,8 +845,8 @@ def DistributedOptimizer(optimizer,
     The collective round, the non-finite guard agreement, and the
     int8_ef error-feedback/stochastic-rounding advance then all run
     exactly ONCE per effective step by construction — accumulation
-    composes with ``overlap``/``compression``/``route``/
-    ``nonfinite_policy`` unchanged. Mutually exclusive with the legacy
+    composes with ``compression``/``route``/``nonfinite_policy``
+    unchanged. Mutually exclusive with the legacy
     ``backward_passes_per_step`` aggregation.
 
     ``quantized_cross`` (requires ``hierarchical``) carries the DCN hop
@@ -892,20 +854,18 @@ def DistributedOptimizer(optimizer,
     quantized allreduce (collectives.quantized_hierarchical_allreduce);
     gradients land within block-absmax rounding error of the exact sum.
 
-    ``overlap`` (default ``None``: observe). Under ``hvd.spmd_step`` over
-    more than one rank, with a SUM/AVERAGE op and ``compression`` none or
-    a cast, each gradient is reduced where it lies (no flat buckets, no
-    copies in or out), and across TPU chips ``spmd_step`` compiles the
-    step with asynchronous all-reduces, so part of the reduction runs
-    under the backward (common/xla_tuning.py; measured in PERF.md, PR
-    28: 107.6 -> 95.1 ms a step for BERT-large on four v5e chips).
-    Bitwise the flat buckets' gradients. ``overlap=False`` keeps the
-    flat-bucket program; ``overlap=True`` on the paths that need a flat
-    buffer (``route``/``hierarchical``/``quantized_cross``, Adasum,
-    ``int8_ef``) plans buckets in readiness order and chains their
-    collectives (common/overlap.py), with ``bucket_order`` optionally
-    pinning a measured leaf permutation (``fusion.measured_order``);
-    same numerics as ``overlap=False`` wherever the sum is per element.
+    The reduction's shape is chosen from what the trace shows, not by
+    an option. Under ``hvd.spmd_step`` over more than one rank, with a
+    SUM/AVERAGE op and ``compression`` none or a cast, each gradient is
+    reduced where it lies (no flat buckets, no copies in or out), and
+    across TPU chips ``spmd_step`` compiles the step with asynchronous
+    all-reduces, so part of the reduction runs under the backward
+    (common/xla_tuning.py; measured in PERF.md, PR 28: 107.6 -> 95.1 ms
+    a step for BERT-large on four v5e chips). Bitwise the flat buckets'
+    gradients. The paths whose numerics depend on the bucket
+    (``route``/``hierarchical``/``quantized_cross``, Adasum,
+    ``int8_ef``) reduce flat buckets of ``fusion_threshold_bytes`` in
+    flatten order (docs/overlap.md).
 
     ``compression`` accepts a Compressor class, a name
     (``"bf16"``/``"int8_ef"``/...), or None — the configured default
@@ -919,7 +879,7 @@ def DistributedOptimizer(optimizer,
     Only fused buckets of at least ``quantize_min_bucket_bytes``
     (default: the HVD_TPU_QUANTIZE_MIN_BYTES knob, 64 KiB) are
     quantized — smaller float buckets ride bf16. Requires a SUM/AVERAGE
-    op; composes with ``overlap`` but not with ``hierarchical`` (use
+    op; does not compose with ``hierarchical`` (use
     ``quantized_cross`` for the int8 DCN hop of the staged pipeline).
 
     ``nonfinite_policy`` (None → ``HVD_TPU_NONFINITE_POLICY`` /
@@ -946,8 +906,7 @@ def DistributedOptimizer(optimizer,
     with ``compression="int8_ef"`` (the residual rides the linear
     phases), with ``op=hvd.Adasum`` (hierarchical Adasum: fast axes
     averaged, the adaptive recursion runs on shards over the slow axis
-    with fast-axis-psum-med scalars), and with ``overlap`` (each
-    chained bucket routes independently). Supersedes the legacy
+    with fast-axis-psum-med scalars). Supersedes the legacy
     ``hierarchical``/``quantized_cross`` booleans — passing both
     raises.
 
@@ -963,7 +922,7 @@ def DistributedOptimizer(optimizer,
     params). Feed it the gradients of
     ``parallel.pipeline.pipeline_accumulate_gradients`` (the 1F1B
     schedule with the same ``(value, grads)`` contract as
-    ``accumulate``). Composes with ``compression``/``overlap``/
+    ``accumulate``). Composes with ``compression``/
     ``zero_stage`` (ZeRO shard grids then span the dp axis, so
     stage-2/3 shards live PER PIPELINE STAGE); supersedes
     ``axis_name``/``route`` — passing an explicit route alongside
@@ -1041,8 +1000,7 @@ def DistributedOptimizer(optimizer,
             fusion_threshold_bytes=fusion_threshold_bytes,
             compression=compression, nonfinite_policy=nonfinite_policy,
             route=route, accum_steps=accum_steps,
-            remat_policy=remat_policy, overlap=True,
-            bucket_order=bucket_order, parallel=pspec)
+            remat_policy=remat_policy, parallel=pspec)
 
     compression = _resolve_compression(compression)
     _check_reduce_safe(compression)
@@ -1107,8 +1065,7 @@ def DistributedOptimizer(optimizer,
         return _reduce_tree(grads, op, axis_name, compression,
                             fusion_threshold_bytes, prescale_factor,
                             postscale_factor, hierarchical, local_axis,
-                            cross_axis, quantized_cross, overlap,
-                            bucket_order, route)
+                            cross_axis, quantized_cross, route)
 
     # Core transformation: reduce + inner update (+ the error-feedback
     # residual/step state when the compressor declares it). The k>1
@@ -1133,7 +1090,7 @@ def DistributedOptimizer(optimizer,
             reduced, new_res = _reduce_tree_ef(
                 grads, state.residual, state.step, op, axis_name,
                 fusion_threshold_bytes, prescale_factor, postscale_factor,
-                overlap, bucket_order, quantize_min_bucket_bytes, route)
+                quantize_min_bucket_bytes, route)
         with jax.named_scope(scopes.UPDATE):
             updates, new_inner = optimizer.update(reduced, state.inner,
                                                   params, **extra)
@@ -1233,8 +1190,6 @@ def DistributedGradFn(grad_fn: Callable,
                       fusion_threshold_bytes: Optional[int] = None,
                       has_value: bool = False,
                       reduce_value: bool = True,
-                      overlap: Optional[bool] = None,
-                      bucket_order=None,
                       quantize_min_bucket_bytes: Optional[int] = None,
                       nonfinite_policy: Optional[str] = None,
                       route=None,
@@ -1250,9 +1205,9 @@ def DistributedGradFn(grad_fn: Callable,
     instead of tuple-sniffing so ``jax.grad(loss, argnums=(0, 1))`` (a
     tuple of gradients) is never misclassified.
 
-    ``overlap``/``bucket_order``: as on :func:`DistributedOptimizer` —
-    by default each gradient reduced where it lies wherever that sums
-    the same numbers; the program's shape only, identical numerics.
+    The reduction's shape is chosen as on :func:`DistributedOptimizer`:
+    each gradient reduced where it lies wherever that sums the same
+    numbers, flat buckets elsewhere.
 
     ``accum_steps`` (EXPLICIT-ONLY on this surface: it changes how the
     first argument is interpreted, so the ``HVD_TPU_ACCUM_STEPS`` env
@@ -1268,7 +1223,7 @@ def DistributedGradFn(grad_fn: Callable,
 
     The batch args are split into k microbatches along their leading
     dim, gradients accumulate in fp32 under ``lax.scan``, and the
-    REDUCTION (with overlap / int8_ef error feedback / route / the
+    REDUCTION (with int8_ef error feedback / route / the
     non-finite guard agreement) runs exactly once on the accumulated
     mean — one collective round and one guard agreement per effective
     step. ``has_value=False`` simply drops the (already computed) loss
@@ -1344,8 +1299,7 @@ def DistributedGradFn(grad_fn: Callable,
 
     def reduce_grads(grads):
         return _reduce_tree(grads, op, axis_name, compression,
-                            fusion_threshold_bytes, overlap=overlap,
-                            bucket_order=bucket_order, route=route)
+                            fusion_threshold_bytes, route=route)
 
     def _reduce_value(val):
         if not reduce_value:
@@ -1380,8 +1334,7 @@ def DistributedGradFn(grad_fn: Callable,
                 res, stp = carry
                 red, new_res = _reduce_tree_ef(
                     g, res, stp, op, axis_name,
-                    fusion_threshold_bytes, overlap=overlap,
-                    bucket_order=bucket_order,
+                    fusion_threshold_bytes,
                     quantize_min_bytes=quantize_min_bucket_bytes,
                     route=route)
                 return red, (new_res, stp + 1)
@@ -1447,7 +1400,10 @@ class AutotunedStepper:
     tuning (parameter_manager.cc: each cycle scores bytes/sec and may
     change the fusion threshold; subsequent cycles fuse differently).
     Under XLA a threshold change means a different bucket plan, i.e. a
-    retrace — so the stepper owns the (re)build::
+    retrace — so the stepper owns the (re)build. The plan exists only on
+    the paths that reduce flat buckets (``route``, ``hierarchical``,
+    Adasum, ``int8_ef``, ZeRO); the default step reduces each gradient
+    in place and builds the same program at every threshold::
 
         def build(threshold_bytes):
             tx = hvd.DistributedOptimizer(optax.sgd(0.01),
@@ -1491,22 +1447,20 @@ class AutotunedStepper:
         self._threshold = tuner.current
         # Joint tuning (reference ParameterManager's hierarchical toggle):
         # build_step then takes (threshold, hierarchical). With a
-        # tune_overlap tuner the signature widens once more to
-        # (threshold, hierarchical, overlap), with tune_compression to
-        # (threshold, hierarchical, overlap, compression), and with
+        # tune_compression tuner the signature widens to
+        # (threshold, hierarchical, compression), and with
         # tune_route to (..., route) — route is the axis-order/
         # reduction-mode candidate ("flat"/"staged"/"staged_int8"/
         # "adasum"; docs/topology.md) — the full point the (re)built
         # step must agree on across ranks.
         self._joint = getattr(tuner, "tune_hierarchical", False)
-        self._joint_overlap = getattr(tuner, "tune_overlap", False)
         self._joint_comp = getattr(tuner, "tune_compression", False)
         self._joint_route = getattr(tuner, "tune_route", False)
         # MFU dimensions (docs/performance.md): accumulation microbatch
         # count, remat policy, weight-update sharding. When ANY of them
         # is tuned, build_step receives the whole
         # :class:`~.common.autotune.TunedPoint` instead of the
-        # positional cascade — eight positional args would be
+        # positional cascade — seven positional args would be
         # unreadable at every call site.
         self._joint_accum = getattr(tuner, "tune_accum", False)
         self._joint_remat = getattr(tuner, "tune_remat", False)
@@ -1520,8 +1474,6 @@ class AutotunedStepper:
         # into its pipeline_accumulate_gradients(wire=) construction.
         self._joint_pp_wire = getattr(tuner, "tune_pp_wire", False)
         self._hier = (tuner.current_hierarchical if self._joint else False)
-        self._ovl = (tuner.current_overlap if self._joint_overlap
-                     else False)
         self._comp = (tuner.current_compression if self._joint_comp
                       else "none")
         self._route = (tuner.current_route if self._joint_route
@@ -1551,18 +1503,14 @@ class AutotunedStepper:
 
             return self._build(TunedPoint(
                 threshold=self._threshold, hierarchical=self._hier,
-                overlap=self._ovl, compression=self._comp,
-                route=self._route, accum=self._accum, remat=self._remat,
-                shard=self._shard, moe_wire=self._moe_wire,
-                pp_wire=self._pp_wire))
+                compression=self._comp, route=self._route,
+                accum=self._accum, remat=self._remat, shard=self._shard,
+                moe_wire=self._moe_wire, pp_wire=self._pp_wire))
         if self._joint_route:
-            return self._build(self._threshold, self._hier, self._ovl,
-                               self._comp, self._route)
+            return self._build(self._threshold, self._hier, self._comp,
+                               self._route)
         if self._joint_comp:
-            return self._build(self._threshold, self._hier, self._ovl,
-                               self._comp)
-        if self._joint_overlap:
-            return self._build(self._threshold, self._hier, self._ovl)
+            return self._build(self._threshold, self._hier, self._comp)
         if self._joint:
             return self._build(self._threshold, self._hier)
         return self._build(self._threshold)
@@ -1574,10 +1522,6 @@ class AutotunedStepper:
     @property
     def hierarchical(self) -> bool:
         return self._hier
-
-    @property
-    def overlap(self) -> bool:
-        return self._ovl
 
     @property
     def compression(self) -> str:
@@ -1627,7 +1571,6 @@ class AutotunedStepper:
             pt = self.tuner.feed_full(self.grad_bytes, dt)
             new = pt.threshold
             new_h = pt.hierarchical if self._joint else self._hier
-            new_o = pt.overlap if self._joint_overlap else self._ovl
             new_c = pt.compression if self._joint_comp else self._comp
             new_r = pt.route if self._joint_route else self._route
             new_a = pt.accum if self._joint_accum else self._accum
@@ -1641,11 +1584,11 @@ class AutotunedStepper:
             if c.rank == 0:
                 self.tuner.record(self.grad_bytes, dt)
             self._calls += 1
-            (new, new_h, new_o, new_c, new_r, new_a, new_m, new_s,
+            (new, new_h, new_c, new_r, new_a, new_m, new_s,
              new_w, new_pw) = (
-                self._threshold, self._hier, self._ovl, self._comp,
-                self._route, self._accum, self._remat, self._shard,
-                self._moe_wire, self._pp_wire)
+                self._threshold, self._hier, self._comp, self._route,
+                self._accum, self._remat, self._shard, self._moe_wire,
+                self._pp_wire)
             if self._calls % self._period == 0 and not self._tuner_done:
                 # Sample boundary — same call index on every process
                 # (SPMD lockstep), so the exchange is synchronous. After
@@ -1656,7 +1599,6 @@ class AutotunedStepper:
                 cur = self.tuner.current_full  # atomic
                 mine = (f"{cur.threshold}"
                         f"|{int(cur.hierarchical) if self._joint else 0}"
-                        f"|{int(cur.overlap) if self._joint_overlap else 0}"
                         f"|{cur.compression if self._joint_comp else 'none'}"
                         f"|{cur.route if self._joint_route else 'flat'}"
                         f"|{cur.accum if self._joint_accum else 1}"
@@ -1671,12 +1613,10 @@ class AutotunedStepper:
                 if v0.endswith(":done"):
                     self._tuner_done = True
                     v0 = v0[:-5]
-                (t_str, h_str, o_str, c_str, r_str, a_str, m_str,
+                (t_str, h_str, c_str, r_str, a_str, m_str,
                  s_str, w_str, pw_str) = v0.split("|")
                 new = int(t_str)
                 new_h = bool(int(h_str)) if self._joint else self._hier
-                new_o = bool(int(o_str)) if self._joint_overlap \
-                    else self._ovl
                 new_c = c_str if self._joint_comp else self._comp
                 new_r = r_str if self._joint_route else self._route
                 new_a = int(a_str) if self._joint_accum else self._accum
@@ -1688,14 +1628,14 @@ class AutotunedStepper:
                 new_pw = pw_str if self._joint_pp_wire \
                     else self._pp_wire
         if (new != self._threshold or new_h != self._hier
-                or new_o != self._ovl or new_c != self._comp
-                or new_r != self._route or new_a != self._accum
-                or new_m != self._remat or new_s != self._shard
+                or new_c != self._comp or new_r != self._route
+                or new_a != self._accum or new_m != self._remat
+                or new_s != self._shard
                 or new_w != self._moe_wire or new_pw != self._pp_wire):
-            (self._threshold, self._hier, self._ovl, self._comp,
-             self._route, self._accum, self._remat, self._shard,
+            (self._threshold, self._hier, self._comp, self._route,
+             self._accum, self._remat, self._shard,
              self._moe_wire, self._pp_wire) = (
-                new, new_h, new_o, new_c, new_r, new_a, new_m, new_s,
+                new, new_h, new_c, new_r, new_a, new_m, new_s,
                 new_w, new_pw)
             self._step = self._rebuild()
             self.rebuilds += 1
@@ -2572,11 +2512,12 @@ class FSDPOptimizer:
 # PARAMETERS at rest as 1/N bucket shards, all-gathered ON DEMAND per
 # readiness-ordered bucket for the step's compute and freed after use
 # (XLA liveness): the gather chain pins bucket order with the
-# optimization-barrier pattern (common/overlap.py, parallel/moe.py), so
+# optimization-barrier pattern (_chain_issue_order, parallel/moe.py), so
 # the async-collective scheduler may prefetch bucket k+1's params under
-# bucket k's compute. Overlap bucketing's readiness order IS the gather
-# schedule — forward (flatten) order for the param gathers, reverse for
-# the gradient reduce-scatters.
+# bucket k's compute. Readiness order IS the gather schedule — forward
+# (flatten) order for the param gathers, reverse for the gradient
+# reduce-scatters. No chip has run a ZeRO step yet: whether the chain
+# earns its place is for the first cell that does (ROADMAP.md queue 3).
 #
 # Wire model per effective step (docs/zero.md): stage 1/2 pay
 # RS(grads) + AG(updates); stage 3 pays AG(params) + RS(grads) — the
@@ -2642,6 +2583,25 @@ def _is_shard_grads(grads, like=None) -> bool:
                         for p in jax.tree.leaves(like)]
 
 
+def _chain_issue_order(flats, fn: Callable) -> list:
+    """Apply ``fn`` (the per-bucket collective) to each flat bucket,
+    pinning the ISSUE ORDER with an ``optimization_barrier`` chain:
+    bucket ``i+1``'s input is tied to bucket ``i``'s collective, so the
+    scheduler cannot start them out of the order given. The collectives
+    serialize against each other — they share one wire and would anyway
+    — while each stays free to run beside the compute that produces or
+    consumes LATER buckets. The barrier is a scheduling fence, not a
+    math op: outputs equal inputs exactly."""
+    outs = []
+    token = None
+    for f in flats:
+        if token is not None:
+            f, token = jax.lax.optimization_barrier((f, token))
+        token = fn(f)
+        outs.append(token)
+    return outs
+
+
 class ZeroOptimizer:
     """One surface over the ZeRO stages (docs/zero.md)::
 
@@ -2701,7 +2661,6 @@ class ZeroOptimizer:
                  nonfinite_policy: Optional[str] = None,
                  route=None, accum_steps: Optional[int] = None,
                  remat_policy: Optional[str] = None,
-                 overlap: bool = True, bucket_order=None,
                  parallel=None):
         stage = int(zero_stage)
         if stage not in (1, 2, 3):
@@ -2759,8 +2718,6 @@ class ZeroOptimizer:
         self.route = C.WirePlan.resolve(route)
         self.accum_steps = _resolve_accum_steps(accum_steps)
         self.remat_policy = resolve_remat_policy(remat_policy)[0]
-        self.overlap = bool(overlap)
-        self.bucket_order = bucket_order
         # Stages 1/2 ride the ZeRO-1 substrate unchanged: same state
         # layout, EF/guard wrapping, gather/reshard — checkpoint- and
         # elastic-compatible by construction.
@@ -2828,10 +2785,8 @@ class ZeroOptimizer:
                 "tree (structure or leaf shapes changed); use a fresh "
                 "instance per param tree, or call unbind() first")
         self._sig = sig
-        order = (self.bucket_order if self.bucket_order is not None
-                 else fusion_lib.ORDER_FLATTEN)
         self._plan = fusion_lib.plan_fusion(
-            params_template, self.fusion_threshold_bytes, order=order)
+            params_template, self.fusion_threshold_bytes)
         self._flat_lens = [b.total_elems for b in self._plan.buckets]
         return self
 
@@ -2900,12 +2855,7 @@ class ZeroOptimizer:
             def ag(s):
                 return C.allgather(s, self.axis_name)
 
-        if self.overlap:
-            from .common import overlap as overlap_lib
-
-            outs = overlap_lib.chain_issue_order(shards, ag)
-        else:
-            outs = [ag(s) for s in shards]
+        outs = _chain_issue_order(shards, ag)
         flats = [o[:length]
                  for o, length in zip(outs, self._flat_lens)]
         for b in self._plan.buckets:
@@ -2981,21 +2931,13 @@ class ZeroOptimizer:
         """Full gradient pytree -> fp32 bucket shards via the EXACT
         reduce-scatter descent (native wires on every hop — the shard
         accumulator must sum losslessly across microbatches), chained
-        in REVERSE (backward-readiness) order under ``overlap``."""
+        in REVERSE (backward-readiness) order."""
         g_flats = fusion_lib.fuse(
             jax.tree.map(lambda g, p: g.astype(p.dtype), grads,
                          params_like), plan)
-        outs: list = [None] * len(g_flats)
-        token = None
-        order = (range(len(g_flats) - 1, -1, -1) if self.overlap
-                 else range(len(g_flats)))
-        for i in order:
-            f = g_flats[i]
-            if self.overlap and token is not None:
-                f, token = jax.lax.optimization_barrier((f, token))
-            s = self._rs_exact(f, route, n, align)
-            outs[i] = s.astype(jnp.float32)
-            token = s
+        outs = [s.astype(jnp.float32) for s in _chain_issue_order(
+            g_flats[::-1],
+            lambda f: self._rs_exact(f, route, n, align))][::-1]
         for b in plan.buckets:
             _zero_count_bytes("grad", b.total_elems,
                               jnp.dtype(b.dtype).itemsize, route,

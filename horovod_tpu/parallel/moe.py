@@ -22,7 +22,7 @@ peer to the allreduce stack:
   on the fast ICI axis and int8 on the slow DCN hop.
 * **overlap pipelining** — ``overlap_chunks=k`` splits the capacity dim
   into ``k`` chunks and chains their exchanges with
-  ``optimization_barrier`` (``common/overlap.py``) so the dispatch
+  ``optimization_barrier`` so the dispatch
   alltoall of chunk ``k+1`` is free to fly while the expert FFN of
   chunk ``k`` computes. Chunking along capacity is a pure reshape —
   numerics are unchanged (``expert_fn`` must therefore be token-wise:
@@ -175,7 +175,7 @@ def ep_index(axis_name: Optional[str] = "ep", route=None):
 def _chain_barrier(x, token):
     """Differentiable ``optimization_barrier``: the lax primitive has no
     VJP rule (it sits INSIDE the differentiated MoE layer, unlike the
-    gradient-side chains in ``common/overlap.py``), so the custom rule
+    gradient-side chain of ``optim.ZeroOptimizer``), so the custom rule
     barriers the cotangents too — the backward walk's exchanges get the
     same issue-order pinning as the forward's. Identity on values both
     ways; numerics untouched."""

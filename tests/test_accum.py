@@ -1,7 +1,7 @@
 """Scan-based gradient accumulation (docs/performance.md §4c): the
 accumulation-equivalence suite — ``accum_steps=k`` gradients match the
 fused large batch within dtype tolerance across the
-{overlap, int8_ef, route, guard} compositions, with exactly ONE
+{int8_ef, route, guard} compositions, with exactly ONE
 collective round and ONE guard agreement per effective step, and the
 error-feedback / loss-scale state transitions bitwise-matching the
 unaccumulated path."""
@@ -205,9 +205,12 @@ def test_gradfn_accum_guard_skips_poisoned_microbatch(hvd, rng):
     assert np.all(np.asarray(ok) == 0)
 
 
-def test_gradfn_accum_overlap_identical(hvd, rng):
-    """overlap=True is scheduling only — bitwise identical under
-    accumulation too."""
+def test_gradfn_accum_reduces_in_place_bitwise_the_flat_buckets(hvd, rng):
+    """The shape of the reduction is chosen after the accumulation as
+    before it: the accumulated mean, reduced where it lies, is bitwise
+    what one flat bucket over ``collectives.allreduce`` gives."""
+    from horovod_tpu.common import fusion
+
     ctx = hvd_mod.init()
     ax = ctx.config.rank_axis
     w0 = rng.standard_normal((64,)).astype(np.float32)
@@ -217,15 +220,20 @@ def test_gradfn_accum_overlap_identical(hvd, rng):
     def loss(w, xb, yb):
         return jnp.mean((xb @ w - yb) ** 2)
 
-    outs = []
-    for overlap in (False, True):
-        gfn = hvd_mod.DistributedGradFn(loss, axis_name=ax,
-                                        accum_steps=2, overlap=overlap,
-                                        fusion_threshold_bytes=64)
+    gfn = hvd_mod.DistributedGradFn(loss, axis_name=ax, accum_steps=2,
+                                    fusion_threshold_bytes=64)
+    local = optim.accumulate_gradients(loss, 2)
 
+    def reference(w, xb, yb):
+        return fusion.fused_apply(
+            local(w, xb, yb)[1],
+            lambda f: C.allreduce(f, C.ReduceOp.AVERAGE, ax), 64)
+
+    outs = []
+    for fn in (reference, gfn):
         def step(xb, yb):
             wl = C.to_local(jnp.asarray(w0), ax)
-            return gfn(wl, xb[0], yb[0])[None]
+            return fn(wl, xb[0], yb[0])[None]
 
         outs.append(np.asarray(
             _spmd(ctx, step)(hvd.scatter(X), hvd.scatter(Y))))

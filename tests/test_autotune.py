@@ -294,7 +294,7 @@ def test_stepper_joint_rebuilds_on_hierarchical_change():
 def test_autotuner_joint_compression():
     """Joint compression axis: synthetic objective where int8_ef (4x
     fewer wire bytes) is fastest at the 16 MiB threshold — the tuner
-    must converge on that pair and expose it via current_quad."""
+    must converge on that pair and expose it via current_full."""
     mb = 1024 * 1024
     candidates = [4 * mb, 16 * mb]
     base = {4 * mb: 300.0, 16 * mb: 1000.0}
@@ -311,9 +311,10 @@ def test_autotuner_joint_compression():
         if t.done:
             break
     assert t.done
-    thr, hier, ovl, comp = t.current_quad
-    assert thr == 16 * mb and comp == "int8_ef"
-    assert hier is False and ovl is False  # untuned axes stay pinned
+    pt = t.current_full
+    assert pt.threshold == 16 * mb and pt.compression == "int8_ef"
+    # untuned axes stay pinned
+    assert pt.hierarchical is False and pt.route == "flat"
 
 
 def test_autotuner_compression_logged_csv(tmp_path):
@@ -331,7 +332,7 @@ def test_autotuner_compression_logged_csv(tmp_path):
 
 def test_stepper_joint_compression_rebuilds():
     """AutotunedStepper with tune_compression passes the full
-    (threshold, hierarchical, overlap, compression) point to build and
+    (threshold, hierarchical, compression) point to build and
     rebuilds when the compression moves."""
     from horovod_tpu.optim import AutotunedStepper
 
@@ -339,8 +340,8 @@ def test_stepper_joint_compression_rebuilds():
                   steps_per_sample=1, tune_compression=True)
     seen = []
 
-    def build(threshold, hierarchical, overlap, compression):
-        seen.append((threshold, hierarchical, overlap, compression))
+    def build(threshold, hierarchical, compression):
+        seen.append((threshold, hierarchical, compression))
         return lambda x: x + 1
 
     stepper = AutotunedStepper(build, grad_bytes=1000, tuner=t,
@@ -348,7 +349,7 @@ def test_stepper_joint_compression_rebuilds():
     for i in range(8):
         stepper(i)
     assert stepper.rebuilds >= 1
-    comps = {c for _, _, _, c in seen}
+    comps = {c for _, _, c in seen}
     assert len(comps) >= 2, seen  # the compression axis was explored
     assert stepper.compression in ("none", "bf16", "int8_ef")
 
@@ -372,7 +373,7 @@ def test_autotuner_mfu_dimensions_space():
     assert pt.shard in (0, 1, 2, 3)
     # Historical accessors unchanged by the widening.
     assert t.current in (1024, 2048)
-    assert t.current_quint[0] in (1024, 2048)
+    assert t.current_point[0] in (1024, 2048)
 
 
 def test_autotuner_accum_pruned_when_compute_bound():
@@ -387,7 +388,7 @@ def test_autotuner_accum_pruned_when_compute_bound():
         before = len(t._space)
         t.feed_full(100.0, 1.0)  # first sample boundary → gate runs
         untried_accum = [p for p in t._space
-                         if p[5] > 0 and p not in t._samples]
+                         if p[4] > 0 and p not in t._samples]
         if expect_pruned:
             assert not untried_accum, t._space
             assert len(t._space) < before

@@ -445,13 +445,13 @@ def test_int8_ef_hierarchical_routes_through_wireplan(mesh2d, rng):
 
 def test_adasum_int8_ef_overlap_acceptance(mesh2d):
     """THE acceptance gate: DistributedOptimizer(op=hvd.Adasum,
-    compression='int8_ef', overlap=True) trains on the simulated 2D
+    compression='int8_ef') trains on the simulated 2D
     mesh to within the documented (2%, docs/compression.md) bound of
     the flat fp32 SUM run, and of the exact (fp32) routed Adasum."""
     flat_mesh = Mesh(np.array(jax.devices()), ("hvd",))
     tx_ada = optim.DistributedOptimizer(
         optax.adam(5e-2), op=hvd_mod.Adasum, compression="int8_ef",
-        overlap=True, route=PLAN_QQ, quantize_min_bucket_bytes=0)
+        route=PLAN_QQ, quantize_min_bucket_bytes=0)
     tx_exact = optim.DistributedOptimizer(
         optax.adam(5e-2), op=hvd_mod.Adasum, route=PLAN)
     tx_flat = optim.DistributedOptimizer(optax.adam(5e-2),
@@ -477,13 +477,14 @@ def test_route_composes_with_nonfinite_guard(mesh2d):
 
 # -- grad consistency across mesh shapes ------------------------------------
 
-def _routed_grad(mesh, axes, route, nranks, g_host, overlap=False):
-    """One int8_ef reduction of a 2-bucket tree; returns (reduced tree,
-    residual psum) on rank 0's view."""
+def _routed_grad(mesh, axes, route, nranks, g_host,
+                 threshold=4096 * 4):
+    """One int8_ef reduction of a two-leaf tree (two buckets at the
+    default threshold); returns the reduced tree on rank 0's view."""
     tx = optim.DistributedOptimizer(
         optax.sgd(1.0), op=hvd_mod.Sum, compression="int8_ef",
-        route=route, overlap=overlap, quantize_min_bucket_bytes=0,
-        fusion_threshold_bytes=4096 * 4)
+        route=route, quantize_min_bucket_bytes=0,
+        fusion_threshold_bytes=threshold)
     shapes = {"a": (3000,), "b": (2000,)}
     p = {k: jnp.zeros(v, jnp.float32) for k, v in shapes.items()}
 
@@ -501,26 +502,26 @@ def _routed_grad(mesh, axes, route, nranks, g_host, overlap=False):
     return {"a": -np.asarray(ua)[0], "b": -np.asarray(ub)[0]}
 
 
-@pytest.mark.parametrize("shape,overlap", [((2, 4), False),
-                                           ((2, 4), True),
-                                           ((2, 2), False),
-                                           ((2, 2), True)],
-                         ids=["2x4", "2x4_overlap", "2x2",
-                              "2x2_overlap"])
-def test_grad_consistency_mesh_sum_vs_flat(rng, shape, overlap,
+@pytest.mark.parametrize("shape,threshold", [((2, 4), 4096 * 4),
+                                             ((2, 4), 1 << 20),
+                                             ((2, 2), 4096 * 4),
+                                             ((2, 2), 1 << 20)],
+                         ids=["2x4", "2x4_one_bucket", "2x2",
+                              "2x2_one_bucket"])
+def test_grad_consistency_mesh_sum_vs_flat(rng, shape, threshold,
                                            mesh2d, mesh2x2):
     """Mesh-routed int8 SUM on the 2x2 (4-device) and 2x4 (8-device)
     simulated meshes matches the flat-axis fp32 reference within the
-    documented int8_ef bound, including under overlap bucketing (the
-    5000-float tree splits into multiple buckets at the 16 KiB
-    threshold)."""
+    documented int8_ef bound, whether the 5000-float tree splits into
+    two buckets (the 16 KiB threshold) or rides one whose block grid
+    straddles the two leaves (1 MiB)."""
     nranks = int(np.prod(shape))
     mesh = mesh2d if nranks == 8 else mesh2x2
     g_host = {"a": (rng.standard_normal((8, 3000)) * 2).astype(
         np.float32), "b": rng.standard_normal((8, 2000)).astype(
         np.float32)}
     got = _routed_grad(mesh, ("cross", "local"), PLAN_Q, nranks,
-                       g_host, overlap=overlap)
+                       g_host, threshold=threshold)
     for k in ("a", "b"):
         want = g_host[k][:nranks].sum(axis=0)
         err = np.abs(got[k] - want)
@@ -614,9 +615,7 @@ def test_autotuner_route_dimension():
     assert tuner.current_route in ("flat", "staged_int8")
     seen = set()
     for _ in range(30):
-        point = tuner.feed_quint(4096.0, 0.01)
-        assert len(point) == 5
-        seen.add(point[4])
+        seen.add(tuner.feed_full(4096.0, 0.01).route)
         if tuner.done:
             break
     assert seen <= {"flat", "staged_int8"}
@@ -647,8 +646,8 @@ def test_stepper_joint_route_rebuilds(hvd):
                       route_candidates=("flat", "staged"))
     built = []
 
-    def build(threshold, hier, ovl, comp, route):
-        built.append((threshold, hier, ovl, comp, route))
+    def build(threshold, hier, comp, route):
+        built.append((threshold, hier, comp, route))
 
         def step(x):
             return x + 1
@@ -661,7 +660,7 @@ def test_stepper_joint_route_rebuilds(hvd):
         if stepper.rebuilds >= 1:
             break
     assert stepper.rebuilds >= 1
-    assert {b[4] for b in built} >= {"flat", "staged"}
+    assert {b[3] for b in built} >= {"flat", "staged"}
     assert stepper.route in ("flat", "staged")
 
 
@@ -772,15 +771,27 @@ def test_sharded_optimizer_routed_int8_ef_close_to_fp32(mesh2d,
     """route=staged_int8 + compression="int8_ef" on the sharded state:
     the staged quantized RS (residual carried through
     mesh_reducescatter) stays within int8_ef tolerance of the fp32
-    trajectory."""
+    trajectory.
+
+    What the tolerance is: AdamW from zero moves every weight by about
+    ``lr`` a step whatever its gradient's size, so after six steps
+    ``scale`` is ~6 lr, and an element whose mean gradient is within the
+    int8 rounding noise can take a step the other way: 2 lr, a third of
+    ``scale``, each time. Over eight data seeds (PR 29) the largest
+    deviation was 0.14-0.47 of ``scale`` routed and 0.23-0.58 on the
+    unrouted ``int8_ef`` twin, with at most 2 of the 256 weights beyond
+    one such step: the routed path drifts no more than its twin, and the
+    old bound (0.35, one step) was thinner than either's spread. Two
+    steps, and few elements beyond one."""
     params, X, Y = sharded_problem
     p, s, loss, _, _ = _run_sharded(mesh2d, ("cross", "local"), PLAN_Q,
                                     params, X, Y, steps=6,
                                     compression="int8_ef")
     ref = _replicated_reference(params, X, Y, steps=6)
-    dw = np.abs(np.asarray(p["w"]) - np.asarray(ref["w"])).max()
+    dev = np.abs(np.asarray(p["w"]) - np.asarray(ref["w"]))
     scale = max(np.abs(np.asarray(ref["w"])).max(), 1e-6)
-    assert dw <= 0.35 * scale, (dw, scale)
+    assert dev.max() <= 0.7 * scale, (dev.max(), scale)
+    assert (dev > 0.35 * scale).sum() <= 4, (dev > 0.35 * scale).sum()
     assert np.isfinite(loss)
     # The EF state really is mesh-sharded: residual length is the
     # 8-rank padded grid, carried as P((cross, local)) shards.

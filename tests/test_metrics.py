@@ -352,13 +352,14 @@ def test_autotuner_publishes_state():
     assert after == before + 1
     assert _value("hvd_tpu_autotune_threshold_bytes") == tuner.current
     # Sample labels carry the full config string (threshold |
-    # hierarchical | overlap | compression | route | accum | remat |
-    # shard | moe_wire | pp_wire | seq_wire — the MFU axes widened it
-    # in PR 8, the MoE dispatch-wire axis in PR 10, the pipeline send
-    # wire in PR 13, the sequence K/V wire in PR 18).
+    # hierarchical | compression | route | accum | remat | shard |
+    # moe_wire | pp_wire | seq_wire — the MFU axes widened it in PR 8,
+    # the MoE dispatch-wire axis in PR 10, the pipeline send wire in
+    # PR 13, the sequence K/V wire in PR 18; PR 29 took the overlap
+    # field out).
     labeled = [s["labels"]["config"] for s in
                _sample_values("hvd_tpu_autotune_samples_total")]
-    assert any(len(cfg.split("|")) == 11 for cfg in labeled)
+    assert any(len(cfg.split("|")) == 10 for cfg in labeled)
 
 
 def test_fusion_plan_metrics():

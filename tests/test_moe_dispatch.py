@@ -630,9 +630,9 @@ def test_autotuner_moe_wire_dimension():
         assert pt.moe_wire in ("none", "bf16", "int8")
         seen.add(pt.moe_wire)
     assert len(seen) >= 2  # the axis is genuinely explored
-    # Pre-existing 8-positional constructions still work (default).
-    assert TunedPoint(1, False, False, "none", "flat", 1, "none",
-                      False).moe_wire == "none"
+    # Constructions that stop at ``shard`` still work (default).
+    assert TunedPoint(1, False, "none", "flat", 1, "none",
+                      0).moe_wire == "none"
 
     # The tuned wire is CONSUMED: AutotunedStepper hands the full
     # TunedPoint (moe_wire included) to the build fn, which rebuilds

@@ -1,11 +1,11 @@
-"""Overlap-aware gradient reduction tests. ISSUE 1: readiness-ordered
-bucket plans are deterministic across ranks, ``overlap=True`` changes
-SCHEDULING but never numerics, the measured-order timeline hook
-round-trips, and the autotuner covers the (threshold, hierarchical,
-overlap) space. ISSUE 28: the DEFAULT reduction of a flat-axis step
-(linear op, per-element wire) reduces each gradient where it lies,
-bitwise what the flat buckets of ``overlap=False`` give; everything that
-needs a flat buffer keeps one; only a TPU mesh of several devices gets
+"""The gradient reduction's one decision (ISSUE 28, ISSUE 29): a
+flat-axis step with a linear op and a per-element wire reduces each
+gradient where it lies, bitwise what flat buckets give (the reference
+here is built in the test: ``fusion.fused_apply`` over
+``collectives.allreduce``, the program every path was before ISSUE 28);
+everything that needs a flat buffer keeps one, in flatten order; nobody
+can ask for another shape. ZeRO's stages 2 and 3 keep their
+``optimization_barrier`` chain; only a TPU mesh of several devices gets
 compiler options."""
 
 import functools
@@ -19,8 +19,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 import horovod_tpu as hvd_mod
-from horovod_tpu.common import fusion, overlap, scopes
-from horovod_tpu.common.autotune import Autotuner
+from horovod_tpu import optim
+from horovod_tpu.common import fusion, scopes
+from horovod_tpu.ops import collectives as C
+from horovod_tpu.ops.compression import Compression
 
 
 def _mlp_tree(rng, depth=6, width=16):
@@ -33,167 +35,107 @@ def _mlp_tree(rng, depth=6, width=16):
         } for i in range(depth)}
 
 
-# -- readiness-ordered planning ---------------------------------------------
+# -- the flatten plan is a checkpoint layout -------------------------------
 
-def test_reverse_order_buckets_cover_last_leaves_first(rng):
-    tree = _mlp_tree(rng, depth=4, width=8)
-    nleaves = len(jax.tree.leaves(tree))
-    # Threshold of one (w, b) pair -> multiple buckets.
-    thr = (8 * 8 + 8) * 4
-    plan = fusion.plan_fusion(tree, thr, order="reverse")
-    assert plan.order == "reverse"
-    assert len(plan.buckets) > 1
-    # Bucket 0 (the first to close) must cover the LAST flatten-order
-    # leaves — the gradients backprop completes first.
-    assert max(plan.buckets[0].leaf_indices) == nleaves - 1
-    assert min(plan.buckets[-1].leaf_indices) == 0
-    # Every leaf appears exactly once.
-    covered = sorted(i for b in plan.buckets for i in b.leaf_indices)
-    assert covered == list(range(nleaves))
-
-
-def test_reverse_plan_roundtrips_and_is_deterministic_across_ranks(rng):
-    tree = _mlp_tree(rng)
-    thr = 1024
-    # Simulated ranks: each plans independently from (shapes, dtypes,
-    # threshold, order) only — identical plans, no negotiation.
-    plans = [fusion.plan_fusion(tree, thr, order="reverse")
-             for _ in range(4)]
-    ref = plans[0]
-    for p in plans[1:]:
-        assert [b.leaf_indices for b in p.buckets] == \
-            [b.leaf_indices for b in ref.buckets]
-        assert [str(b.dtype) for b in p.buckets] == \
-            [str(b.dtype) for b in ref.buckets]
-    # fuse/unfuse round-trip under the permuted plan.
-    back = fusion.unfuse(fusion.fuse(tree, ref), ref)
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_explicit_order_permutation_validated(rng):
-    tree = _mlp_tree(rng, depth=2, width=4)
-    n = len(jax.tree.leaves(tree))
-    perm = list(range(n - 1, -1, -1))
-    plan = fusion.plan_fusion(tree, 64, order=perm)
-    assert plan.order == "explicit"
-    with pytest.raises(ValueError, match="permutation"):
-        fusion.plan_fusion(tree, 64, order=[0, 0, 1])
-
-
-def test_buckets_emitted_in_closing_order_for_interleaved_dtypes():
-    """Under a readiness order, a bucket opened early but fed leaves
-    throughout the visit closes LAST and must be emitted last — opening
-    (bucket-id) order would pin the early-ready bucket's collective
-    behind it. The flatten default keeps the historical id-order
-    emission: the ZeRO-1/FSDP sharded-state layout indexes plan.buckets
-    positionally, so the default plan must not reorder across releases
-    (code review #3 + follow-up)."""
+def test_flatten_plan_emits_buckets_in_opening_order_for_interleaved_dtypes():
+    """The ZeRO-1/FSDP sharded-state layout indexes ``plan.buckets``
+    positionally, so the plan must not reorder across releases: the
+    float32 bucket opens first (id 0) and is emitted first, although the
+    int32 bucket closes before it."""
     # Flatten order = sorted keys: a0(f32) b(int32) z1 z2 z3(f32).
-    # Reverse visit: z3 z2 z1 b a0 — the f32 bucket opens first (id 0)
-    # but closes only at a0 (pos 4); the int32 bucket closes at pos 3.
     tree = {"a0": jnp.ones((4,), jnp.float32),
             "b": jnp.arange(3, dtype=jnp.int32),
             "z1": jnp.ones((4,), jnp.float32),
             "z2": jnp.ones((4,), jnp.float32),
             "z3": jnp.ones((4,), jnp.float32)}
-    plan = fusion.plan_fusion(tree, 1 << 20, order="reverse")
-    assert [str(b.dtype) for b in plan.buckets] == ["int32", "float32"]
-    # Default flatten order: unchanged historical emission (f32 bucket
-    # id 0 first) — sharded-state checkpoint layout stability.
-    plan_flat = fusion.plan_fusion(tree, 1 << 20, order="flatten")
-    assert [str(b.dtype) for b in plan_flat.buckets] == \
-        ["float32", "int32"]
+    plan = fusion.plan_fusion(tree, 1 << 20)
+    assert [str(b.dtype) for b in plan.buckets] == ["float32", "int32"]
     back = fusion.unfuse(fusion.fuse(tree, plan), plan)
     for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_mixed_dtype_reverse_order_groups_by_dtype(rng):
-    tree = {"a": jnp.ones((4,), jnp.float32),
-            "b": jnp.arange(3, dtype=jnp.int32),
-            "c": jnp.ones((5,), jnp.float32)}
-    plan = fusion.plan_fusion(tree, 1 << 20, order="reverse")
-    dtypes = [str(b.dtype) for b in plan.buckets]
-    assert sorted(dtypes) == ["float32", "int32"]
-    back = fusion.unfuse(fusion.fuse(tree, plan), plan)
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# -- measured-order hook ----------------------------------------------------
-
-def test_measured_order_from_timeline_trace(tmp_path, rng):
-    from horovod_tpu.common.timeline import (Timeline,
-                                             readiness_order_from_trace)
-
-    trace = str(tmp_path / "tl.json")
-    tl = Timeline(use_native=False)
-    tl.start(trace)
-    # Leaf names in keystr form, recorded out of flatten order — the
-    # trace's first-seen order is the measured readiness order.
-    for name in ("['layer01']['w']", "['layer00']['b']"):
-        tl.begin(name, "XLA_ALLREDUCE")
-        tl.end(name, "XLA_ALLREDUCE")
-    tl.stop()
-
-    names = readiness_order_from_trace(trace)
-    assert names == ["['layer01']['w']", "['layer00']['b']"]
-
-    tree = _mlp_tree(rng, depth=2, width=4)
-    perm = fusion.measured_order(tree, names)
-    leaves_paths = jax.tree_util.tree_flatten_with_path(tree)[0]
-    keystrs = [jax.tree_util.keystr(p) for p, _ in leaves_paths]
-    # Measured leaves lead, in measured order...
-    assert keystrs[perm[0]] == "['layer01']['w']"
-    assert keystrs[perm[1]] == "['layer00']['b']"
-    # ...and the rest follow in reverse flatten order, covering all.
-    assert sorted(perm) == list(range(len(keystrs)))
-    unmeasured = [i for i in perm[2:]]
-    assert unmeasured == sorted(unmeasured, reverse=True)
-    # The permutation drives a valid plan.
-    plan = fusion.plan_fusion(tree, 64, order=perm)
-    back = fusion.unfuse(fusion.fuse(tree, plan), plan)
-    for a, b in zip(jax.tree.leaves(tree), jax.tree.leaves(back)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# -- issue-order chaining ---------------------------------------------------
+# -- ZeRO's issue-order chain ------------------------------------------------
 
 def test_chain_issue_order_is_identity_on_values(rng):
     flats = [jnp.asarray(rng.standard_normal((n,)).astype(np.float32))
              for n in (5, 7, 3)]
-    outs = overlap.chain_issue_order(flats, lambda f: f * 2.0)
+    outs = optim._chain_issue_order(flats, lambda f: f * 2.0)
     for f, o in zip(flats, outs):
         np.testing.assert_allclose(np.asarray(o), np.asarray(f) * 2.0,
                                    rtol=1e-6)
 
 
-def test_fused_apply_overlapped_matches_fused_apply(rng):
-    tree = _mlp_tree(rng)
-    plain = fusion.fused_apply(tree, lambda f: f * 3.0,
-                               threshold_bytes=512)
-    ovl = overlap.fused_apply_overlapped(tree, lambda f: f * 3.0, 512)
-    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(ovl)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+def _zero_step_jaxpr(stage, rng):
+    """The traced step of one ZeRO stage on four ranks, two buckets."""
+    params = {"b": jnp.zeros((2,), jnp.float32),
+              "w": jnp.asarray(rng.standard_normal((8, 2))
+                               .astype(np.float32))}
+    X = jnp.asarray(rng.standard_normal((16, 8)).astype(np.float32))
+    tx = hvd_mod.ZeroOptimizer(optax.sgd(0.1), zero_stage=stage,
+                               axis_name="z", fusion_threshold_bytes=16)
+    mesh = Mesh(np.array(jax.devices()[:4]), ("z",))
+
+    def loss(p, xb):
+        return ((xb @ p["w"] + p["b"]) ** 2).mean()
+
+    if stage == 3:
+        sspecs, stspecs = tx.shard_specs(params), tx.state_specs(params)
+        assert len(sspecs) == 2
+
+        def step(p, xb):
+            sh = tx.shard_params(p)
+            full = tx.gather_params(sh)
+            g = tx.reduce_grads(jax.grad(loss)(full, xb), full)
+            return tx.update(g, tx.init(sh), sh)[0]
+
+        outs = sspecs
+    else:
+        def step(p, xb):
+            g = jax.grad(loss)(p, xb)
+            if stage == 2:
+                g = tx.reduce_grads(g, p)
+            return tx.update(g, tx.init(p), p)[0]
+
+        outs = P()
+    return str(jax.make_jaxpr(jax.shard_map(
+        step, mesh=mesh, in_specs=(P(), P("z")), out_specs=outs,
+        check_vma=False))(params, X))
 
 
-def test_overlap_inserts_optimization_barrier(rng):
-    """overlap=True must change the traced program (the barrier chain),
-    not the math — the 'changes scheduling, not numerics' proof's
-    structural half."""
-    tree = _mlp_tree(rng, depth=4, width=8)
-
-    text_plain = str(jax.make_jaxpr(
-        lambda t: fusion.fused_apply(t, lambda f: f * 2.0, 512))(tree))
-    text_ovl = str(jax.make_jaxpr(
-        lambda t: overlap.fused_apply_overlapped(
-            t, lambda f: f * 2.0, 512))(tree))
-    assert "optimization_barrier" not in text_plain
-    assert "optimization_barrier" in text_ovl
+@pytest.mark.parametrize("stage", [1, 2, 3])
+def test_zero_stages_2_and_3_carry_the_barrier_chain(hvd, rng, stage):
+    """ZeRO's own program is unchanged by ISSUE 29: the reverse-order
+    chained reduce-scatter (stages 2 and 3) and the chained gather of
+    parameter shards (stage 3) are in the traced step; stage 1 (the
+    ShardedOptimizer) never had a chain."""
+    barriers = _zero_step_jaxpr(stage, rng).count("optimization_barrier")
+    # n buckets chain n-1 times: one chain at stage 2 (the scatter), two
+    # at stage 3 (the gather as well).
+    assert barriers == {1: 0, 2: 1, 3: 2}[stage]
 
 
-# -- SPMD equivalence: overlap=True == overlap=False ------------------------
+# -- the references, built here and not in the package ----------------------
+
+def _flat_bucket_reduce(axis_name, threshold, op=hvd_mod.Average,
+                        compression=Compression.none, prescale=1.0,
+                        postscale=1.0):
+    """Flat buckets in flatten order, one ``collectives.allreduce`` each:
+    the program of every path before ISSUE 28, spelled out."""
+    def one(flat):
+        w, ctx = compression.compress(flat)
+        return compression.decompress(
+            C.allreduce(w, op, axis_name, prescale, postscale), ctx)
+
+    return lambda grads: fusion.fused_apply(grads, one, threshold)
+
+
+def _reference_tx(inner, reduce):
+    """``inner`` fed gradients that ``reduce`` has reduced."""
+    return optax.GradientTransformation(
+        inner.init,
+        lambda g, s, p=None: inner.update(reduce(g), s, p))
+
 
 def _train(hvd, tx, params, X, Y, steps=5):
     ax = hvd.rank_axis()
@@ -219,37 +161,51 @@ def _train(hvd, tx, params, X, Y, steps=5):
     return p, losses
 
 
+def _assert_trees_bitwise(want, got):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_overlap_equivalence_distributed_optimizer(hvd, rng):
-    """overlap=True vs overlap=False: bit-identical updates on CPU —
-    overlap changes the schedule, never the numerics."""
+    """The default step against SGD on flat-bucket gradients: the same
+    numbers summed in the same precision, so bit-identical updates."""
     width = 8
     params = _mlp_tree(rng, depth=4, width=width)
     X = rng.standard_normal((16, width)).astype(np.float32)
     Y = rng.standard_normal((16, width)).astype(np.float32)
     thr = (width * width + width) * 4  # multiple buckets
+    ax = hvd.rank_axis()
 
-    tx_off = hvd_mod.DistributedOptimizer(
-        optax.sgd(0.05), axis_name=hvd.rank_axis(),
-        fusion_threshold_bytes=thr, overlap=False)
-    tx_on = hvd_mod.DistributedOptimizer(
-        optax.sgd(0.05), axis_name=hvd.rank_axis(),
-        fusion_threshold_bytes=thr, overlap=True)
+    tx = hvd_mod.DistributedOptimizer(
+        optax.sgd(0.05), axis_name=ax, fusion_threshold_bytes=thr)
+    ref = _reference_tx(optax.sgd(0.05), _flat_bucket_reduce(ax, thr))
 
-    p_off, l_off = _train(hvd, tx_off, params, X, Y)
-    p_on, l_on = _train(hvd, tx_on, params, X, Y)
-
-    # Same buckets, different order/chain: the per-bucket collective
-    # contents are identical arrays, so CPU results match bitwise.
-    np.testing.assert_array_equal(np.asarray(l_off), np.asarray(l_on))
-    for a, b in zip(jax.tree.leaves(p_off), jax.tree.leaves(p_on)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    p_ref, l_ref = _train(hvd, ref, params, X, Y)
+    p_def, l_def = _train(hvd, tx, params, X, Y)
+    np.testing.assert_array_equal(np.asarray(l_ref), np.asarray(l_def))
+    _assert_trees_bitwise(p_ref, p_def)
 
 
-def test_overlap_equivalence_grad_fn(hvd, rng):
+GRADFN_CASES = {
+    "average_none": (hvd_mod.Average, "none"),
+    "average_bf16": (hvd_mod.Average, "bf16"),
+    "sum_none": (hvd_mod.Sum, "none"),
+    "sum_bf16": (hvd_mod.Sum, "bf16"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRADFN_CASES))
+def test_overlap_equivalence_grad_fn(hvd, rng, case):
+    """``DistributedGradFn`` shares ``_reduce_tree``: the same decision
+    (no copy under the reduce scope) and the flat buckets' bits."""
+    op, wire = GRADFN_CASES[case]
     width = 8
     params = _mlp_tree(rng, depth=3, width=width)
     X = rng.standard_normal((16, width)).astype(np.float32)
     ax = hvd.rank_axis()
+    thr = (width * width + width) * 4
 
     def loss_fn(p, xb):
         h = xb
@@ -257,42 +213,34 @@ def test_overlap_equivalence_grad_fn(hvd, rng):
             h = jnp.tanh(h @ p[k]["w"] + p[k]["b"])
         return jnp.mean(h ** 2)
 
-    def grads_with(overlap_on):
-        gfn = hvd_mod.DistributedGradFn(
-            jax.grad(loss_fn), axis_name=ax,
-            fusion_threshold_bytes=(width * width + width) * 4,
-            overlap=overlap_on)
-
-        @hvd.spmd_step(in_specs=(P(), P(ax)), out_specs=P())
-        def run(p, xb):
-            return gfn(p, xb)
-
-        return run(params, X)
-
-    g_off, g_on = grads_with(False), grads_with(True)
-    for a, b in zip(jax.tree.leaves(g_off), jax.tree.leaves(g_on)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    gfn = hvd_mod.DistributedGradFn(
+        jax.grad(loss_fn), op=op, axis_name=ax, compression=wire,
+        fusion_threshold_bytes=thr)
+    reduce = _flat_bucket_reduce(ax, thr, op, Compression.by_name(wire))
+    run = hvd.spmd_step(gfn, in_specs=(P(), P(ax)), out_specs=P())
+    ref = hvd.spmd_step(lambda p, xb: reduce(jax.grad(loss_fn)(p, xb)),
+                        in_specs=(P(), P(ax)), out_specs=P())
+    _assert_trees_bitwise(ref(params, X), run(params, X))
+    text = run.lower(params, X).compile().as_text()
+    assert not _copies_under_reduce(text)
 
 
 def test_overlap_composes_with_compression(hvd, rng):
-    from horovod_tpu.ops.compression import Compression
-
     width = 8
     params = _mlp_tree(rng, depth=3, width=width)
     X = rng.standard_normal((16, width)).astype(np.float32)
     Y = rng.standard_normal((16, width)).astype(np.float32)
     thr = (width * width + width) * 4
+    ax = hvd.rank_axis()
 
-    def tx(overlap_on):
-        return hvd_mod.DistributedOptimizer(
-            optax.sgd(0.05), axis_name=hvd.rank_axis(),
-            compression=Compression.fp16, fusion_threshold_bytes=thr,
-            overlap=overlap_on)
-
-    p_off, _ = _train(hvd, tx(False), params, X, Y, steps=3)
-    p_on, _ = _train(hvd, tx(True), params, X, Y, steps=3)
-    for a, b in zip(jax.tree.leaves(p_off), jax.tree.leaves(p_on)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    tx = hvd_mod.DistributedOptimizer(
+        optax.sgd(0.05), axis_name=ax, compression=Compression.fp16,
+        fusion_threshold_bytes=thr)
+    ref = _reference_tx(optax.sgd(0.05), _flat_bucket_reduce(
+        ax, thr, compression=Compression.fp16))
+    p_ref, _ = _train(hvd, ref, params, X, Y, steps=3)
+    p_def, _ = _train(hvd, tx, params, X, Y, steps=3)
+    _assert_trees_bitwise(p_ref, p_def)
 
 
 # -- the default path: reduced where it lies (ISSUE 28) ---------------------
@@ -353,41 +301,51 @@ IN_PLACE_CASES = {
 }
 
 
+def _flat_reference_step(hvd, op=hvd_mod.Average, compression="none",
+                         prescale_factor=1.0, postscale_factor=1.0):
+    """The flat-bucket twin of ``_reduce_step`` with the same scales and
+    wire."""
+    ax = hvd.rank_axis()
+    reduce = _flat_bucket_reduce(ax, THR, op,
+                                 Compression.by_name(compression),
+                                 prescale_factor, postscale_factor)
+    return hvd.spmd_step(
+        lambda per_rank: reduce(jax.tree.map(lambda x: x[0], per_rank)),
+        in_specs=P(ax), out_specs=P())
+
+
 @pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
 def test_default_reduction_is_bitwise_the_flat_buckets(hvd, rng, case):
-    """No ``overlap`` argument (observe: axis bound, eight ranks) against
-    explicit ``overlap=False``, the parent's program."""
+    """Axis bound over eight ranks: each leaf reduced where it lies,
+    against the flat buckets' sum with the same scales and wire."""
     kind, kwargs = IN_PLACE_CASES[case]
     grads = _grad_tree(kind, rng)
-    flat = _reduce_step(hvd, overlap=False, **kwargs)(grads)
-    for chosen in ({}, {"overlap": True}):
-        got = _reduce_step(hvd, **chosen, **kwargs)(grads)
-        assert jax.tree.structure(got) == jax.tree.structure(flat)
-        for a, b in zip(jax.tree.leaves(flat), jax.tree.leaves(got)):
-            assert a.dtype == b.dtype and a.shape == b.shape
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    flat = _flat_reference_step(hvd, **kwargs)(grads)
+    _assert_trees_bitwise(flat, _reduce_step(hvd, **kwargs)(grads))
 
 
 @pytest.mark.parametrize("inner", ["sgd", "adamw"])
 def test_default_training_matches_overlap_false(hvd, rng, inner):
-    """Through a real optimizer over several steps. The reduced
-    gradients are bitwise the flat buckets' (above); a linear update
-    keeps that to the parameters. A stateful one XLA:CPU compiles next
-    to another producer (a leaf, not a slice of a bucket) and may
-    contract a multiply-add differently: an ulp, so ``adamw`` is held to
-    a few of them."""
+    """Through a real optimizer over several steps, against plain optax
+    on ``lax.pmean``-ed gradients. The reduced gradients are bitwise the
+    reference's (above; eight ranks, so the mean is exact either way); a
+    linear update keeps that to the parameters. A stateful one XLA:CPU
+    compiles next to another producer and may contract a multiply-add
+    differently: an ulp, so ``adamw`` is held to a few of them."""
     params = _mlp_tree(rng, depth=4, width=8)
     X = rng.standard_normal((16, 8)).astype(np.float32)
     Y = rng.standard_normal((16, 8)).astype(np.float32)
 
-    def tx(**kwargs):
-        return hvd_mod.DistributedOptimizer(
-            {"sgd": optax.sgd(0.05), "adamw": optax.adamw(1e-2)}[inner],
-            axis_name=hvd.rank_axis(), fusion_threshold_bytes=THR,
-            **kwargs)
+    ax = hvd.rank_axis()
 
-    p_flat, l_flat = _train(hvd, tx(overlap=False), params, X, Y, steps=4)
-    p_def, l_def = _train(hvd, tx(), params, X, Y, steps=4)
+    def make():
+        return {"sgd": optax.sgd(0.05), "adamw": optax.adamw(1e-2)}[inner]
+
+    tx = hvd_mod.DistributedOptimizer(make(), axis_name=ax,
+                                      fusion_threshold_bytes=THR)
+    ref = _reference_tx(make(), lambda g: jax.lax.pmean(g, ax))
+    p_flat, l_flat = _train(hvd, ref, params, X, Y, steps=4)
+    p_def, l_def = _train(hvd, tx, params, X, Y, steps=4)
     same = np.testing.assert_array_equal if inner == "sgd" else \
         functools.partial(np.testing.assert_allclose, rtol=2e-6, atol=1e-7)
     same(np.asarray(l_flat), np.asarray(l_def))
@@ -406,11 +364,13 @@ def _copies_under_reduce(compiled_text):
             and scopes.REDUCE in ln]
 
 
-def test_default_step_has_no_concatenate_under_hvd_reduce(hvd, rng):
-    grads = _grad_tree("plain", rng)
-    default = _reduce_step(hvd).lower(grads).compile().as_text()
-    flat = _reduce_step(hvd, overlap=False).lower(grads).compile().as_text()
-    assert _copies_under_reduce(flat)            # the twin still packs
+@pytest.mark.parametrize("case", sorted(IN_PLACE_CASES))
+def test_default_step_has_no_concatenate_under_hvd_reduce(hvd, rng, case):
+    """The structural half of the decision, for every input that takes
+    it: no bucket is packed or unpacked."""
+    kind, kwargs = IN_PLACE_CASES[case]
+    grads = _grad_tree(kind, rng)
+    default = _reduce_step(hvd, **kwargs).lower(grads).compile().as_text()
     assert not _copies_under_reduce(default)
     names = _op_names(default)
     assert not any(scopes.PACK in n.split("/") or scopes.UNPACK
@@ -439,8 +399,7 @@ NEEDS_A_FLAT_BUFFER = {
 @pytest.mark.parametrize("case", sorted(NEEDS_A_FLAT_BUFFER))
 def test_paths_that_need_a_flat_buffer_keep_it(hvd, rng, case):
     """Block-scaled payloads, the router's and the staged pipeline's
-    shards and Adasum's per-bucket dot products: the default (no
-    ``overlap`` argument) still packs, as the parent did."""
+    shards and Adasum's per-bucket dot products: these still pack."""
     kwargs = NEEDS_A_FLAT_BUFFER[case]
     grads = _grad_tree("plain", rng)
     if "route" in kwargs or "hierarchical" in kwargs:
@@ -473,17 +432,15 @@ UNBOUND_JAXPR = """\
   in (m, n, o, p) }"""
 
 
-@pytest.mark.parametrize("overlap_arg", [{}, {"overlap": False},
-                                         {"overlap": True}],
-                         ids=["default", "false", "true"])
-def test_step_with_no_axis_bound_is_the_parents_jaxpr(overlap_arg):
+@pytest.mark.parametrize("case", ["default"])
+def test_step_with_no_axis_bound_is_the_parents_jaxpr(case):
     tree = {"a": {"b": jnp.ones((3,), jnp.float32),
                   "w": jnp.ones((2, 3), jnp.float32)},
             "s": jnp.ones((), jnp.float32),
             "z": jnp.ones((4,), jnp.bfloat16)}
     tx = hvd_mod.DistributedOptimizer(
         optax.sgd(0.5), axis_name="hvd", compression="none",
-        fusion_threshold_bytes=16, **overlap_arg)
+        fusion_threshold_bytes=16)
     state = tx.init(tree)
     text = str(jax.make_jaxpr(lambda g: tx.update(g, state, tree)[0])(tree))
     assert text == UNBOUND_JAXPR
@@ -502,113 +459,62 @@ def test_spmd_step_gives_a_cpu_mesh_no_compiler_options(hvd, monkeypatch):
     assert [k.get("compiler_options") for k in seen] == [None]
 
 
-# -- staged per-group VJP ---------------------------------------------------
-
-def test_staged_value_and_grad_matches_monolithic(rng):
-    width = 6
-    stages = 3
-    params = [
-        {"w": jnp.asarray(rng.standard_normal((width, width))
-                          .astype(np.float32)) * 0.3,
-         "b": jnp.zeros((width,), jnp.float32)}
-        for _ in range(stages)]
-    x = jnp.asarray(rng.standard_normal((4, width)).astype(np.float32))
-
-    def stage_fn(p, act):
-        return jnp.tanh(act @ p["w"] + p["b"])
-
-    def loss_fn(act):
-        return jnp.mean(act ** 2)
-
-    def monolithic(ps):
-        act = x
-        for p in ps:
-            act = stage_fn(p, act)
-        return loss_fn(act)
-
-    ref_loss, ref_grads = jax.value_and_grad(monolithic)(params)
-    loss, grads = overlap.staged_value_and_grad(
-        [stage_fn] * stages, loss_fn, params, x)
-    np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(ref_grads), jax.tree.leaves(grads)):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-
-    # With a reduce_fn the chain applies it per stage — scale by 2 and
-    # check both the math and the barrier in the traced program.
-    loss2, grads2 = overlap.staged_value_and_grad(
-        [stage_fn] * stages, loss_fn, params, x,
-        reduce_fn=lambda g: jax.tree.map(lambda v: v * 2.0, g))
-    for a, b in zip(jax.tree.leaves(ref_grads), jax.tree.leaves(grads2)):
-        np.testing.assert_allclose(np.asarray(a) * 2.0, np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
-    text = str(jax.make_jaxpr(lambda ps: overlap.staged_value_and_grad(
-        [stage_fn] * stages, loss_fn, ps, x,
-        reduce_fn=lambda g: g)[1])(params))
-    assert "optimization_barrier" in text
-
-    with pytest.raises(ValueError, match="stage fns"):
-        overlap.staged_value_and_grad([stage_fn], loss_fn, params, x)
+def test_axis_bound_over_one_rank_takes_the_flat_path(rng):
+    """``axis_size > 1`` is part of the decision: a one-device mesh is
+    the one-chip cells' program with the axis bound, so it packs as they
+    do, and what all-reduce XLA:CPU keeps spans that one device."""
+    grads = jax.tree.map(lambda x: x[:1], _grad_tree("plain", rng))
+    mesh = Mesh(np.array(jax.devices()[:1]), ("hvd",))
+    text = _mesh_step(mesh, "hvd", axis_name="hvd").lower(
+        grads).compile().as_text()
+    assert any(scopes.REDUCE_PACK in n for n in _op_names(text))
+    groups = re.findall(r" all-reduce\(.*replica_groups=(\{[^ ]*\}),", text)
+    assert set(groups) <= {"{{0}}"}, groups
 
 
-# -- autotune over the (threshold, hierarchical, overlap) space -------------
-
-def test_autotuner_triple_space_converges():
-    mb = 1024 * 1024
-    candidates = [4 * mb, 16 * mb, 64 * mb]
-    base = {4 * mb: 300.0, 16 * mb: 1000.0, 64 * mb: 500.0}
-    t = Autotuner(candidates_bytes=candidates, warmup_samples=0,
-                  steps_per_sample=2, tune_hierarchical=True,
-                  tune_overlap=True)
-    assert len(t._space) == len(candidates) * 2 * 2
-    for _ in range(200):
-        for _ in range(t.steps_per_sample):
-            score = base[t.current] \
-                * (2.0 if t.current_hierarchical else 1.0) \
-                * (1.5 if t.current_overlap else 1.0)
-            t.record(score, 1.0)
-        if t.ready():
-            t.suggest()
-        if t.done:
-            break
-    assert t.done
-    assert t.current == 16 * mb
-    assert t.current_hierarchical is True
-    assert t.current_overlap is True
+def test_defaulted_route_on_a_flat_mesh_reduces_in_place(hvd, rng,
+                                                         monkeypatch):
+    """A route that arrives as a default (``HVD_TPU_ROUTE``) and whose
+    axes the step does not bind falls back to the live rank axis; the
+    shape is then decided as if no route had been given."""
+    monkeypatch.setattr(hvd_mod.common.basics.context().config, "route",
+                        "staged")
+    grads = _grad_tree("plain", rng)
+    step = _reduce_step(hvd)
+    monkeypatch.undo()
+    _assert_trees_bitwise(_flat_reference_step(hvd)(grads), step(grads))
+    assert not _copies_under_reduce(step.lower(grads).compile().as_text())
 
 
-def test_autotuner_triple_csv_columns(tmp_path):
-    log = str(tmp_path / "triple.csv")
-    t = Autotuner(candidates_bytes=[1024, 2048], warmup_samples=0,
-                  steps_per_sample=1, tune_overlap=True, log_file=log)
-    t.record(100.0, 1.0)
-    t.suggest()
-    lines = open(log).read().strip().splitlines()
-    assert lines[0] == ("unix_time,threshold_bytes,overlap,"
-                       "score_bytes_per_sec,steps")
-    assert len(lines[1].split(",")) == 5
-
-
-def test_stepper_triple_rebuilds_on_overlap_change():
-    from horovod_tpu.optim import AutotunedStepper
-
-    t = Autotuner(candidates_bytes=[1024, 2048], warmup_samples=0,
-                  steps_per_sample=1, tune_hierarchical=True,
-                  tune_overlap=True)
+def test_error_feedback_plan_is_flatten_order_for_mixed_dtypes(
+        hvd, monkeypatch):
+    """``_reduce_tree_ef`` plans as every sharded surface does: bucket
+    ``i`` of the plan is what ``_ef_key(step, i)`` seeds, so the order
+    is part of the numerics. Mixed dtypes interleaved: float32 opens
+    first and is bucket 0."""
+    ax = hvd.rank_axis()
+    tree = {"a0": jnp.ones((8, 64), jnp.float32),
+            "b": jnp.ones((8, 64), jnp.bfloat16),
+            "z1": jnp.ones((8, 64), jnp.float32)}
     seen = []
+    real = fusion.assign_wire_dtypes
 
-    def build(threshold, hierarchical, overlap_on):
-        seen.append((threshold, hierarchical, overlap_on))
-        return lambda x: x + 1
+    def spy(plan, qmin, **kw):
+        out = real(plan, qmin, **kw)
+        seen.append(out)
+        return out
 
-    stepper = AutotunedStepper(build, grad_bytes=1000, tuner=t,
-                               block=False)
-    for i in range(30):
-        stepper(i)
-        if t.done:
-            break
-    assert stepper.rebuilds >= 1
-    assert any(o for _, _, o in seen) and any(not o for _, _, o in seen), \
-        seen
-    assert stepper.overlap in (True, False)
-    assert len(seen[0]) == 3
+    tx = hvd_mod.DistributedOptimizer(
+        optax.identity(), axis_name=ax, compression="int8_ef",
+        fusion_threshold_bytes=1 << 20, quantize_min_bucket_bytes=256)
+
+    def run(per_rank):
+        g = jax.tree.map(lambda x: x[0], per_rank)
+        return tx.update(g, tx.init(g), g)[0]
+
+    monkeypatch.setattr(fusion, "assign_wire_dtypes", spy)
+    hvd.spmd_step(run, in_specs=P(ax), out_specs=P()).lower(tree)
+    (plan,) = seen
+    assert [str(b.dtype) for b in plan.buckets] == ["float32", "bfloat16"]
+    assert [b.leaf_indices for b in plan.buckets] == [(0, 2), (1,)]
+    assert plan.wire_dtypes == ("int8", "none")

@@ -14,6 +14,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.common import scopes
+from horovod_tpu.ops import collectives as C
 from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import pallas_kernels as pk
 
@@ -22,12 +23,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 OPTIMIZERS = {
     "none": dict(compression="none"),
     "int8_ef": dict(compression="int8_ef", quantize_min_bucket_bytes=1024),
-    "overlap": dict(compression="none", overlap=True),
-    "flat": dict(compression="none", overlap=False),
+    "bf16": dict(compression="bf16"),
+    "adasum": dict(compression="none", op=C.ReduceOp.ADASUM),
 }
-# A per-element wire over the flat axis reduces each gradient where it
-# lies: no flat bucket, so nothing under pack / unpack.
-IN_PLACE = {"none", "overlap"}
+# A linear op with a per-element wire over the flat axis reduces each
+# gradient where it lies: no flat bucket, so nothing under pack / unpack.
+# Adasum's per-bucket dots keep the bucket.
+IN_PLACE = {"none", "bf16"}
 
 
 def _model(family):

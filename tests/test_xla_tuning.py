@@ -1,71 +1,11 @@
-"""Overlap-flag helper tests (tier-1-safe, no backend init): the merge
-must be idempotent, must never clobber user-set entries, and must put
-the TPU compiler's flags where libtpu reads them — never into XLA_FLAGS,
-where jaxlib aborts on them."""
+"""The overlap flag set and its one route to the compiler (tier-1-safe,
+no TPU): ``step_compiler_options`` hands the set to a TPU mesh of
+several devices and to nothing else, never writes the environment, and
+leaves a flag the user set in ``LIBTPU_INIT_ARGS`` to the user."""
 
 import pytest
 
 from horovod_tpu.common import xla_tuning
-
-
-def test_merge_appends_only_missing_flags():
-    existing = "--xla_tpu_some_user_choice=8"
-    merged = xla_tuning.merge_flags(existing,
-                                    xla_tuning.TPU_OVERLAP_FLAGS)
-    toks = merged.split()
-    # User token survives, in place, first.
-    assert toks[0] == existing
-    for name, value in xla_tuning.TPU_OVERLAP_FLAGS:
-        assert f"{name}={value}" in toks
-
-
-def test_merge_preserves_user_value_for_same_flag():
-    name = xla_tuning.TPU_OVERLAP_FLAGS[0][0]
-    user = f"{name}=false"
-    merged = xla_tuning.merge_flags(user, xla_tuning.TPU_OVERLAP_FLAGS)
-    toks = merged.split()
-    assert user in toks
-    # The helper's value for that flag must NOT appear alongside.
-    assert f"{name}=true" not in toks
-    assert sum(xla_tuning.flag_name(t) == name for t in toks) == 1
-
-
-def test_enable_is_idempotent():
-    env = {"LIBTPU_INIT_ARGS": "--xla_foo=bar"}
-    first = xla_tuning.enable_overlap_scheduling(env)
-    second = xla_tuning.enable_overlap_scheduling(env)
-    assert first == second == env["LIBTPU_INIT_ARGS"]
-    assert env["LIBTPU_INIT_ARGS"].split().count("--xla_foo=bar") == 1
-    assert xla_tuning.overlap_flags_active(env)
-
-
-def test_enable_never_touches_xla_flags():
-    """jaxlib aborts the process on an XLA_FLAGS name it does not know
-    — on every backend, the CPU included — and it knows none of these:
-    they go to libtpu's own variable, which nothing else reads."""
-    existing = "--xla_force_host_platform_device_count=8"
-    for env in ({"XLA_FLAGS": existing, "JAX_PLATFORMS": "cpu"},
-                {"XLA_FLAGS": existing, "JAX_PLATFORMS": "tpu"},
-                {"XLA_FLAGS": existing}):
-        out = xla_tuning.enable_overlap_scheduling(env)
-        assert env["XLA_FLAGS"] == existing
-        assert env["LIBTPU_INIT_ARGS"] == out
-        assert xla_tuning.overlap_flags_active(env)
-
-
-def test_overlap_flags_inactive_until_enabled():
-    assert not xla_tuning.overlap_flags_active({})
-    # The old home of the flags no longer counts.
-    assert not xla_tuning.overlap_flags_active({"XLA_FLAGS": " ".join(
-        f"{n}={v}" for n, v in xla_tuning.TPU_OVERLAP_FLAGS)})
-
-
-def test_extra_flags_and_bare_flag_names():
-    env = {"LIBTPU_INIT_ARGS": "--xla_dump_to"}  # bare flag, no value
-    out = xla_tuning.enable_overlap_scheduling(
-        env, extra_flags=(("--xla_custom_knob", "7"),))
-    assert "--xla_custom_knob=7" in out.split()
-    assert "--xla_dump_to" in out.split()
 
 
 def test_flag_set_is_what_the_chip_kept():
@@ -112,12 +52,15 @@ def test_step_options_leave_user_set_flags_alone():
     opts = xla_tuning.step_compiler_options(_mesh_devices("tpu", 4), env)
     assert name[2:] not in opts
     assert len(opts) == len(xla_tuning.TPU_OVERLAP_FLAGS) - 1
-    # the whole set pinned by the user: nothing left to ask for
-    env = {"LIBTPU_INIT_ARGS": xla_tuning.enable_overlap_scheduling({})}
+    # the whole set pinned by the user, one flag bare (no value): nothing
+    # left to ask for
+    pinned = [name] + [f"{n}={v}"
+                       for n, v in xla_tuning.TPU_OVERLAP_FLAGS[1:]]
+    env = {"LIBTPU_INIT_ARGS": " ".join(pinned)}
     assert xla_tuning.step_compiler_options(
         _mesh_devices("tpu", 4), env) is None
     # and the call itself writes nowhere
-    assert set(env) == {"LIBTPU_INIT_ARGS"}
+    assert env == {"LIBTPU_INIT_ARGS": " ".join(pinned)}
 
 
 def test_step_options_accept_a_flat_list_of_devices():
@@ -126,11 +69,22 @@ def test_step_options_accept_a_flat_list_of_devices():
     assert opts is not None
 
 
-def test_config_knob_parses_env(monkeypatch):
-    from horovod_tpu.common.config import Config
+def test_step_options_read_a_mesh_of_any_rank():
+    """``spmd_step`` passes ``mesh.devices``, an array of the mesh's own
+    shape: a 2x2 TPU mesh is four devices, not two rows."""
+    grid = _mesh_devices("tpu", 4).reshape(2, 2)
+    assert xla_tuning.step_compiler_options(grid, env={}) == \
+        xla_tuning.step_compiler_options(_mesh_devices("tpu", 4), env={})
+    # one row of one device is still one chip
+    assert xla_tuning.step_compiler_options(
+        _mesh_devices("tpu", 1).reshape(1, 1), env={}) is None
 
-    monkeypatch.delenv("HVD_TPU_OVERLAP_XLA_FLAGS", raising=False)
-    monkeypatch.delenv("HOROVOD_OVERLAP_XLA_FLAGS", raising=False)
-    assert Config.from_env().overlap_xla_flags is False
-    monkeypatch.setenv("HVD_TPU_OVERLAP_XLA_FLAGS", "1")
-    assert Config.from_env().overlap_xla_flags is True
+
+def test_step_options_for_the_devices_of_a_real_cpu_mesh():
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()).reshape(2, -1), ("cross", "local"))
+    assert mesh.devices.size > 1
+    assert xla_tuning.step_compiler_options(mesh.devices, env={}) is None

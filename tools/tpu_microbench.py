@@ -10,9 +10,6 @@ Sections:
              dispatched then synchronized once vs N blocking host
              round-trips, plus compute-overlap (independent matmul chain
              issued while a large collective is in flight).
-  grad_overlap — in-jit backward/collective overlap: readiness-ordered
-             bucketed reduce (overlap=True) vs the monolithic
-             whole-tree reduce on a deep MLP; ratio ≈ 1.0 off-TPU.
   fusion   — grouped (fused-bucket) vs per-tensor eager allreduce.
 
 Unlike tools/perf_evidence.py this does NOT force the CPU backend — it
@@ -276,83 +273,6 @@ def overlap_section():
         compute["serialized_ms"] / compute["overlapped_ms"], 2)
     return {"dispatch": dispatch, "compute_overlap": compute,
             "world_size": hvd.size()}
-
-
-def grad_overlap_section():
-    """Overlap-aware gradient fusion (the ISSUE-1 tentpole): a deep MLP
-    trained with the whole-tree monolithic reduce (one bucket, can only
-    start after ALL of backward) vs readiness-ordered buckets + issue-
-    order chaining (``overlap=True``: reverse-flatten buckets fire while
-    backprop still computes earlier layers). On a TPU pod with the
-    latency-hiding scheduler the ratio is the overlap win; on CPU or a
-    single chip it degrades gracefully to ~1.0 (same numerics either
-    way — tests/test_overlap.py proves bitwise equality)."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    import horovod_tpu as hvd
-    from horovod_tpu.common import fusion as fusion_lib
-
-    hvd.init()
-    n = hvd.size()
-    ax = hvd.rank_axis()
-    depth, width = (4, 64) if SMALL else (16, 1024)
-    batch = 4 * n if SMALL else 16 * n
-
-    rng = jax.random.PRNGKey(11)
-    params = {
-        f"layer{i:02d}": {
-            "w": jax.random.normal(jax.random.fold_in(rng, i),
-                                   (width, width), jnp.float32) * 0.05,
-            "b": jnp.zeros((width,), jnp.float32),
-        } for i in range(depth)}
-    x = jax.random.normal(jax.random.fold_in(rng, 100), (batch, width))
-    y = jax.random.normal(jax.random.fold_in(rng, 101), (batch, width))
-
-    def loss(p, xb, yb):
-        h = xb
-        for i in range(depth):
-            layer = p[f"layer{i:02d}"]
-            h = jnp.tanh(h @ layer["w"] + layer["b"])
-        return jnp.mean((h - yb) ** 2)
-
-    # ~2 layers per bucket -> depth/2 collectives to interleave with the
-    # backward walk; the monolithic arm uses one huge bucket.
-    bucketed_threshold = 2 * (width * width + width) * 4
-    n_buckets = len(fusion_lib.plan_fusion(
-        params, bucketed_threshold, order="reverse").buckets)
-
-    def build(overlap):
-        gfn = hvd.DistributedGradFn(
-            jax.value_and_grad(loss), axis_name=ax, has_value=True,
-            fusion_threshold_bytes=(bucketed_threshold if overlap
-                                    else 1 << 30),
-            overlap=overlap)
-
-        @hvd.spmd_step(in_specs=(P(), P(ax), P(ax)),
-                       out_specs=(P(), P()))
-        def step(p, xb, yb):
-            l, g = gfn(p, xb, yb)
-            newp = jax.tree.map(lambda w, gg: w - 0.01 * gg, p, g)
-            return newp, l
-
-        return step
-
-    serial_step, overlap_step = build(False), build(True)
-    out = {
-        "world_size": n,
-        "depth": depth,
-        "width": width,
-        "buckets_overlapped": n_buckets,
-        "serialized_ms": round(_time_ms(
-            lambda: serial_step(params, x, y)), 3),
-        "overlapped_ms": round(_time_ms(
-            lambda: overlap_step(params, x, y)), 3),
-    }
-    out["speedup"] = round(out["serialized_ms"] / out["overlapped_ms"], 2)
-    _log(f"grad_overlap: {out}")
-    return out
 
 
 def fusion_section():
@@ -963,7 +883,7 @@ def seq_attention_section():
 
 
 SECTIONS = {"flash": flash_section, "striped": striped_section,
-            "overlap": overlap_section, "grad_overlap": grad_overlap_section,
+            "overlap": overlap_section,
             "fusion": fusion_section, "kernels": kernels_section,
             "compression": compression_section,
             "mesh_routing": mesh_routing_section,
