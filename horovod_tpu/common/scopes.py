@@ -21,7 +21,10 @@ LM_HEAD = "hvd_lm_head"      # models/gpt.py, bert.py, looplm.py: vocabulary mat
 # the weighting of the exits' losses
 LOOP_EXIT = "hvd_loop_exit"
 
-# Pallas kernels: the ``name=`` of each ``pallas_call``.
+# Pallas kernels: the ``name=`` of each ``pallas_call``. FLASH_DKV is the
+# whole flash backward: the dk/dv call also gives dq. FLASH_DQ is carried
+# by no call since then; it stays because the benchmark's data file
+# quotes FLASH_KERNELS (its ``flash_dq_ms`` reads 0.000).
 FLASH_FWD = "hvd_flash_fwd"
 FLASH_DQ = "hvd_flash_dq"
 FLASH_DKV = "hvd_flash_dkv"

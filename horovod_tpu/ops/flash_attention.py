@@ -35,15 +35,22 @@ no ``where``; under ``causal`` only the blocks the diagonal crosses pay
 for the iota / compare / select, blocks under it run bare and blocks
 above it are skipped.
 
-Three kernels, named in common/scopes.py. Forward and dq: grid
-(B, H/G, S/block_q), K/V whole in VMEM per (batch, head group), an
-in-kernel loop over their blocks with carried fp32 state. dk/dv: grid
-(B, H/G, S/block_k, S/block_q) with the q blocks innermost and dk, dv in
-fp32 VMEM scratch, so VMEM holds O(block_q + block_k) rows; it computes
-the scores transposed (k·qᵀ), which makes dv = pᵀ·dO and dk = dsᵀ·q
-plain matmuls and lets the row vectors broadcast along sublanes.
-``block_q`` / ``block_k`` default to a choice from S, D, the dtype and
-a VMEM budget (``_choose_blocks``); passing them caps the choice.
+Two kernels, named in common/scopes.py. Forward: grid (B, H/G,
+S/block_q), K/V whole in VMEM per (batch, head group), an in-kernel loop
+over their blocks with carried fp32 state. Backward, one call for dq, dk
+and dv: grid (B, H/G, S/block_k, S/block_q) with the q blocks innermost.
+It computes the scores of a block once, transposed (k·qᵀ), which makes
+dv = pᵀ·dO and dk = dsᵀ·q plain matmuls and lets the row vectors
+broadcast along sublanes; dq's share, (dsᵀ)ᵀ·k, is the one transposed
+product: five S×S×d products and one ``exp`` a block. dk and dv wait in
+fp32 VMEM scratch while the q blocks pass; dq waits in an fp32 scratch
+over the whole sequence of the (batch, head group), (S, lanes), added to
+in ascending k order, and leaves through an output block of the same
+extent, so it goes to HBM once and no partial dq ever does: S × lanes ×
+(4 + 2 × itemsize) bytes, the size class of the K and V the forward
+holds. ``block_q`` / ``block_k`` default to a choice from S, D, the
+dtype and a VMEM budget (``_choose_blocks``); passing them caps the
+choice.
 
 ``flash_attention`` (what the models call) has a backward with no lse
 cotangent at all; ``flash_attention_with_lse`` (ring attention's
@@ -116,11 +123,13 @@ def _lane_block(s: int, target: int) -> Optional[int]:
 
 
 def _vmem_estimate(s: int, d: int, itemsize: int, bq: int, bk: int) -> int:
-    """Bytes of VMEM the hungriest of the three calls plans for: K and V
-    whole (forward, dq) and the q-side / output blocks, each double
-    buffered and lane-padded, plus the fp32 (bq, bk) temporaries."""
+    """Bytes of VMEM the hungrier of the two calls plans for. What stays
+    for a whole sequence: K and V, double buffered (forward), or dq as an
+    fp32 accumulator and its double-buffered output (backward). Beside
+    it the q-side / k-side / output blocks, each double buffered and
+    lane-padded, and the fp32 (bq, bk) temporaries."""
     lanes = -(-d // _LANE) * _LANE
-    resident = 2 * 2 * s * lanes * itemsize
+    resident = s * lanes * max(2 * 2 * itemsize, 4 + 2 * itemsize)
     blocks = 2 * 4 * max(bq, bk) * lanes * itemsize
     scores = 6 * bq * bk * 4
     return resident + blocks + scores
@@ -252,7 +261,7 @@ def _loop_key_blocks(step, init, qi, block_q, block_k, nk, causal):
 
 
 def _key_block(k_ref, v_ref, m_ref, qi, j, block_q, block_k, on_diagonal):
-    """What a step of the forward / dq loop reads for k block j: the K
+    """What a step of the forward's loop reads for k block j: the K
     and V tiles (bk, lanes), the key mask (1, bk) or None, and the causal
     select (bq, bk) or None."""
     ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
@@ -301,46 +310,36 @@ def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
     o_ref[...] = sum(outs[1:], outs[0]).astype(o_ref.dtype)
 
 
-def _dq_kernel(*refs, block_k, causal, scale, has_mask):
+def _pt_dst(qg, dog, k, v, lse, dd, on_scores, kmask, keep):
+    """(pᵀ, dsᵀ) of one head over a (bk, bq) block, in fp32: the scores
+    transposed (k·qᵀ), so that the rows ``lse`` and ``dd`` (1, bq)
+    broadcast along sublanes and dv = pᵀ·dO, dk = dsᵀ·q are plain
+    products. dd = delta - dlse, delta_i = rowsum(dO_i * o_i): lse =
+    logsumexp(s) and dlse/ds = p, so an lse cotangent folds into ds as
+    p * dlse. ``kmask`` (bk, 1) and ``keep`` (bk, bq) or None."""
+    pt = jnp.exp(_masked(_scores(k, qg, on_scores), kmask, keep) - lse)
+    return pt, pt * (_dot(v, dog, _NT) - dd)
+
+
+def _bwd_kernel(*refs, causal, scale, has_mask):
+    """dq, dk and dv of one (q block, k block) of a head group: the
+    scores are built once (transposed, k·qᵀ) and feed all three."""
     q_ref, k_ref, v_ref = refs[:3]
     m_ref = refs[3] if has_mask else None
-    do_ref, lse_ref, dd_ref, dq_ref = refs[-4:]
-    block_q, lanes = q_ref.shape
-    heads = lse_ref.shape[0]
-    nk = k_ref.shape[0] // block_k
-    qi = pl.program_id(2)
-    q, on_scores = _scaled(q_ref, scale)
-    do = do_ref[...]
-    # Rows as (bq, 1) columns. dd = delta - dlse, delta_i = rowsum(dO_i *
-    # o_i): lse = logsumexp(s) and dlse/ds = p, so an lse cotangent
-    # folds into ds as p * dlse.
-    lse = [lse_ref[g, 0, :][:, None] for g in range(heads)]
-    dd = [dd_ref[g, 0, :][:, None] for g in range(heads)]
-
-    def step(j, dq, on_diagonal):
-        k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
-                                       block_k, on_diagonal)
-        for g in range(heads):
-            kg = _head(k, g, heads)
-            s = _masked(_scores(q, kg, on_scores), kmask, keep)
-            p = jnp.exp(s - lse[g])                         # (bq, bk)
-            ds = p * (_dot(do, _head(v, g, heads), _NT) - dd[g])
-            dq = dq + _dot(ds.astype(k.dtype), kg, _NN)
-        return dq
-
-    dq = _loop_key_blocks(step, jnp.zeros((block_q, lanes), jnp.float32),
-                          qi, block_q, block_k, nk, causal)
-    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
-
-
-def _dkv_kernel(*refs, causal, scale, has_mask):
-    q_ref, k_ref, v_ref = refs[:3]
-    m_ref = refs[3] if has_mask else None
-    do_ref, lse_ref, dd_ref, dk_ref, dv_ref, dk_acc, dv_acc = refs[-7:]
+    (do_ref, lse_ref, dd_ref, dq_ref, dk_ref, dv_ref,
+     dq_acc, dk_acc, dv_acc) = refs[-9:]
     block_q = q_ref.shape[0]
     block_k = k_ref.shape[0]
     heads = lse_ref.shape[0]
     ki, qi = pl.program_id(2), pl.program_id(3)
+    # This q block's rows of dq, which VMEM holds for the whole sequence:
+    # zeroed where the first k block meets them, added to at every visible
+    # block (so in ascending k order, in fp32), written out after the last.
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
     @pl.when(qi == 0)
     def _():
@@ -358,14 +357,16 @@ def _dkv_kernel(*refs, causal, scale, has_mask):
             else None
         for g in range(heads):
             qg, dog = _head(q, g, heads), _head(do, g, heads)
-            st = _masked(_scores(k, qg, on_scores), kmask,
-                         keep)                              # (bk, bq) = sᵀ
-            pt = jnp.exp(st - lse_ref[g])                   # rows (1, bq)
+            pt, dst = _pt_dst(qg, dog, k, v, lse_ref[g], dd_ref[g],
+                              on_scores, kmask, keep)
             dv_acc[...] += _dot(pt.astype(do.dtype), dog, _NN)
-            dst = pt * (_dot(v, dog, _NT) - dd_ref[g])
+            dst = dst.astype(q.dtype)
             # dk = scale * dsᵀ·q: q carries the scale already, or the
             # accumulator takes it when it is written out
-            dk_acc[...] += _dot(dst.astype(q.dtype), qg, _NN)
+            dk_acc[...] += _dot(dst, qg, _NN)
+            # dq's share, ds·k: the one transposed product, of the tile
+            # already cast for the MXU (half the vregs through the XLU)
+            dq_acc[rows, :] += _dot(dst.T, _head(k, g, heads), _NN)
 
     if causal:
         bare = qi * block_q >= (ki + 1) * block_k - 1
@@ -373,8 +374,16 @@ def _dkv_kernel(*refs, causal, scale, has_mask):
         pl.when(bare)(lambda: block(False))
         pl.when(jnp.logical_and(visible, jnp.logical_not(bare)))(
             lambda: block(True))
+        # the next k block starts past this q block's last row
+        last_k = jnp.logical_and(
+            visible, (ki + 1) * block_k >= (qi + 1) * block_q)
     else:
         block(False)
+        last_k = ki == pl.num_programs(2) - 1
+
+    @pl.when(last_k)
+    def _():
+        dq_ref[rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
 
     @pl.when(qi == pl.num_programs(3) - 1)
     def _():
@@ -451,9 +460,8 @@ class _Layout:
 
 
 def _q_major_specs(layout, s, bq):
-    """Block specs of the forward and dq calls, grid (B, H/G, S/bq): a
-    q-side tile, K / V whole, the (B, 1, S) key mask whole, and a row
-    slice."""
+    """Block specs of the forward call, grid (B, H/G, S/bq): a q-side
+    tile, K / V whole, the (B, 1, S) key mask whole, and a row slice."""
     q_spec = layout.tile(bq, lambda b, g, i: (b, g, i))
     kv_spec = layout.tile(s, lambda b, g, i: (b, g, 0))
     m_spec = pl.BlockSpec((None, 1, s), lambda b, g, i: (b, 0, 0))
@@ -461,11 +469,14 @@ def _q_major_specs(layout, s, bq):
     return q_spec, kv_spec, m_spec, row_spec
 
 
-def _k_major_specs(layout, bq, bk, causal):
-    """Block specs of the dk/dv call, grid (B, H/G, S/bk, S/bq), q blocks
-    innermost. Under ``causal`` the q blocks above the diagonal are
-    skipped; their index is clamped to the first visible one so that a
-    skipped step fetches nothing new."""
+def _k_major_specs(layout, s, bq, bk, causal):
+    """Block specs of the backward call, grid (B, H/G, S/bk, S/bq), q
+    blocks innermost: a q-side tile, a k-side tile, a block of the key
+    mask, a row slice, and dq's tile. Under ``causal`` the q blocks
+    above the diagonal are skipped; their index is clamped to the first
+    visible one so that a skipped step fetches nothing new. dq's tile is
+    the whole sequence of a (batch, head group): it stays in VMEM over
+    both block dimensions and goes to HBM once."""
     def qi(j, i):
         return jnp.maximum(i, jax.lax.div(j * bk, bq)) if causal else i
 
@@ -473,17 +484,19 @@ def _k_major_specs(layout, bq, bk, causal):
     kv_spec = layout.tile(bk, lambda b, g, j, i: (b, g, j))
     m_spec = pl.BlockSpec((None, 1, bk), lambda b, g, j, i: (b, 0, j))
     row_spec = layout.row(bq, lambda b, g, j, i: (b, g, qi(j, i)))
-    return q_spec, kv_spec, m_spec, row_spec
+    dq_spec = layout.tile(s, lambda b, g, j, i: (b, g, 0))
+    return q_spec, kv_spec, m_spec, row_spec, dq_spec
 
 
-def _compiler_params(s, d, itemsize, bq, bk, inner_arbitrary=False):
-    """Batch, head and the outer block dimension are independent; the
-    dk/dv call's innermost (q block) dimension accumulates. The scoped
-    VMEM limit follows the plan instead of shrinking the blocks."""
+def _compiler_params(s, d, itemsize, bq, bk, backward=False):
+    """Batch and head are independent, and so is the forward's q block
+    dimension; the backward call's two block dimensions accumulate (dk
+    and dv over the q blocks, dq over the k blocks). The scoped VMEM
+    limit follows the plan instead of shrinking the blocks."""
     plan = _vmem_estimate(s, d, itemsize, bq, bk)
+    blocks = ("arbitrary", "arbitrary") if backward else ("parallel",)
     return pltpu.CompilerParams(
-        dimension_semantics=("parallel",) * 3
-        + ("arbitrary",) * inner_arbitrary,
+        dimension_semantics=("parallel", "parallel") + blocks,
         vmem_limit_bytes=min(max(2 * plan, _VMEM_FLOOR), _VMEM_CEIL))
 
 
@@ -524,45 +537,39 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
     has_mask = mask3 is not None
     dot = layout.to_kernel(do)
     # delta_i = rowsum(dO_i * o_i) — one fused elementwise pass in-graph;
-    # with the lse cotangent folded in it is the one row operand both
-    # kernels read beside lse.
+    # with the lse cotangent folded in it is the one row operand the
+    # kernel reads beside lse.
     dd = layout.rowsum(dot, ot)
     if dlse is not None:
         dd = dd - dlse.astype(jnp.float32)
     dd = dd[:, :, None, :]
     masks = [mask3] * has_mask
-    kw = dict(causal=causal, scale=1.0 / np.sqrt(d), has_mask=has_mask)
-    itemsize = qt.dtype.itemsize
-
-    q_spec, kv_spec, m_spec, row_spec = _q_major_specs(layout, s, bq)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, block_k=bk, **kw),
-        grid=(b, layout.groups, s // bq),
-        in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
-        + [q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
-        compiler_params=_compiler_params(s, d, itemsize, bq, bk),
-        interpret=interpret,
-        name=scopes.FLASH_DQ,
-    )(qt, kt, vt, *masks, dot, lse, dd)
-
-    q_spec, kv_spec, m_spec, row_spec = _k_major_specs(layout, bq, bk,
-                                                       causal)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **kw),
+    q_spec, kv_spec, m_spec, row_spec, dq_spec = _k_major_specs(
+        layout, s, bq, bk, causal)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal,
+                          scale=1.0 / np.sqrt(d), has_mask=has_mask),
         grid=(b, layout.groups, s // bk, s // bq),
         in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
         + [q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(kt.shape, kt.dtype),
-                   jax.ShapeDtypeStruct(vt.shape, vt.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, layout.lanes), jnp.float32)] * 2,
-        compiler_params=_compiler_params(s, d, itemsize, bq, bk,
-                                         inner_arbitrary=True),
+        out_specs=[dq_spec, kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+                   for x in (qt, kt, vt)],
+        scratch_shapes=[pltpu.VMEM((s, layout.lanes), jnp.float32)]
+        + [pltpu.VMEM((bk, layout.lanes), jnp.float32)] * 2,
+        compiler_params=_compiler_params(s, d, qt.dtype.itemsize, bq, bk,
+                                         backward=True),
         interpret=interpret,
         name=scopes.FLASH_DKV,
     )(qt, kt, vt, *masks, dot, lse, dd)
+    # Where a model joins the three straight back into the gradient of a
+    # fused qkv projection (BERT's), XLA:TPU sees one call's outputs
+    # concatenated and builds the join as three update-slice copies into
+    # a zero buffer, 70 us a layer at (8, 512, 3 x 1024), in place of
+    # fusing it into its consumers as it does for operands of separate
+    # origin. The barrier costs nothing (a Mosaic call fuses with nothing
+    # anyway) and gives dq a separate origin.
+    dq = jax.lax.optimization_barrier(dq)
     return (layout.from_kernel(dq), layout.from_kernel(dk),
             layout.from_kernel(dv), None)
 

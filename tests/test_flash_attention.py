@@ -53,6 +53,15 @@ def _grads(fn, q, k, v):
                     argnums=(0, 1, 2))(q, k, v)
 
 
+def _lse_loss(fn, w):
+    """sum(o ** 2) + sum(w * lse) of ``fn``'s (o, lse): a loss with a
+    live lse cotangent ``w``."""
+    def f(q, k, v):
+        o, lse = fn(q, k, v)
+        return (o.astype(jnp.float32) ** 2).sum() + (w * lse).sum()
+    return f
+
+
 @pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
 @pytest.mark.parametrize("masking", MASKS)
 @pytest.mark.parametrize("blocks", BLOCKS, ids=BLOCK_IDS)
@@ -143,12 +152,6 @@ def test_heads_side_by_side_on_the_lanes(rng, h, d, packed, heads):
     mask = _mask(rng, "keys", s=128)
     w = jnp.asarray(rng.standard_normal((B, h, 128)), jnp.float32)
 
-    def loss(fn):
-        def f(q, k, v):
-            o, lse = fn(q, k, v)
-            return (o ** 2).sum() + (w * lse).sum()
-        return f
-
     flash = lambda *a: flash_attention_with_lse(  # noqa: E731
         *a, mask=mask, causal=True, use_pallas=True, block_q=64,
         block_k=32)
@@ -156,8 +159,8 @@ def test_heads_side_by_side_on_the_lanes(rng, h, d, packed, heads):
     for got, want in zip(flash(q, k, v), ref(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-4, atol=2e-4)
-    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.grad(_lse_loss(flash, w), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(_lse_loss(ref, w), argnums=(0, 1, 2))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=5e-3, atol=5e-3,
@@ -220,12 +223,6 @@ def test_lse_output_and_its_cotangent(rng, blocks, masking, causal):
     mask = _mask(rng, masking)
     w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
 
-    def loss(fn):
-        def f(q, k, v):
-            o, lse = fn(q, k, v)
-            return (o ** 2).sum() + (w * lse).sum()
-        return f
-
     flash = lambda *a: flash_attention_with_lse(  # noqa: E731
         *a, mask=mask, causal=causal, use_pallas=True, **_kw(blocks))
     ref = lambda *a: _reference_with_lse(*a, mask, causal)  # noqa: E731
@@ -236,12 +233,175 @@ def test_lse_output_and_its_cotangent(rng, blocks, masking, causal):
                                rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(lse), np.asarray(lse_ref),
                                rtol=2e-4, atol=2e-4)
-    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
-    g_ref = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    g_flash = jax.grad(_lse_loss(flash, w), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(_lse_loss(ref, w), argnums=(0, 1, 2))(q, k, v)
     for gf, gr, name in zip(g_flash, g_ref, "qkv"):
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gr),
                                    rtol=5e-3, atol=5e-3,
                                    err_msg=f"d{name} with an lse cotangent")
+
+
+# dq is gathered over the k blocks in VMEM and leaves the backward call
+# once a (batch, head group): S = 4 x the smaller block, so every q block
+# meets several k blocks and the other way round. (H, D): two heads
+# packed on the lanes, one head of 128, one head a block at width 80.
+GATHER_BLOCKS = [(32, 32), (32, 64), (64, 32)]
+GATHER_LAYOUTS = [(2, 64), (1, 128), (2, 80)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("operands", ["bare", "keys_and_dlse"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("h, d", GATHER_LAYOUTS,
+                         ids=["packed64", "d128", "perhead80"])
+@pytest.mark.parametrize("block_q, block_k", GATHER_BLOCKS,
+                         ids=["bq32_bk32", "bq32_bk64", "bq64_bk32"])
+def test_dq_gathered_over_several_k_and_q_blocks(rng, block_q, block_k, h,
+                                                 d, causal, operands,
+                                                 dtype):
+    """One backward call gives dq, dk and dv: dq's rows wait in VMEM
+    while the k blocks pass, dk's and dv's while the q blocks do. With
+    the key mask and a live lse cotangent, or with neither."""
+    s = 128
+    q, k, v = _qkv(rng, d=d, s=s, h=h, dtype=dtype)
+    qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    dlse = operands == "keys_and_dlse"
+    mask = _mask(rng, "keys" if dlse else "none", s=s, keep=0.8)
+    w = jnp.asarray(rng.standard_normal((B, h, s)) * dlse, jnp.float32)
+    flash = lambda *a: flash_attention_with_lse(  # noqa: E731
+        *a, mask=mask, causal=causal, use_pallas=True, block_q=block_q,
+        block_k=block_k)
+    ref = lambda *a: _reference_with_lse(*a, mask, causal)  # noqa: E731
+    g_flash = jax.grad(_lse_loss(flash, w), argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(_lse_loss(ref, w), argnums=(0, 1, 2))(qf, kf, vf)
+    tol = 5e-3 if dtype == jnp.float32 else 3e-2
+    for gf, gr, name in zip(g_flash, g_ref, "qkv"):
+        assert gf.dtype == dtype and gf.shape == (B, s, h, d)
+        scale = max(1.0, float(np.abs(np.asarray(gr)).max()))
+        np.testing.assert_allclose(
+            np.asarray(gf, np.float32), np.asarray(gr), rtol=tol,
+            atol=tol * scale, err_msg=f"d{name} bq={block_q} bk={block_k}")
+
+
+def _twin_dq_kernel(*refs, block_k, causal, scale, has_mask, transposed):
+    """dq alone, as a kernel of its own: a q block against all the k
+    blocks in an in-kernel loop, the carried fp32 dq added to in
+    ascending k order. What the backward was before dq moved into the
+    dk/dv call, built from that call's helpers; ``transposed`` takes ds
+    from the same ``_pt_dst`` as the fused call, else the scores are
+    built untransposed (q·kᵀ), the other way round the same products."""
+    q_ref, k_ref, v_ref = refs[:3]
+    m_ref = refs[3] if has_mask else None
+    do_ref, lse_ref, dd_ref, dq_ref = refs[-4:]
+    block_q, lanes = q_ref.shape
+    heads = lse_ref.shape[0]
+    qi = fa.pl.program_id(2)
+    q, on_scores = fa._scaled(q_ref, scale)
+    do = do_ref[...]
+
+    def step(j, dq, on_diagonal):
+        k, v, kmask, keep = fa._key_block(k_ref, v_ref, m_ref, qi, j,
+                                          block_q, block_k, on_diagonal)
+        for g in range(heads):
+            kg = fa._head(k, g, heads)
+            if transposed:
+                _, dst = fa._pt_dst(
+                    fa._head(q, g, heads), fa._head(do, g, heads), k, v,
+                    lse_ref[g], dd_ref[g], on_scores,
+                    None if kmask is None else kmask.T,
+                    None if keep is None else keep.T)
+                ds = dst.T
+            else:
+                p = jnp.exp(fa._masked(fa._scores(q, kg, on_scores), kmask,
+                                       keep) - lse_ref[g, 0, :][:, None])
+                ds = p * (fa._dot(do, fa._head(v, g, heads), fa._NT)
+                          - dd_ref[g, 0, :][:, None])
+            dq = dq + fa._dot(ds.astype(k.dtype), kg, fa._NN)
+        return dq
+
+    dq = fa._loop_key_blocks(step, jnp.zeros((block_q, lanes), jnp.float32),
+                             qi, block_q, block_k,
+                             k_ref.shape[0] // block_k, causal)
+    dq_ref[...] = (dq * scale).astype(dq_ref.dtype)
+
+
+def _twin_dq(res, do, dlse, causal, bq, bk, transposed):
+    """dq by the twin kernel, from the forward's residuals."""
+    qt, kt, vt, mask3, ot, lse = res
+    b, h, _, s = lse.shape
+    d = qt.shape[-1] // h if qt.ndim == 3 else qt.shape[-1]
+    layout = fa._Layout(h, d)
+    dot = layout.to_kernel(do)
+    dd = (layout.rowsum(dot, ot) - dlse)[:, :, None, :]
+    has_mask = mask3 is not None
+    q_spec, kv_spec, m_spec, row_spec = fa._q_major_specs(layout, s, bq)
+    dq = fa.pl.pallas_call(
+        lambda *refs: _twin_dq_kernel(
+            *refs, block_k=bk, causal=causal, scale=1.0 / np.sqrt(d),
+            has_mask=has_mask, transposed=transposed),
+        grid=(b, layout.groups, s // bq),
+        in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
+        + [q_spec, row_spec, row_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, qt.dtype),
+        interpret=True,
+    )(qt, kt, vt, *([mask3] * has_mask), dot, lse, dd)
+    return layout.from_kernel(dq)
+
+
+@pytest.mark.parametrize("scores", ["transposed", "untransposed"])
+@pytest.mark.parametrize("operands", ["bare", "keys_and_dlse"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("h, d", GATHER_LAYOUTS,
+                         ids=["packed64", "d128", "perhead80"])
+@pytest.mark.parametrize("block_q, block_k", GATHER_BLOCKS,
+                         ids=["bq32_bk32", "bq32_bk64", "bq64_bk32"])
+def test_dq_is_the_two_kernel_twins(rng, block_q, block_k, h, d, causal,
+                                    operands, scores):
+    """The fused call adds a q block's contributions in ascending k
+    order, in fp32: from the same ds it is the dq of a kernel that does
+    nothing else (a loop, a carried accumulator) bit for bit in fp32;
+    with the twin's scores built the other way round (what the dq kernel
+    did), to the last places, the CPU's two matmuls adding the 128 lanes
+    in their own orders."""
+    s = 128
+    q, k, v = _qkv(rng, d=d, s=s, h=h)
+    do = jnp.asarray(rng.standard_normal(q.shape), jnp.float32)
+    dlse = jnp.asarray(rng.standard_normal((B, h, s))
+                       * (operands == "keys_and_dlse"), jnp.float32)
+    mask = _mask(rng, "keys" if operands == "keys_and_dlse" else "none",
+                 s=s, keep=0.8)
+    mask = None if mask is None else jnp.asarray(mask)
+    res = fa._forward(q, k, v, mask, causal, block_q, block_k, True)[2]
+    dq = fa._backward(causal, block_q, block_k, True, res, do, dlse)[0]
+    twin = _twin_dq(res, do, dlse, causal, block_q, block_k,
+                    scores == "transposed")
+    assert np.abs(np.asarray(twin)).max() > 0.1
+    if scores == "transposed":
+        np.testing.assert_array_equal(np.asarray(dq), np.asarray(twin))
+    else:
+        np.testing.assert_allclose(np.asarray(dq), np.asarray(twin),
+                                   rtol=1e-5, atol=1e-5)
+
+
+# (S, D, itemsize, bq, bk) -> bytes: what stays for a whole sequence (the
+# forward's K and V, double buffered, or the backward's fp32 dq and its
+# double-buffered output, whichever is more) + the blocks + the scores.
+@pytest.mark.parametrize("case, resident", [
+    ((4096, 64, 2, 512, 512), 4096 * 128 * 8),     # bf16: 2+2 MB either way
+    ((16384, 64, 2, 512, 512), 16384 * 128 * 8),   # 16 MB
+    ((4096, 64, 4, 512, 512), 4096 * 128 * 16),    # fp32: K and V are more
+    ((4096, 128, 4, 512, 512), 4096 * 128 * 16),
+    ((4096, 64, 1, 512, 512), 4096 * 128 * 6),     # 8-bit: dq is more
+    ((2048, 80, 2, 512, 512), 2048 * 128 * 8),     # width 80: padded lanes
+])
+def test_vmem_plan_counts_the_resident_dq(case, resident):
+    s, d, itemsize, bq, bk = case
+    lanes = -(-d // 128) * 128
+    assert resident >= s * lanes * (4 + 2 * itemsize)   # dq fits in it
+    assert fa._vmem_estimate(*case) == resident \
+        + 2 * 4 * max(bq, bk) * lanes * itemsize + 6 * bq * bk * 4
 
 
 def test_fallback_off_tpu_and_odd_seq(rng):

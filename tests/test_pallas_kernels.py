@@ -385,12 +385,13 @@ def test_flash_block_specs_obey_mosaic_tiling_rule():
         assert layout.groups * layout.heads == h
         qkv = (b, s, h * d) if layout.packed else (b, h, s, d)
         rows, mask = (b, h, 1, s), (b, 1, s)
-        # (spec, array) pairs exactly as the three pallas_calls bind
-        # them: forward and dq (q blocks outermost), then dk/dv.
+        # (spec, array) pairs exactly as the two pallas_calls bind
+        # them: the forward (q blocks outermost), then the backward,
+        # whose last spec is dq's whole sequence of a head group.
         pairs = list(zip(_q_major_specs(layout, s, bq),
                          (qkv, qkv, mask, rows)))
-        pairs += zip(_k_major_specs(layout, bq, bk, True),
-                     (qkv, qkv, mask, rows))
+        pairs += zip(_k_major_specs(layout, s, bq, bk, True),
+                     (qkv, qkv, mask, rows, qkv))
         for spec, array in pairs:
             assert ok(spec.block_shape, array), (
                 f"Mosaic-untileable block {spec.block_shape} over "
