@@ -1,0 +1,277 @@
+"""Decoder-only LM whose layer kind comes from a pattern: softmax
+grouped-query attention in the layers ``gqa_layers`` names and gated
+delta-rule linear attention (KDA) in the others, every layer followed by a
+sparse mixture of SwiGLU experts with a shared expert (the Solar-Open2 /
+Kimi-Linear shape).
+
+    x <- x + Attn_l(RMSNorm(x));   x <- x + MoE_l(RMSNorm(x))
+
+The model is written for ONE RANK OF A DEPLOYMENT that divides each layer
+over several chips: it is told how many query, K/V and KDA heads it holds
+and which experts (``held_experts = (first, count)`` of ``num_experts``),
+and computes their part of each layer. The router keeps its published
+width and its experts per token; a route to an expert held elsewhere adds
+nothing here (``parallel/moe.py`` ``held_experts_layer``). Nothing stands
+in for the absent chips.
+
+- **GQA layer** (no positions): q over the held query heads, k and v over
+  the held K/V heads, causal flash attention with K/V head = query head //
+  group in the kernels' index maps (``ops/flash_attention.py``), an
+  elementwise sigmoid gate on the attention output, then ``W_o``.
+- **KDA layer**: q, k, v through a causal depthwise convolution of
+  ``conv_size`` taps and SiLU; q and k L2-normalised a head, q scaled by
+  head_dim^-0.5; per-channel decay ``log alpha = -exp(A) softplus(W_f^up
+  W_f^down x + b)``; ``beta = 2 sigmoid(w_beta x)``; the chunked recurrence
+  (``ops/linear_attention.py``); a per-head RMSNorm of the output times
+  ``sigmoid(W_g^up W_g^down x)``, then ``W_o``.
+- **MoE**: softmax router over ``num_experts``, top ``top_k``, weights
+  normalised over the k; the held experts as grouped matmuls under
+  ``hvd_moe_experts``, routing under ``hvd_moe_route``; one shared SwiGLU
+  expert on every token under ``hvd_moe_shared``.
+
+Same TPU choices as ``models/looplm.py``, whose ``RMSNorm`` and untied
+head (``hvd_lm_head``) it shares: bf16 compute / fp32 parameters, every
+layer rematerialised (``nn.remat``), the cross-entropy computed inside the
+head's rematerialised call. The held experts' load, routes and drops of a
+step, summed over the layers, are published from inside the step where
+``publish_stats`` asks for it (``moe.record_held_stats``).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..common import scopes
+from ..ops.flash_attention import flash_attention
+from ..ops.linear_attention import kda_attention
+from ..parallel import moe
+from .looplm import RMSNorm, _Head
+
+
+def _dense(dtype):
+    return functools.partial(nn.Dense, use_bias=False, dtype=dtype,
+                             param_dtype=jnp.float32)
+
+
+class GatedGQA(nn.Module):
+    """Causal softmax attention, ``num_heads`` query heads on
+    ``num_kv_heads`` K/V heads, no positions, output gate."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        dense = _dense(self.dtype)
+        wide, narrow = (n * self.head_dim
+                        for n in (self.num_heads, self.num_kv_heads))
+        q = dense(wide, name="q")(x).reshape(b, s, self.num_heads, -1)
+        k, v = (dense(narrow, name=n)(x).reshape(b, s, self.num_kv_heads, -1)
+                for n in ("k", "v"))
+        o = flash_attention(q, k, v, causal=True).reshape(b, s, wide)
+        o = o * nn.sigmoid(dense(wide, name="gate")(x))
+        return dense(hidden, name="o")(o)
+
+
+def _decay_bias_init(key, shape, dtype=jnp.float32):
+    """softplus^-1 of a step drawn log-uniformly from [1e-3, 1e-1]."""
+    step = jnp.exp(jax.random.uniform(
+        key, shape, dtype, np.log(1e-3), np.log(1e-1)))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+def _decay_rate_init(key, shape, dtype=jnp.float32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def causal_conv(x, taps):
+    """Depthwise causal convolution along S: ``y_t = sum_j taps[j]
+    x_{t - (n - 1) + j}``, fp32. x: (B, S, C); taps: (n, C)."""
+    n = taps.shape[0]
+    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
+    s = x.shape[1] - (n - 1)
+    return sum(x[:, j:j + s] * taps[j] for j in range(n))
+
+
+class KDA(nn.Module):
+    """Gated delta-rule linear attention over ``num_heads`` heads of
+    ``head_dim`` for q, k and v alike."""
+
+    num_heads: int
+    head_dim: int
+    conv_size: int = 4
+    gate_rank: int = 128
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        heads, width = self.num_heads, self.head_dim
+        dense = _dense(self.dtype)
+
+        def mixed(name):
+            taps = self.param("conv_" + name, nn.initializers.lecun_normal(
+                in_axis=0, out_axis=()), (self.conv_size, heads * width),
+                jnp.float32)
+            y = nn.silu(causal_conv(dense(heads * width, name=name)(x), taps))
+            return y.reshape(b, s, heads, width)
+
+        def unit(y):
+            return y * jax.lax.rsqrt((y * y).sum(-1, keepdims=True) + 1e-6)
+
+        q = (unit(mixed("q")) * width ** -0.5).astype(self.dtype)
+        k = unit(mixed("k")).astype(self.dtype)
+        v = mixed("v").astype(self.dtype)
+
+        rate = self.param("A_log", _decay_rate_init, (heads,), jnp.float32)
+        bias = self.param("dt_bias", _decay_bias_init, (heads * width,),
+                          jnp.float32)
+        f = dense(heads * width, name="f_up")(
+            dense(self.gate_rank, name="f_down")(x))
+        log_alpha = -jnp.exp(rate)[:, None] * jax.nn.softplus(
+            (f.astype(jnp.float32) + bias).reshape(b, s, heads, width))
+        beta = 2.0 * nn.sigmoid(
+            dense(heads, name="beta")(x).astype(jnp.float32))
+
+        o = kda_attention(q, k, v, log_alpha, beta)
+        o = RMSNorm(self.norm_eps, self.dtype, name="o_norm")(o)
+        gate = dense(heads * width, name="g_up")(
+            dense(self.gate_rank, name="g_down")(x))
+        o = o.reshape(b, s, heads * width) * nn.sigmoid(gate)
+        return dense(hidden, name="o")(o)
+
+
+class SparseExperts(nn.Module):
+    """The held routed experts' part of the layer plus the shared expert.
+    Returns ``(y, stats)``, the stats of ``moe.held_experts_layer``."""
+
+    num_experts: int
+    held_experts: Tuple[int, int]
+    top_k: int
+    expert_dim: int
+    shared_dim: int
+    routed_scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        count = self.held_experts[1]
+        bank = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=0)
+        router = self.param("router", nn.initializers.lecun_normal(),
+                            (hidden, self.num_experts), jnp.float32)
+        banks = [self.param(name, bank, shape, jnp.float32)
+                 for name, shape in (
+                     ("experts_gate", (count, hidden, self.expert_dim)),
+                     ("experts_up", (count, hidden, self.expert_dim)),
+                     ("experts_down", (count, self.expert_dim, hidden)))]
+        y, stats = moe.held_experts_layer(
+            x.reshape(b * s, hidden), router, *banks, self.num_experts,
+            self.held_experts, self.top_k, self.routed_scale)
+        with jax.named_scope(scopes.MOE_SHARED):
+            dense = _dense(self.dtype)
+            shared = dense(hidden, name="shared_down")(
+                nn.silu(dense(self.shared_dim, name="shared_gate")(x))
+                * dense(self.shared_dim, name="shared_up")(x))
+        return y.reshape(b, s, hidden) + shared, stats
+
+
+class SolarLayer(nn.Module):
+    """``x + Attn(norm(x))`` then ``x + MoE(norm(x))``. ``attn`` is the
+    layer's attention class and ``attn_args`` / ``moe_args`` the
+    constructor arguments of it and of ``SparseExperts``."""
+
+    attn: Any
+    attn_args: Tuple
+    moe_args: Tuple
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
+        x = x + self.attn(*self.attn_args, name="attn")(
+            norm(name="attn_norm")(x))
+        y, stats = SparseExperts(*self.moe_args, name="moe")(
+            norm(name="mlp_norm")(x))
+        return x + y, stats
+
+
+class SolarLM(nn.Module):
+    """``apply(tokens)`` -> fp32 logits (B, S, vocab); ``apply(tokens,
+    labels)`` -> the cross-entropy of each position (B, S), which is what
+    training at a real size can hold. The head counts, the experts and the
+    vocabulary are those HELD HERE; ``num_experts`` and ``top_k`` are the
+    router's own."""
+
+    vocab_size: int = 24576
+    num_layers: int = 4
+    hidden: int = 4096
+    gqa_layers: Tuple[int, ...] = (0,)
+    num_heads: int = 8
+    num_kv_heads: int = 1
+    head_dim: int = 128
+    kda_heads: int = 8
+    kda_head_dim: int = 128
+    conv_size: int = 4
+    gate_rank: int = 128
+    num_experts: int = 320
+    held_experts: Tuple[int, int] = (0, 8)
+    top_k: int = 8
+    expert_dim: int = 1280
+    shared_dim: int = 1280
+    routed_scale: float = 1.0
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    # Set the hvd_tpu_moe_* gauges from inside the step (a host callback:
+    # such a program is not kept in JAX's persistent compile cache).
+    publish_stats: bool = False
+
+    def setup(self):
+        self.tok_emb = nn.Embed(self.vocab_size, self.hidden,
+                                param_dtype=jnp.float32)
+        layer = nn.remat(SolarLayer)
+        gqa = (GatedGQA, (self.num_heads, self.num_kv_heads, self.head_dim,
+                          self.dtype))
+        kda = (KDA, (self.kda_heads, self.kda_head_dim, self.conv_size,
+                     self.gate_rank, self.norm_eps, self.dtype))
+        experts = (self.num_experts, tuple(self.held_experts), self.top_k,
+                   self.expert_dim, self.shared_dim, self.routed_scale,
+                   self.dtype)
+        for i in range(self.num_layers):
+            setattr(self, f"layer{i}", layer(
+                *(gqa if i in self.gqa_layers else kda), experts,
+                self.norm_eps, self.dtype))
+        self.final_norm = RMSNorm(self.norm_eps, self.dtype)
+        self.lm_head = nn.remat(_Head)(self.vocab_size, self.dtype)
+
+    def __call__(self, tokens, labels=None):
+        h = self.tok_emb(tokens).astype(self.dtype)
+        total = None
+        for i in range(self.num_layers):
+            h, stats = getattr(self, f"layer{i}")(h)
+            total = stats if total is None else jax.tree.map(
+                jnp.add, total, stats)
+        if self.publish_stats and not self.is_initializing():
+            moe.record_held_stats(total, self.held_experts[0])
+        return self.lm_head(self.final_norm(h), labels)
+
+
+def solar_loss(model, params, tokens, weights=None):
+    """Mean next-token cross-entropy of ``tokens`` (B, S + 1), weighted by
+    ``weights`` (B, S) where given. No auxiliary loss."""
+    ce = model.apply({"params": params}, tokens[:, :-1], tokens[:, 1:])
+    if weights is None:
+        return ce.mean()
+    return (ce * weights).sum() / weights.sum()

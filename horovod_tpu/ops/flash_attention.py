@@ -52,6 +52,13 @@ holds. ``block_q`` / ``block_k`` default to a choice from S, D, the
 dtype and a VMEM budget (``_choose_blocks``); passing them caps the
 choice.
 
+Grouped-query attention: k and v may hold fewer heads than q (H a
+multiple of Hkv, q head h reads K/V head h // (H / Hkv)). Where a block is
+one head (width 128, or the per-head layout) that is the K/V index maps
+alone: nothing is repeated in HBM, and the backward writes each q head's
+dk and dv, which are then summed over the group. Where a 128-lane block
+packs several narrow heads, K and V are repeated before the call.
+
 ``flash_attention`` (what the models call) has a backward with no lse
 cotangent at all; ``flash_attention_with_lse`` (ring attention's
 blockwise-combine interface) returns the logsumexp as a differentiable
@@ -160,10 +167,18 @@ def _resolve_blocks(s, d, dtype, block_q, block_k, interpret):
     return (bq, bk) if bq and bk else None
 
 
+def _repeat_heads(x, group):
+    """(B, S, Hkv, D) -> (B, S, Hkv * group, D), each head ``group``
+    times in a row."""
+    return x if group == 1 else jnp.repeat(x, group, axis=2)
+
+
 def reference_attention(q, k, v, mask=None, causal=False):
     """Plain softmax attention on (B, S, H, D); ``mask`` is a (B, S) key
-    mask (1 = attend). The jnp fallback and the numerics oracle."""
+    mask (1 = attend); k and v may hold H / group heads. The jnp fallback
+    and the numerics oracle."""
     d = q.shape[-1]
+    k, v = (_repeat_heads(x, q.shape[2] // x.shape[2]) for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) / np.sqrt(d)
     if mask is not None:
@@ -420,7 +435,7 @@ class _Layout:
 
     def to_kernel(self, x):
         b, s = x.shape[:2]
-        return x.reshape(b, s, self.h * self.d) if self.packed \
+        return x.reshape(b, s, x.shape[2] * self.d) if self.packed \
             else jnp.swapaxes(x, 1, 2)
 
     def from_kernel(self, x):
@@ -459,29 +474,38 @@ class _Layout:
         return pl.BlockSpec((None, self.heads, 1, rows), at)
 
 
-def _q_major_specs(layout, s, bq):
+def _kv_head(group):
+    """The K/V head (group) a q head (group) reads."""
+    return (lambda g: g) if group == 1 else (lambda g: g // group)
+
+
+def _q_major_specs(layout, s, bq, group=1):
     """Block specs of the forward call, grid (B, H/G, S/bq): a q-side
     tile, K / V whole, the (B, 1, S) key mask whole, and a row slice."""
+    kv = _kv_head(group)
     q_spec = layout.tile(bq, lambda b, g, i: (b, g, i))
-    kv_spec = layout.tile(s, lambda b, g, i: (b, g, 0))
+    kv_spec = layout.tile(s, lambda b, g, i: (b, kv(g), 0))
     m_spec = pl.BlockSpec((None, 1, s), lambda b, g, i: (b, 0, 0))
     row_spec = layout.row(bq, lambda b, g, i: (b, g, i))
     return q_spec, kv_spec, m_spec, row_spec
 
 
-def _k_major_specs(layout, s, bq, bk, causal):
+def _k_major_specs(layout, s, bq, bk, causal, group=1):
     """Block specs of the backward call, grid (B, H/G, S/bk, S/bq), q
     blocks innermost: a q-side tile, a k-side tile, a block of the key
     mask, a row slice, and dq's tile. Under ``causal`` the q blocks
     above the diagonal are skipped; their index is clamped to the first
     visible one so that a skipped step fetches nothing new. dq's tile is
     the whole sequence of a (batch, head group): it stays in VMEM over
-    both block dimensions and goes to HBM once."""
+    both block dimensions and goes to HBM once. With ``group`` > 1 the
+    k-side tile is the group's one K/V head's."""
+    kv = _kv_head(group)
+
     def qi(j, i):
         return jnp.maximum(i, jax.lax.div(j * bk, bq)) if causal else i
 
     q_spec = layout.tile(bq, lambda b, g, j, i: (b, g, qi(j, i)))
-    kv_spec = layout.tile(bk, lambda b, g, j, i: (b, g, j))
+    kv_spec = layout.tile(bk, lambda b, g, j, i: (b, kv(g), j))
     m_spec = pl.BlockSpec((None, 1, bk), lambda b, g, j, i: (b, 0, j))
     row_spec = layout.row(bq, lambda b, g, j, i: (b, g, qi(j, i)))
     dq_spec = layout.tile(s, lambda b, g, j, i: (b, g, 0))
@@ -506,8 +530,9 @@ def _forward(q, k, v, mask, causal, bq, bk, interpret):
     moves nothing twice."""
     b, s, h, d = q.shape
     layout = _Layout(h, d)
+    group = h // k.shape[2]
     has_mask = mask is not None
-    q_spec, kv_spec, m_spec, row_spec = _q_major_specs(layout, s, bq)
+    q_spec, kv_spec, m_spec, row_spec = _q_major_specs(layout, s, bq, group)
     qt, kt, vt = (layout.to_kernel(x) for x in (q, k, v))
     mask3 = mask.astype(jnp.float32)[:, None, :] if has_mask else None
     ot, lse = pl.pallas_call(
@@ -534,6 +559,8 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
     # Packed operands are (B, S, H·D), per-head ones (B, H, S, D).
     d = qt.shape[-1] // h if qt.ndim == 3 else qt.shape[-1]
     layout = _Layout(h, d)
+    group = qt.shape[-1] // kt.shape[-1] if qt.ndim == 3 \
+        else h // kt.shape[1]
     has_mask = mask3 is not None
     dot = layout.to_kernel(do)
     # delta_i = rowsum(dO_i * o_i) — one fused elementwise pass in-graph;
@@ -545,15 +572,18 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
     dd = dd[:, :, None, :]
     masks = [mask3] * has_mask
     q_spec, kv_spec, m_spec, row_spec, dq_spec = _k_major_specs(
-        layout, s, bq, bk, causal)
+        layout, s, bq, bk, causal, group)
+    # dk and dv leave a query head at a time: the k-side tile of a call
+    # with no grouping, whatever this one's is
+    dkv_spec = _k_major_specs(layout, s, bq, bk, causal)[1]
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal,
                           scale=1.0 / np.sqrt(d), has_mask=has_mask),
         grid=(b, layout.groups, s // bk, s // bq),
         in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
         + [q_spec, row_spec, row_spec],
-        out_specs=[dq_spec, kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype)
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct(qt.shape, x.dtype)
                    for x in (qt, kt, vt)],
         scratch_shapes=[pltpu.VMEM((s, layout.lanes), jnp.float32)]
         + [pltpu.VMEM((bk, layout.lanes), jnp.float32)] * 2,
@@ -570,8 +600,12 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
     # origin. The barrier costs nothing (a Mosaic call fuses with nothing
     # anyway) and gives dq a separate origin.
     dq = jax.lax.optimization_barrier(dq)
-    return (layout.from_kernel(dq), layout.from_kernel(dk),
-            layout.from_kernel(dv), None)
+    dq, dk, dv = (layout.from_kernel(x) for x in (dq, dk, dv))
+    if group > 1:       # a K/V head's gradient: its q heads' shares, summed
+        dk, dv = (x.reshape(b, s, h // group, group, d)
+                  .astype(jnp.float32).sum(3).astype(x.dtype)
+                  for x in (dk, dv))
+    return dq, dk, dv, None
 
 
 # Two interfaces over the same kernels: the need differs by caller (is
@@ -653,6 +687,18 @@ def _say_path(shape, dtype, bq, bk, has_mask, causal, dlse):
         tuple(shape), dtype, bq, bk, has_mask, causal, dlse)
 
 
+def _kv_heads_for_kernels(q, k, v):
+    """K and V as the kernels take them: as they are where a block is one
+    head (the index maps do the grouping), repeated to q's head count
+    where a 128-lane block packs several narrow heads. Outside the
+    ``custom_vjp``, so JAX sums the repeated heads' gradients."""
+    h, d = q.shape[2:]
+    group = h // k.shape[2]
+    if group > 1 and _Layout(h, d).heads > 1:
+        return _repeat_heads(k, group), _repeat_heads(v, group)
+    return k, v
+
+
 def _engage(q, mask, causal, use_pallas, block_q, block_k, dlse):
     """(bq, bk, interpret) of the kernel path for this call, or None
     where flash_available declines; says which path engaged."""
@@ -687,7 +733,8 @@ def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
     path = _engage(q, mask, causal, use_pallas, block_q, block_k, True)
     if path is None:
         return None
-    return _flash_lse(q, k, v, mask, causal, *path)
+    return _flash_lse(q, *_kv_heads_for_kernels(q, k, v), mask, causal,
+                      *path)
 
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
@@ -695,7 +742,8 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
                     block_q: Optional[int] = None,
                     block_k: Optional[int] = None):
     """Blockwise online-softmax attention on (B, S, H, D), returned in
-    q's dtype.
+    q's dtype. k and v may hold fewer heads, (B, S, Hkv, D) with H a
+    multiple of Hkv (grouped-query attention).
 
     ``mask``: optional (B, S) key mask (1 = attend). ``use_pallas=None``
     auto-selects the Pallas kernel on TPU with a jnp fallback elsewhere;
@@ -706,7 +754,7 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
     path = _engage(q, mask, causal, use_pallas, block_q, block_k, False)
     if path is None:
         return reference_attention(q, k, v, mask, causal)
-    return _flash(q, k, v, mask, causal, *path)
+    return _flash(q, *_kv_heads_for_kernels(q, k, v), mask, causal, *path)
 
 
 def attend(q, k, v, mask=None):

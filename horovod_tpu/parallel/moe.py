@@ -31,10 +31,22 @@ peer to the allreduce stack:
   (dropped token-routes, demanded per-expert load); the host-side
   :func:`record_moe_stats` publishes it as the
   ``hvd_tpu_moe_{dropped_tokens,dropped_frac,expert_load}`` gauges.
+
+Beside it, the dropless path for a rank that is TOLD WHICH EXPERTS IT
+HOLDS (:func:`held_experts_layer`, docs/moe.md): the router scores all
+``num_experts``, takes the top k, and this rank computes its own
+experts' part of the result for the routes that reach them — every one
+of them, whatever the imbalance. No (T, E, C) one-hot exists: the routes
+are sorted by expert and the SwiGLU experts run as grouped matmuls over
+ragged groups (``jax.lax.ragged_dot``), a block of routes at a time, in a
+loop as long as the routes demand. It is what an expert-parallel
+exchange would feed; it adds no exchange itself.
 """
 
 from __future__ import annotations
 
+import functools
+import math
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -43,23 +55,31 @@ import numpy as np
 from jax import lax
 
 from ..common import metrics as metrics_lib
+from ..common import scopes
 
 _METRICS_ON = metrics_lib.enabled()
 _M_DROPPED = metrics_lib.gauge(
     "hvd_tpu_moe_dropped_tokens",
-    "token-routes dropped by capacity overflow in the most recently "
-    "recorded MoE step (global count across the ep world; set by "
-    "record_moe_stats from a moe_layer return_stats=True dict)")
+    "token-routes dropped in the most recently recorded MoE step: by "
+    "capacity overflow on the capacity path (global count across the ep "
+    "world; record_moe_stats), routes to a held expert left uncomputed "
+    "on the held-experts path, which must read 0 (record_held_stats)")
 _M_DROP_FRAC = metrics_lib.gauge(
     "hvd_tpu_moe_dropped_frac",
-    "dropped token-routes as a fraction of all top-2 routes in the most "
+    "dropped token-routes as a fraction of all top-k routes in the most "
     "recently recorded MoE step (the capacity-factor health number; "
     "docs/moe.md runbook)")
 _M_LOAD = metrics_lib.gauge(
     "hvd_tpu_moe_expert_load",
-    "demanded token-routes per expert (top-2 assignments INCLUDING "
+    "demanded token-routes per expert (top-k assignments INCLUDING "
     "dropped ones — the skew signal) in the most recently recorded MoE "
-    "step", labels=("expert",))
+    "step; on the held-experts path the experts held here, by their "
+    "global index, summed over the layers", labels=("expert",))
+_M_LOCAL = metrics_lib.gauge(
+    "hvd_tpu_moe_local_routes",
+    "top-k token-routes that reached an expert held on this rank in the "
+    "most recently recorded step, summed over the layers (held-experts "
+    "path; record_held_stats)")
 
 
 def top2_gating(logits, capacity: int, noise=None):
@@ -415,3 +435,220 @@ def chaos_skew_gate(gate_w):
     scale = spec.scale if spec.scale else 10.0
     g = jnp.asarray(gate_w)
     return g.at[..., target].add(jnp.asarray(scale, g.dtype))
+
+
+# -- the dropless path of a rank that holds some of the experts -------------
+
+def route_top_k(x, router_w, top_k: int, scale: float = 1.0):
+    """Softmax router over ALL experts: ``(experts (T, k) int32, weights
+    (T, k) fp32)``, the weights the chosen scores normalised to sum to 1
+    over a token's k choices, times ``scale``. The scores are computed
+    from fp32 operands in three bf16 passes (``Precision.HIGH``): a route
+    is a discrete choice, and a score rounded to bf16 flips one in ten of
+    the eighth-against-ninth decisions among 320 experts."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=lax.Precision.HIGH)
+    scores, experts = lax.top_k(jax.nn.softmax(logits, -1), top_k)
+    return experts, scores / scores.sum(-1, keepdims=True) * scale
+
+
+def _block_group_sizes(group_sizes, block, block_rows):
+    """How many rows of the sorted routes' block ``block`` (scalar or
+    (n,)) belong to each group: the overlap of the group's span with
+    [block * block_rows, (block + 1) * block_rows)."""
+    ends = jnp.cumsum(group_sizes)
+    lo = jnp.asarray(block)[..., None] * block_rows
+    return jnp.clip(jnp.minimum(ends, lo + block_rows)
+                    - jnp.maximum(ends - group_sizes, lo), 0)
+
+
+def _expert_block(xg, weights, valid, w_gate, w_up, w_down, sizes):
+    """The SwiGLU experts on one block of routes sorted by expert: rows
+    ``xg`` (R, D), ``sizes`` (G,) rows a group. Each row's result times
+    its route's weight, fp32; 0 for the rows past the groups. Those rows
+    are masked on the way in and on the way out: what a grouped matmul
+    leaves in rows no group owns is not defined on every backend, in the
+    forward or in the transposes (the TPU's kernel leaves them
+    unwritten)."""
+    xg = jnp.where(valid[:, None], xg, jnp.zeros_like(xg))
+    dot = functools.partial(lax.ragged_dot, group_sizes=sizes,
+                            preferred_element_type=jnp.float32)
+    hidden = jax.nn.silu(dot(xg, w_gate)) * dot(xg, w_up)
+    y = dot(hidden.astype(xg.dtype), w_down)
+    return jnp.where(valid[:, None], y * weights[:, None], 0.0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _grouped_experts(block_rows, x, w_gate, w_up, w_down, weights, tokens,
+                     group_sizes):
+    """Sum over the routes of weight * expert(x[token]), as (T, D) fp32.
+    ``tokens`` / ``weights``: the routes sorted by expert (a multiple of
+    ``block_rows`` long; only the first ``group_sizes.sum()`` count),
+    ``w_*``: the (G, ., .) banks. The routes go through a block at a time
+    in a loop of ceil(routes / block_rows) steps, the first outside it:
+    the usual step fills one block and pays for no second."""
+    return _grouped_experts_fwd(block_rows, x, w_gate, w_up, w_down,
+                                weights, tokens, group_sizes)[0]
+
+
+def _route_block(block_rows, tokens, weights, group_sizes, block):
+    start = block * block_rows
+    sizes = _block_group_sizes(group_sizes, block, block_rows)
+    valid = start + jnp.arange(block_rows) < group_sizes.sum()
+    return (lax.dynamic_slice_in_dim(tokens, start, block_rows),
+            lax.dynamic_slice_in_dim(weights, start, block_rows),
+            valid, sizes)
+
+
+def _grouped_experts_fwd(block_rows, x, w_gate, w_up, w_down, weights,
+                         tokens, group_sizes):
+    banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    blocks = -(-group_sizes.sum() // block_rows)
+
+    def add_block(block, out):
+        idx, wts, valid, sizes = _route_block(
+            block_rows, tokens, weights, group_sizes, block)
+        return out.at[idx].add(
+            _expert_block(x[idx], wts, valid, *banks, sizes))
+
+    out = add_block(0, jnp.zeros(x.shape, jnp.float32))
+    out = lax.while_loop(lambda c: c[0] < blocks,
+                         lambda c: (c[0] + 1, add_block(*c)), (1, out))[1]
+    return out, (x, w_gate, w_up, w_down, weights, tokens, group_sizes)
+
+
+def _grouped_experts_bwd(block_rows, residuals, dout):
+    """A block at a time, as the forward went: the block's forward again
+    and its transpose (JAX's, of the three grouped matmuls); dx scattered
+    back to the tokens, the banks' gradients summed in fp32."""
+    x, w_gate, w_up, w_down, weights, tokens, group_sizes = residuals
+    banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
+    blocks = -(-group_sizes.sum() // block_rows)
+
+    def add_block(block, carry):
+        dx, dweights, dbanks = carry
+        idx, wts, valid, sizes = _route_block(
+            block_rows, tokens, weights, group_sizes, block)
+        _, vjp = jax.vjp(
+            lambda xg, w, *b: _expert_block(xg, w, valid, *b, sizes),
+            x[idx], wts, *banks)
+        dxg, dwts, *db = vjp(dout[idx].astype(jnp.float32))
+        db = tuple(b.astype(jnp.float32) for b in db)
+        return (dx.at[idx].add(dxg.astype(jnp.float32)),
+                lax.dynamic_update_slice_in_dim(
+                    dweights, dwts, block * block_rows, 0),
+                # the first block's are the sum so far: nothing to add to
+                db if dbanks is None else tuple(
+                    a + b for a, b in zip(dbanks, db)))
+
+    carry = add_block(0, (jnp.zeros(x.shape, jnp.float32),
+                          jnp.zeros_like(weights), None))
+    dx, dweights, dbanks = lax.while_loop(
+        lambda c: c[0] < blocks,
+        lambda c: (c[0] + 1, add_block(*c)), (1, carry))[1]
+    return (dx.astype(x.dtype),
+            *(g.astype(w.dtype) for g, w in
+              zip(dbanks, (w_gate, w_up, w_down))),
+            dweights, None, None)
+
+
+_grouped_experts.defvjp(_grouped_experts_fwd, _grouped_experts_bwd)
+
+
+def default_block_rows(tokens: int, top_k: int, held: int,
+                       num_experts: int) -> int:
+    """Rows of a block of routes: two and a half times what a balanced
+    router sends to ``held`` of ``num_experts`` experts, so that one block
+    is the step of an untrained router too (on the chip a random softmax
+    router sent a layer's 8 of 320 experts 0.58 to 1.84 times the balanced
+    share, steadily by seed and layer, and a second block costs 6 ms of a
+    330 ms step: the banks' gradients are summed a second time); a
+    multiple of 512 (the grouped matmul's row tile on the TPU) where there
+    are that many, else of 8."""
+    expected = tokens * top_k * held / num_experts
+    tile = 512 if expected >= 512 else 8
+    most = -(-tokens * min(top_k, held) // 8) * 8
+    return min(most, -(-math.ceil(2.5 * expected) // tile) * tile)
+
+
+def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
+                       held: Tuple[int, int], top_k: int,
+                       scale: float = 1.0,
+                       block_rows: Optional[int] = None):
+    """The routed experts' part of an MoE layer that the experts held on
+    this rank give: ``held = (first, count)`` of ``num_experts``, their
+    SwiGLU banks ``w_gate``, ``w_up`` (count, D, F) and ``w_down``
+    (count, F, D). x: (T, D) tokens; router_w: (D, num_experts).
+
+    The router scores all ``num_experts`` and takes ``top_k``
+    (:func:`route_top_k`); a route to an expert held elsewhere is that
+    rank's to compute and adds nothing here. Every route to a held expert
+    is computed, however many there are (no capacity, no drop): the
+    routes are sorted by expert and run through the grouped matmuls
+    ``block_rows`` at a time (:func:`default_block_rows`), in as many
+    blocks as they fill. Summed over the ranks that hold all the experts,
+    the results are the whole routed layer; over an ep axis it is what
+    the exchange would feed (``first = ep_index * count``), and it adds
+    no exchange.
+
+    Returns ``(y (T, D) in x's dtype, stats)``: ``expert_load`` (count,)
+    routes demanded of each held expert, ``local_routes`` their sum,
+    ``dropped_tokens`` routes to a held expert that no block computed
+    (0), all fp32 (:func:`record_held_stats`)."""
+    first, count = held
+    t = x.shape[0]
+    if block_rows is None:
+        block_rows = default_block_rows(t, top_k, count, num_experts)
+    with jax.named_scope(scopes.MOE_ROUTE):
+        experts, weights = route_top_k(x, router_w, top_k, scale)
+        local = (experts - first).reshape(-1)
+        is_held = (local >= 0) & (local < count)
+        key = jnp.where(is_held, local, count)      # the others sort last
+        order = jnp.argsort(key, stable=True)
+        pad = -order.size % block_rows
+        tokens = jnp.pad(order // top_k, (0, pad)).astype(jnp.int32)
+        sorted_weights = jnp.pad(weights.reshape(-1)[order], (0, pad))
+        group_sizes = jnp.bincount(key, length=count + 1)[:count] \
+            .astype(jnp.int32)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        y = _grouped_experts(block_rows, x, w_gate, w_up, w_down,
+                             sorted_weights, tokens, group_sizes)
+    routes = group_sizes.sum()
+    n_blocks = tokens.size // block_rows
+    done = _block_group_sizes(group_sizes, jnp.arange(n_blocks),
+                              block_rows).sum(-1)
+    computed = jnp.where(jnp.arange(n_blocks) < -(-routes // block_rows),
+                         done, 0).sum()
+    stats = {"expert_load": group_sizes.astype(jnp.float32),
+             "local_routes": routes.astype(jnp.float32),
+             "dropped_tokens": (routes - computed).astype(jnp.float32)}
+    return y.astype(x.dtype), stats
+
+
+def record_held_stats(stats, first: int = 0) -> None:
+    """Publish a step's :func:`held_experts_layer` stats (summed over the
+    layers by the caller) from INSIDE the jitted step: one
+    ``jax.debug.callback`` that sets ``hvd_tpu_moe_expert_load{expert=}``
+    (the held experts, by global index from ``first``),
+    ``hvd_tpu_moe_local_routes`` and ``hvd_tpu_moe_dropped_tokens``. A
+    no-op, and no callback in the program, with metrics off. The price
+    of a callback: JAX does not keep a program with a host callback in
+    its persistent compile cache, so the step compiles in every process
+    (docs/moe.md); a caller that can return the stats from its step
+    publishes them on the host instead, with plain floats, through the
+    same function outside ``jit``."""
+    if not _METRICS_ON:
+        return
+
+    def publish(load, routes, dropped):
+        _M_LOCAL.set(float(routes))
+        _M_DROPPED.set(float(dropped))
+        for e, v in enumerate(np.asarray(load).reshape(-1)):
+            _M_LOAD.labels(expert=str(first + e)).set(float(v))
+
+    values = (stats["expert_load"], stats["local_routes"],
+              stats["dropped_tokens"])
+    if any(isinstance(v, jax.core.Tracer) for v in values):
+        jax.debug.callback(publish, *values)
+    else:
+        publish(*values)

@@ -404,6 +404,103 @@ def test_vmem_plan_counts_the_resident_dq(case, resident):
         + 2 * 4 * max(bq, bk) * lanes * itemsize + 6 * bq * bk * 4
 
 
+# (query heads, K/V heads, width): one head a 128-lane block (the index
+# maps group), a group of the whole head count, two narrow heads a block
+# (K/V repeated before the call), the per-head layout, and no grouping.
+GQA = [(8, 1, 128), (4, 2, 128), (4, 2, 64), (6, 2, 80), (4, 4, 128)]
+
+
+def _plain_grouped(q, k, v, mask, causal):
+    """Plain attention with every K/V head written out a group's times."""
+    group = q.shape[2] // k.shape[2]
+    return reference_attention(q, jnp.repeat(k, group, 2),
+                               jnp.repeat(v, group, 2), mask, causal)
+
+
+@pytest.mark.parametrize("with_lse", [False, True], ids=["o", "o_and_lse"])
+@pytest.mark.parametrize("masking", ["none", "keys"])
+@pytest.mark.parametrize("h, hkv, d", GQA,
+                         ids=[f"h{h}_kv{k}_d{d}" for h, k, d in GQA])
+def test_grouped_query_attention(rng, h, hkv, d, masking, with_lse):
+    """K/V heads < query heads: o, dq and the K/V heads' gradients, each
+    the sum over its group of query heads, against plain attention; k and
+    v keep their own shapes in and out."""
+    s = 128
+    q = jnp.asarray(rng.standard_normal((B, s, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((B, s, hkv, d)), jnp.float32)
+            for _ in range(2))
+    mask = _mask(rng, masking, s)
+    w = jnp.asarray(rng.standard_normal((B, h, s)), jnp.float32)
+
+    def ours(q, k, v):
+        if with_lse:
+            o, lse = flash_attention_with_lse(
+                q, k, v, mask=mask, causal=True, use_pallas=True,
+                block_q=64, block_k=32)
+            return (o ** 2).sum() + (w * lse).sum()
+        return (flash_attention(q, k, v, mask=mask, causal=True,
+                                use_pallas=True, block_q=64,
+                                block_k=32) ** 2).sum()
+
+    def plain(q, k, v):
+        o = _plain_grouped(q, k, v, mask, True)
+        if not with_lse:
+            return (o ** 2).sum()
+        group = h // hkv
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q,
+                            jnp.repeat(k, group, 2)) / np.sqrt(d)
+        visible = jnp.tril(jnp.ones((s, s), bool))[None, None]
+        if mask is not None:
+            visible = visible & (mask[:, None, None, :] > 0)
+        lse = jax.nn.logsumexp(jnp.where(visible, logits, -1e30), -1)
+        return (o ** 2).sum() + (w * lse).sum()
+
+    with jax.default_matmul_precision("highest"):
+        got = jax.value_and_grad(ours, argnums=(0, 1, 2))(q, k, v)
+        want = jax.value_and_grad(plain, argnums=(0, 1, 2))(q, k, v)
+    assert float(got[0]) == pytest.approx(float(want[0]), rel=1e-5)
+    for g, wanted, x in zip(got[1], want[1], (q, k, v)):
+        assert g.shape == x.shape
+        np.testing.assert_allclose(g, wanted, rtol=2e-4, atol=2e-4)
+
+
+def test_grouping_repeats_nothing_where_a_block_is_one_head():
+    """Width 128: the kernels read the one K/V head through their index
+    maps (operands (B, S, Hkv * D) as they lie) and write each query
+    head's dk and dv; two narrow heads a block: K/V reach the call
+    repeated to the query heads' count."""
+    def operands(h, hkv, d):
+        q = jnp.ones((1, 128, h, d), jnp.float32)
+        k = jnp.ones((1, 128, hkv, d), jnp.float32)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, use_pallas=True).sum(),
+            argnums=(0, 1, 2)))(q, k, k)
+        calls = []
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                for value in eqn.params.values():
+                    for sub in value if isinstance(value, (list, tuple)) \
+                            else [value]:
+                        sub = getattr(sub, "jaxpr", sub)
+                        if hasattr(sub, "eqns"):
+                            walk(sub)
+
+        walk(jaxpr.jaxpr)
+        fwd, bwd = calls
+        return ([v.aval.shape for v in fwd.invars[:3]],
+                [v.aval.shape for v in bwd.outvars])
+
+    ins, outs = operands(8, 1, 128)
+    assert ins == [(1, 128, 1024), (1, 128, 128), (1, 128, 128)]
+    assert outs == [(1, 128, 1024)] * 3      # dk, dv a query head
+    ins, outs = operands(4, 2, 64)
+    assert ins == [(1, 128, 256)] * 3 and outs == [(1, 128, 256)] * 3
+
+
 def test_fallback_off_tpu_and_odd_seq(rng):
     # use_pallas=None off-TPU and an un-tileable sequence both fall back
     # to the reference path — identical result, no error.

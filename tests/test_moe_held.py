@@ -1,0 +1,237 @@
+"""The dropless path of a rank that is told which experts it holds
+(``parallel/moe.py`` ``held_experts_layer``) and the shares of a layer
+``models/solar.py`` computes, on the CPU: against a dense loop over the
+experts, under a skewed router, and share by share against the whole
+layer of the plain reference."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.catalog import Catalog
+from horovod_tpu.common import faults as faults_lib
+from horovod_tpu.models import solar
+from horovod_tpu.parallel import moe
+
+T, D, F, E, K = 64, 16, 24, 16, 4
+REFERENCE = Catalog().module("reference", "solar_open2")
+
+
+@pytest.fixture(scope="module")
+def layer():
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    return {"x": jax.random.normal(ks[0], (T, D)),
+            "router": jax.random.normal(ks[1], (D, E)),
+            "gate": jax.random.normal(ks[2], (E, D, F)) * 0.2,
+            "up": jax.random.normal(ks[3], (E, D, F)) * 0.2,
+            "down": jax.random.normal(ks[4], (E, F, D)) * 0.2}
+
+
+def _held(p, held, block_rows=None, router=None):
+    a, b = held[0], held[0] + held[1]
+    return moe.held_experts_layer(
+        p["x"], p["router"] if router is None else router, p["gate"][a:b],
+        p["up"][a:b], p["down"][a:b], E, held, K, block_rows=block_rows)
+
+
+def _dense(x, router, gate, up, down, held):
+    """Every held expert on every token, weighted by the token's
+    normalised top-k score for it (0 where it was not chosen)."""
+    scores, experts = jax.lax.top_k(jax.nn.softmax(x @ router, -1), K)
+    weights = scores / scores.sum(-1, keepdims=True)
+    y = 0.0
+    for e in range(held[0], held[0] + held[1]):
+        w = (weights * (experts == e)).sum(-1)
+        y = y + w[:, None] * (
+            (jax.nn.silu(x @ gate[e]) * (x @ up[e])) @ down[e])
+    return y
+
+
+# held experts, rows a block: every expert in one block; a share in blocks
+# so small that the loop runs eight times; the default block; a share at
+# the end of the router's columns.
+@pytest.mark.parametrize("held, block_rows", [
+    ((0, 16), None), ((4, 4), 8), ((4, 4), None), ((12, 4), 16)])
+def test_the_held_experts_part_and_its_gradients(layer, held, block_rows):
+    keys = ("x", "router", "gate", "up", "down")
+
+    def ours(*args):
+        return _held(dict(zip(keys, args)), held, block_rows)[0]
+
+    def dense(*args):
+        return _dense(*args, held)
+
+    args = [layer[k] for k in keys]
+    with jax.default_matmul_precision("highest"):
+        y, stats = _held(layer, held, block_rows)
+        want = dense(*args)
+        got_grads = jax.grad(lambda *a: (ours(*a) ** 2).sum(),
+                             argnums=range(5))(*args)
+        want_grads = jax.grad(lambda *a: (dense(*a) ** 2).sum(),
+                              argnums=range(5))(*args)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    for got, wanted in zip(got_grads, want_grads):
+        np.testing.assert_allclose(got, wanted, atol=2e-5)
+    # the counts are the router's own
+    experts = moe.route_top_k(layer["x"], layer["router"], K)[0]
+    demanded = [(experts == e).sum() for e in range(held[0], sum(held))]
+    assert stats["expert_load"].tolist() == demanded
+    assert float(stats["local_routes"]) == sum(demanded)
+    assert float(stats["dropped_tokens"]) == 0.0
+
+
+def test_the_shares_add_up_to_the_whole_routed_layer(layer):
+    """16 experts in 4 shares of 4: the parts the four ranks compute add
+    up to the part of a rank that holds every expert."""
+    with jax.default_matmul_precision("highest"):
+        parts = [_held(layer, (first, 4)) for first in (0, 4, 8, 12)]
+        whole, stats = _held(layer, (0, 16))
+    np.testing.assert_allclose(sum(y for y, _ in parts), whole, atol=2e-6)
+    assert sum(float(s["local_routes"]) for _, s in parts) == T * K \
+        == float(stats["local_routes"])
+
+
+def test_no_route_is_dropped_under_a_skewed_router(layer):
+    """``chaos_skew_gate`` drives every token's first choice to expert 5:
+    the share that holds it computes all T routes to it, block after
+    block of 16 rows; the capacity path's arithmetic
+    (1.25 T k / E = 20 rows an expert) would have dropped two thirds of
+    them."""
+    faults_lib.install(faults_lib.FaultPlan.from_json(
+        '{"seed": 1, "faults": [{"site": "moe_skew", "step": 1, '
+        '"scale": 30.0, "target": "5"}]}'))
+    try:
+        router = moe.chaos_skew_gate(layer["router"])
+    finally:
+        faults_lib.uninstall()
+    assert float(router[:, 5].min()) > float(layer["router"].max())
+    # 2.5 x the 64 routes a balanced router sends to 4 of 16 experts
+    assert moe.default_block_rows(T, K, 4, E) == 160
+    # the gate skews a router's WEIGHTS: tokens with positive features
+    # all score the hot column highest
+    layer = {**layer, "x": jnp.abs(layer["x"])}
+    with jax.default_matmul_precision("highest"):
+        y, stats = _held(layer, (4, 4), block_rows=16, router=router)
+        want = _dense(layer["x"], router, layer["gate"], layer["up"],
+                      layer["down"], (4, 4))
+    assert stats["expert_load"][1] == T     # over the capacity path's 20
+    assert float(stats["local_routes"]) >= 4 * 16   # four blocks ran
+    assert float(stats["dropped_tokens"]) == 0.0
+    np.testing.assert_allclose(y, want, atol=2e-6)
+
+
+def test_block_rows_follow_the_expected_routes():
+    # the cell: 8192 tokens, top-8, 8 of 320 held: 1,638 routes expected
+    assert moe.default_block_rows(8192, 8, 8, 320) == 4096
+    # never more rows than there can be routes
+    assert moe.default_block_rows(16, 4, 16, 16) == 64
+
+
+def test_the_gauges_are_set_from_inside_the_step(layer):
+    import horovod_tpu as hvd
+
+    def step(x):
+        y, stats = _held({**layer, "x": x}, (4, 4))
+        moe.record_held_stats(stats, first=4)
+        return y.sum()
+
+    jax.block_until_ready(jax.jit(jax.grad(step))(layer["x"]))
+    jax.effects_barrier()
+    metrics = hvd.metrics()
+    load = {s["labels"]["expert"]: s["value"]
+            for s in metrics["hvd_tpu_moe_expert_load"]["samples"]}
+    experts = moe.route_top_k(layer["x"], layer["router"], K)[0]
+    for e in range(4, 8):
+        assert load[str(e)] == float((experts == e).sum())
+    assert metrics["hvd_tpu_moe_local_routes"]["samples"][0]["value"] \
+        == sum(load[str(e)] for e in range(4, 8))
+    assert metrics["hvd_tpu_moe_dropped_tokens"]["samples"][0]["value"] == 0
+    for name in ("hvd_tpu_moe_dropped_frac", "hvd_tpu_moe_expert_load"):
+        assert "top-k" in metrics[name]["help"]
+        assert "top-2" not in metrics[name]["help"]
+
+
+# -- the shares of a whole layer, against the plain reference ---------------
+
+CONFIG = {"hidden_size": 32, "head_dim": 8, "rms_norm_eps": 1e-5,
+          "linear_attn_config": {"head_dim": 8, "num_heads": 4,
+                                 "short_conv_kernel_size": 4},
+          "num_experts_per_tok": 4, "routed_scaling_factor": 1,
+          "held_experts_first": 0}
+
+
+def _columns(kernel, share, shares, width):
+    """The share's heads of a (hidden, heads x width) kernel."""
+    heads = kernel.shape[-1] // width // shares
+    return kernel[..., share * heads * width:(share + 1) * heads * width]
+
+
+def _rows(kernel, share, shares):
+    n = kernel.shape[0] // shares
+    return kernel[share * n:(share + 1) * n]
+
+
+def test_the_heads_shares_add_up_to_the_whole_attention_layers():
+    """Heads in 2 shares: each share's GQA (2 of 4 query heads on 1 of 2
+    K/V heads) and KDA (2 of 4 heads) module output, ``W_o``'s rows of
+    those heads included, add up to the uncut reference's layer."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 32))
+    gqa = solar.GatedGQA(4, 2, 8, jnp.float32)
+    kda = solar.KDA(4, 8, 4, 8, 1e-5, jnp.float32)
+    whole_gqa = gqa.init(jax.random.PRNGKey(2), x)["params"]
+    whole_kda = kda.init(jax.random.PRNGKey(3), x)["params"]
+    with jax.default_matmul_precision("highest"):
+        want_gqa = REFERENCE._gqa(x, whole_gqa, CONFIG)
+        want_kda = REFERENCE._kda(x, whole_kda, CONFIG)
+        got_gqa = got_kda = 0.0
+        for share in range(2):
+            cols = lambda k: {"kernel": _columns(  # noqa: E731
+                whole[k]["kernel"], share, 2, 8)}
+            whole = whole_gqa
+            part = {**{k: cols(k) for k in ("q", "k", "v", "gate")},
+                    "o": {"kernel": _rows(whole["o"]["kernel"], share, 2)}}
+            got_gqa += solar.GatedGQA(2, 1, 8, jnp.float32).apply(
+                {"params": part}, x)
+            whole = whole_kda
+            part = {**{k: cols(k) for k in ("q", "k", "v", "f_up", "g_up")},
+                    **{k: whole[k] for k in ("f_down", "g_down", "o_norm")},
+                    **{"conv_" + k: _columns(whole["conv_" + k], share, 2, 8)
+                       for k in "qkv"},
+                    "A_log": _rows(whole["A_log"], share, 2),
+                    "dt_bias": _rows(whole["dt_bias"], share, 2),
+                    "beta": {"kernel": _columns(whole["beta"]["kernel"],
+                                                share, 2, 1)},
+                    "o": {"kernel": _rows(whole["o"]["kernel"], share, 2)}}
+            got_kda += solar.KDA(2, 8, 4, 8, 1e-5, jnp.float32).apply(
+                {"params": part}, x)
+    np.testing.assert_allclose(got_gqa, want_gqa, atol=3e-6)
+    np.testing.assert_allclose(got_kda, want_kda, atol=3e-6)
+
+
+def test_the_expert_shares_and_the_shared_expert_once_are_the_whole_layer():
+    """The share test of the sizing rule: 16 experts in 4 shares of 4.
+    Every share computes its experts' routes and the shared expert; the
+    routed parts of all shares plus the shared expert counted ONCE are
+    what the uncut reference gives for the whole layer."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+    whole = solar.SparseExperts(16, (0, 16), 4, 12, 12, 1.0, jnp.float32)
+    params = whole.init(jax.random.PRNGKey(5), x)["params"]
+    with jax.default_matmul_precision("highest"):
+        want = REFERENCE._moe(x, params, CONFIG)
+        shared = REFERENCE._swiglu(
+            x, *(params["shared_" + k]["kernel"]
+                 for k in ("gate", "up", "down")))
+        routed = 0.0
+        for first in (0, 4, 8, 12):
+            part = {**params, **{k: params[k][first:first + 4] for k in (
+                "experts_gate", "experts_up", "experts_down")}}
+            y, _ = solar.SparseExperts(
+                16, (first, 4), 4, 12, 12, 1.0, jnp.float32).apply(
+                    {"params": part}, x)
+            routed += y - shared
+            # the reference given the same share gives the same part
+            np.testing.assert_allclose(y, REFERENCE._moe(
+                x, part, {**CONFIG, "held_experts_first": first}),
+                atol=3e-6)
+    np.testing.assert_allclose(routed + shared, want, atol=3e-6)

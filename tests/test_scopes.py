@@ -223,11 +223,56 @@ def _constants():
             if k.isupper() and isinstance(v, str)}
 
 
+ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
+             + scopes.LINEAR_ATTN_SCOPES + scopes.FLASH_KERNELS
+             + scopes.BUCKET_KERNELS)
+
+
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 17
-    assert set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.FLASH_KERNELS
-               + scopes.BUCKET_KERNELS) <= set(values)
+    assert len(values) == len(set(values)) == 21
+    assert set(ALL_NAMES) <= set(values)
+    # tuples of their own: a scope of one model's step is not one every
+    # family carries
+    assert scopes.MOE_SCOPES == ("hvd_moe_route", "hvd_moe_experts",
+                                 "hvd_moe_shared")
+    assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
+    assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES) \
+        & set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES)
+
+
+@pytest.fixture(scope="module")
+def solar_op_names():
+    """Every ``op_name`` of a tiny expert / linear-attention model's
+    differentiated step, as lowered."""
+    from horovod_tpu.models import SolarLM, solar_loss
+
+    model = SolarLM(vocab_size=64, num_layers=2, hidden=32, gqa_layers=(0,),
+                    num_heads=2, num_kv_heads=1, head_dim=16, kda_heads=2,
+                    kda_head_dim=16, gate_rank=8, num_experts=8,
+                    held_experts=(2, 4), top_k=2, expert_dim=16,
+                    shared_dim=16)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    text = jax.jit(jax.grad(lambda p: solar_loss(model, p, tokens))).lower(
+        params).as_text(debug_info=True)
+    return set(re.findall(r'"(jit\([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", scopes.MOE_SCOPES
+                         + scopes.LINEAR_ATTN_SCOPES)
+def test_an_expert_linear_attention_models_scopes_are_on_its_step(
+        solar_op_names, scope):
+    """Forward and backward, and only under the layers that have them:
+    KDA's in the linear layer, none of it in the softmax one."""
+    under = [n for n in solar_op_names if _under(scope).search(n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    if scope == scopes.KDA:
+        assert all("layer1" in n for n in under)
+    for n in under:     # siblings: no instruction under two of them
+        assert sum(bool(_under(s).search(n)) for s in scopes.MOE_SCOPES
+                   + scopes.LINEAR_ATTN_SCOPES + (scopes.LM_HEAD,)) == 1, n
 
 
 def test_the_benchmarks_data_file_quotes_the_same_names():
@@ -246,7 +291,6 @@ def test_the_docs_list_the_same_names():
     with open(os.path.join(ROOT, "docs", "timeline.md")) as f:
         docs = f.read()
     listed = set(re.findall(r"`(hvd_[a-z0-9_/]+)`", docs))
-    want = set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.FLASH_KERNELS
-               + scopes.BUCKET_KERNELS)
+    want = set(ALL_NAMES)
     assert want <= listed
     assert {n for n in listed if not n.startswith("hvd_tpu")} <= want

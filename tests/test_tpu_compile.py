@@ -172,6 +172,54 @@ def test_flash_attention_compiles_for_v5e(v5e, on_tpu, direction, shape,
         assert not re.search(rf"\[{b},{h},{s},{d}\]", hlo)
 
 
+def test_grouped_query_flash_compiles_for_v5e(v5e, on_tpu):
+    """The expert, linear-attention cell's softmax layer: 8 query heads of
+    128 on ONE K/V head, S4096. K and V cross as (B, S, 128), nothing is
+    repeated; the backward writes dk and dv a query head ((B, S, 1024))
+    and XLA sums them over the group."""
+    b, s, h, hkv, d = 2, 4096, 8, 1, 128
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True) \
+            .astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e,
+                   ((b, s, h, d), jnp.bfloat16),
+                   *[((b, s, hkv, d), jnp.bfloat16)] * 2)
+    assert _mosaic_calls(hlo) == 2
+    wide, narrow = f"bf16[{b},{s},{h * d}]", f"bf16[{b},{s},{d}]"
+    fwd, = re.findall(r"%[\w.]*hvd_flash_fwd[\w.]* = [^\n]*", hlo)
+    operands = fwd.split("operand_layout_constraints=")[1]
+    assert operands.count(wide) == 1 and operands.count(narrow) == 2
+    bwd, = re.findall(r"%[\w.]*hvd_flash_dkv[\w.]* = [^\n]*", hlo)
+    assert bwd.split(" custom-call(")[0].count(wide) == 3
+
+
+
+def test_the_grouped_expert_matmuls_compile_for_v5e(v5e):
+    """``jax.lax.ragged_dot`` at the cell's shapes (a block of 4096 routes to 8
+    experts of 4096 x 1280), forward and both transposes: XLA's own
+    Mosaic kernels, no dense fallback over the groups."""
+    from horovod_tpu.parallel import moe
+
+    def loss(xg, weights, gate, up, down, sizes):
+        valid = jnp.arange(xg.shape[0]) < sizes.sum()
+        return moe._expert_block(xg, weights, valid, gate, up, down,
+                                 sizes).sum()
+
+    bank = ((8, 4096, 1280), jnp.bfloat16)
+    hlo = _compile(jax.grad(loss, argnums=(0, 2, 3, 4)), v5e,
+                   ((4096, 4096), jnp.bfloat16), ((4096,), jnp.float32),
+                   bank, bank, ((8, 1280, 4096), jnp.bfloat16),
+                   ((8,), jnp.int32))
+    kernels = re.findall(r"%ragged-dot-none[\w.]* = (\w+\[[\d,]*\])", hlo)
+    # two forward (the third's result feeds no gradient of a sum), three
+    # for the rows' gradients, three for the banks'
+    assert len(kernels) == 8
+    assert sum(k.startswith("f32[8,") or k.startswith("bf16[8,")
+               for k in kernels) == 3
+
+
 def test_flash_with_lse_backward_compiles_for_v5e(v5e, on_tpu):
     """Ring attention's interface: a key mask operand, the lse as an
     output and its cotangent folded into the one row operand — on a
