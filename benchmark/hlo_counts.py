@@ -1,5 +1,6 @@
 """Exact counts from a compiled step's HLO text: collective operations,
-the bytes per chip they move, and Mosaic (Pallas) kernel calls.
+the bytes per chip they move, and Mosaic (Pallas) kernel calls, all of
+them and those that carry a flash attention kernel's name.
 
 Counts, not times: they repeat exactly and a CPU rehearsal can print
 them. The names matched are data, read by ``load_names``.
@@ -14,8 +15,7 @@ import re
 _HERE = os.path.dirname(os.path.abspath(__file__))
 # What a cell's own file of names may add to: the lists a later PR's kernel
 # or scope name belongs in, and the phases its parts make up.
-_EXTENDED = ("flash_kernels", "not_flash_kernels", "dense_markers",
-             "program_scopes")
+_EXTENDED = ("flash_kernels", "dense_markers", "program_scopes")
 
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8,
@@ -24,6 +24,7 @@ _SHAPE = re.compile(r"\b(pred|[suf]\d+|bf16)\[([\d,]*)\]")
 # `%name = <result type> opcode(operands...)`; async pairs carry the
 # payload in `-start`, so `-done` is not counted again.
 _INSTR = re.compile(r"=\s*(\(?[^=]*?)\s([a-z][a-z\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
 
 
 def load_names(more=()) -> dict:
@@ -66,15 +67,24 @@ def _shape_bytes(text: str) -> list:
 
 
 def count(hlo_text: str, names: dict = None) -> dict:
-    """``{"collectives": {opcode: {"ops", "bytes"}}, "mosaic_calls": n}``.
+    """``{"collectives": {opcode: {"ops", "bytes"}}, "mosaic_calls": n,
+    "flash_mosaic_calls": n}``.
 
     Bytes are those of each collective's result on one chip. An async
     ``-start`` returns (operands, results): only the results half is
-    counted."""
+    counted. A Mosaic call is a flash kernel's where its own instruction
+    name or its ``op_name`` holds a name of ``flash_kernels``, as
+    ``trace_reduce.classify`` tells a trace's events."""
     names = names or load_names()
     opcodes = tuple(names["collective_opcodes"])
     collectives = {}
+    mosaic = flash = 0
     for line in hlo_text.splitlines():
+        if names["mosaic_call_marker"] in line:
+            mosaic += 1
+            own = " ".join([line.split(" = ", 1)[0],
+                            *_OP_NAME.findall(line)])
+            flash += any(k in own for k, _ in names["flash_kernels"])
         m = _INSTR.search(line)
         if not m:
             continue
@@ -90,5 +100,5 @@ def count(hlo_text: str, names: dict = None) -> dict:
         entry = collectives.setdefault(base, {"ops": 0, "bytes": 0})
         entry["ops"] += 1
         entry["bytes"] += sum(sizes)
-    return {"collectives": collectives,
-            "mosaic_calls": hlo_text.count(names["mosaic_call_marker"])}
+    return {"collectives": collectives, "mosaic_calls": mosaic,
+            "flash_mosaic_calls": flash}

@@ -448,7 +448,7 @@ def run(run):
             rehearsal=run.rehearse)
     run.say("hlo", **counts,
             mosaic_calls_are_all_flash=(
-                counts["mosaic_calls"] in (0, 3 * attention["calls"])))
+                counts["mosaic_calls"] == counts["flash_mosaic_calls"]))
 
     # -- correctness: the system's first steps are also the warm-up ---------
     t_check = time.perf_counter()

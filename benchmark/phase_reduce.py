@@ -5,17 +5,28 @@ splits two of them by the names the program gives its work
 (``horovod_tpu/common/scopes.py``; the strings are quoted as data in
 ``phase_names.json``):
 
-    flash_s = flash_fwd + flash_dq + flash_dkv (+ other_kernel)
-    dense_s = bucket_copy + lm_head + optimizer + bwd + fwd + unattributed
+    flash_s = flash_fwd + flash_dkv
+    dense_s = bucket_copy + lm_head + optimizer + bwd + fwd + other_kernel
+              + unattributed
 
-A flash event (``trace_reduce.classify``) goes to the kernel whose name
-its instruction or its ``op_name`` carries. A dense event goes to the
-first marker of ``dense_markers`` its ``op_name`` holds, to
-``dense_default`` where it has an ``op_name`` and none of them, and to
-``no_op_name`` where nothing names it. A fusion is one event and carries
-one ``op_name``: a fusion that holds instructions of two scopes counts
-whole under the one XLA gave the fusion (on the v5e AdamW's update rides
-in the weight-gradient matmuls' fusions, which are named for the matmul).
+One rule for both: a device event belongs to the layer whose name its
+``op_name`` or its own instruction name holds, whether XLA compiled it or
+Mosaic did. A flash event (``trace_reduce.classify``: a Mosaic call that
+carries an attention kernel's name) goes to that kernel's part. A dense
+event, an XLA operation or any other Mosaic call, goes to the first
+marker of ``dense_markers`` that its ``op_name`` or its instruction name
+(``%hvd_kda_bwd.9``) holds; else a Mosaic call goes to ``kernel_default``
+(XLA's ``ragged-dot`` kernels, whose ``op_name`` is the kernel's own), an
+XLA operation to ``dense_default`` where it has an ``op_name`` and to
+``no_op_name`` where nothing names it. So a later PR's kernel counts
+under its layer where its ``pallas_call`` is named with the layer's scope
+string as a prefix (``scopes.KDA + "_fwd"``: the name is enough where a
+backward's ``op_name`` has lost the forward's scope) or is called under
+the scope, and nothing here needs an edit. A fusion is
+one event and carries one ``op_name``: a fusion that holds instructions
+of two scopes counts whole under the one XLA gave the fusion (on the v5e
+AdamW's update rides in the weight-gradient matmuls' fusions, which are
+named for the matmul).
 
 Where the ``op_name`` comes from, in this order:
 
@@ -54,8 +65,8 @@ import json
 import os
 
 from benchmark.hlo_counts import load_names
-from benchmark.trace_reduce import (classify, load_xplane, short_name,
-                                    subtract, total, union)
+from benchmark.trace_reduce import (classify, is_mosaic_call, load_xplane,
+                                    short_name, subtract, total, union)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(_HERE)
@@ -293,39 +304,44 @@ def reduce_phases(trace: dict, names: dict) -> dict:
     part that took no time."""
     flash_markers = [tuple(m) for m in names["flash_kernels"]]
     dense_markers = [tuple(m) for m in names["dense_markers"]]
-    flash_parts = [p for _, p in flash_markers] + [names["flash_default"]]
-    dense_parts = ([p for _, p in dense_markers]
+    flash_parts = [p for _, p in flash_markers]
+    # A kernel's interval is the kernel's: it claims before the markers.
+    dense_parts = ([names["kernel_default"]] + [p for _, p in dense_markers]
                    + [names["dense_default"], names["no_op_name"]])
     seconds = dict.fromkeys(flash_parts + dense_parts, 0.0)
     named = {"flash": False, "dense": False}
     programs = {}               # program id -> read_hlo(...), when needed
     n, from_hlo_ns = 0, 0.0
 
-    def dense_part(op_name):
-        return _part(dense_markers, names["dense_default"], (op_name,))
+    def dense_part(*texts):
+        return _part(dense_markers, None, texts)
 
     @functools.cache            # an instruction runs once a step
     def part_of(name, own_text, op_name, program):
         """``(part or None, named through the HLO)`` of one event."""
         cls = classify(name, own_text, names)
+        bare = short_name(name).lstrip("%")
         if cls == "flash":
             # The instruction's own name, never its operands'.
-            part = _part(flash_markers, names["flash_default"],
-                         (short_name(name), op_name))
-            named["flash"] |= part != names["flash_default"]
-            return part, False
+            named["flash"] = True
+            return _part(flash_markers, None, (bare, own_text, op_name)), False
         if cls != "dense":
             return None, False
+        named["dense"] |= any(s in op_name or s in bare
+                              for s in names["program_scopes"])
+        part = dense_part(op_name, bare)
+        if part:
+            return part, False
+        if is_mosaic_call(name, own_text, names):
+            return names["kernel_default"], False
         if op_name:
-            named["dense"] |= any(s in op_name
-                                  for s in names["program_scopes"])
-            return dense_part(op_name), False
+            return names["dense_default"], False
         if program not in programs:
             programs[program] = read_hlo(
                 trace.get("hlo", {}).get(program, b""))
         votes = collections.Counter(
-            dense_part(op) for op in candidates(
-                programs[program], short_name(name).lstrip("%")))
+            dense_part(op) or names["dense_default"] for op in candidates(
+                programs[program], bare))
         if not votes:
             return names["no_op_name"], False
         return votes.most_common(1)[0][0], True
@@ -377,8 +393,7 @@ def phases(record: dict, root: str = ROOT):
             steps = trace["steps"]
             parts = {p: 1e3 * s / steps
                      for p, s in reduced["seconds"].items()}
-            flash = {p for _, p in names["flash_kernels"]} \
-                | {names["flash_default"]}
+            flash = {p for _, p in names["flash_kernels"]}
             record["phases"] = {
                 "ms": {phase: sum(parts[p] for p in members)
                        for phase, members in names["phases"].items()},

@@ -12,9 +12,9 @@ summed would count nested or overlapping events twice (the repo's only
 banked reduction reported a busy share of 2.8 that way).
 
 Device events fall into three classes by the name lists of
-``hlo_counts.load_names``: collective, flash kernel (a Mosaic call, unless
-it is named as one of ``not_flash_kernels``), and every other operation
-("dense"). Per device:
+``hlo_counts.load_names``: collective, flash kernel (a Mosaic call that
+carries the name of one of ``flash_kernels``), and every other operation
+("dense"), XLA's own or any other Mosaic call. Per device:
 
     flash      = union(flash events)
     dense      = union(other events) - flash
@@ -80,8 +80,10 @@ def overlap(a: Interval, b: Interval) -> float:
 def load_xplane(path: str, names: dict) -> dict:
     """``{"devices": {plane: [[name, start_ns, dur_ns, text], ...]},
     "host": [[name, start_ns, dur_ns], ...]}``. ``text`` joins the
-    event's string stats (the chip's trace carries the HLO ``op_name``
-    there, which is where an unnamed Pallas kernel can be told)."""
+    event's own string stats: empty on TPU v5 lite with JAX 0.9.0, where
+    the ``op_name`` is a stat of the event's metadata
+    (``phase_reduce.read_metadata``) and an event's name is its whole HLO
+    instruction, a Pallas kernel's ``name=`` in it."""
     from jax.profiler import ProfileData
 
     data = ProfileData.from_file(path)
@@ -123,22 +125,31 @@ def short_name(name: str) -> str:
 
 def classify(name: str, text: str, names: dict) -> str:
     """``"collective"``, ``"flash"`` or ``"dense"``. A Mosaic call is a
-    flash kernel unless its own name (never an operand's) or its
-    ``op_name`` holds an entry of ``not_flash_kernels``: a wire's or a
-    grouped matmul's kernel is dense work, so that ``flash_ms`` and
-    ``flash_roofline_pct`` stay the attention kernels'."""
+    flash kernel only where its own name (never an operand's) or the
+    text of its own stats holds the name of one of ``flash_kernels``: the
+    attention kernels by what they are. Every other Mosaic call (a
+    wire's, a recurrence's, a grouped matmul's, XLA's own) is dense work
+    of the layer that owns it (``phase_reduce``), so that ``flash_ms``
+    and ``flash_roofline_pct`` are the attention kernels' alone whatever
+    kernels a later PR brings. A cell whose attention kernel carries
+    another name lists it in a file of names (``hlo_counts.load_names``)."""
     bare = short_name(name).lstrip("%")
     if any(bare == op or bare.startswith(op + ".")
            or bare.startswith(op + "-start") or bare.startswith(op + "-done")
            for op in names["collective_opcodes"]):
         return "collective"
-    if any(marker in name or marker in text
-           for marker in names["flash_kernel_markers"]):
-        if any(kernel in bare or kernel in text
-               for kernel in names["not_flash_kernels"]):
-            return "dense"
+    if is_mosaic_call(name, text, names) and any(
+            kernel in bare or kernel in text
+            for kernel, _ in names["flash_kernels"]):
         return "flash"
     return "dense"
+
+
+def is_mosaic_call(name: str, text: str, names: dict) -> bool:
+    """Whether a device event is a Pallas (Mosaic) kernel's call, the
+    repo's own or one XLA itself lowers to (``ragged-dot``)."""
+    marker = names["mosaic_call_marker"]
+    return marker in name or marker in text
 
 
 MIN_GAP_NS = 2000.0   # shorter idle gaps are counted together, unattributed

@@ -203,9 +203,8 @@ def test_the_new_cell_sees_what_its_limits_are_held_against(fault, failing):
 # -- a cell's own file of names -----------------------------------------------
 
 MORE_NAMES = {
-    "comment": "a later PR's grouped matmul and its scope",
-    "not_flash_kernels": ["hvd_grouped_matmul"],
-    "flash_kernels": [["hvd_flash_fwd_gqa", "flash_fwd"]],
+    "comment": "a later PR's attention kernel, grouped matmul and scope",
+    "flash_kernels": [["hvd_latent_attn_fwd", "flash_fwd"]],
     "dense_markers": [["hvd_update/experts", "expert_update"],
                       ["hvd_moe_dispatch", "moe_dispatch"]],
     "program_scopes": ["hvd_moe_dispatch"],
@@ -216,11 +215,10 @@ MORE_NAMES = {
 def test_a_file_of_names_extends_the_lists_and_adds_phases():
     plain = hlo_counts.load_names()
     names = hlo_counts.load_names(
-        [{"not_flash_kernels": ["hvd_fp8_quantize"]}, MORE_NAMES])
-    assert names["not_flash_kernels"] == plain["not_flash_kernels"] + [
-        "hvd_fp8_quantize", "hvd_grouped_matmul"]       # in the order given
+        [{"flash_kernels": [["hvd_ring_attn", "flash_fwd"]]}, MORE_NAMES])
     assert names["flash_kernels"] == plain["flash_kernels"] + [
-        ["hvd_flash_fwd_gqa", "flash_fwd"]]
+        ["hvd_ring_attn", "flash_fwd"],
+        ["hvd_latent_attn_fwd", "flash_fwd"]]           # in the order given
     # A file's markers go first: 'hvd_update/experts' before 'hvd_update'.
     assert names["dense_markers"] == \
         MORE_NAMES["dense_markers"] + plain["dense_markers"]
@@ -232,24 +230,27 @@ def test_a_file_of_names_extends_the_lists_and_adds_phases():
     assert hlo_counts.load_names() == plain         # and nothing stays
 
 
-def test_a_named_mosaic_call_that_is_no_flash_kernel_is_dense():
+def test_a_mosaic_call_is_a_flash_kernel_only_where_a_list_names_it():
+    """The allow-list: a grouped matmul, a wire's kernel and an attention
+    kernel under a name of its own are all dense work until a cell's file
+    adds the last to ``flash_kernels``; the others need no list."""
     call = ('%{}.4 = f32[8]{{0}} custom-call(%hvd_flash_fwd.2), '
             'custom_call_target="tpu_custom_call"')
     plain = hlo_counts.load_names()
-    assert trace_reduce.classify(
-        call.format("hvd_grouped_matmul"), "", plain) == "flash"
-    assert trace_reduce.classify(
-        call.format("hvd_int8_quantize_sr"), "", plain) == "dense"
+    for kernel in ("hvd_grouped_matmul", "hvd_int8_quantize_sr",
+                   "hvd_latent_attn_fwd"):
+        assert trace_reduce.classify(
+            call.format(kernel), "", plain) == "dense"
     names = hlo_counts.load_names([MORE_NAMES])
     assert trace_reduce.classify(
         call.format("hvd_grouped_matmul"), "", names) == "dense"
     # By its own name, never an operand's; and the phases follow.
     assert trace_reduce.classify(
-        call.format("hvd_flash_fwd_gqa"), "", names) == "flash"
+        call.format("hvd_latent_attn_fwd"), "", names) == "flash"
     events = [
         [call.format("hvd_grouped_matmul"), 0.0, 4e3, "",
          "jit(step)/jvp(GPT)/hvd_moe_dispatch/hvd_grouped_matmul", 1],
-        [call.format("hvd_flash_fwd_gqa"), 4e3, 2e3, "", "", 1],
+        [call.format("hvd_latent_attn_fwd"), 4e3, 2e3, "", "", 1],
         ["%fusion.1 = f32[8]{0} fusion(%p.1), kind=kLoop", 6e3, 1e3, "",
          "jit(step)/hvd_update/experts/mul", 1]]
     seconds = phase_reduce.reduce_phases(
@@ -259,7 +260,10 @@ def test_a_named_mosaic_call_that_is_no_flash_kernel_is_dense():
 
 
 @pytest.mark.parametrize("more", [
+    {"kernel_default": "flash_fwd"},
     {"flash_default": "flash_fwd"},
+    # the deny-list is no more: a file that brings one is told so
+    {"not_flash_kernels": ["hvd_grouped_matmul"]},
     {"collective_opcodes": ["all-reduce"]},
     {"phases": {"optimizer": ["fwd"]}},
 ])
@@ -269,13 +273,16 @@ def test_a_file_of_names_may_not_change_what_is_there(more):
 
 
 def test_names_are_read_for_the_cell_that_lists_them_and_no_other(tmp_path):
-    """No cell of today lists any, so each is reduced by the names of
-    every cell whatever files a later PR adds; a cell that lists one gets
-    it, through the record, and the cell beside it does not."""
+    """None of the seven cells of PR 33 lists any, so each is reduced by
+    the names of every cell whatever files a later PR adds (a later PR's
+    cell may list one: these seven are held by name, not "every cell"); a
+    cell that lists one gets it, through the record, and the cell beside
+    it does not."""
     cat = Catalog()
-    for entry in cat.index["workloads"]:
-        assert cat.names(cat.cell(entry["name"])) == []
-    assert not os.path.exists(os.path.join(ROOT, "benchmark", "names"))
+    for cell in ("gpt2s-s512", "gpt2s-s2048", "gpt2s-s4096",
+                 "bert-large-s512", "bert-large-s512-dp4",
+                 "ouro-2.6b-l8-s2048", "solar-open2-l4-e8-s4096"):
+        assert cat.names(cat.cell(cell)) == []
     assert phase_reduce.load_names is hlo_counts.load_names
 
     (tmp_path / "names").mkdir()

@@ -17,6 +17,12 @@ from benchmark.catalog import ROOT, Catalog
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 NAMES = hlo_counts.load_names()
+# A Mosaic call as the chip's trace names it: its whole HLO instruction.
+MOSAIC = ('%{} = bf16[8,128]{{1,0}} custom-call(bf16[8,128]{{1,0}} {}), '
+          'custom_call_target="tpu_custom_call"')
+BUCKET_KERNELS = ("hvd_scale", "hvd_adasum_dot_norms", "hvd_adasum_combine",
+                  "hvd_int8_quantize", "hvd_int8_quantize_sr",
+                  "hvd_int8_dequantize")
 
 
 @pytest.fixture(scope="module")
@@ -73,16 +79,58 @@ def test_a_trace_without_device_events_reduces_to_nothing():
     ("%all-gather-done.7", "", "collective"),
     ("%collective-permute.2", "", "collective"),
     ("%all-reduce-scatter-fusion", "", "dense"),
-    ('%attn.9 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %x), '
-     'custom_call_target="tpu_custom_call"', "", "flash"),
     ('%custom-call.228 = f32[768,768]{1,0} custom-call(f32[192,768]{1,0} '
      '%s), custom_call_target="ConcatBitcast"', "", "dense"),
     ("%fusion.3 = f32[4]{0} fusion(f32[4]{0} %all-reduce.1), kind=kLoop",
      "", "dense"),
     ("%fusion.77", "jit(step)/mul", "dense"),
+    # A Mosaic call is a flash kernel by what it is, an attention kernel
+    # named in ``flash_kernels``: by its own instruction name, as the
+    # chip's compiler writes it, or by the text of its own stats ...
+    (MOSAIC.format("hvd_flash_fwd.2", "%p.1"), "", "flash"),
+    (MOSAIC.format("hvd_flash_dkv.3", "%hvd_flash_fwd.2"), "", "flash"),
+    (MOSAIC.format("hvd_flash_dq.5", "%p.1"), "", "flash"),
+    (MOSAIC.format("transpose_jvp_hvd_flash_dkv__.1", "%p.1"), "", "flash"),
+    (MOSAIC.format("custom-call.5", "%p.1"),
+     "jit(step)/jvp(GPT)/layer0/attn/hvd_flash_fwd/pallas_call", "flash"),
+    # ... and every other Mosaic call is dense work of the layer that
+    # owns it: a recurrence's kernel under or outside its scope, a
+    # grouped matmul of the repo's own or XLA's, a wire's (the six
+    # ``scopes.BUCKET_KERNELS``), one nothing names, and one that merely
+    # takes a flash kernel's result as an operand.
+    (MOSAIC.format("hvd_kda_fwd.7", "%p.1"),
+     "jit(step)/jvp(SolarLM)/layer1/attn/hvd_kda/pallas_call", "dense"),
+    (MOSAIC.format("hvd_kda_bwd.9", "%p.1"), "", "dense"),
+    (MOSAIC.format("hvd_moe_experts_gmm.3", "%p.1"), "", "dense"),
+    (MOSAIC.format("ragged-dot-none.4", "%p.1"), "ragged-dot-none", "dense"),
+    (MOSAIC.format("ragged-dot-metadata.4", "%p.1"), "", "dense"),
+    *[(MOSAIC.format(kernel + ".2", "%p.1"), "", "dense")
+      for kernel in BUCKET_KERNELS],
+    (MOSAIC.format("hvd_int8_dequantize.2", "%p.1"),
+     "jit(step)/hvd_reduce/hvd_int8_dequantize/pallas_call", "dense"),
+    (MOSAIC.format("attn.9", "%x"), "", "dense"),
+    (MOSAIC.format("hvd_grouped_matmul.4", "%hvd_flash_fwd.2"), "", "dense"),
 ])
 def test_classify_by_the_name_lists(name, text, want):
     assert trace_reduce.classify(name, text, NAMES) == want
+
+
+def test_the_bucket_kernels_of_the_table_are_the_programs():
+    """The six wire kernels of ``ops/pallas_kernels.py``: dense by the
+    allow-list, with no list of their own to keep in step (the data
+    file's ``not_flash_kernels`` is read by nothing under ``benchmark/``
+    and stays for ``tests/test_scopes.py``). None of them holds an
+    attention kernel's name, or it would be taken for one."""
+    from horovod_tpu.common import scopes
+
+    assert BUCKET_KERNELS == scopes.BUCKET_KERNELS
+    assert NAMES["not_flash_kernels"] == list(BUCKET_KERNELS)
+    flash = [k for k, _ in NAMES["flash_kernels"]]
+    assert flash == list(scopes.FLASH_KERNELS)
+    assert not any(f in k for f in flash for k in BUCKET_KERNELS)
+    assert not any(f in scope for f in flash for scope in (
+        scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
+        + scopes.LINEAR_ATTN_SCOPES))
 
 
 def test_interval_arithmetic():
@@ -196,9 +244,21 @@ def test_hlo_counts_collectives_bytes_and_mosaic_calls():
         '%attn = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} %x), '
         'custom_call_target="tpu_custom_call"',
         '%fusion.2 = f32[4]{0} fusion(f32[4]{0} %all-reduce.1), kind=kLoop',
+        # the attention kernels, named as the chip's compiler writes them
+        # (by the name or by the op_name), and a grouped matmul that takes
+        # one's result: an operand's name makes no flash kernel
+        '%hvd_flash_fwd.2 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} '
+        '%x), custom_call_target="tpu_custom_call"',
+        '%custom-call.7 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} '
+        '%x), custom_call_target="tpu_custom_call", metadata={op_name='
+        '"jit(step)/transpose(jvp(hvd_flash_dkv))/pallas_call"}',
+        '%ragged-dot-none.4 = bf16[8,128]{1,0} custom-call(bf16[8,128]{1,0} '
+        '%hvd_flash_fwd.2), custom_call_target="tpu_custom_call", '
+        'metadata={op_name="ragged-dot-none"}',
     ])
     got = hlo_counts.count(hlo, NAMES)
-    assert got["mosaic_calls"] == 1
+    assert got["mosaic_calls"] == 4
+    assert got["flash_mosaic_calls"] == 2
     assert got["collectives"] == {
         "all-reduce": {"ops": 2, "bytes": 1024 * 256 * 4 + 128 * 2},
         "all-gather": {"ops": 1, "bytes": 8 * 4 * 4}}
@@ -346,9 +406,16 @@ def test_reduction_of_a_slice_recorded_on_the_chip():
     them: whole HLO instructions, the kernel told by its Mosaic target."""
     with open(os.path.join(HERE, "fixtures", "trace_chip_excerpt.json")) as f:
         trace = json.load(f)
-    got = trace_reduce.reduce_trace(trace, steps=1, names=NAMES)
+    # Recorded before the kernels had names: ``%attn.36`` is dense work by
+    # the names of every cell, and a flash kernel for the cell whose file
+    # of names adds it to the allow-list.
+    plain = trace_reduce.reduce_trace(trace, steps=1, names=NAMES)["mean"]
+    named = hlo_counts.load_names([{"flash_kernels": [["attn", "flash_fwd"]]}])
+    got = trace_reduce.reduce_trace(trace, steps=1, names=named)
     mean = got["mean"]
     assert got["devices"] == 1
+    assert plain["flash_s"] == 0.0
+    assert plain["dense_s"] == pytest.approx(mean["dense_s"] + mean["flash_s"])
     assert mean["flash_s"] == pytest.approx(1.829717e-3)   # %attn.36 alone
     assert mean["collective_s"] == 0.0
     assert mean["busy_s"] <= got["window_s"]
