@@ -42,6 +42,12 @@ ADASUM_COMBINE = "hvd_adasum_combine"
 INT8_QUANTIZE = "hvd_int8_quantize"
 INT8_QUANTIZE_SR = "hvd_int8_quantize_sr"
 INT8_DEQUANTIZE = "hvd_int8_dequantize"
+# ops/linear_attention.py: the recurrence's two kernels, made under the
+# scope KDA and named with it as their prefix, so that a reader of a
+# trace finds the layer by either (the backward's op_name loses the scope
+# under transpose(); the instruction's own name keeps it)
+KDA_FWD = KDA + "_fwd"
+KDA_BWD = KDA + "_bwd"
 
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
@@ -50,3 +56,4 @@ LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)
+KDA_KERNELS = (KDA_FWD, KDA_BWD)

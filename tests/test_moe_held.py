@@ -235,3 +235,32 @@ def test_the_expert_shares_and_the_shared_expert_once_are_the_whole_layer():
                 x, part, {**CONFIG, "held_experts_first": first}),
                 atol=3e-6)
     np.testing.assert_allclose(routed + shared, want, atol=3e-6)
+
+
+def test_the_loss_held_before_the_backward_is_the_same_loss():
+    """``SolarLM.apply(tokens, labels)`` goes through the head's
+    ``custom_vjp`` (``_loss_before_the_backward``: what the step tells
+    XLA:TPU's scheduler) and the KDA layers' ballast (the identity off a
+    TPU): loss and every gradient equal those of the cross-entropy taken
+    from ``apply(tokens)``'s logits, which meets neither."""
+    model = solar.SolarLM(vocab_size=64, num_layers=2, hidden=32,
+                          gqa_layers=(0,), num_heads=2, num_kv_heads=1,
+                          head_dim=16, kda_heads=2, kda_head_dim=16,
+                          gate_rank=8, num_experts=4, held_experts=(0, 4),
+                          top_k=2, expert_dim=16, shared_dim=16,
+                          dtype=jnp.float32)
+    tokens = jax.random.randint(jax.random.PRNGKey(7), (2, 25), 0, 64)
+    params = model.init(jax.random.PRNGKey(8), tokens[:, :-1])["params"]
+
+    def from_logits(p):
+        logits = model.apply({"params": p}, tokens[:, :-1])
+        picked = jnp.take_along_axis(logits, tokens[:, 1:, None], -1)[..., 0]
+        return (jax.nn.logsumexp(logits, -1) - picked).mean()
+
+    with jax.default_matmul_precision("highest"):
+        got, got_grads = jax.value_and_grad(
+            lambda p: solar.solar_loss(model, p, tokens))(params)
+        want, want_grads = jax.value_and_grad(from_logits)(params)
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)

@@ -161,6 +161,38 @@ def test_flash_kernels_carry_their_names(with_lse):
     assert set(scopes.FLASH_KERNELS) - set(both) == {scopes.FLASH_DQ}
 
 
+def test_kda_kernels_carry_their_names():
+    """The recurrence's kernels meet the contract the benchmark's readers
+    find a layer's kernel by (PERF.md, the ``kda_ms`` row): the scope
+    string is each call's prefix (the instruction's own name holds it,
+    which the backward's ``op_name`` under ``transpose(`` does not) and
+    the call is made under the scope; no name is an attention kernel's or
+    a bucket kernel's, so ``flash_ms`` cannot count it."""
+    from horovod_tpu.ops import linear_attention as la
+
+    x = jnp.ones((1, 64, 2, 128), jnp.float32)
+    args = (x, x, x, -x, x[..., 0])
+
+    def loss(*a):
+        return la.kda_attention(*a, use_pallas=True).sum()
+
+    forward = _pallas_calls(jax.make_jaxpr(loss)(*args).jaxpr, [])
+    assert [e.params["name"] for e in forward] == [scopes.KDA_FWD]
+    both = _pallas_calls(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2, 3, 4)))(*args).jaxpr, [])
+    names = [e.params["name"] for e in both]
+    assert names == [scopes.KDA_FWD, scopes.KDA_BWD] \
+        == list(scopes.KDA_KERNELS)
+    # made under the scope: a path component of the forward's op_name
+    # (the differentiated calls' wrap it: ``transpose(jvp(hvd_kda))``)
+    text = jax.jit(loss).lower(*args).as_text(debug_info=True)
+    assert f"{scopes.KDA}/{scopes.KDA_FWD}" in text
+    for name in names:
+        assert name.startswith(scopes.KDA + "_")
+        assert "hvd_flash" not in name
+    assert not set(names) & set(scopes.FLASH_KERNELS + scopes.BUCKET_KERNELS)
+
+
 def test_the_flash_backward_call_has_three_outputs():
     q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(_flash_loss(False), argnums=(0, 1, 2)))(
@@ -225,12 +257,12 @@ def _constants():
 
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.LINEAR_ATTN_SCOPES + scopes.FLASH_KERNELS
-             + scopes.BUCKET_KERNELS)
+             + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 21
+    assert len(values) == len(set(values)) == 23
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries

@@ -220,6 +220,104 @@ def test_the_grouped_expert_matmuls_compile_for_v5e(v5e):
                for k in kernels) == 3
 
 
+def test_the_linear_attention_block_runs_its_recurrence_as_kernels(v5e,
+                                                                   on_tpu):
+    """The expert cell's KDA block (8 heads of 128 on 2 x S4096, under
+    ``nn.remat`` as the model has it), differentiated: the recurrence is
+    three Mosaic calls (the forward, the forward run again, the backward)
+    whose instruction names carry the scope, which is how the benchmark's
+    readers find them, and XLA is left no triangular solve and no loop
+    under the scope."""
+    import flax.linen as nn
+
+    from horovod_tpu.common import scopes
+    from horovod_tpu.models import solar
+
+    block = nn.remat(solar.KDA)(num_heads=8, head_dim=128)
+    x = jax.ShapeDtypeStruct((2, 4096, 4096), jnp.bfloat16, sharding=v5e)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=v5e),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), x))
+
+    def loss(p, x):         # its cotangent needs the block's output
+        return (block.apply(p, x).astype(jnp.float32) ** 2).sum()
+
+    hlo = jax.jit(jax.grad(loss)).lower(params, x).compile().as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    # (and the model's own call that does nothing: the scheduler's ballast)
+    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == sorted(
+        [scopes.KDA_FWD] * 2 + [scopes.KDA_BWD, "solar_scheduler_ballast"])
+    under = [line for line in hlo.splitlines() if scopes.KDA in line]
+    assert under and not any(
+        re.search(r"triangular.solve| while\(", line) for line in under)
+    assert "triangular-solve" not in hlo
+    # the backward call gives its five gradients and nothing else
+    backward, = [line.split(" custom-call(")[0] for line in hlo.splitlines()
+                 if re.match(r"\s*%hvd_kda_bwd[\w.]* = ", line)]
+    assert len(re.findall(r"(?:bf16|f32)\[[\d,]+\]", backward)) == 5
+
+
+def test_the_expert_cells_step_fits_where_the_scheduler_is_told(v5e, on_tpu):
+    """``models/solar.py`` tells XLA:TPU's scheduler two things (the loss
+    before the backward, the weight of the XLA recurrence's temporaries at
+    each KDA layer's backward) whose effect is the compiler's to give: the
+    whole training step of the expert cell (the model's defaults, 2 x
+    S4096, AdamW with bf16 first moments, donated), compiled for a
+    described v5e, took 11.57 GiB with the recurrence as XLA code, 13.03
+    with the kernels and nothing said, 11.98 with the loss alone, and takes
+    10.45 with both (on the chip to the digit: PERF.md, PR 34). A compiler
+    that stops listening fails here."""
+    import optax
+
+    from horovod_tpu.models import solar
+
+    model = solar.SolarLM()
+    tokens = jax.ShapeDtypeStruct((2, 4097), jnp.int32, sharding=v5e)
+    tx = optax.adamw(1e-4, mu_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    state = jax.eval_shape(tx.init, params)
+
+    def step(params, state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: solar.solar_loss(model, p, tokens))(params)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, loss
+
+    memory = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile().memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert held / 2 ** 30 < 10.8
+
+
+@pytest.mark.parametrize("chunk", [32, 128])
+def test_the_recurrence_kernels_compile_at_every_chunk_they_take(v5e, on_tpu,
+                                                                 chunk):
+    """``_kernels_take`` sends a chunk to the kernels only where Mosaic
+    compiles them for it (64 is the block's test above)."""
+    from horovod_tpu.ops import linear_attention as la
+
+    assert la._KERNEL_CHUNKS == (32, 64, 128)
+    x = jax.ShapeDtypeStruct((1, 256, 2, 128), jnp.bfloat16, sharding=v5e)
+    decay = jax.ShapeDtypeStruct(x.shape, jnp.float32, sharding=v5e)
+    beta = jax.ShapeDtypeStruct(x.shape[:3], jnp.float32, sharding=v5e)
+
+    def loss(*a):
+        return la.kda_attention(*a, chunk=chunk).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        x, x, x, decay, beta).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2
+
+
 def test_flash_with_lse_backward_compiles_for_v5e(v5e, on_tpu):
     """Ring attention's interface: a key mask operand, the lse as an
     output and its cotangent folded into the one row operand — on a
