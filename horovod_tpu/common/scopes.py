@@ -20,14 +20,18 @@ LM_HEAD = "hvd_lm_head"      # models/gpt.py, bert.py, looplm.py: vocabulary mat
 # models/looplm.py: the exit gate, the exit distribution, its entropy and
 # the weighting of the exits' losses
 LOOP_EXIT = "hvd_loop_exit"
-# parallel/moe.py, the held-experts path: the router, top-k, the sort of
-# the routes by expert and the weighted combine; the grouped matmuls of
-# the routed experts; the shared expert every token meets
+# parallel/moe.py, the held-experts path: the router (scores by a softmax
+# over the experts, or by a sigmoid with a selection bias), top-k, the
+# sort of the routes by expert and the weighted combine; the grouped
+# matmuls of the routed experts; the shared expert every token meets
 MOE_ROUTE = "hvd_moe_route"
 MOE_EXPERTS = "hvd_moe_experts"
 MOE_SHARED = "hvd_moe_shared"
 # ops/linear_attention.py: the gated delta-rule recurrence, chunked
 KDA = "hvd_kda"
+# ops/short_conv.py: the gated short convolution's two gates and its taps,
+# not the two projections around them
+SHORT_CONV = "hvd_short_conv"
 
 # Pallas kernels: the ``name=`` of each ``pallas_call``. FLASH_DKV is the
 # whole flash backward: the dk/dv call also gives dq. FLASH_DQ is carried
@@ -53,6 +57,7 @@ STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 MOE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)   # an expert layer's
 LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
+SHORT_CONV_SCOPES = (SHORT_CONV,)   # a gated-convolution layer's
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)

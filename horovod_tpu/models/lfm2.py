@@ -1,0 +1,202 @@
+"""Decoder-only LM whose token mixer and whose feed-forward both change
+with depth (the LFM2-MoE shape): a gated short convolution in most layers
+and rotary grouped-query attention with per-head RMSNorm on q and k in
+the layers ``layer_types`` calls ``"full_attention"``; a dense SwiGLU MLP
+in the first ``num_dense_layers`` layers and a sparse mixture of SwiGLU
+experts, routed by a sigmoid with a selection bias, in the rest.
+
+    x <- x + Mix_l(RMSNorm(x));   x <- x + FFN_l(RMSNorm(x))
+
+- **Short convolution**: ``[B, C, X] = split(W_in u)``, ``out = W_out (C
+  * conv(B * X))``, ``conv`` causal and depthwise over ``conv_taps``
+  tokens (``ops/short_conv.py``; the gates and the taps under
+  ``hvd_short_conv``, the two projections outside it). No activation, no
+  norm inside.
+- **Attention**: q over ``num_heads`` heads, k and v over ``num_kv_heads``;
+  q and k through an RMSNorm over the head's channels (one scale vector
+  each, shared by the heads), then rotary positions over the whole head
+  width (``models/gpt.py`` ``rope``); causal flash attention, which is
+  handed the K/V heads as they are (``ops/flash_attention.py`` groups
+  them); then ``W_o``.
+- **Experts**: ``models/solar.py`` ``SparseExperts`` with ``score =
+  "sigmoid"``, the selection bias and no shared expert: the top-k of
+  ``sigmoid(W_r x) + b``, weighted by the unbiased scores of the chosen,
+  normalised (``parallel/moe.py`` ``route_top_k``). ``b`` is a parameter
+  no gradient reaches; nothing here moves it to balance the load.
+
+Like ``models/solar.py`` the model is written for ONE RANK OF A
+DEPLOYMENT: ``held_experts = (first, count)`` of the router's
+``num_experts``, and the vocabulary rows it is given. The head is TIED:
+the embedding's table is the head's kernel. Same TPU choices as
+``models/looplm.py``, whose ``RMSNorm`` and head arithmetic
+(``head_losses``, under ``hvd_lm_head``) it shares: bf16 compute / fp32
+parameters, every layer rematerialised, the cross-entropy computed inside
+the head's rematerialised call. The stats an expert layer returns (the
+held experts' load, routes and drops) are not published from the step:
+nothing reads them of this model yet, and the host callback that would
+set the gauges (``moe.record_held_stats``) costs a program its place in
+JAX's persistent compile cache.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.flash_attention import flash_attention
+from ..ops.short_conv import gated_short_conv
+from .gpt import rope
+from .looplm import RMSNorm, head_losses
+from .solar import SparseExperts, _dense, solar_loss
+
+ATTENTION = "full_attention"    # a ``layer_types`` entry; any other: conv
+# The published pattern of LFM2-8B-A1B's 24 layers: attention in layers 2,
+# 6, 10, 14, 18 and 21.
+_PATTERN = tuple(ATTENTION if i in (2, 6, 10, 14, 18, 21) else "conv"
+                 for i in range(24))
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution as a layer's token mixer."""
+
+    taps: int = 3
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        hidden = u.shape[-1]
+        dense = _dense(self.dtype)
+        taps = self.param("conv", nn.initializers.lecun_normal(
+            in_axis=0, out_axis=()), (self.taps, hidden), jnp.float32)
+        b, c, x = jnp.split(dense(3 * hidden, name="in_proj")(u), 3, -1)
+        return dense(hidden, name="out_proj")(gated_short_conv(b, c, x, taps))
+
+
+class RotaryGQA(nn.Module):
+    """Causal softmax attention, ``num_heads`` query heads on
+    ``num_kv_heads`` K/V heads, q and k normed a head and rotated."""
+
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    rope_base: float = 1e6
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        b, s, hidden = u.shape
+        dense = _dense(self.dtype)
+        norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
+        wide, narrow = (n * self.head_dim
+                        for n in (self.num_heads, self.num_kv_heads))
+        q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
+        k, v = (dense(narrow, name=n)(u).reshape(b, s, self.num_kv_heads, -1)
+                for n in ("k", "v"))
+        q = rope(norm(name="q_norm")(q), base=self.rope_base)
+        k = rope(norm(name="k_norm")(k), base=self.rope_base)
+        o = flash_attention(q, k, v, causal=True)
+        return dense(hidden, name="o")(o.reshape(b, s, wide))
+
+
+class DenseFFN(nn.Module):
+    """``W_down(silu(W_gate x) * W_up x)``. Returns ``(y, None)``: what an
+    expert layer returns, with no stats."""
+
+    mlp_dim: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        dense = _dense(self.dtype)
+        y = nn.silu(dense(self.mlp_dim, name="gate")(x)) \
+            * dense(self.mlp_dim, name="up")(x)
+        return dense(x.shape[-1], name="down")(y), None
+
+
+class Lfm2Layer(nn.Module):
+    """``x + Mix(norm(x))`` then ``x + FFN(norm(x))``; the FFN's stats
+    are left behind. ``mixer`` / ``ffn`` are the two classes and
+    ``mixer_args`` / ``ffn_args`` their constructor arguments."""
+
+    mixer: Any
+    mixer_args: Tuple
+    ffn: Any
+    ffn_args: Tuple
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
+        x = x + self.mixer(*self.mixer_args, name="mixer")(
+            norm(name="op_norm")(x))
+        y, _ = self.ffn(*self.ffn_args, name="ffn")(
+            norm(name="ffn_norm")(x))
+        return x + y
+
+
+class Lfm2LM(nn.Module):
+    """``apply(tokens)`` -> fp32 logits (B, S, vocab); ``apply(tokens,
+    labels)`` -> the cross-entropy of each position (B, S), which is what
+    training at a real size can hold. ``layer_types`` may be longer than
+    ``num_layers`` (a published pattern read up to the depth held); the
+    experts and the vocabulary are those HELD HERE; ``num_experts`` and
+    ``top_k`` are the router's own."""
+
+    vocab_size: int = 16384
+    num_layers: int = 8
+    hidden: int = 2048
+    layer_types: Tuple[str, ...] = _PATTERN
+    num_heads: int = 32
+    num_kv_heads: int = 8
+    head_dim: int = 64
+    conv_taps: int = 3
+    num_dense_layers: int = 2
+    mlp_dim: int = 7168
+    num_experts: int = 32
+    held_experts: Tuple[int, int] = (0, 8)
+    top_k: int = 4
+    expert_dim: int = 1792
+    routed_scale: float = 1.0
+    rope_base: float = 1e6
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    def setup(self):
+        self.tok_emb = nn.Embed(self.vocab_size, self.hidden,
+                                param_dtype=jnp.float32)
+        layer = nn.remat(Lfm2Layer)
+        conv = (ShortConv, (self.conv_taps, self.dtype))
+        attention = (RotaryGQA, (self.num_heads, self.num_kv_heads,
+                                 self.head_dim, self.rope_base,
+                                 self.norm_eps, self.dtype))
+        dense = (DenseFFN, (self.mlp_dim, self.dtype))
+        experts = (SparseExperts, (
+            self.num_experts, tuple(self.held_experts), self.top_k,
+            self.expert_dim, 0, self.routed_scale, self.dtype, "sigmoid",
+            True))
+        for i in range(self.num_layers):
+            setattr(self, f"layer{i}", layer(
+                *(attention if self.layer_types[i] == ATTENTION else conv),
+                *(dense if i < self.num_dense_layers else experts),
+                self.norm_eps, self.dtype))
+        self.final_norm = RMSNorm(self.norm_eps, self.dtype)
+
+    def __call__(self, tokens, labels=None):
+        h = self.tok_emb(tokens).astype(self.dtype)
+        for i in range(self.num_layers):
+            h = getattr(self, f"layer{i}")(h)
+        head = jax.checkpoint(functools.partial(
+            head_losses, dtype=self.dtype, tied=True))
+        return head(self.final_norm(h), self.tok_emb.embedding, labels)
+
+
+# Mean next-token cross-entropy of ``tokens`` (B, S + 1), weighted where
+# ``weights`` (B, S) are given; no auxiliary loss: the expert model's,
+# which asks of a model only ``apply(tokens, labels)``.
+lfm2_loss = solar_loss

@@ -93,6 +93,22 @@ class LoopLayer(nn.Module):
         return x + norm(name="mlp_out_norm")(dense(hidden, name="down")(y))
 
 
+def head_losses(z, kernel, labels=None, dtype=jnp.bfloat16, tied=False):
+    """fp32 logits of a state over the vocabulary (bf16 operands, fp32
+    accumulation, under ``hvd_lm_head``), or, given the labels, the
+    cross-entropy of each position. ``kernel`` is (hidden, vocab), or
+    with ``tied`` an embedding's table (vocab, hidden)."""
+    with jax.named_scope(scopes.LM_HEAD):
+        logits = jax.lax.dot_general(
+            z.astype(dtype), kernel.astype(dtype),
+            (((z.ndim - 1,), (1 if tied else 0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    if labels is None:
+        return logits
+    picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
+    return jax.nn.logsumexp(logits, -1) - picked
+
+
 class _Head(nn.Module):
     """Untied vocabulary head: fp32 logits of a state, or, given the
     labels, the cross-entropy of each position (the logits then never
@@ -105,15 +121,7 @@ class _Head(nn.Module):
     def __call__(self, z, labels=None):
         kernel = self.param("kernel", nn.initializers.lecun_normal(),
                             (z.shape[-1], self.vocab_size), jnp.float32)
-        with jax.named_scope(scopes.LM_HEAD):
-            logits = jax.lax.dot_general(
-                z.astype(self.dtype), kernel.astype(self.dtype),
-                (((z.ndim - 1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        if labels is None:
-            return logits
-        picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-        return jax.nn.logsumexp(logits, -1) - picked
+        return head_losses(z, kernel, labels, self.dtype)
 
 
 class _ExitGate(nn.Module):

@@ -24,7 +24,9 @@ in for the absent chips.
   W_f^down x + b)``; ``beta = 2 sigmoid(w_beta x)``; the chunked recurrence
   (``ops/linear_attention.py``); a per-head RMSNorm of the output times
   ``sigmoid(W_g^up W_g^down x)``, then ``W_o``.
-- **MoE**: softmax router over ``num_experts``, top ``top_k``, weights
+- **MoE**: a router over ``num_experts`` (this model's scores them by a
+  softmax; ``SparseExperts`` also takes the sigmoid router with a
+  selection bias that ``models/lfm2.py`` runs), top ``top_k``, weights
   normalised over the k; the held experts as grouped matmuls under
   ``hvd_moe_experts``, routing under ``hvd_moe_route``; one shared SwiGLU
   expert on every token under ``hvd_moe_shared``.
@@ -53,6 +55,7 @@ from ..common import scopes
 from ..ops.flash_attention import flash_attention
 from ..ops.linear_attention import kda_attention
 from ..ops.pallas_kernels import _decide
+from ..ops.short_conv import causal_conv
 from ..parallel import moe
 from .looplm import RMSNorm, _Head
 
@@ -94,15 +97,6 @@ def _decay_bias_init(key, shape, dtype=jnp.float32):
 
 def _decay_rate_init(key, shape, dtype=jnp.float32):
     return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
-
-
-def causal_conv(x, taps):
-    """Depthwise causal convolution along S: ``y_t = sum_j taps[j]
-    x_{t - (n - 1) + j}``, fp32. x: (B, S, C); taps: (n, C)."""
-    n = taps.shape[0]
-    x = jnp.pad(x.astype(jnp.float32), ((0, 0), (n - 1, 0), (0, 0)))
-    s = x.shape[1] - (n - 1)
-    return sum(x[:, j:j + s] * taps[j] for j in range(n))
 
 
 # -- what the step tells XLA:TPU's scheduler ---------------------------------
@@ -224,7 +218,12 @@ class KDA(nn.Module):
 
 
 class SparseExperts(nn.Module):
-    """The held routed experts' part of the layer plus the shared expert.
+    """The held routed experts' part of the layer plus the shared expert
+    (none where ``shared_dim`` is 0). ``score`` is the router's score
+    function (``moe.route_top_k``); with ``select_bias`` the top-k is
+    taken of the scores plus a vector ``select_bias`` (num_experts,) that
+    no gradient reaches, N(0, 0.02^2) at the start: a balancing term that
+    a trainer moves outside the loss, which this layer does not do.
     Returns ``(y, stats)``, the stats of ``moe.held_experts_layer``."""
 
     num_experts: int
@@ -234,6 +233,8 @@ class SparseExperts(nn.Module):
     shared_dim: int
     routed_scale: float = 1.0
     dtype: Any = jnp.bfloat16
+    score: str = "softmax"
+    select_bias: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -248,9 +249,17 @@ class SparseExperts(nn.Module):
                      ("experts_gate", (count, hidden, self.expert_dim)),
                      ("experts_up", (count, hidden, self.expert_dim)),
                      ("experts_down", (count, self.expert_dim, hidden)))]
+        bias = None
+        if self.select_bias:
+            bias = jax.lax.stop_gradient(self.param(
+                "select_bias", nn.initializers.normal(0.02),
+                (self.num_experts,), jnp.float32))
         y, stats = moe.held_experts_layer(
             x.reshape(b * s, hidden), router, *banks, self.num_experts,
-            self.held_experts, self.top_k, self.routed_scale)
+            self.held_experts, self.top_k, self.routed_scale,
+            score=self.score, select_bias=bias)
+        if not self.shared_dim:
+            return y.reshape(b, s, hidden), stats
         with jax.named_scope(scopes.MOE_SHARED):
             dense = _dense(self.dtype)
             shared = dense(hidden, name="shared_down")(

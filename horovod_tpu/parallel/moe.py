@@ -439,17 +439,36 @@ def chaos_skew_gate(gate_w):
 
 # -- the dropless path of a rank that holds some of the experts -------------
 
-def route_top_k(x, router_w, top_k: int, scale: float = 1.0):
-    """Softmax router over ALL experts: ``(experts (T, k) int32, weights
-    (T, k) fp32)``, the weights the chosen scores normalised to sum to 1
-    over a token's k choices, times ``scale``. The scores are computed
-    from fp32 operands in three bf16 passes (``Precision.HIGH``): a route
-    is a discrete choice, and a score rounded to bf16 flips one in ten of
-    the eighth-against-ninth decisions among 320 experts."""
+_SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+           "sigmoid": jax.nn.sigmoid}
+
+
+def route_top_k(x, router_w, top_k: int, scale: float = 1.0,
+                score: str = "softmax", select_bias=None):
+    """A router over ALL experts: ``(experts (T, k) int32, weights (T, k)
+    fp32)``. ``score`` is what makes scores of the router's logits:
+    ``"softmax"`` over the experts, or ``"sigmoid"`` of each logit by
+    itself. The k experts are the top k of the scores, or of ``scores +
+    select_bias`` where a bias (num_experts,) is given: the bias decides
+    who is chosen and never what a choice weighs. The weights are the
+    chosen experts' own scores normalised to sum to 1 over a token's k
+    choices, times ``scale``; a sigmoid's sum can be near 0, so it is
+    divided by ``sum + 1e-6``. The scores are computed from fp32 operands
+    in three bf16 passes (``Precision.HIGH``): a route is a discrete
+    choice, and a score rounded to bf16 flips one in ten of the
+    eighth-against-ninth decisions among 320 experts."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGH)
-    scores, experts = lax.top_k(jax.nn.softmax(logits, -1), top_k)
-    return experts, scores / scores.sum(-1, keepdims=True) * scale
+    scores = _SCORES[score](logits)
+    if select_bias is None:
+        chosen, experts = lax.top_k(scores, top_k)
+    else:
+        experts = lax.top_k(scores + select_bias, top_k)[1]
+        chosen = jnp.take_along_axis(scores, experts, -1)
+    total = chosen.sum(-1, keepdims=True)
+    if score == "sigmoid":
+        total = total + 1e-6
+    return experts, chosen / total * scale
 
 
 def _block_group_sizes(group_sizes, block, block_rows):
@@ -574,22 +593,23 @@ def default_block_rows(tokens: int, top_k: int, held: int,
 def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
                        held: Tuple[int, int], top_k: int,
                        scale: float = 1.0,
-                       block_rows: Optional[int] = None):
+                       block_rows: Optional[int] = None,
+                       score: str = "softmax", select_bias=None):
     """The routed experts' part of an MoE layer that the experts held on
     this rank give: ``held = (first, count)`` of ``num_experts``, their
     SwiGLU banks ``w_gate``, ``w_up`` (count, D, F) and ``w_down``
     (count, F, D). x: (T, D) tokens; router_w: (D, num_experts).
 
     The router scores all ``num_experts`` and takes ``top_k``
-    (:func:`route_top_k`); a route to an expert held elsewhere is that
-    rank's to compute and adds nothing here. Every route to a held expert
-    is computed, however many there are (no capacity, no drop): the
-    routes are sorted by expert and run through the grouped matmuls
-    ``block_rows`` at a time (:func:`default_block_rows`), in as many
-    blocks as they fill. Summed over the ranks that hold all the experts,
-    the results are the whole routed layer; over an ep axis it is what
-    the exchange would feed (``first = ep_index * count``), and it adds
-    no exchange.
+    (:func:`route_top_k`, which ``score`` and ``select_bias`` go to); a
+    route to an expert held elsewhere is that rank's to compute and adds
+    nothing here. Every route to a held expert is computed, however many
+    there are (no capacity, no drop): the routes are sorted by expert and
+    run through the grouped matmuls ``block_rows`` at a time
+    (:func:`default_block_rows`), in as many blocks as they fill. Summed
+    over the ranks that hold all the experts, the results are the whole
+    routed layer; over an ep axis it is what the exchange would feed
+    (``first = ep_index * count``), and it adds no exchange.
 
     Returns ``(y (T, D) in x's dtype, stats)``: ``expert_load`` (count,)
     routes demanded of each held expert, ``local_routes`` their sum,
@@ -600,7 +620,8 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
     if block_rows is None:
         block_rows = default_block_rows(t, top_k, count, num_experts)
     with jax.named_scope(scopes.MOE_ROUTE):
-        experts, weights = route_top_k(x, router_w, top_k, scale)
+        experts, weights = route_top_k(x, router_w, top_k, scale, score,
+                                       select_bias)
         local = (experts - first).reshape(-1)
         is_held = (local >= 0) & (local < count)
         key = jnp.where(is_held, local, count)      # the others sort last

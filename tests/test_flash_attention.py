@@ -406,8 +406,11 @@ def test_vmem_plan_counts_the_resident_dq(case, resident):
 
 # (query heads, K/V heads, width): one head a 128-lane block (the index
 # maps group), a group of the whole head count, two narrow heads a block
-# (K/V repeated before the call), the per-head layout, and no grouping.
-GQA = [(8, 1, 128), (4, 2, 128), (4, 2, 64), (6, 2, 80), (4, 4, 128)]
+# (K/V repeated before the call), the per-head layout, no grouping, and
+# LFM2's 32 query heads on 8 K/V heads of 64 (packed two a block, each K/V
+# head repeated fourfold before the call).
+GQA = [(8, 1, 128), (4, 2, 128), (4, 2, 64), (6, 2, 80), (4, 4, 128),
+       (32, 8, 64)]
 
 
 def _plain_grouped(q, k, v, mask, causal):

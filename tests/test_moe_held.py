@@ -126,6 +126,9 @@ def test_block_rows_follow_the_expected_routes():
     assert moe.default_block_rows(8192, 8, 8, 320) == 4096
     # never more rows than there can be routes
     assert moe.default_block_rows(16, 4, 16, 16) == 64
+    # the gated-convolution cell: 16,384 tokens, top-4, 8 of 32 held:
+    # 16,384 routes expected, and two and a half times that a block
+    assert moe.default_block_rows(16384, 4, 8, 32) == 40960
 
 
 def test_the_gauges_are_set_from_inside_the_step(layer):
@@ -264,3 +267,148 @@ def test_the_loss_held_before_the_backward_is_the_same_loss():
     np.testing.assert_allclose(got, want, rtol=1e-6)
     for g, w in zip(jax.tree.leaves(got_grads), jax.tree.leaves(want_grads)):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
+# -- the second router: a sigmoid's scores and a selection bias --------------
+
+LFM2 = Catalog().module("reference", "lfm2")
+
+
+def test_sigmoid_routing_selects_by_the_biased_scores_and_weighs_by_the_plain(
+        layer):
+    x, router = layer["x"], layer["router"]
+    bias = jax.random.normal(jax.random.PRNGKey(6), (E,)) * 0.3
+    with jax.default_matmul_precision("highest"):
+        s = np.asarray(jax.nn.sigmoid(x @ router), np.float64)
+        experts, weights = moe.route_top_k(x, router, K, 1.5, "sigmoid",
+                                           bias)
+        plain = moe.route_top_k(x, router, K, 1.5, "sigmoid")
+    assert experts.shape == weights.shape == (T, K)
+    biased = s + np.asarray(bias, np.float64)
+    for t in range(T):
+        chosen = set(np.argsort(-biased[t])[:K])
+        assert set(np.asarray(experts[t])) == chosen
+        # the combine weights: the UNBIASED scores of the chosen, over
+        # their sum + 1e-6, times the scale
+        picked = s[t, np.asarray(experts[t])]
+        np.testing.assert_allclose(
+            weights[t], 1.5 * picked / (picked.sum() + 1e-6), rtol=2e-6)
+    # the bias changed who is chosen for a real share of the tokens
+    moved = [set(np.asarray(a)) != set(np.asarray(b))
+             for a, b in zip(experts, plain[0])]
+    assert 0.2 < np.mean(moved) < 1.0
+    # without a bias the top-k is of the scores themselves
+    for t in range(T):
+        assert set(np.asarray(plain[0][t])) == set(np.argsort(-s[t])[:K])
+    # the 1e-6: scores that are all but 0 are not blown up to sum to 1
+    cold = moe.route_top_k(jnp.ones((3, D)), jnp.full((D, E), -4.0), K,
+                           1.0, "sigmoid")[1]      # every score e^-64
+    assert float(cold.max()) < 1e-20 and bool(jnp.isfinite(cold).all())
+    # the bias gets no gradient: it reaches the indices alone
+    grad = jax.grad(lambda b: moe.route_top_k(
+        x, router, K, 1.0, "sigmoid", b)[1].sum())(bias)
+    assert float(jnp.abs(grad).max()) == 0.0
+
+
+@pytest.mark.parametrize("held", [(0, 16), (4, 4)])
+def test_the_sigmoid_routers_part_and_its_gradients(layer, held):
+    """The held experts' part under the second router against the plain
+    reference's dense loop (``benchmark/reference/lfm2.py``)."""
+    bias = jax.random.normal(jax.random.PRNGKey(6), (E,)) * 0.3
+    config = {"num_experts_per_tok": K, "routed_scaling_factor": 1.0,
+              "held_experts_first": held[0]}
+    a, b = held[0], held[0] + held[1]
+    keys = ("x", "router", "gate", "up", "down")
+
+    def ours(x, router, gate, up, down):
+        return moe.held_experts_layer(
+            x, router, gate[a:b], up[a:b], down[a:b], E, held, K,
+            score="sigmoid", select_bias=bias)[0]
+
+    def plain(x, router, gate, up, down):
+        return LFM2._experts(x, {
+            "router": router, "select_bias": bias,
+            "experts_gate": gate[a:b], "experts_up": up[a:b],
+            "experts_down": down[a:b]}, config)
+
+    args = [layer[k] for k in keys]
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(ours(*args), plain(*args), atol=2e-6)
+        got = jax.grad(lambda *a: (ours(*a) ** 2).sum(), range(5))(*args)
+        want = jax.grad(lambda *a: (plain(*a) ** 2).sum(), range(5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+def test_four_shares_of_8_experts_are_the_uncut_32_expert_layer():
+    """The share test of the sizing rule for the sigmoid-bias layer: 32
+    experts at top-4 in 4 shares of 8 (``SparseExperts`` with no shared
+    expert, as ``models/lfm2.py`` builds it). The parts the four chips
+    compute add up to what the uncut plain reference gives for the whole
+    layer, and the reference given a share gives that share's part."""
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+    args = (32, 4, 12, 0, 1.0, jnp.float32, "sigmoid", True)
+    whole = solar.SparseExperts(args[0], (0, 32), *args[1:])
+    params = whole.init(jax.random.PRNGKey(5), x)["params"]
+    assert set(params) == {"router", "select_bias", "experts_gate",
+                           "experts_up", "experts_down"}   # no shared expert
+    bias = params["select_bias"]
+    assert 0.01 < float(bias.std()) < 0.04
+    # the same draw whichever experts a rank holds: a model's start does
+    # not depend on how a deployment cuts it
+    part = solar.SparseExperts(args[0], (8, 8), *args[1:]).init(
+        jax.random.PRNGKey(5), x)["params"]["select_bias"]
+    np.testing.assert_array_equal(part, bias)
+    config = {"num_experts_per_tok": 4, "routed_scaling_factor": 1.0,
+              "held_experts_first": 0}
+    with jax.default_matmul_precision("highest"):
+        want = LFM2._experts(x, params, config)
+        total = 0.0
+        for first in (0, 8, 16, 24):
+            part = {**params, **{k: params[k][first:first + 8] for k in (
+                "experts_gate", "experts_up", "experts_down")}}
+            y, stats = solar.SparseExperts(
+                args[0], (first, 8), *args[1:]).apply({"params": part}, x)
+            np.testing.assert_allclose(y, LFM2._experts(
+                x, part, {**config, "held_experts_first": first}), atol=3e-6)
+            assert float(stats["dropped_tokens"]) == 0.0
+            total += y
+    np.testing.assert_allclose(total, want, atol=3e-6)
+    assert float(jnp.abs(want).max()) > 0.1
+
+
+def _route_top_k_before_it_took_a_score(x, router_w, top_k, scale=1.0):
+    """``route_top_k`` as it stood while a softmax was the one router."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGH)
+    scores, experts = jax.lax.top_k(jax.nn.softmax(logits, -1), top_k)
+    return experts, scores / scores.sum(-1, keepdims=True) * scale
+
+
+def test_with_the_defaults_the_softmax_path_is_bit_equal_to_before(
+        layer, monkeypatch):
+    """Solar's cell calls ``held_experts_layer`` with the arguments it
+    always did: the same jaxpr, the same outputs and the same gradients,
+    to the bit."""
+    assert str(jax.make_jaxpr(lambda x, w: moe.route_top_k(x, w, K, 1.5))(
+        layer["x"], layer["router"])) == str(jax.make_jaxpr(
+            lambda x, w: _route_top_k_before_it_took_a_score(x, w, K, 1.5))(
+                layer["x"], layer["router"]))
+    keys = ("x", "router", "gate", "up", "down")
+    args = [layer[k] for k in keys]
+
+    def run():
+        def part(*a):
+            return _held(dict(zip(keys, a)), (4, 4))[0]
+        return part(*args), jax.grad(lambda *a: (part(*a) ** 2).sum(),
+                                     range(5))(*args)
+
+    now, now_grads = run()
+    monkeypatch.setattr(
+        moe, "route_top_k",
+        lambda x, w, k, scale, score, bias:
+        _route_top_k_before_it_took_a_score(x, w, k, scale))
+    before, before_grads = run()
+    assert (np.asarray(now) == np.asarray(before)).all()
+    for a, b in zip(now_grads, before_grads):
+        assert (np.asarray(a) == np.asarray(b)).all()

@@ -256,20 +256,23 @@ def _constants():
 
 
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
-             + scopes.LINEAR_ATTN_SCOPES + scopes.FLASH_KERNELS
+             + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES
+             + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 23
+    assert len(values) == len(set(values)) == 24
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
     assert scopes.MOE_SCOPES == ("hvd_moe_route", "hvd_moe_experts",
                                  "hvd_moe_shared")
     assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
-    assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES) \
+    assert scopes.SHORT_CONV_SCOPES == ("hvd_short_conv",)
+    assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES
+                   + scopes.SHORT_CONV_SCOPES) \
         & set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES)
 
 
@@ -305,6 +308,48 @@ def test_an_expert_linear_attention_models_scopes_are_on_its_step(
     for n in under:     # siblings: no instruction under two of them
         assert sum(bool(_under(s).search(n)) for s in scopes.MOE_SCOPES
                    + scopes.LINEAR_ATTN_SCOPES + (scopes.LM_HEAD,)) == 1, n
+
+
+@pytest.fixture(scope="module")
+def lfm2_op_names():
+    """Every ``op_name`` of a tiny gated-convolution / attention model's
+    differentiated step, as lowered: layers c A c, the first dense."""
+    from horovod_tpu.models import Lfm2LM, lfm2_loss
+
+    model = Lfm2LM(vocab_size=64, num_layers=3, hidden=32,
+                   layer_types=("conv", "full_attention", "conv"),
+                   num_heads=2, num_kv_heads=1, head_dim=16,
+                   num_dense_layers=1, mlp_dim=48, num_experts=8,
+                   held_experts=(2, 4), top_k=2, expert_dim=16)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    text = jax.jit(jax.value_and_grad(
+        lambda p: lfm2_loss(model, p, tokens))).lower(params).as_text(
+            debug_info=True)
+    return set(re.findall(r'"(jit\([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", scopes.SHORT_CONV_SCOPES
+                         + scopes.MOE_SCOPES[:2] + (scopes.LM_HEAD,))
+def test_a_gated_convolution_models_scopes_are_on_its_step(lfm2_op_names,
+                                                           scope):
+    """Forward and backward, and only under the layers that have them:
+    the convolution's in the two convolution layers, the experts' in the
+    two layers past the dense one, the tied head's once."""
+    under = [n for n in lfm2_op_names if _under(scope).search(n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    layers = {m for n in under for m in re.findall(r"layer\d", n)}
+    assert layers == {scopes.SHORT_CONV: {"layer0", "layer2"},
+                      scopes.MOE_ROUTE: {"layer1", "layer2"},
+                      scopes.MOE_EXPERTS: {"layer1", "layer2"},
+                      scopes.LM_HEAD: set()}[scope]
+    for n in under:     # siblings: no instruction under two of them
+        assert sum(bool(_under(s).search(n)) for s in scopes.MOE_SCOPES
+                   + scopes.SHORT_CONV_SCOPES + (scopes.LM_HEAD,)) == 1, n
+    # this model has no shared expert
+    assert not any(_under(scopes.MOE_SHARED).search(n)
+                   for n in lfm2_op_names)
 
 
 def test_the_benchmarks_data_file_quotes_the_same_names():

@@ -298,6 +298,58 @@ def test_the_expert_cells_step_fits_where_the_scheduler_is_told(v5e, on_tpu):
     assert held / 2 ** 30 < 10.8
 
 
+def test_the_gated_convolution_cells_step_fits_with_two_rows(v5e, on_tpu):
+    """The whole training step of the gated-convolution, sigmoid-routed
+    cell (``Lfm2LM``'s defaults, 2 x S8192, AdamW with bf16 first moments,
+    donated) compiled for a described v5e: 11.588 GiB with every layer
+    under one ``nn.remat`` and the experts' blocks of 40,960 routes (on the
+    chip to the digit: PERF.md, PR 35; 10.893 with blocks of 24,576), so
+    two rows fit and nothing had to be split. Its two attention layers are
+    the first flash calls at S 8192 (K and V of two packed heads whole in
+    VMEM) and the first on the packed width-64 layout with a K/V group:
+    K and V reach the kernels repeated to the 32 query heads."""
+    import optax
+
+    from horovod_tpu.models import lfm2
+
+    model = lfm2.Lfm2LM()
+    tokens = jax.ShapeDtypeStruct((2, 8193), jnp.int32, sharding=v5e)
+    tx = optax.adamw(1e-4, mu_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    state = jax.eval_shape(tx.init, params)
+
+    def step(params, state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: lfm2.lfm2_loss(model, p, tokens))(params)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, loss
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 11.1 < held / 2 ** 30 < 11.8
+    hlo = compiled.as_text()
+    calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    flash = sorted(re.sub(r"[.\d]+$", "", c) for c in calls
+                   if "hvd_flash" in c)
+    # two attention layers: forward, forward again, one backward each
+    assert flash == ["hvd_flash_dkv"] * 2 + ["hvd_flash_fwd"] * 4
+    forward = next(line for line in hlo.splitlines()
+                   if re.match(r"\s*%hvd_flash_fwd[\w.]* = ", line))
+    operands = forward.split("operand_layout_constraints=")[1]
+    assert operands.count("bf16[2,8192,2048]") == 3     # q, and K/V repeated
+
+
 @pytest.mark.parametrize("chunk", [32, 128])
 def test_the_recurrence_kernels_compile_at_every_chunk_they_take(v5e, on_tpu,
                                                                  chunk):
