@@ -254,10 +254,16 @@ class SparseExperts(nn.Module):
             bias = jax.lax.stop_gradient(self.param(
                 "select_bias", nn.initializers.normal(0.02),
                 (self.num_experts,), jnp.float32))
+        # ``init`` keeps nothing of the result: one block of the default
+        # size, so that the init program traces no copies of it
+        # (``moe.first_block_rungs``; a second of a cached set-up)
+        block_rows = moe.default_block_rows(
+            b * s, self.top_k, count, self.num_experts) \
+            if self.is_initializing() else None
         y, stats = moe.held_experts_layer(
             x.reshape(b * s, hidden), router, *banks, self.num_experts,
             self.held_experts, self.top_k, self.routed_scale,
-            score=self.score, select_bias=bias)
+            block_rows=block_rows, score=self.score, select_bias=bias)
         if not self.shared_dim:
             return y.reshape(b, s, hidden), stats
         with jax.named_scope(scopes.MOE_SHARED):

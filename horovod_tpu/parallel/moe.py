@@ -46,6 +46,7 @@ exchange would feed; it adds no exchange itself.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from typing import Callable, Optional, Tuple
 
@@ -80,6 +81,14 @@ _M_LOCAL = metrics_lib.gauge(
     "top-k token-routes that reached an expert held on this rank in the "
     "most recently recorded step, summed over the layers (held-experts "
     "path; record_held_stats)")
+_M_WORKED = metrics_lib.gauge(
+    "hvd_tpu_moe_worked_rows",
+    "rows the held experts' blocks worked on in the most recently "
+    "recorded step, summed over the layers: the first block's rung and "
+    "every further block whole; local_routes over it is the fill of the "
+    "passes around the grouped matmuls (record_held_stats)")
+_HELD_GAUGES = {"local_routes": _M_LOCAL, "dropped_tokens": _M_DROPPED,
+                "worked_rows": _M_WORKED}
 
 
 def top2_gating(logits, capacity: int, noise=None):
@@ -498,56 +507,81 @@ def _expert_block(xg, weights, valid, w_gate, w_up, w_down, sizes):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _grouped_experts(block_rows, x, w_gate, w_up, w_down, weights, tokens,
+def _grouped_experts(rungs, x, w_gate, w_up, w_down, weights, tokens,
                      group_sizes):
     """Sum over the routes of weight * expert(x[token]), as (T, D) fp32.
     ``tokens`` / ``weights``: the routes sorted by expert (a multiple of
-    ``block_rows`` long; only the first ``group_sizes.sum()`` count),
-    ``w_*``: the (G, ., .) banks. The routes go through a block at a time
-    in a loop of ceil(routes / block_rows) steps, the first outside it:
-    the usual step fills one block and pays for no second."""
-    return _grouped_experts_fwd(block_rows, x, w_gate, w_up, w_down,
-                                weights, tokens, group_sizes)[0]
+    ``block_rows = rungs[-1]`` long; only the first ``group_sizes.sum()``
+    count), ``w_*``: the (G, ., .) banks. The routes go through a block
+    at a time in a loop of ceil(routes / block_rows) steps, the first
+    outside it: the usual step fills one block and pays for no second.
+    That first block works on the smallest of the static row counts
+    ``rungs`` that holds the routes (:func:`first_block_rungs`): the rows
+    past them are rows no route fills."""
+    return _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights,
+                                tokens, group_sizes)[0]
 
 
-def _route_block(block_rows, tokens, weights, group_sizes, block):
-    start = block * block_rows
-    sizes = _block_group_sizes(group_sizes, block, block_rows)
-    valid = start + jnp.arange(block_rows) < group_sizes.sum()
-    return (lax.dynamic_slice_in_dim(tokens, start, block_rows),
-            lax.dynamic_slice_in_dim(weights, start, block_rows),
+def _route_block(rows, tokens, weights, group_sizes, block):
+    start = block * rows
+    sizes = _block_group_sizes(group_sizes, block, rows)
+    valid = start + jnp.arange(rows) < group_sizes.sum()
+    return (lax.dynamic_slice_in_dim(tokens, start, rows),
+            lax.dynamic_slice_in_dim(weights, start, rows),
             valid, sizes)
 
 
-def _grouped_experts_fwd(block_rows, x, w_gate, w_up, w_down, weights,
-                         tokens, group_sizes):
+def _rung_taken(rungs, routes):
+    """Index of the smallest rung that holds ``routes`` (the last one
+    where none does: the loop takes the routes past it)."""
+    return sum((routes > r).astype(jnp.int32) for r in rungs[:-1])
+
+
+def _first_block(rungs, rung, add_block, carry):
+    """``add_block(rows, 0, carry)`` at the rung taken: a ``lax.switch``
+    over the static row counts, or the one call where there is one."""
+    if len(rungs) == 1:
+        return add_block(rungs[0], 0, carry)
+    return lax.switch(rung, [functools.partial(add_block, rows, 0)
+                             for rows in rungs], carry)
+
+
+def _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights, tokens,
+                         group_sizes):
+    block_rows = rungs[-1]
     banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
     blocks = -(-group_sizes.sum() // block_rows)
+    rung = _rung_taken(rungs, group_sizes.sum())
 
-    def add_block(block, out):
+    def add_block(rows, block, out):
         idx, wts, valid, sizes = _route_block(
-            block_rows, tokens, weights, group_sizes, block)
+            rows, tokens, weights, group_sizes, block)
         return out.at[idx].add(
             _expert_block(x[idx], wts, valid, *banks, sizes))
 
-    out = add_block(0, jnp.zeros(x.shape, jnp.float32))
-    out = lax.while_loop(lambda c: c[0] < blocks,
-                         lambda c: (c[0] + 1, add_block(*c)), (1, out))[1]
-    return out, (x, w_gate, w_up, w_down, weights, tokens, group_sizes)
+    out = _first_block(rungs, rung, add_block,
+                       jnp.zeros(x.shape, jnp.float32))
+    out = lax.while_loop(
+        lambda c: c[0] < blocks,
+        lambda c: (c[0] + 1, add_block(block_rows, *c)), (1, out))[1]
+    return out, (x, w_gate, w_up, w_down, weights, tokens, group_sizes,
+                 rung)
 
 
-def _grouped_experts_bwd(block_rows, residuals, dout):
-    """A block at a time, as the forward went: the block's forward again
-    and its transpose (JAX's, of the three grouped matmuls); dx scattered
-    back to the tokens, the banks' gradients summed in fp32."""
-    x, w_gate, w_up, w_down, weights, tokens, group_sizes = residuals
+def _grouped_experts_bwd(rungs, residuals, dout):
+    """A block at a time, as the forward went (the first at the forward's
+    rung): the block's forward again and its transpose (JAX's, of the
+    three grouped matmuls); dx scattered back to the tokens, the banks'
+    gradients summed in fp32."""
+    x, w_gate, w_up, w_down, weights, tokens, group_sizes, rung = residuals
+    block_rows = rungs[-1]
     banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
     blocks = -(-group_sizes.sum() // block_rows)
 
-    def add_block(block, carry):
+    def add_block(rows, block, carry):
         dx, dweights, dbanks = carry
         idx, wts, valid, sizes = _route_block(
-            block_rows, tokens, weights, group_sizes, block)
+            rows, tokens, weights, group_sizes, block)
         _, vjp = jax.vjp(
             lambda xg, w, *b: _expert_block(xg, w, valid, *b, sizes),
             x[idx], wts, *banks)
@@ -555,16 +589,17 @@ def _grouped_experts_bwd(block_rows, residuals, dout):
         db = tuple(b.astype(jnp.float32) for b in db)
         return (dx.at[idx].add(dxg.astype(jnp.float32)),
                 lax.dynamic_update_slice_in_dim(
-                    dweights, dwts, block * block_rows, 0),
+                    dweights, dwts, block * rows, 0),
                 # the first block's are the sum so far: nothing to add to
                 db if dbanks is None else tuple(
                     a + b for a, b in zip(dbanks, db)))
 
-    carry = add_block(0, (jnp.zeros(x.shape, jnp.float32),
+    carry = _first_block(rungs, rung, add_block,
+                         (jnp.zeros(x.shape, jnp.float32),
                           jnp.zeros_like(weights), None))
     dx, dweights, dbanks = lax.while_loop(
         lambda c: c[0] < blocks,
-        lambda c: (c[0] + 1, add_block(*c)), (1, carry))[1]
+        lambda c: (c[0] + 1, add_block(block_rows, *c)), (1, carry))[1]
     return (dx.astype(x.dtype),
             *(g.astype(w.dtype) for g, w in
               zip(dbanks, (w_gate, w_up, w_down))),
@@ -590,6 +625,37 @@ def default_block_rows(tokens: int, top_k: int, held: int,
     return min(most, -(-math.ceil(2.5 * expected) // tile) * tile)
 
 
+def first_block_rungs(expected: float, block_rows: int) -> Tuple[int, ...]:
+    """The static row counts the first block of routes may work on,
+    ascending, the last ``block_rows`` itself: the step takes the smallest
+    that holds the routes it counted (``_grouped_experts``), because every
+    pass around the grouped matmuls (gather, masks, SwiGLU, weighting,
+    scatter-add) runs over a block's rows whether a route fills them or
+    not. Each rung under the block keeps an eighth of the headroom of the
+    rung above it over the ``expected`` routes of a balanced router,
+    ``expected * (1 + 1.5 / 8**j)`` rounded up to the grouped matmul's
+    row tile (512), and a rung exists only where it sits at least 4,096
+    rows under the rung above. Both numbers are read off the chip
+    (PERF.md section 6, PR 37). A padded row cost 0.40 to 0.46 us a layer
+    a step (45.4 ms for 16,384 rows in 6 layers), so 4,096 rows are 1.8
+    ms a layer; a copy of the block in the program costs 9 s of cold
+    compile, 1.4 s of a cached set-up and 44 MiB, and a rung 1,024 rows
+    under the next read no faster. An eighth and not a quarter because
+    a layer's routes lie within 0.85 to 1.12 of the balanced share (48
+    layers of an untrained sigmoid router): a quarter's lowest rung
+    (1.094) sat inside that range and needed a third rung to catch every
+    twelfth layer, a third copy that won 0.4% of the step; at 1.19 one
+    rung under the block holds them all. 16,384 expected routes in a
+    block of 40,960 give (19456, 40960); 1,638 in a block of 4,096 give
+    the block alone, and no ``conditional`` in the program."""
+    rungs = (block_rows,)
+    for j in itertools.count(1):
+        rows = -(-math.ceil(expected * (1 + 1.5 / 8 ** j)) // 512) * 512
+        if rungs[0] - rows < 4096:
+            return rungs
+        rungs = (rows, *rungs)
+
+
 def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
                        held: Tuple[int, int], top_k: int,
                        scale: float = 1.0,
@@ -606,7 +672,9 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
     nothing here. Every route to a held expert is computed, however many
     there are (no capacity, no drop): the routes are sorted by expert and
     run through the grouped matmuls ``block_rows`` at a time
-    (:func:`default_block_rows`), in as many blocks as they fill. Summed
+    (:func:`default_block_rows`), in as many blocks as they fill, the
+    first on the rows its routes fill (:func:`first_block_rungs`; an
+    explicit ``block_rows`` is one rung). Summed
     over the ranks that hold all the experts, the results are the whole
     routed layer; over an ep axis it is what the exchange would feed
     (``first = ep_index * count``), and it adds no exchange.
@@ -614,11 +682,17 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
     Returns ``(y (T, D) in x's dtype, stats)``: ``expert_load`` (count,)
     routes demanded of each held expert, ``local_routes`` their sum,
     ``dropped_tokens`` routes to a held expert that no block computed
-    (0), all fp32 (:func:`record_held_stats`)."""
+    (0), ``worked_rows`` the rows the blocks worked on (the first block's
+    rung and every further block whole: ``local_routes`` over it is the
+    fill of the passes), all fp32 (:func:`record_held_stats`)."""
     first, count = held
     t = x.shape[0]
     if block_rows is None:
         block_rows = default_block_rows(t, top_k, count, num_experts)
+        rungs = first_block_rungs(t * top_k * count / num_experts,
+                                  block_rows)
+    else:
+        rungs = (block_rows,)       # the caller's size is the caller's
     with jax.named_scope(scopes.MOE_ROUTE):
         experts, weights = route_top_k(x, router_w, top_k, scale, score,
                                        select_bias)
@@ -632,17 +706,20 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
         group_sizes = jnp.bincount(key, length=count + 1)[:count] \
             .astype(jnp.int32)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        y = _grouped_experts(block_rows, x, w_gate, w_up, w_down,
+        y = _grouped_experts(rungs, x, w_gate, w_up, w_down,
                              sorted_weights, tokens, group_sizes)
     routes = group_sizes.sum()
     n_blocks = tokens.size // block_rows
+    ran = -(-routes // block_rows)
     done = _block_group_sizes(group_sizes, jnp.arange(n_blocks),
                               block_rows).sum(-1)
-    computed = jnp.where(jnp.arange(n_blocks) < -(-routes // block_rows),
-                         done, 0).sum()
+    computed = jnp.where(jnp.arange(n_blocks) < ran, done, 0).sum()
+    worked = jnp.asarray(rungs)[_rung_taken(rungs, routes)] \
+        + block_rows * (jnp.maximum(ran, 1) - 1)
     stats = {"expert_load": group_sizes.astype(jnp.float32),
              "local_routes": routes.astype(jnp.float32),
-             "dropped_tokens": (routes - computed).astype(jnp.float32)}
+             "dropped_tokens": (routes - computed).astype(jnp.float32),
+             "worked_rows": worked.astype(jnp.float32)}
     return y.astype(x.dtype), stats
 
 
@@ -651,7 +728,8 @@ def record_held_stats(stats, first: int = 0) -> None:
     layers by the caller) from INSIDE the jitted step: one
     ``jax.debug.callback`` that sets ``hvd_tpu_moe_expert_load{expert=}``
     (the held experts, by global index from ``first``),
-    ``hvd_tpu_moe_local_routes`` and ``hvd_tpu_moe_dropped_tokens``. A
+    ``hvd_tpu_moe_local_routes``, ``hvd_tpu_moe_worked_rows`` and
+    ``hvd_tpu_moe_dropped_tokens``. A
     no-op, and no callback in the program, with metrics off. The price
     of a callback: JAX does not keep a program with a host callback in
     its persistent compile cache, so the step compiles in every process
@@ -661,15 +739,17 @@ def record_held_stats(stats, first: int = 0) -> None:
     if not _METRICS_ON:
         return
 
-    def publish(load, routes, dropped):
-        _M_LOCAL.set(float(routes))
-        _M_DROPPED.set(float(dropped))
+    # a stats dict written out by hand may lack the newer counters
+    scalars = {k: stats[k] for k in _HELD_GAUGES if k in stats}
+
+    def publish(load, scalars):
+        for k, v in scalars.items():
+            _HELD_GAUGES[k].set(float(v))
         for e, v in enumerate(np.asarray(load).reshape(-1)):
             _M_LOAD.labels(expert=str(first + e)).set(float(v))
 
-    values = (stats["expert_load"], stats["local_routes"],
-              stats["dropped_tokens"])
-    if any(isinstance(v, jax.core.Tracer) for v in values):
+    values = (stats["expert_load"], scalars)
+    if any(isinstance(v, jax.core.Tracer) for v in jax.tree.leaves(values)):
         jax.debug.callback(publish, *values)
     else:
         publish(*values)

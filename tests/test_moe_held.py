@@ -131,6 +131,192 @@ def test_block_rows_follow_the_expected_routes():
     assert moe.default_block_rows(16384, 4, 8, 32) == 40960
 
 
+# -- the first block's rungs -------------------------------------------------
+
+# tokens, top_k, held, experts -> the rungs: the gated-convolution cell
+# (16,384 expected routes: 1.1875 / 2.5 times them); Solar's cell (1,638
+# expected: 2,458 rows of headroom pay for no second copy); this file's
+# layer and a block capped at the routes there can be (headroom under
+# 4,096 rows: one rung); ten times the cell's tokens (a third rung fits
+# 4,096 rows under the second)
+@pytest.mark.parametrize("shape, rungs", [
+    ((16384, 4, 8, 32), (19456, 40960)),
+    ((8192, 8, 8, 320), (4096,)),
+    ((T, K, 4, E), (160,)),
+    ((16, 4, 16, 16), (64,)),
+    ((163840, 4, 8, 32), (167936, 194560, 409600))])
+def test_the_first_blocks_rungs_follow_the_expected_routes(shape, rungs):
+    tokens, top_k, held, experts = shape
+    block = moe.default_block_rows(*shape)
+    got = moe.first_block_rungs(tokens * top_k * held / experts, block)
+    assert got == rungs and got[-1] == block
+    assert all(r % 512 == 0 for r in got[:-1])
+    assert all(b - a >= 4096 for a, b in zip(got, got[1:]))
+    assert all(r >= tokens * top_k * held / experts for r in got)
+
+
+def _routes(n, seed=3):
+    """``n`` routes of the layer's T tokens to 4 experts, sorted by
+    expert and padded as ``held_experts_layer`` hands them on."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    experts = jnp.sort(jax.random.randint(ks[0], (n,), 0, 4))
+    tokens = jax.random.randint(ks[1], (n,), 0, T).astype(jnp.int32)
+    weights = jax.random.uniform(ks[2], (n,), minval=0.1)
+    return experts, tokens, weights
+
+
+LADDER = (16, 32, 48)
+
+
+# routes inside each rung, at a rung's edge and one past it, none at all,
+# and past the last rung: the loop still runs (three blocks at 100)
+@pytest.mark.parametrize("n", [0, 5, 16, 17, 30, 32, 33, 48, 49, 100])
+def test_every_rung_gives_the_dense_result_and_its_gradients(layer, n):
+    experts, tokens, weights = _routes(n)
+    sizes = jnp.bincount(experts, length=4).astype(jnp.int32)
+    pad = -n % LADDER[-1] if n else LADDER[-1]
+    banks = [layer[k][4:8] for k in ("gate", "up", "down")]
+
+    def ours(x, gate, up, down, w):
+        return moe._grouped_experts(
+            LADDER, x, gate, up, down, jnp.pad(w, (0, pad)),
+            jnp.pad(tokens, (0, pad)), sizes)
+
+    def dense(x, gate, up, down, w):
+        xg = x[tokens]
+        h = jax.nn.silu(jnp.einsum("nd,ndf->nf", xg, gate[experts])) \
+            * jnp.einsum("nd,ndf->nf", xg, up[experts])
+        y = jnp.einsum("nf,nfd->nd", h, down[experts]) * w[:, None]
+        return jnp.zeros_like(x).at[tokens].add(y)
+
+    args = (layer["x"], *banks, weights)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(ours(*args), dense(*args), atol=2e-6)
+        got = jax.grad(lambda *a: (ours(*a) ** 2).sum(), range(5))(*args)
+        want = jax.grad(lambda *a: (dense(*a) ** 2).sum(), range(5))(*args)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+# held experts under a ladder of (8, 24, block): a share whose routes sit
+# in the lowest rung, in the middle one, in the block itself; and a hot
+# expert whose routes overflow the block into the loop
+@pytest.mark.parametrize("held, hot", [
+    ((5, 1), False), ((4, 1), False), ((0, 16), False), ((5, 1), True)])
+def test_worked_rows_are_the_rung_taken_and_the_loops_blocks(
+        layer, monkeypatch, held, hot):
+    monkeypatch.setattr(moe, "first_block_rungs",
+                        lambda expected, block: (8, 24, block))
+    router = layer["router"]
+    if hot:
+        layer = {**layer, "x": jnp.abs(layer["x"])}
+        router = router.at[:, 5].add(30.0)
+    block = moe.default_block_rows(T, K, held[1], E)
+    with jax.default_matmul_precision("highest"):
+        y, stats = _held(layer, held, router=router)
+        want = _dense(layer["x"], router, layer["gate"], layer["up"],
+                      layer["down"], held)
+    np.testing.assert_allclose(y, want, atol=2e-6)
+    routes = int(stats["local_routes"])
+    rung = next((r for r in (8, 24) if routes <= r), block)
+    more = max(-(-routes // block), 1) - 1
+    assert (more > 0) == hot
+    assert float(stats["worked_rows"]) == rung + more * block
+    assert float(stats["dropped_tokens"]) == 0.0
+    # an explicit block is one rung, whatever the rule would give
+    assert float(_held(layer, held, block, router)[1]["worked_rows"]) \
+        == (more + 1) * block
+
+
+def _lowered_for_the_tpu(tokens, hidden, width, experts, top_k, **kw):
+    """The layer and its gradients at a cell's shapes (8 held experts,
+    bf16 rows, fp32 banks), lowered for the TPU and not run: its text."""
+    def loss(x, router, gate, up, down):
+        y = moe.held_experts_layer(x, router, gate, up, down, experts,
+                                   (0, 8), top_k, **kw)[0]
+        return (y.astype(jnp.float32) ** 2).sum()
+
+    shape = jax.ShapeDtypeStruct
+    args = (shape((tokens, hidden), jnp.bfloat16),
+            shape((hidden, experts), jnp.float32),
+            shape((8, hidden, width), jnp.float32),
+            shape((8, hidden, width), jnp.float32),
+            shape((8, width, hidden), jnp.float32))
+    return jax.jit(jax.grad(loss, range(5))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+
+
+def _count(text):
+    return (text.count('"stablehlo.case"'), text.count('"chlo.ragged_dot"'))
+
+
+def test_solars_cell_holds_no_conditional_and_the_other_cell_two(
+        monkeypatch):
+    solar_cell = (8192, 4096, 1280, 320, 8)
+    lfm2_cell = (16384, 2048, 1792, 32, 4)
+    with_the_rule = {cell: _lowered_for_the_tpu(*cell)
+                     for cell in (solar_cell, lfm2_cell)}
+    # a block's forward is 3 grouped matmuls and its backward those and
+    # their 6 transposes, once outside the loop and once inside: 24
+    assert _count(with_the_rule[solar_cell]) == (0, 24)
+    # a conditional in the forward and one in the backward, two copies
+    # of the first block each
+    assert _count(with_the_rule[lfm2_cell]) == (2, 36)
+    assert _count(_lowered_for_the_tpu(*lfm2_cell, block_rows=40960)) \
+        == (0, 24)
+    monkeypatch.setattr(moe, "first_block_rungs",
+                        lambda expected, block: (block,))
+    for cell, text in with_the_rule.items():
+        one_rung = _lowered_for_the_tpu(*cell)
+        assert _count(one_rung) == (0, 24)
+        assert (one_rung == text) == (cell == solar_cell)
+
+
+def _conds(jaxpr):
+    """Every ``cond`` equation of a jaxpr, however deep."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield eqn
+        for v in jax.tree.leaves(
+                eqn.params, is_leaf=lambda p: hasattr(p, "eqns")
+                or hasattr(p, "jaxpr")):
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                yield from _conds(sub)
+
+
+def test_the_forward_and_the_backward_switch_over_three_branches_each(layer):
+    banks = [layer[k][4:8] for k in ("gate", "up", "down")]
+    experts, tokens, weights = _routes(48)
+    sizes = jnp.bincount(experts, length=4).astype(jnp.int32)
+
+    def loss(x, *b):
+        return (moe._grouped_experts(LADDER, x, *b, weights, tokens,
+                                     sizes) ** 2).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, range(4)))(layer["x"], *banks)
+    assert [len(e.params["branches"]) for e in _conds(jaxpr.jaxpr)] == [3, 3]
+    one = jax.make_jaxpr(jax.grad(
+        lambda x: (moe._grouped_experts((48,), x, *banks, weights, tokens,
+                                        sizes) ** 2).sum()))(layer["x"])
+    assert list(_conds(one.jaxpr)) == []
+
+
+def test_the_init_program_holds_no_copies_of_the_block(monkeypatch):
+    """``SparseExperts`` initialises through one block of the default
+    size (nothing of ``init``'s result is kept, and a switch in it was a
+    second of the LFM2 cell's cached set-up); ``apply`` switches."""
+    monkeypatch.setattr(moe, "first_block_rungs",
+                        lambda expected, block: (8, 24, block))
+    x = jax.random.normal(jax.random.PRNGKey(4), (2, 24, 32))
+    experts = solar.SparseExperts(16, (0, 4), 4, 12, 0, 1.0, jnp.float32)
+    init = jax.make_jaxpr(lambda: experts.init(jax.random.PRNGKey(5), x))()
+    assert list(_conds(init.jaxpr)) == []
+    params = experts.init(jax.random.PRNGKey(5), x)
+    apply = jax.make_jaxpr(lambda p: experts.apply(p, x)[0])(params)
+    assert [len(e.params["branches"]) for e in _conds(apply.jaxpr)] == [3]
+
+
 def test_the_gauges_are_set_from_inside_the_step(layer):
     import horovod_tpu as hvd
 
@@ -150,6 +336,8 @@ def test_the_gauges_are_set_from_inside_the_step(layer):
     assert metrics["hvd_tpu_moe_local_routes"]["samples"][0]["value"] \
         == sum(load[str(e)] for e in range(4, 8))
     assert metrics["hvd_tpu_moe_dropped_tokens"]["samples"][0]["value"] == 0
+    # one block of the default size, whole: no rung under 160 rows
+    assert metrics["hvd_tpu_moe_worked_rows"]["samples"][0]["value"] == 160
     for name in ("hvd_tpu_moe_dropped_frac", "hvd_tpu_moe_expert_load"):
         assert "top-k" in metrics[name]["help"]
         assert "top-2" not in metrics[name]["help"]

@@ -301,10 +301,13 @@ def test_the_expert_cells_step_fits_where_the_scheduler_is_told(v5e, on_tpu):
 def test_the_gated_convolution_cells_step_fits_with_two_rows(v5e, on_tpu):
     """The whole training step of the gated-convolution, sigmoid-routed
     cell (``Lfm2LM``'s defaults, 2 x S8192, AdamW with bf16 first moments,
-    donated) compiled for a described v5e: 11.588 GiB with every layer
-    under one ``nn.remat`` and the experts' blocks of 40,960 routes (on the
-    chip to the digit: PERF.md, PR 35; 10.893 with blocks of 24,576), so
-    two rows fit and nothing had to be split. Its two attention layers are
+    donated) compiled for a described v5e: 11.602 GiB with every layer
+    under one ``nn.remat`` and the experts' first block a switch over
+    19,456 / 40,960 rows (11.588 with the one block of 40,960, both on
+    the chip to the digit: PERF.md, PRs 35 and 37), so two rows fit and
+    nothing had to be split. The switch is in the forward and the backward
+    of each of the six expert layers and not in the forward ``nn.remat``
+    runs again, which keeps no expert block. Its two attention layers are
     the first flash calls at S 8192 (K and V of two packed heads whole in
     VMEM) and the first on the packed width-64 layout with a K/V group:
     K and V reach the kernels repeated to the 32 query heads."""
@@ -338,6 +341,7 @@ def test_the_gated_convolution_cells_step_fits_with_two_rows(v5e, on_tpu):
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
     assert 11.1 < held / 2 ** 30 < 11.8
     hlo = compiled.as_text()
+    assert len(re.findall(r" conditional\(", hlo)) == 12
     calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
                        r'custom_call_target="tpu_custom_call"', hlo)
     flash = sorted(re.sub(r"[.\d]+$", "", c) for c in calls
