@@ -32,6 +32,17 @@ KDA = "hvd_kda"
 # ops/short_conv.py: the gated short convolution's two gates and its taps,
 # not the two projections around them
 SHORT_CONV = "hvd_short_conv"
+# models/*.py, the parts of a block, one vocabulary for every family
+# (docs/timeline.md). A token mixer's kernel (flash attention, the KDA
+# recurrence, the gated short convolution) lies outside MIXER_PROJ, and
+# the norms are named where a layer calls them, never inside a norm's
+# class: a mixer's own q / k / o norms count under the mixer, once.
+MIXER_PROJ = "hvd_mixer_proj"   # a mixer's projections, gates and norms
+ROPE = "hvd_rope"            # models/gpt.py rope(): under MIXER_PROJ
+MLP = "hvd_mlp"              # the dense feed-forward (no expert layer's)
+NORM = "hvd_norm"            # the block-level norms and the final norm
+EMBED = "hvd_embed"          # the token (and position) embedding lookup
+LOSS = "hvd_loss"            # models/looplm.py head_losses: after LM_HEAD
 
 # Pallas kernels: the ``name=`` of each ``pallas_call``. FLASH_DKV is the
 # whole flash backward: the dk/dv call also gives dq. FLASH_DQ is carried
@@ -58,6 +69,9 @@ LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 MOE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)   # an expert layer's
 LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
 SHORT_CONV_SCOPES = (SHORT_CONV,)   # a gated-convolution layer's
+# a block's parts: ROPE where positions are rotary, LOSS where the
+# cross-entropy is the model's own
+BLOCK_SCOPES = (MIXER_PROJ, ROPE, MLP, NORM, EMBED, LOSS)
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)

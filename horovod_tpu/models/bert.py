@@ -34,17 +34,19 @@ class SelfAttention(nn.Module):
     def __call__(self, x, mask=None):
         b, s, h = x.shape
         head_dim = h // self.num_heads
-        qkv = nn.Dense(3 * h, dtype=self.dtype, param_dtype=jnp.float32,
-                       name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(b, s, self.num_heads, head_dim)
-        k = k.reshape(b, s, self.num_heads, head_dim)
-        v = v.reshape(b, s, self.num_heads, head_dim)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            qkv = nn.Dense(3 * h, dtype=self.dtype,
+                           param_dtype=jnp.float32, name="qkv")(x)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            q = q.reshape(b, s, self.num_heads, head_dim)
+            k = k.reshape(b, s, self.num_heads, head_dim)
+            v = v.reshape(b, s, self.num_heads, head_dim)
         attend = self.attend_fn or default_attend
-        o = attend(q, k, v, mask)
-        o = o.reshape(b, s, h)
-        return nn.Dense(h, dtype=self.dtype, param_dtype=jnp.float32,
-                        name="out")(o)
+        o = attend(q, k, v, mask)   # the kernels' own names, no scope's
+        with jax.named_scope(scopes.MIXER_PROJ):
+            o = o.reshape(b, s, h)
+            return nn.Dense(h, dtype=self.dtype, param_dtype=jnp.float32,
+                            name="out")(o)
 
 
 class TransformerLayer(nn.Module):
@@ -55,16 +57,19 @@ class TransformerLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None):
-        y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
+        with jax.named_scope(scopes.NORM):
+            y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
         y = SelfAttention(self.num_heads, self.dtype,
                           self.attend_fn, name="attn")(y, mask)
         x = x + y
-        y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
-        y = nn.Dense(self.mlp_dim, dtype=self.dtype,
-                     param_dtype=jnp.float32)(y)
-        y = nn.gelu(y)
-        y = nn.Dense(x.shape[-1], dtype=self.dtype,
-                     param_dtype=jnp.float32)(y)
+        with jax.named_scope(scopes.NORM):
+            y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
+        with jax.named_scope(scopes.MLP):
+            y = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                         param_dtype=jnp.float32)(y)
+            y = nn.gelu(y)
+            y = nn.Dense(x.shape[-1], dtype=self.dtype,
+                         param_dtype=jnp.float32)(y)
         return x + y
 
 
@@ -87,19 +92,21 @@ class Bert(nn.Module):
         emb = nn.Embed(self.vocab_size, self.hidden_size,
                        param_dtype=jnp.float32, dtype=self.dtype,
                        name="tok_emb")
-        x = emb(input_ids)
-        pos = self.param("pos_emb", nn.initializers.normal(0.02),
-                         (self.max_len, self.hidden_size), jnp.float32)
-        if positions is None:
-            pe = pos[None, :x.shape[1]]
-        else:
-            pe = jnp.take(pos, positions, axis=0)
-        x = x + pe.astype(self.dtype)
+        with jax.named_scope(scopes.EMBED):
+            x = emb(input_ids)
+            pos = self.param("pos_emb", nn.initializers.normal(0.02),
+                             (self.max_len, self.hidden_size), jnp.float32)
+            if positions is None:
+                pe = pos[None, :x.shape[1]]
+            else:
+                pe = jnp.take(pos, positions, axis=0)
+            x = x + pe.astype(self.dtype)
         for i in range(self.num_layers):
             x = TransformerLayer(self.num_heads, self.mlp_dim, self.dtype,
                                  self.attend_fn, name=f"layer_{i}")(x, mask)
-        x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
-                         name="final_ln")(x)
+        with jax.named_scope(scopes.NORM):
+            x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                             name="final_ln")(x)
         # Masked-LM logits via embedding tie (standard BERT pretraining).
         # bf16 operands + fp32 accumulation: the V x H head matmul at
         # fp32 runs ~4x off the MXU's bf16 peak; accumulating in fp32

@@ -30,18 +30,19 @@ def rope(x, positions=None, base: float = 10000.0):
     the default arange, which is how a sequence-parallel shard applies
     its GLOBAL positions to a LOCAL block."""
     b, s, h, d = x.shape
-    if positions is None:
-        positions = jnp.arange(s)[None, :]
-    positions = positions.astype(jnp.float32)
-    half = d // 2
-    freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-    angles = positions[:, :, None] * freqs[None, None, :]   # (B, S, D/2)
-    cos = jnp.cos(angles)[:, :, None, :]                     # (B, S, 1, D/2)
-    sin = jnp.sin(angles)[:, :, None, :]
-    x1, x2 = x[..., :half], x[..., half:]
-    rotated = jnp.concatenate([x1 * cos - x2 * sin,
-                               x1 * sin + x2 * cos], axis=-1)
-    return rotated.astype(x.dtype)
+    with jax.named_scope(scopes.ROPE):
+        if positions is None:
+            positions = jnp.arange(s)[None, :]
+        positions = positions.astype(jnp.float32)
+        half = d // 2
+        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        angles = positions[:, :, None] * freqs[None, None, :]  # (B, S, D/2)
+        cos = jnp.cos(angles)[:, :, None, :]                # (B, S, 1, D/2)
+        sin = jnp.sin(angles)[:, :, None, :]
+        x1, x2 = x[..., :half], x[..., half:]
+        rotated = jnp.concatenate([x1 * cos - x2 * sin,
+                                   x1 * sin + x2 * cos], axis=-1)
+        return rotated.astype(x.dtype)
 
 
 def _causal_attend(q, k, v, mask=None):
@@ -234,83 +235,72 @@ class CausalSelfAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, cache=None, cache_ctx=None):
+        """The projections on either side of the attention call carry
+        ``hvd_mixer_proj`` (the rotation ``hvd_rope`` inside it); the
+        call itself lies outside, under its kernels' own names."""
         b, s, h = x.shape
         head_dim = h // self.num_heads
         if self.seq_axis and cache is None and positions is None:
             positions = seq_positions(self.seq_axis, self.seq_impl, s)
+        if cache is not None:
+            from ..serve import kvcache as kv_lib
+
+            # Incremental (serve) path: RoPE with each token's GLOBAL
+            # position, scatter the new K/V into their ring lines, and
+            # attend over the cache slab (docs/serve.md). Keys are
+            # stored ALREADY ROPED, so absolute positions survive the
+            # ring wrap without re-rotation.
+            idx, positions, k_pos = cache_ctx
         if self.tp_axis:
             from ..parallel import tensor_parallel as tp_lib
 
             ntp = jax.lax.axis_size(self.tp_axis)
             heads_l = self.num_heads // ntp
             qkv_k, qkv_b = _DenseMaster(3 * h, name="qkv")(h)
-            w3 = tp_lib.shard_heads(qkv_k, self.num_heads,
-                                    self.tp_axis, fused=3)
-            b3 = tp_lib.shard_heads(qkv_b, self.num_heads,
-                                    self.tp_axis, fused=3)
-            xd = x.astype(self.dtype)
-
-            def proj(i):
-                w = w3[:, i].reshape(h, heads_l * head_dim)
-                bb = b3[i].reshape(heads_l * head_dim)
-                y = xd @ w.astype(self.dtype) + bb.astype(self.dtype)
-                return y.reshape(b, s, heads_l, head_dim)
-
             out_k, out_b = _DenseMaster(h, name="out")(h)
-            w_loc = tp_lib.shard_head_rows(out_k, self.num_heads,
-                                           self.tp_axis)
-            if cache is not None:
-                from ..serve import kvcache as kv_lib
+            with jax.named_scope(scopes.MIXER_PROJ):
+                w3 = tp_lib.shard_heads(qkv_k, self.num_heads,
+                                        self.tp_axis, fused=3)
+                b3 = tp_lib.shard_heads(qkv_b, self.num_heads,
+                                        self.tp_axis, fused=3)
+                xd = x.astype(self.dtype)
 
-                idx, q_pos, k_pos = cache_ctx
-                q = rope(proj(0), q_pos)
-                k = rope(proj(1), q_pos)
+                def proj(i):
+                    w = w3[:, i].reshape(h, heads_l * head_dim)
+                    bb = b3[i].reshape(heads_l * head_dim)
+                    y = xd @ w.astype(self.dtype) + bb.astype(self.dtype)
+                    return y.reshape(b, s, heads_l, head_dim)
+
+                w_loc = tp_lib.shard_head_rows(out_k, self.num_heads,
+                                               self.tp_axis)
+                q = rope(proj(0), positions)
+                k = rope(proj(1), positions)
                 v = proj(2)
-                cache = kv_lib.layer_write(cache, idx, k, v)
-                k_all, v_all = kv_lib.layer_read(cache, jnp.float32)
-                o = _cache_attend(q, k_all, v_all, q_pos,
-                                  k_pos).reshape(b, s,
-                                                 heads_l * head_dim)
-                return tp_lib.row_parallel(
-                    o, w_loc.astype(self.dtype), self.tp_axis,
-                    out_b.astype(self.dtype)), cache
-            q = rope(proj(0), positions)
-            k = rope(proj(1), positions)
-            v = proj(2)
-            attend = self.attend_fn or self._resolve_attend()
-            o = attend(q, k, v).reshape(b, s, heads_l * head_dim)
-            return tp_lib.row_parallel(o, w_loc.astype(self.dtype),
-                                       self.tp_axis,
-                                       out_b.astype(self.dtype))
-        qkv = nn.Dense(3 * h, dtype=self.dtype, param_dtype=jnp.float32,
-                       name="qkv")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        else:
+            heads_l = self.num_heads
+            with jax.named_scope(scopes.MIXER_PROJ):
+                qkv = nn.Dense(3 * h, dtype=self.dtype,
+                               param_dtype=jnp.float32, name="qkv")(x)
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = rope(q.reshape(b, s, heads_l, head_dim), positions)
+                k = rope(k.reshape(b, s, heads_l, head_dim), positions)
+                v = v.reshape(b, s, heads_l, head_dim)
         if cache is not None:
-            # Incremental (serve) path: RoPE with each token's GLOBAL
-            # position, scatter the new K/V into their ring lines, and
-            # attend over the cache slab (docs/serve.md). Keys are
-            # stored ALREADY ROPED, so absolute positions survive the
-            # ring wrap without re-rotation.
-            from ..serve import kvcache as kv_lib
-
-            idx, q_pos, k_pos = cache_ctx
-            q = rope(q.reshape(b, s, self.num_heads, head_dim), q_pos)
-            k = rope(k.reshape(b, s, self.num_heads, head_dim), q_pos)
-            v = v.reshape(b, s, self.num_heads, head_dim)
             cache = kv_lib.layer_write(cache, idx, k, v)
             k_all, v_all = kv_lib.layer_read(cache, jnp.float32)
-            o = _cache_attend(q, k_all, v_all, q_pos,
-                              k_pos).reshape(b, s, h)
-            return nn.Dense(h, dtype=self.dtype,
-                            param_dtype=jnp.float32,
-                            name="out")(o), cache
-        q = rope(q.reshape(b, s, self.num_heads, head_dim), positions)
-        k = rope(k.reshape(b, s, self.num_heads, head_dim), positions)
-        v = v.reshape(b, s, self.num_heads, head_dim)
-        attend = self.attend_fn or self._resolve_attend()
-        o = attend(q, k, v).reshape(b, s, h)
-        return nn.Dense(h, dtype=self.dtype, param_dtype=jnp.float32,
-                        name="out")(o)
+            o = _cache_attend(q, k_all, v_all, positions, k_pos)
+        else:
+            o = (self.attend_fn or self._resolve_attend())(q, k, v)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            o = o.reshape(b, s, heads_l * head_dim)
+            if self.tp_axis:
+                o = tp_lib.row_parallel(o, w_loc.astype(self.dtype),
+                                        self.tp_axis,
+                                        out_b.astype(self.dtype))
+            else:
+                o = nn.Dense(h, dtype=self.dtype, param_dtype=jnp.float32,
+                             name="out")(o)
+        return o if cache is None else (o, cache)
 
     def _resolve_attend(self) -> Callable:
         if self.seq_axis:
@@ -345,7 +335,8 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, positions=None, cache=None, cache_ctx=None):
-        y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
+        with jax.named_scope(scopes.NORM):
+            y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
         if cache is not None:
             a, cache = CausalSelfAttention(
                 self.num_heads, self.dtype, self.attend_fn,
@@ -360,14 +351,14 @@ class DecoderLayer(nn.Module):
                                         seq_impl=self.seq_impl,
                                         seq_wire=self.seq_wire,
                                         name="attn")(y, positions)
-        y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
+        with jax.named_scope(scopes.NORM):
+            y = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32)(x)
         if self.moe_experts:
-            out = x + MoeMlp(self.moe_experts, self.mlp_dim,
-                             self.moe_capacity_factor, self.dtype,
-                             self.moe_axis, self.moe_route,
-                             self.moe_wire, self.moe_overlap_chunks,
-                             self.moe_router_noise,
-                             name="moe")(y)
+            y = MoeMlp(self.moe_experts, self.mlp_dim,
+                       self.moe_capacity_factor, self.dtype, self.moe_axis,
+                       self.moe_route, self.moe_wire,
+                       self.moe_overlap_chunks, self.moe_router_noise,
+                       name="moe")(y)
         elif self.tp_axis:
             from ..parallel import tensor_parallel as tp_lib
 
@@ -375,23 +366,24 @@ class DecoderLayer(nn.Module):
                                   name="mlp_in")(x.shape[-1])
             k2, b2 = _DenseMaster(x.shape[-1],
                                   name="mlp_out")(self.mlp_dim)
-            y = tp_lib.tp_mlp(
-                y.astype(self.dtype),
-                tp_lib.shard_column(k1.astype(self.dtype),
-                                    self.tp_axis),
-                tp_lib.shard_column(b1.astype(self.dtype),
-                                    self.tp_axis),
-                tp_lib.shard_row(k2.astype(self.dtype), self.tp_axis),
-                b2.astype(self.dtype), self.tp_axis,
-                activation=nn.gelu)
-            out = x + y
+            with jax.named_scope(scopes.MLP):
+                y = tp_lib.tp_mlp(
+                    y.astype(self.dtype),
+                    tp_lib.shard_column(k1.astype(self.dtype),
+                                        self.tp_axis),
+                    tp_lib.shard_column(b1.astype(self.dtype),
+                                        self.tp_axis),
+                    tp_lib.shard_row(k2.astype(self.dtype), self.tp_axis),
+                    b2.astype(self.dtype), self.tp_axis,
+                    activation=nn.gelu)
         else:
-            y = nn.Dense(self.mlp_dim, dtype=self.dtype,
-                         param_dtype=jnp.float32, name="mlp_in")(y)
-            y = nn.gelu(y)
-            y = nn.Dense(x.shape[-1], dtype=self.dtype,
-                         param_dtype=jnp.float32, name="mlp_out")(y)
-            out = x + y
+            with jax.named_scope(scopes.MLP):
+                y = nn.Dense(self.mlp_dim, dtype=self.dtype,
+                             param_dtype=jnp.float32, name="mlp_in")(y)
+                y = nn.gelu(y)
+                y = nn.Dense(x.shape[-1], dtype=self.dtype,
+                             param_dtype=jnp.float32, name="mlp_out")(y)
+        out = x + y
         return out if cache is None else (out, cache)
 
 
@@ -447,7 +439,8 @@ class GPT(nn.Module):
     def __call__(self, tokens, positions=None, cache=None):
         emb = nn.Embed(self.vocab_size, self.hidden,
                        param_dtype=jnp.float32, name="tok_emb")
-        x = emb(tokens).astype(self.dtype)
+        with jax.named_scope(scopes.EMBED):
+            x = emb(tokens).astype(self.dtype)
         layer_cls = nn.remat(DecoderLayer) if self.remat else DecoderLayer
         cache_ctx = None
         new_layers = []
@@ -484,8 +477,9 @@ class GPT(nn.Module):
                 new_layers.append(lc)
             else:
                 x = layer(x, positions)
-        x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
-                         name="final_ln")(x)
+        with jax.named_scope(scopes.NORM):
+            x = nn.LayerNorm(dtype=self.dtype, param_dtype=jnp.float32,
+                             name="final_ln")(x)
         # Weight-tied head: bf16 operands + fp32 accumulation — the
         # V x H matmul at fp32 runs ~4x off the MXU's bf16 peak, and
         # fp32 accumulation keeps the softmax stable (standard LM-head

@@ -47,6 +47,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ..common import scopes
 from ..ops.flash_attention import flash_attention
 from ..ops.short_conv import gated_short_conv
 from .gpt import rope
@@ -72,8 +73,11 @@ class ShortConv(nn.Module):
         dense = _dense(self.dtype)
         taps = self.param("conv", nn.initializers.lecun_normal(
             in_axis=0, out_axis=()), (self.taps, hidden), jnp.float32)
-        b, c, x = jnp.split(dense(3 * hidden, name="in_proj")(u), 3, -1)
-        return dense(hidden, name="out_proj")(gated_short_conv(b, c, x, taps))
+        with jax.named_scope(scopes.MIXER_PROJ):
+            b, c, x = jnp.split(dense(3 * hidden, name="in_proj")(u), 3, -1)
+        y = gated_short_conv(b, c, x, taps)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            return dense(hidden, name="out_proj")(y)
 
 
 class RotaryGQA(nn.Module):
@@ -94,13 +98,15 @@ class RotaryGQA(nn.Module):
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
         wide, narrow = (n * self.head_dim
                         for n in (self.num_heads, self.num_kv_heads))
-        q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
-        k, v = (dense(narrow, name=n)(u).reshape(b, s, self.num_kv_heads, -1)
-                for n in ("k", "v"))
-        q = rope(norm(name="q_norm")(q), base=self.rope_base)
-        k = rope(norm(name="k_norm")(k), base=self.rope_base)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
+            k, v = (dense(narrow, name=n)(u).reshape(
+                b, s, self.num_kv_heads, -1) for n in ("k", "v"))
+            q = rope(norm(name="q_norm")(q), base=self.rope_base)
+            k = rope(norm(name="k_norm")(k), base=self.rope_base)
         o = flash_attention(q, k, v, causal=True)
-        return dense(hidden, name="o")(o.reshape(b, s, wide))
+        with jax.named_scope(scopes.MIXER_PROJ):
+            return dense(hidden, name="o")(o.reshape(b, s, wide))
 
 
 class DenseFFN(nn.Module):
@@ -113,9 +119,10 @@ class DenseFFN(nn.Module):
     @nn.compact
     def __call__(self, x):
         dense = _dense(self.dtype)
-        y = nn.silu(dense(self.mlp_dim, name="gate")(x)) \
-            * dense(self.mlp_dim, name="up")(x)
-        return dense(x.shape[-1], name="down")(y), None
+        with jax.named_scope(scopes.MLP):
+            y = nn.silu(dense(self.mlp_dim, name="gate")(x)) \
+                * dense(self.mlp_dim, name="up")(x)
+            return dense(x.shape[-1], name="down")(y), None
 
 
 class Lfm2Layer(nn.Module):
@@ -133,10 +140,12 @@ class Lfm2Layer(nn.Module):
     @nn.compact
     def __call__(self, x):
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
-        x = x + self.mixer(*self.mixer_args, name="mixer")(
-            norm(name="op_norm")(x))
-        y, _ = self.ffn(*self.ffn_args, name="ffn")(
-            norm(name="ffn_norm")(x))
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="op_norm")(x)
+        x = x + self.mixer(*self.mixer_args, name="mixer")(y)
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="ffn_norm")(x)
+        y, _ = self.ffn(*self.ffn_args, name="ffn")(y)
         return x + y
 
 
@@ -188,12 +197,15 @@ class Lfm2LM(nn.Module):
         self.final_norm = RMSNorm(self.norm_eps, self.dtype)
 
     def __call__(self, tokens, labels=None):
-        h = self.tok_emb(tokens).astype(self.dtype)
+        with jax.named_scope(scopes.EMBED):
+            h = self.tok_emb(tokens).astype(self.dtype)
         for i in range(self.num_layers):
             h = getattr(self, f"layer{i}")(h)
         head = jax.checkpoint(functools.partial(
             head_losses, dtype=self.dtype, tied=True))
-        return head(self.final_norm(h), self.tok_emb.embedding, labels)
+        with jax.named_scope(scopes.NORM):
+            z = self.final_norm(h)
+        return head(z, self.tok_emb.embedding, labels)
 
 
 # Mean next-token cross-entropy of ``tokens`` (B, S + 1), weighted where

@@ -79,25 +79,37 @@ class LoopLayer(nn.Module):
                                   param_dtype=jnp.float32)
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
 
-        y = norm(name="attn_norm")(x)
-        q, k, v = (dense(heads * width, name=n)(y).reshape(b, s, heads, width)
-                   for n in ("q", "k", "v"))
-        o = flash_attention(rope(q, base=self.rope_base),
-                            rope(k, base=self.rope_base), v, causal=True)
-        o = dense(hidden, name="o")(o.reshape(b, s, heads * width))
-        x = x + norm(name="attn_out_norm")(o)
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="attn_norm")(x)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            q, k, v = (dense(heads * width, name=n)(y).reshape(
+                b, s, heads, width) for n in ("q", "k", "v"))
+            q, k = rope(q, base=self.rope_base), rope(k, base=self.rope_base)
+        o = flash_attention(q, k, v, causal=True)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            o = dense(hidden, name="o")(o.reshape(b, s, heads * width))
+        with jax.named_scope(scopes.NORM):
+            o = norm(name="attn_out_norm")(o)
+        x = x + o
 
-        y = norm(name="mlp_norm")(x)
-        y = nn.silu(dense(self.mlp_dim, name="gate")(y)) \
-            * dense(self.mlp_dim, name="up")(y)
-        return x + norm(name="mlp_out_norm")(dense(hidden, name="down")(y))
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="mlp_norm")(x)
+        with jax.named_scope(scopes.MLP):
+            y = nn.silu(dense(self.mlp_dim, name="gate")(y)) \
+                * dense(self.mlp_dim, name="up")(y)
+            y = dense(hidden, name="down")(y)
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="mlp_out_norm")(y)
+        return x + y
 
 
 def head_losses(z, kernel, labels=None, dtype=jnp.bfloat16, tied=False):
     """fp32 logits of a state over the vocabulary (bf16 operands, fp32
     accumulation, under ``hvd_lm_head``), or, given the labels, the
-    cross-entropy of each position. ``kernel`` is (hidden, vocab), or
-    with ``tied`` an embedding's table (vocab, hidden)."""
+    cross-entropy of each position (the log-sum-exp and the pick of the
+    label under ``hvd_loss``, beside the matmul's scope and not inside
+    it). ``kernel`` is (hidden, vocab), or with ``tied`` an embedding's
+    table (vocab, hidden)."""
     with jax.named_scope(scopes.LM_HEAD):
         logits = jax.lax.dot_general(
             z.astype(dtype), kernel.astype(dtype),
@@ -105,8 +117,9 @@ def head_losses(z, kernel, labels=None, dtype=jnp.bfloat16, tied=False):
             preferred_element_type=jnp.float32)
     if labels is None:
         return logits
-    picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
-    return jax.nn.logsumexp(logits, -1) - picked
+    with jax.named_scope(scopes.LOSS):
+        picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
+        return jax.nn.logsumexp(logits, -1) - picked
 
 
 class _Head(nn.Module):
@@ -170,7 +183,8 @@ class LoopLM(nn.Module):
     def one_pass(self, h):
         for i in range(self.num_layers):
             h = getattr(self, f"layer{i}")(h)
-        return self.final_norm(h)
+        with jax.named_scope(scopes.NORM):
+            return self.final_norm(h)
 
     def __call__(self, tokens, labels=None):
         def step(model, h, labels):
@@ -180,8 +194,9 @@ class LoopLM(nn.Module):
         passes = nn.scan(step, variable_broadcast="params",
                          split_rngs={"params": False},
                          in_axes=nn.broadcast, length=self.passes)
-        return passes(self, self.tok_emb(tokens).astype(self.dtype),
-                      labels)[1]
+        with jax.named_scope(scopes.EMBED):
+            h = self.tok_emb(tokens).astype(self.dtype)
+        return passes(self, h, labels)[1]
 
 
 def exit_log_distribution(gates):

@@ -80,12 +80,15 @@ class GatedGQA(nn.Module):
         dense = _dense(self.dtype)
         wide, narrow = (n * self.head_dim
                         for n in (self.num_heads, self.num_kv_heads))
-        q = dense(wide, name="q")(x).reshape(b, s, self.num_heads, -1)
-        k, v = (dense(narrow, name=n)(x).reshape(b, s, self.num_kv_heads, -1)
-                for n in ("k", "v"))
-        o = flash_attention(q, k, v, causal=True).reshape(b, s, wide)
-        o = o * nn.sigmoid(dense(wide, name="gate")(x))
-        return dense(hidden, name="o")(o)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            q = dense(wide, name="q")(x).reshape(b, s, self.num_heads, -1)
+            k, v = (dense(narrow, name=n)(x).reshape(
+                b, s, self.num_kv_heads, -1) for n in ("k", "v"))
+        o = flash_attention(q, k, v, causal=True)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            o = o.reshape(b, s, wide) * nn.sigmoid(
+                dense(wide, name="gate")(x))
+            return dense(hidden, name="o")(o)
 
 
 def _decay_bias_init(key, shape, dtype=jnp.float32):
@@ -194,27 +197,32 @@ class KDA(nn.Module):
         def unit(y):
             return y * jax.lax.rsqrt((y * y).sum(-1, keepdims=True) + 1e-6)
 
-        q = (unit(mixed("q")) * width ** -0.5).astype(self.dtype)
-        k = unit(mixed("k")).astype(self.dtype)
-        v = mixed("v").astype(self.dtype)
+        # everything around the recurrence under the one name; the
+        # recurrence itself under its own (``hvd_kda``), outside it
+        with jax.named_scope(scopes.MIXER_PROJ):
+            q = (unit(mixed("q")) * width ** -0.5).astype(self.dtype)
+            k = unit(mixed("k")).astype(self.dtype)
+            v = mixed("v").astype(self.dtype)
 
-        rate = self.param("A_log", _decay_rate_init, (heads,), jnp.float32)
-        bias = self.param("dt_bias", _decay_bias_init, (heads * width,),
-                          jnp.float32)
-        f = dense(heads * width, name="f_up")(
-            dense(self.gate_rank, name="f_down")(x))
-        log_alpha = -jnp.exp(rate)[:, None] * jax.nn.softplus(
-            (f.astype(jnp.float32) + bias).reshape(b, s, heads, width))
-        beta = 2.0 * nn.sigmoid(
-            dense(heads, name="beta")(x).astype(jnp.float32))
+            rate = self.param("A_log", _decay_rate_init, (heads,),
+                              jnp.float32)
+            bias = self.param("dt_bias", _decay_bias_init, (heads * width,),
+                              jnp.float32)
+            f = dense(heads * width, name="f_up")(
+                dense(self.gate_rank, name="f_down")(x))
+            log_alpha = -jnp.exp(rate)[:, None] * jax.nn.softplus(
+                (f.astype(jnp.float32) + bias).reshape(b, s, heads, width))
+            beta = 2.0 * nn.sigmoid(
+                dense(heads, name="beta")(x).astype(jnp.float32))
 
         o = _as_heavy_as_the_xla_code(
             kda_attention(q, k, v, log_alpha, beta))
-        o = RMSNorm(self.norm_eps, self.dtype, name="o_norm")(o)
-        gate = dense(heads * width, name="g_up")(
-            dense(self.gate_rank, name="g_down")(x))
-        o = o.reshape(b, s, heads * width) * nn.sigmoid(gate)
-        return dense(hidden, name="o")(o)
+        with jax.named_scope(scopes.MIXER_PROJ):
+            o = RMSNorm(self.norm_eps, self.dtype, name="o_norm")(o)
+            gate = dense(heads * width, name="g_up")(
+                dense(self.gate_rank, name="g_down")(x))
+            o = o.reshape(b, s, heads * width) * nn.sigmoid(gate)
+            return dense(hidden, name="o")(o)
 
 
 class SparseExperts(nn.Module):
@@ -288,10 +296,12 @@ class SolarLayer(nn.Module):
     @nn.compact
     def __call__(self, x):
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
-        x = x + self.attn(*self.attn_args, name="attn")(
-            norm(name="attn_norm")(x))
-        y, stats = SparseExperts(*self.moe_args, name="moe")(
-            norm(name="mlp_norm")(x))
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="attn_norm")(x)
+        x = x + self.attn(*self.attn_args, name="attn")(y)
+        with jax.named_scope(scopes.NORM):
+            y = norm(name="mlp_norm")(x)
+        y, stats = SparseExperts(*self.moe_args, name="moe")(y)
         return x + y, stats
 
 
@@ -344,7 +354,8 @@ class SolarLM(nn.Module):
         self.lm_head = nn.remat(_Head)(self.vocab_size, self.dtype)
 
     def __call__(self, tokens, labels=None):
-        h = self.tok_emb(tokens).astype(self.dtype)
+        with jax.named_scope(scopes.EMBED):
+            h = self.tok_emb(tokens).astype(self.dtype)
         total = None
         for i in range(self.num_layers):
             h, stats = getattr(self, f"layer{i}")(h)
@@ -352,7 +363,8 @@ class SolarLM(nn.Module):
                 jnp.add, total, stats)
         if self.publish_stats and not self.is_initializing():
             moe.record_held_stats(total, self.held_experts[0])
-        z = self.final_norm(h)
+        with jax.named_scope(scopes.NORM):
+            z = self.final_norm(h)
         if labels is None:
             return self.lm_head(z)
         return _loss_before_the_backward(self.lm_head, z, labels)

@@ -3,6 +3,8 @@
 written for, and the benchmark's data file and the docs quote the same
 strings."""
 
+import contextlib
+import hashlib
 import json
 import os
 import re
@@ -33,13 +35,45 @@ IN_PLACE = {"none", "bf16"}
 
 
 def _model(family):
+    """The tiny model of one of the five families."""
+    from horovod_tpu import models
+
     if family == "gpt":
-        from horovod_tpu.models.gpt import gpt_tiny
+        return models.gpt.gpt_tiny(vocab_size=128)
+    if family == "bert":
+        return models.bert.bert_tiny(vocab_size=128)
+    if family == "ouro":
+        return models.looplm.LoopLM(vocab_size=64, num_layers=2, hidden=32,
+                                    num_heads=2, head_dim=16, mlp_dim=48,
+                                    passes=3)
+    if family == "solar":
+        return models.SolarLM(
+            vocab_size=64, num_layers=2, hidden=32, gqa_layers=(0,),
+            num_heads=2, num_kv_heads=1, head_dim=16, kda_heads=2,
+            kda_head_dim=16, gate_rank=8, num_experts=8, held_experts=(2, 4),
+            top_k=2, expert_dim=16, shared_dim=16)
+    return models.Lfm2LM(
+        vocab_size=64, num_layers=3, hidden=32,
+        layer_types=("conv", "full_attention", "conv"), num_heads=2,
+        num_kv_heads=1, head_dim=16, num_dense_layers=1, mlp_dim=48,
+        num_experts=8, held_experts=(2, 4), top_k=2, expert_dim=16)
 
-        return gpt_tiny(vocab_size=128)
-    from horovod_tpu.models.bert import bert_tiny
 
-    return bert_tiny(vocab_size=128)
+@contextlib.contextmanager
+def _compiled_here():
+    """JAX's persistent compile cache off: an ``op_name`` is metadata,
+    which is not in the cache's key, so a warm cache hands back the names
+    of whichever program of this text was compiled first."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        compilation_cache.reset_cache()
 
 
 @pytest.fixture(scope="module")
@@ -72,8 +106,9 @@ def op_names(hvd):
 
             jitted = hvd.spmd_step(step, in_specs=(P(), P(), P(ax)),
                                    out_specs=(P(), P(), P()))
-            text = jitted.lower(params, tx.init(params),
-                                tokens).compile().as_text()
+            with _compiled_here():
+                text = jitted.lower(params, tx.init(params),
+                                    tokens).compile().as_text()
             cache[family, optimizer] = set(
                 re.findall(r'op_name="([^"]*)"', text))
         return cache[family, optimizer]
@@ -257,13 +292,13 @@ def _constants():
 
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES
-             + scopes.FLASH_KERNELS
+             + scopes.BLOCK_SCOPES + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 24
+    assert len(values) == len(set(values)) == 30
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
@@ -271,8 +306,10 @@ def test_each_name_is_written_once():
                                  "hvd_moe_shared")
     assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
     assert scopes.SHORT_CONV_SCOPES == ("hvd_short_conv",)
+    assert scopes.BLOCK_SCOPES == ("hvd_mixer_proj", "hvd_rope", "hvd_mlp",
+                                   "hvd_norm", "hvd_embed", "hvd_loss")
     assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES
-                   + scopes.SHORT_CONV_SCOPES) \
+                   + scopes.SHORT_CONV_SCOPES + scopes.BLOCK_SCOPES) \
         & set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES)
 
 
@@ -280,13 +317,9 @@ def test_each_name_is_written_once():
 def solar_op_names():
     """Every ``op_name`` of a tiny expert / linear-attention model's
     differentiated step, as lowered."""
-    from horovod_tpu.models import SolarLM, solar_loss
+    from horovod_tpu.models import solar_loss
 
-    model = SolarLM(vocab_size=64, num_layers=2, hidden=32, gqa_layers=(0,),
-                    num_heads=2, num_kv_heads=1, head_dim=16, kda_heads=2,
-                    kda_head_dim=16, gate_rank=8, num_experts=8,
-                    held_experts=(2, 4), top_k=2, expert_dim=16,
-                    shared_dim=16)
+    model = _model("solar")
     tokens = jnp.zeros((2, 33), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
     text = jax.jit(jax.grad(lambda p: solar_loss(model, p, tokens))).lower(
@@ -314,13 +347,9 @@ def test_an_expert_linear_attention_models_scopes_are_on_its_step(
 def lfm2_op_names():
     """Every ``op_name`` of a tiny gated-convolution / attention model's
     differentiated step, as lowered: layers c A c, the first dense."""
-    from horovod_tpu.models import Lfm2LM, lfm2_loss
+    from horovod_tpu.models import lfm2_loss
 
-    model = Lfm2LM(vocab_size=64, num_layers=3, hidden=32,
-                   layer_types=("conv", "full_attention", "conv"),
-                   num_heads=2, num_kv_heads=1, head_dim=16,
-                   num_dense_layers=1, mlp_dim=48, num_experts=8,
-                   held_experts=(2, 4), top_k=2, expert_dim=16)
+    model = _model("lfm2")
     tokens = jnp.zeros((2, 33), jnp.int32)
     params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
     text = jax.jit(jax.value_and_grad(
@@ -350,6 +379,115 @@ def test_a_gated_convolution_models_scopes_are_on_its_step(lfm2_op_names,
     # this model has no shared expert
     assert not any(_under(scopes.MOE_SHARED).search(n)
                    for n in lfm2_op_names)
+
+
+@pytest.fixture(scope="module")
+def loop_op_names():
+    """Every ``op_name`` of a tiny looped model's differentiated step, as
+    compiled (the lowered text names a scan's body apart from its call)."""
+    from horovod_tpu.models.looplm import looplm_loss
+
+    model = _model("ouro")
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    with _compiled_here():
+        text = jax.jit(jax.value_and_grad(
+            lambda p: looplm_loss(model, p, tokens, 0.05))).lower(
+                params).compile().as_text()
+    return set(re.findall(r'op_name="([^"]*)"', text))
+
+
+# -- the block's parts, one vocabulary for the five families ------------------
+
+# What each family's step has of ``BLOCK_SCOPES``: no rotation where the
+# positions are learned (bert) or absent (solar), no dense feed-forward
+# where every layer is an expert layer (solar: its shared expert keeps
+# ``hvd_moe_shared``), the loss where it is the program's own and not the
+# caller's (``looplm.head_losses``).
+BLOCK_PARTS = {
+    "gpt": (scopes.MIXER_PROJ, scopes.ROPE, scopes.MLP, scopes.NORM,
+            scopes.EMBED),
+    "bert": (scopes.MIXER_PROJ, scopes.MLP, scopes.NORM, scopes.EMBED),
+    "ouro": scopes.BLOCK_SCOPES,
+    "solar": (scopes.MIXER_PROJ, scopes.NORM, scopes.EMBED, scopes.LOSS),
+    "lfm2": scopes.BLOCK_SCOPES,
+}
+
+
+@pytest.fixture(scope="module")
+def block_op_names(request):
+    """``block_op_names(family)``: the family's fixture above."""
+    def get(family):
+        if family in ("gpt", "bert"):
+            return request.getfixturevalue("op_names")(family, "none")
+        return request.getfixturevalue(
+            {"ouro": "loop", "solar": "solar", "lfm2": "lfm2"}[family]
+            + "_op_names")
+    return get
+
+
+@pytest.mark.parametrize("scope", scopes.BLOCK_SCOPES)
+@pytest.mark.parametrize("family", sorted(BLOCK_PARTS))
+def test_a_blocks_part_is_on_the_forward_and_the_backward(
+        block_op_names, family, scope):
+    """Each part a family has is named on its forward and (through the
+    name stack JAX keeps for the transpose) on its backward; a part it
+    has not is on nothing."""
+    under = [n for n in block_op_names(family) if _under(scope).search(n)]
+    has = scope in BLOCK_PARTS[family]
+    assert any("transpose(" not in n for n in under) == has
+    assert any("transpose(" in n for n in under) == has
+    if scope == scopes.LOSS:    # beside the head's matmul, not inside it
+        assert not any(_under(scopes.LM_HEAD).search(n) for n in under)
+
+
+@pytest.mark.parametrize("family", sorted(BLOCK_PARTS))
+def test_the_blocks_parts_are_siblings_but_the_rope(block_op_names, family):
+    """No instruction lies under two parts, under a part and a kernel's
+    scope, or under the dense feed-forward and an expert layer's scope;
+    the rotation alone is nested, and only in the mixer's projections."""
+    flat = tuple(s for s in scopes.BLOCK_SCOPES if s != scopes.ROPE) \
+        + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES \
+        + scopes.MOE_SCOPES + (scopes.LM_HEAD,)
+    for n in block_op_names(family):
+        assert sum(bool(_under(s).search(n)) for s in flat) <= 1, n
+        if _under(scopes.ROPE).search(n):
+            assert re.search(f"(^|/){scopes.MIXER_PROJ}/{scopes.ROPE}(/|$)",
+                             n), n
+
+
+# sha256 over "path shape dtype" of every leaf, read from the commit
+# before the block's parts were named: a ``named_scope`` is no module, so
+# checkpoints, the benchmark's references and its ``correct`` see the
+# same trees.
+PARAMETER_TREES = {
+    "gpt": (27, "a7f6131fef453c4b252fc4037da6a1f6"
+                "b00ef35d079c3a2f53b3b54006f28fa9"),
+    "bert": (28, "e41ed3f52d352426463a426558881ad3"
+                 "77e8863f59f24e5f9896b1f6bfd83dc7"),
+    "ouro": (27, "f20b9a5539ea02c166ba4fd695aad377"
+                 "09d91d8b27fbfb23fca67015cf7fbbd5"),
+    "solar": (41, "3d4fa473e8f9466514122007176114ea"
+                  "97502b02123cc3b863565a8d468c7df1"),
+    "lfm2": (33, "caaab0bbdd628848f8450920f0347935"
+                 "ceb1781b832c174b008a1ae5be7ee606"),
+}
+
+
+def _tree_digest(params):
+    leaves = jax.tree_util.tree_leaves_with_path(params)
+    lines = [f"{jax.tree_util.keystr(path)} {leaf.shape} {leaf.dtype}"
+             for path, leaf in leaves]
+    return len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
+
+
+@pytest.mark.parametrize("family", sorted(PARAMETER_TREES))
+def test_naming_the_parts_left_the_parameter_trees_alone(family):
+    model = _model(family)
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), tokens))["params"]
+    assert _tree_digest(params) == PARAMETER_TREES[family]
 
 
 def test_the_benchmarks_data_file_quotes_the_same_names():
