@@ -203,10 +203,53 @@ def _flash_kernels(shape, dtype, causal=True):
     return out
 
 
+def _rope_kernels(shape, dtype):
+    """The rotation's kernels at one (b, S, h, d) against the formula a
+    head at a time (``rotate_heads``: on a TPU their jnp twin is XLA's
+    slices and pads, no reference), forward and gradient, on positions of
+    each row's own. The products and the one sum are the formula's, so the
+    two may differ by how the sum is rounded: one unit in the last place
+    of |x| + |its partner| forward, two backward (the formula rounds each
+    term of a gradient to the dtype before it adds them)."""
+    import jax
+    import jax.numpy as jnp
+
+    from horovod_tpu.ops import rope
+
+    b, s = shape[:2]
+    x, w = (jax.random.normal(key, shape, jnp.float32).astype(dtype)
+            for key in jax.random.split(jax.random.PRNGKey(SEED), 2))
+    positions = jnp.arange(s)[None] * 3 + jnp.arange(b)[:, None] * 7
+
+    def forward(use, x):
+        if use:
+            return rope.rotate(x, positions, use_pallas=True)
+        return rope.rotate_heads(x, positions)
+
+    def backward(use, x):
+        return jax.grad(lambda x: (forward(use, x).astype(jnp.float32)
+                                   * w.astype(jnp.float32)).sum())(x)
+
+    def bound(x):
+        a, half = jnp.abs(x.astype(jnp.float32)), shape[3] // 2
+        return a + jnp.concatenate([a[..., half:], a[..., :half]], axis=-1)
+
+    ulp = float(jnp.finfo(dtype).eps)
+    out = {}
+    for name, fn, operand, ulps in (("fwd", forward, x, 1),
+                                    ("dx", backward, w, 2)):
+        got, want = (y.astype(jnp.float32) for y in _kernel_and_twin(fn, x))
+        out[name] = float(jnp.max(jnp.abs(got - want)
+                                  - ulps * ulp * bound(operand)))
+    return out
+
+
 def phase_kernels(bucket=BUCKET, attn_shapes=ATTN_SHAPES,
                   dtypes=("float32", "bfloat16")):
-    """Every Pallas kernel of ops/pallas_kernels.py and
-    ops/flash_attention.py at a real size against its jnp twin."""
+    """Every Pallas kernel of ops/pallas_kernels.py,
+    ops/flash_attention.py and ops/rope.py at a real size against its jnp
+    twin (the rotation's against the formula it replaced, on q of the
+    causal attention shapes)."""
     import jax.numpy as jnp
 
     worst = {}
@@ -216,6 +259,9 @@ def phase_kernels(bucket=BUCKET, attn_shapes=ATTN_SHAPES,
             name = "x".join(map(str, shape)) + ("" if causal else ".full")
             worst[f"flash.{dtype.name}.{name}"] = _flash_kernels(
                 shape, dtype, causal)
+            if causal:
+                worst[f"rope.{dtype.name}.{name}"] = _rope_kernels(
+                    shape, dtype)
     _say("kernels", bucket_elems=bucket, excess_over_tolerance=worst)
     bad = {f"{group}.{k}": v for group, checks in worst.items()
            for k, v in checks.items() if not v <= 0}
@@ -296,9 +342,10 @@ def phase_train(hvd, model, batch=8, seq_len=512, steps=6, warmup=2,
                 require_flash=True):
     """A trainer that takes a few steps: init -> DistributedOptimizer ->
     the jitted step on one device. With ``require_flash`` the compiled
-    step must hold two Mosaic calls per layer — flash forward, and the
+    step must hold two flash kernels per layer — flash forward, and the
     backward that gives dq, dk and dv — i.e. no layer gave way to
-    ``reference_attention``."""
+    ``reference_attention`` (the rotation's four kernels a layer are
+    Mosaic calls too, under names of their own)."""
     import jax
     import numpy as np
 
@@ -318,10 +365,12 @@ def phase_train(hvd, model, batch=8, seq_len=512, steps=6, warmup=2,
         compiled = step.lower(params, opt_state, tokens).compile()
         compile_s.append(round(time.perf_counter() - t0, 3))
         entries.append(_cache_entries())
-    flash_calls = compiled.as_text().count(MOSAIC_CALL)
+    flash_calls = sum(
+        MOSAIC_CALL in line and "hvd_flash" in line.split(" = ")[0]
+        for line in compiled.as_text().splitlines())
     if require_flash:
         _require(flash_calls == 2 * model.num_layers,
-                 f"{flash_calls} Mosaic calls in the step, expected "
+                 f"{flash_calls} flash kernels in the step, expected "
                  f"{2 * model.num_layers}: flash_attention gave way to "
                  "the reference")
     mem = compiled.memory_analysis()

@@ -38,7 +38,7 @@ SHORT_CONV = "hvd_short_conv"
 # the norms are named where a layer calls them, never inside a norm's
 # class: a mixer's own q / k / o norms count under the mixer, once.
 MIXER_PROJ = "hvd_mixer_proj"   # a mixer's projections, gates and norms
-ROPE = "hvd_rope"            # models/gpt.py rope(): under MIXER_PROJ
+ROPE = "hvd_rope"            # ops/rope.py rotate(): under MIXER_PROJ
 MLP = "hvd_mlp"              # the dense feed-forward (no expert layer's)
 NORM = "hvd_norm"            # the block-level norms and the final norm
 EMBED = "hvd_embed"          # the token (and position) embedding lookup
@@ -63,6 +63,10 @@ INT8_DEQUANTIZE = "hvd_int8_dequantize"
 # under transpose(); the instruction's own name keeps it)
 KDA_FWD = KDA + "_fwd"
 KDA_BWD = KDA + "_bwd"
+# ops/rope.py: the rotation on packed rows and its transpose, made under
+# the scope ROPE and named with it as their prefix, as KDA's are
+ROPE_FWD = ROPE + "_fwd"
+ROPE_BWD = ROPE + "_bwd"
 
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
@@ -76,3 +80,4 @@ FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)
 KDA_KERNELS = (KDA_FWD, KDA_BWD)
+ROPE_KERNELS = (ROPE_FWD, ROPE_BWD)

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import scopes
+from ..ops import rope as rope_lib
 from ..ops.flash_attention import flash_attention
 
 
@@ -28,21 +29,10 @@ def rope(x, positions=None, base: float = 10000.0):
     """Rotary position embedding on (B, S, H, D) — rotate each head-dim
     pair by a position-dependent angle. ``positions`` (B, S) overrides
     the default arange, which is how a sequence-parallel shard applies
-    its GLOBAL positions to a LOCAL block."""
-    b, s, h, d = x.shape
-    with jax.named_scope(scopes.ROPE):
-        if positions is None:
-            positions = jnp.arange(s)[None, :]
-        positions = positions.astype(jnp.float32)
-        half = d // 2
-        freqs = base ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
-        angles = positions[:, :, None] * freqs[None, None, :]  # (B, S, D/2)
-        cos = jnp.cos(angles)[:, :, None, :]                # (B, S, 1, D/2)
-        sin = jnp.sin(angles)[:, :, None, :]
-        x1, x2 = x[..., :half], x[..., half:]
-        rotated = jnp.concatenate([x1 * cos - x2 * sin,
-                                   x1 * sin + x2 * cos], axis=-1)
-        return rotated.astype(x.dtype)
+    its GLOBAL positions to a LOCAL block. Computed under ``hvd_rope`` on
+    the packed (B, S, H·D) rows the flash kernels read wherever H and D
+    pack (``ops/rope.py``): the reshapes at this edge are views."""
+    return rope_lib.rotate(x, positions, base)
 
 
 def _causal_attend(q, k, v, mask=None):

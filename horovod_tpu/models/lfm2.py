@@ -15,7 +15,10 @@ experts, routed by a sigmoid with a selection bias, in the rest.
 - **Attention**: q over ``num_heads`` heads, k and v over ``num_kv_heads``;
   q and k through an RMSNorm over the head's channels (one scale vector
   each, shared by the heads), then rotary positions over the whole head
-  width (``models/gpt.py`` ``rope``); causal flash attention, which is
+  width, a head at a time (``ops/rope.py`` ``rotate_heads``: XLA fuses
+  the halves into the passes of the per-head norm before it; on packed
+  rows, as ``models/gpt.py`` ``rope`` has it, the step held 0.41 GiB
+  more, PERF.md PR 39); causal flash attention, which is
   handed the K/V heads as they are (``ops/flash_attention.py`` groups
   them); then ``W_o``.
 - **Experts**: ``models/solar.py`` ``SparseExperts`` with ``score =
@@ -49,8 +52,8 @@ import jax.numpy as jnp
 
 from ..common import scopes
 from ..ops.flash_attention import flash_attention
+from ..ops.rope import rotate_heads
 from ..ops.short_conv import gated_short_conv
-from .gpt import rope
 from .looplm import RMSNorm, head_losses
 from .solar import SparseExperts, _dense, solar_loss
 
@@ -102,8 +105,8 @@ class RotaryGQA(nn.Module):
             q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
             k, v = (dense(narrow, name=n)(u).reshape(
                 b, s, self.num_kv_heads, -1) for n in ("k", "v"))
-            q = rope(norm(name="q_norm")(q), base=self.rope_base)
-            k = rope(norm(name="k_norm")(k), base=self.rope_base)
+            q = rotate_heads(norm(name="q_norm")(q), base=self.rope_base)
+            k = rotate_heads(norm(name="k_norm")(k), base=self.rope_base)
         o = flash_attention(q, k, v, causal=True)
         with jax.named_scope(scopes.MIXER_PROJ):
             return dense(hidden, name="o")(o.reshape(b, s, wide))

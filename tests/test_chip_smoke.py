@@ -28,7 +28,9 @@ def test_kernels_phase_tiny(capsys):
     worst = chip_smoke.phase_kernels(
         bucket=18 * 4096 - 5, attn_shapes=(((1, 128, 2, 64), True),),
         dtypes=("float32",))
-    assert set(worst) == {"bucket.float32", "flash.float32.1x128x2x64"}
+    assert set(worst) == {"bucket.float32", "flash.float32.1x128x2x64",
+                          "rope.float32.1x128x2x64"}
+    assert set(worst["rope.float32.1x128x2x64"]) == {"fwd", "dx"}
     assert worst["bucket.float32"][
         "quantize_int8_stochastic.q_mismatches"] == 0
     assert _last_json(capsys.readouterr().out)["phase"] == "kernels"

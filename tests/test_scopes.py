@@ -228,6 +228,75 @@ def test_kda_kernels_carry_their_names():
     assert not set(names) & set(scopes.FLASH_KERNELS + scopes.BUCKET_KERNELS)
 
 
+def test_rope_kernels_carry_their_names():
+    """The rotation's kernels meet the same contract (PERF.md, the
+    ``kda_ms`` row): made under ``hvd_rope`` and named with it as their
+    prefix, so ``rope_ms`` finds them by either, and no attention
+    kernel's or bucket kernel's name, so ``flash_ms`` cannot count them."""
+    from horovod_tpu.ops import rope as rope_lib
+
+    x = jnp.ones((1, 64, 2, 64), jnp.bfloat16)
+
+    def loss(x):
+        return rope_lib.rotate(x, use_pallas=True).astype(jnp.float32).sum()
+
+    forward = _pallas_names(jax.make_jaxpr(loss)(x).jaxpr, [])
+    assert forward == [scopes.ROPE_FWD]
+    names = _pallas_names(jax.make_jaxpr(jax.grad(loss))(x).jaxpr, [])
+    assert names == [scopes.ROPE_FWD, scopes.ROPE_BWD] \
+        == list(scopes.ROPE_KERNELS)
+    # made under the scope: a path component of the compiled op_names
+    # before the kernel's own (here, where nothing encloses it, the
+    # differentiated calls' wrap it), through the pass's own ``jit``,
+    # which the layers of a model share and XLA inlines
+    def op_names(fn):
+        with _compiled_here():
+            text = jax.jit(fn).lower(x).compile().as_text()
+        return re.findall(r'op_name="([^"]*)"', text)
+
+    assert [name for name in op_names(loss) if re.search(
+        rf"/{scopes.ROPE}/(jit\(\w+\)/)?{scopes.ROPE_FWD}/", name)]
+    assert [name for name in op_names(jax.grad(loss)) if re.search(
+        rf"/transpose\(jvp\({scopes.ROPE}\)\)/(jit\(\w+\)/)?"
+        rf"{scopes.ROPE_BWD}/", name)]
+    for name in names:
+        assert name.startswith(scopes.ROPE + "_")
+    assert not set(names) & set(scopes.FLASH_KERNELS + scopes.BUCKET_KERNELS
+                                + scopes.KDA_KERNELS)
+
+
+def test_the_benchmarks_readers_count_the_rope_kernels_as_the_rotation():
+    """On a hand-made trace of the two calls as the chip names them, the
+    benchmark's readers (not this PR's to edit) give ``rope_ms`` and
+    ``mixer_proj_ms`` their time, the flash kernels none of it, and the
+    cell's own partition lays them in ``fwd`` and ``bwd``."""
+    from benchmark import hlo_counts, of_which, phase_reduce
+    from benchmark.catalog import Catalog
+
+    call = ('%{}.{} = bf16[8]{{0}} custom-call(%p.1), '
+            'custom_call_target="tpu_custom_call"')
+    under = "layer0/attn/hvd_mixer_proj/hvd_rope/"
+    events = [[call.format(scopes.ROPE_FWD, 4), 0.0, 3e3, "",
+               f"jit(step)/jvp(GPT)/{under}{scopes.ROPE_FWD}/pallas_call", 1],
+              [call.format(scopes.FLASH_FWD, 2), 4e3, 9e3, "",
+               f"jit(step)/jvp(GPT)/layer0/attn/{scopes.FLASH_FWD}"
+               "/pallas_call", 1],
+              [call.format(scopes.ROPE_BWD, 6), 14e3, 5e3, "",
+               f"jit(step)/transpose(jvp(GPT))/{under}{scopes.ROPE_BWD}"
+               "/pallas_call", 1]]
+    trace = {"devices": {"/device:TPU:0": events}, "hlo": {}}
+    got = phase_reduce.reduce_phases(trace, hlo_counts.load_names())
+    assert got["seconds"]["fwd"] == pytest.approx(3e-6)
+    assert got["seconds"]["bwd"] == pytest.approx(5e-6)
+    assert got["seconds"]["flash_fwd"] == pytest.approx(9e-6)
+    assert got["seconds"]["other_kernel"] == 0.0
+    record = {"trace": {"steps": 1},
+              "of_which_trace": of_which._without_loops(trace)}
+    for metric in ("rope_ms", "mixer_proj_ms"):
+        read = Catalog().module("layer_metrics", metric).read
+        assert read(record) == pytest.approx(8e-3)
+
+
 def test_the_flash_backward_call_has_three_outputs():
     q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(_flash_loss(False), argnums=(0, 1, 2)))(
@@ -293,12 +362,13 @@ def _constants():
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES
              + scopes.BLOCK_SCOPES + scopes.FLASH_KERNELS
-             + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS)
+             + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
+             + scopes.ROPE_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 30
+    assert len(values) == len(set(values)) == 32
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries

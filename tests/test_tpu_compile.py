@@ -196,6 +196,100 @@ def test_grouped_query_flash_compiles_for_v5e(v5e, on_tpu):
 
 
 
+# (B, S, H, D), dtype: q of the three GPT cells' kind, of the looped cell
+# (one head a tile), of the gated-convolution cell and its eight K/V
+# heads, and the narrow and wide widths no cell runs.
+ROPE_SHAPES = {"gpt_b32_s512": ((32, 512, 12, 64), jnp.bfloat16),
+               "gpt_b6_s4096": ((6, 4096, 12, 64), jnp.bfloat16),
+               "ouro_b2_s2048_d128": ((2, 2048, 16, 128), jnp.bfloat16),
+               "lfm2_q_b2_s8192": ((2, 8192, 32, 64), jnp.bfloat16),
+               "lfm2_k_b2_s8192": ((2, 8192, 8, 64), jnp.bfloat16),
+               "d16_fp32": ((4, 256, 8, 16), jnp.float32),
+               "d32": ((4, 256, 4, 32), jnp.bfloat16),
+               "d256": ((2, 256, 2, 256), jnp.bfloat16),
+               "gpt_fp32": ((2, 512, 12, 64), jnp.float32)}
+
+
+def _under_rope(hlo):
+    """The opcode of every instruction of the entry computation that lies
+    under ``hvd_rope`` and moves a whole operand: fusions by their kind's
+    name (``pad_maximum_fusion``), the rest by opcode."""
+    found = []
+    for line in hlo[hlo.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \S+ ([\w\-]+)\(", line)
+        if m and "hvd_rope" in line and "[1," not in line.split(" = ")[1][:40]:
+            found.append(re.sub(r"[.\d]+$", "", m.group(1))
+                         if m.group(2) == "fusion" else m.group(2))
+    return found
+
+
+@pytest.mark.parametrize("positions", [False, True],
+                         ids=["arange", "positions"])
+@pytest.mark.parametrize("shape", sorted(ROPE_SHAPES))
+def test_the_rotation_compiles_for_v5e(v5e, on_tpu, shape, positions):
+    """``pltpu.roll`` inside a tile, the lane mask, the tile loop and the
+    blocks ``_block_rows`` picks are met by Mosaic only here. Forward and
+    backward are one kernel call each on a projection's (B, S, H·D) rows
+    as they lie: the reshapes to (B, S, H, D) and back at ``rope``'s edge
+    are views, and no copy, pad, concatenate or transpose of the rows or
+    of their cotangent stands beside the calls."""
+    from horovod_tpu.models.gpt import rope
+
+    (b, s, h, d), dtype = ROPE_SHAPES[shape]
+    specs = [((b, s, h * d), dtype)] + [((b, s), jnp.int32)] * positions
+
+    def loss(rows, *pos):
+        rotated = rope(rows.reshape(b, s, h, d), *pos).reshape(rows.shape)
+        return (rotated.astype(jnp.float32) ** 2).sum()
+
+    hlo = _compile(jax.grad(loss), v5e, *specs)
+    assert _mosaic_calls(hlo) == 2
+    assert "hvd_rope_fwd" in hlo and "hvd_rope_bwd" in hlo
+    kind = _hlo_type(dtype, b, s, h, d)
+    assert len(re.findall(rf"%hvd_rope_(?:fwd|bwd)[\w.]* = {kind} "
+                          r"custom-call\(", hlo)) == 2
+    assert not re.search(rf"\[{b},{s},{h},{d // 2}\]", hlo)
+    entry = hlo[hlo.index("ENTRY"):]
+    for opcode in (" pad(", " concatenate(", " transpose(", " copy("):
+        assert opcode not in entry, opcode
+
+
+def test_a_rotary_attention_layer_hands_its_rows_to_the_kernels_as_they_lie(
+        v5e, on_tpu):
+    """gpt2-small's attention at 32 x S512, forward and backward: q's and
+    k's thirds of the fused projection's rows go through the rotation's
+    kernels and into the flash kernels as (B, S, 768) rows, and back; under
+    ``hvd_rope`` nothing but the four kernel calls and the small tables: no
+    copy, pad or concatenate of a (B, S, ...) operand (the (B, S, H, D)
+    formula left eight copies and four pad fusions a layer there, and
+    ``jnp.roll`` on the packed rows ten and four: PERF.md, PR 39)."""
+    from horovod_tpu.models.gpt import CausalSelfAttention
+
+    b, s, hidden, heads = 32, 512, 768, 12
+    layer = CausalSelfAttention(num_heads=heads)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8, hidden), jnp.bfloat16)))
+
+    def loss(params, x):
+        return (layer.apply(params, x).astype(jnp.float32) ** 2).sum()
+
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=v5e),
+        (params, jax.ShapeDtypeStruct((b, s, hidden), jnp.bfloat16)))
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    assert _mosaic_calls(hlo) == 6
+    rows = re.escape(f"bf16[{b},{s},{hidden}]")
+    for name in ("hvd_rope_fwd", "hvd_rope_bwd"):
+        assert len(re.findall(rf"%{name}[\w.]* = {rows}", hlo)) == 2
+    moved = [op for op in _under_rope(hlo) if op != "custom-call"]
+    assert not [op for op in moved if "copy" in op or "pad" in op
+                or "concatenate" in op or op == "transpose"], moved
+    assert not re.search(rf"\[{b},{s},{heads},{hidden // heads // 2}\]", hlo)
+    assert "dynamic-update-slice" not in hlo[hlo.index("ENTRY"):]
+
+
 def test_the_grouped_expert_matmuls_compile_for_v5e(v5e):
     """``jax.lax.ragged_dot`` at the cell's shapes (a block of 4096 routes to 8
     experts of 4096 x 1280), forward and both transposes: XLA's own
