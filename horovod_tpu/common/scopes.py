@@ -43,6 +43,10 @@ MLP = "hvd_mlp"              # the dense feed-forward (no expert layer's)
 NORM = "hvd_norm"            # the block-level norms and the final norm
 EMBED = "hvd_embed"          # the token (and position) embedding lookup
 LOSS = "hvd_loss"            # models/looplm.py head_losses: after LM_HEAD
+# models/sdar.py sdar_loss: block-diffusion training's noise (a rate a
+# block, a mask a token, drawn on the device from the batch's own seeds)
+# and the assembly of [noisy ; clean] with its positions
+BD_NOISE = "hvd_bd_noise"
 
 # Pallas kernels: the ``name=`` of each ``pallas_call``. FLASH_DKV is the
 # whole flash backward: the dk/dv call also gives dq. FLASH_DQ is carried
@@ -73,6 +77,7 @@ LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 MOE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)   # an expert layer's
 LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
 SHORT_CONV_SCOPES = (SHORT_CONV,)   # a gated-convolution layer's
+BLOCK_DIFFUSION_SCOPES = (BD_NOISE,)    # a block-diffusion loss's
 # a block's parts: ROPE where positions are rotary, LOSS where the
 # cross-entropy is the model's own
 BLOCK_SCOPES = (MIXER_PROJ, ROPE, MLP, NORM, EMBED, LOSS)

@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import scopes
-from ..ops.flash_attention import flash_attention
+from ..ops.flash_attention import CAUSAL, flash_attention
 from ..ops.rope import rotate_heads
 from ..ops.short_conv import gated_short_conv
 from .looplm import RMSNorm, head_losses
@@ -84,8 +84,10 @@ class ShortConv(nn.Module):
 
 
 class RotaryGQA(nn.Module):
-    """Causal softmax attention, ``num_heads`` query heads on
-    ``num_kv_heads`` K/V heads, q and k normed a head and rotated."""
+    """Softmax attention, ``num_heads`` query heads on ``num_kv_heads``
+    K/V heads, q and k normed a head and rotated by ``positions`` (1 or
+    B, S; None: 0 … S-1), under ``mask_kind`` (``ops/flash_attention.py``
+    ``MaskKind``; causal unless told)."""
 
     num_heads: int
     num_kv_heads: int
@@ -93,9 +95,10 @@ class RotaryGQA(nn.Module):
     rope_base: float = 1e6
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    mask_kind: Any = CAUSAL
 
     @nn.compact
-    def __call__(self, u):
+    def __call__(self, u, positions=None):
         b, s, hidden = u.shape
         dense = _dense(self.dtype)
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
@@ -105,9 +108,11 @@ class RotaryGQA(nn.Module):
             q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
             k, v = (dense(narrow, name=n)(u).reshape(
                 b, s, self.num_kv_heads, -1) for n in ("k", "v"))
-            q = rotate_heads(norm(name="q_norm")(q), base=self.rope_base)
-            k = rotate_heads(norm(name="k_norm")(k), base=self.rope_base)
-        o = flash_attention(q, k, v, causal=True)
+            q = rotate_heads(norm(name="q_norm")(q), positions,
+                             self.rope_base)
+            k = rotate_heads(norm(name="k_norm")(k), positions,
+                             self.rope_base)
+        o = flash_attention(q, k, v, mask_kind=self.mask_kind)
         with jax.named_scope(scopes.MIXER_PROJ):
             return dense(hidden, name="o")(o.reshape(b, s, wide))
 
@@ -131,7 +136,9 @@ class DenseFFN(nn.Module):
 class Lfm2Layer(nn.Module):
     """``x + Mix(norm(x))`` then ``x + FFN(norm(x))``; the FFN's stats
     are left behind. ``mixer`` / ``ffn`` are the two classes and
-    ``mixer_args`` / ``ffn_args`` their constructor arguments."""
+    ``mixer_args`` / ``ffn_args`` their constructor arguments; what the
+    layer is called with beside ``x`` (an attention mixer's positions)
+    goes to the mixer."""
 
     mixer: Any
     mixer_args: Tuple
@@ -141,11 +148,11 @@ class Lfm2Layer(nn.Module):
     dtype: Any = jnp.bfloat16
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, *mixer_inputs):
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
         with jax.named_scope(scopes.NORM):
             y = norm(name="op_norm")(x)
-        x = x + self.mixer(*self.mixer_args, name="mixer")(y)
+        x = x + self.mixer(*self.mixer_args, name="mixer")(y, *mixer_inputs)
         with jax.named_scope(scopes.NORM):
             y = norm(name="ffn_norm")(x)
         y, _ = self.ffn(*self.ffn_args, name="ffn")(y)

@@ -232,7 +232,9 @@ class SparseExperts(nn.Module):
     taken of the scores plus a vector ``select_bias`` (num_experts,) that
     no gradient reaches, N(0, 0.02^2) at the start: a balancing term that
     a trainer moves outside the loss, which this layer does not do.
-    Returns ``(y, stats)``, the stats of ``moe.held_experts_layer``."""
+    ``whole_blocks`` is ``moe.held_experts_layer``'s: the routes' blocks
+    worked whole, a step's cost the same whatever its routes. Returns
+    ``(y, stats)``, the stats of ``moe.held_experts_layer``."""
 
     num_experts: int
     held_experts: Tuple[int, int]
@@ -243,6 +245,7 @@ class SparseExperts(nn.Module):
     dtype: Any = jnp.bfloat16
     score: str = "softmax"
     select_bias: bool = False
+    whole_blocks: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -271,7 +274,8 @@ class SparseExperts(nn.Module):
         y, stats = moe.held_experts_layer(
             x.reshape(b * s, hidden), router, *banks, self.num_experts,
             self.held_experts, self.top_k, self.routed_scale,
-            block_rows=block_rows, score=self.score, select_bias=bias)
+            block_rows=block_rows, score=self.score, select_bias=bias,
+            whole_blocks=self.whole_blocks)
         if not self.shared_dim:
             return y.reshape(b, s, hidden), stats
         with jax.named_scope(scopes.MOE_SHARED):

@@ -59,6 +59,25 @@ alone: nothing is repeated in HBM, and the backward writes each q head's
 dk and dv, which are then summed over the group. Where a 128-lane block
 packs several narrow heads, K and V are repeated before the call.
 
+Which (query, key) pairs exist is a static **mask kind** (``MaskKind``),
+carried through both kernels the way the blocks are: it says of a (query
+tile, key tile) pair whether it is empty, full or partial, from the
+tiles' indices alone, and gives the elementwise predicate of the partial
+ones. ``NO_MASK`` (every tile full) and ``CAUSAL`` (the text above) are
+two instances, what ``causal=False`` / ``True`` mean; the third is
+``BlockDiffusionMask(block)`` over a noisy and a clean copy of one
+sequence laid end to end, ``[noisy ; clean]`` of L positions each in
+blocks of ``block`` tokens: a noisy query sees its own noisy block both
+ways and the clean blocks strictly before it, a clean query the clean
+blocks up to its own, nobody a noisy key of another block. Three quarters
+of that 2L square are empty tiles, which neither kernel visits: the
+forward's loop runs over the two key ranges a q block can see, the
+backward's grid steps of an empty pair do nothing and fetch nothing new
+(their block indices are clamped to the nearest visited pair's). The
+blocks tile L, so that no tile straddles the two copies. How many tiles
+a call visits of how many is counted where the call is traced
+(``tiles_visited``, the gauge ``hvd_tpu_flash_attention_tiles``).
+
 ``flash_attention`` (what the models call) has a backward with no lse
 cotangent at all; ``flash_attention_with_lse`` (ring attention's
 blockwise-combine interface) returns the logsumexp as a differentiable
@@ -70,6 +89,7 @@ real kernel bodies in interpret mode.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 import math
@@ -103,9 +123,17 @@ _M_PATHS = metrics_lib.counter(
     "hvd_tpu_flash_attention_traces_total",
     "flash-attention kernel paths traced, by what engaged: sequence "
     "length, head width, dtype, the blocks chosen, key mask operand, "
-    "causal, and whether the lse cotangent is a backward operand",
+    "causal, the mask kind, and whether the lse cotangent is a backward "
+    "operand",
     labels=("seq_len", "head_dim", "dtype", "block_q", "block_k",
-            "has_mask", "causal", "dlse"))
+            "has_mask", "causal", "dlse", "mask_kind"))
+_M_TILES = metrics_lib.gauge(
+    "hvd_tpu_flash_attention_tiles",
+    "(query tile, key tile) pairs of one flash-attention call's S x S "
+    "square, a batch row and head: tiles=\"visited\" those the kernels "
+    "work on under the call's mask kind, tiles=\"square\" all of them; "
+    "static, set where the call is traced",
+    labels=("mask_kind", "seq_len", "block_q", "block_k", "tiles"))
 
 
 def _pick_block(s: int, target: int = 128) -> Optional[int]:
@@ -156,14 +184,16 @@ def _choose_blocks(s: int, d: int, dtype) -> tuple:
     return tq, tk
 
 
-def _resolve_blocks(s, d, dtype, block_q, block_k, interpret):
+def _resolve_blocks(s, d, dtype, block_q, block_k, interpret, span=None):
     """The (bq, bk) a call runs with, or None where no block tiles s.
     ``block_q`` / ``block_k`` of None are chosen from the shape; given,
-    they cap the block."""
+    they cap the block. ``span``: the length the blocks must tile where
+    that is not s itself (``MaskKind.span``)."""
     tq, tk = _choose_blocks(s, d, dtype)
     pick = _pick_block if interpret else _lane_block
-    bq = pick(s, tq if block_q is None else block_q)
-    bk = pick(s, tk if block_k is None else block_k)
+    span = s if span is None else span
+    bq = pick(span, tq if block_q is None else block_q)
+    bk = pick(span, tk if block_k is None else block_k)
     return (bq, bk) if bq and bk else None
 
 
@@ -173,21 +203,26 @@ def _repeat_heads(x, group):
     return x if group == 1 else jnp.repeat(x, group, axis=2)
 
 
-def reference_attention(q, k, v, mask=None, causal=False):
+def reference_attention(q, k, v, mask=None, causal=False, mask_kind=None):
     """Plain softmax attention on (B, S, H, D); ``mask`` is a (B, S) key
-    mask (1 = attend); k and v may hold H / group heads. The jnp fallback
-    and the numerics oracle."""
+    mask (1 = attend); k and v may hold H / group heads; ``mask_kind`` as
+    :func:`flash_attention` takes it, written out as its (S, S) boolean
+    matrix (``MaskKind.dense``). The jnp fallback and the numerics
+    oracle."""
     d = q.shape[-1]
+    kind = _as_kind(mask_kind or causal)
     k, v = (_repeat_heads(x, q.shape[2] // x.shape[2]) for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                         k.astype(jnp.float32)) / np.sqrt(d)
     if mask is not None:
         logits = jnp.where(mask[:, None, None, :] > 0, logits, _NEG)
-    if causal:
+    if kind == CAUSAL:
         s = q.shape[1]
         rows = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
         logits = jnp.where((rows >= cols)[None, None], logits, _NEG)
+    elif kind != NO_MASK:
+        logits = jnp.where(kind.dense(q.shape[1])[None, None], logits, _NEG)
     probs = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs,
                       v.astype(jnp.float32)).astype(q.dtype)
@@ -259,34 +294,256 @@ def _masked(s, kmask, keep):
     return s
 
 
-def _loop_key_blocks(step, init, qi, block_q, block_k, nk, causal):
-    """Run ``step(j, carry, on_diagonal)`` over a q block's k blocks:
-    first those wholly under the diagonal (no causal select), then the
-    ones it crosses; later ones hold nothing visible and are skipped."""
-    if not causal:
-        return jax.lax.fori_loop(0, nk, lambda j, c: step(j, c, False),
-                                 init)
-    n_bare = jax.lax.div(qi * block_q + 1, block_k)
-    n_visible = jnp.minimum(
-        jax.lax.div((qi + 1) * block_q + block_k - 1, block_k), nk)
-    carry = jax.lax.fori_loop(
-        0, n_bare, lambda j, c: step(j, c, False), init)
-    return jax.lax.fori_loop(
-        n_bare, n_visible, lambda j, c: step(j, c, True), carry)
+# -- mask kinds ---------------------------------------------------------------
+#
+# A mask kind is static (hashable, compared by value: it rides where
+# ``causal`` rode, a non-differentiable argument of the ``custom_vjp``) and
+# speaks in tile indices: the kernels ask it which key blocks a q block
+# loops over and which of them need the elementwise select (forward),
+# whether a (q block, k block) grid step is bare, partial or empty and
+# which q block a skipped step should pretend to be (backward). The
+# scalars it is asked with are the kernels' own (program ids, loop
+# counters); ``tiles_visited`` asks the same methods with numbers.
+
+@dataclasses.dataclass(frozen=True)
+class MaskKind:
+    """No structure: every (query, key) pair exists, every tile is full.
+    The base of the others, and what ``causal=False`` means."""
+
+    name = "none"
+
+    def span(self, s):
+        """The length the blocks must tile: no tile may straddle a seam
+        of the mask."""
+        return s
+
+    def dense(self, s):
+        """The (S, S) boolean matrix, rows the queries (numpy)."""
+        return np.ones((s, s), bool)
+
+    def keep(self, s, row0, col0, shape, rows_dim):
+        """The predicate over a partial tile whose first row / column are
+        row0 / col0; ``rows_dim`` is the tile dimension the q rows run
+        along."""
+        raise NotImplementedError("no tile of this kind is partial")
+
+    def key_segments(self, s, qi, block_q, block_k, nk):
+        """``((first, end, partial), ...)``: the ranges of k blocks q
+        block ``qi`` visits, in the order the forward loops over them."""
+        return ((0, nk, False),)
+
+    def tile(self, s, qi, ki, block_q, block_k):
+        """``(bare, visible)`` of one grid step of the backward; a
+        Python ``True`` for ``bare`` says every step is."""
+        return True, True
+
+    def last_key_block(self, s, qi, ki, block_q, block_k, nk, visible):
+        """Whether q block ``qi`` meets no k block after ``ki``: dq's
+        rows are written out then."""
+        return ki == nk - 1
+
+    def first_query_block(self, s, j, i, block_q, block_k):
+        """The q block whose tiles the backward's grid step (k block j,
+        q block i) fetches: i itself where the pair is visited, else a
+        neighbour that is, so that a skipped step fetches nothing new."""
+        return i
 
 
-def _key_block(k_ref, v_ref, m_ref, qi, j, block_q, block_k, on_diagonal):
+@dataclasses.dataclass(frozen=True)
+class _Causal(MaskKind):
+    """rows >= cols: the blocks wholly under the diagonal run bare,
+    those it crosses pay for the select, those above it are skipped."""
+
+    name = "causal"
+
+    def dense(self, s):
+        return np.tril(np.ones((s, s), bool))
+
+    def keep(self, s, row0, col0, shape, rows_dim):
+        return _below_diagonal(row0, col0, shape, rows_dim)
+
+    def key_segments(self, s, qi, block_q, block_k, nk):
+        n_bare = jax.lax.div(qi * block_q + 1, block_k)
+        n_visible = jnp.minimum(
+            jax.lax.div((qi + 1) * block_q + block_k - 1, block_k), nk)
+        return (0, n_bare, False), (n_bare, n_visible, True)
+
+    def tile(self, s, qi, ki, block_q, block_k):
+        bare = qi * block_q >= (ki + 1) * block_k - 1
+        visible = (qi + 1) * block_q > ki * block_k
+        return bare, visible
+
+    def last_key_block(self, s, qi, ki, block_q, block_k, nk, visible):
+        # the next k block starts past this q block's last row
+        return jnp.logical_and(
+            visible, (ki + 1) * block_k >= (qi + 1) * block_q)
+
+    def first_query_block(self, s, j, i, block_q, block_k):
+        return jnp.maximum(i, jax.lax.div(j * block_k, block_q))
+
+
+def _block_of(x, block):
+    """x // block of non-negative int32s: a shift where that says it."""
+    if block & (block - 1) == 0:
+        return jnp.right_shift(x, block.bit_length() - 1)
+    return jax.lax.div(x, jnp.int32(block))
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusionMask(MaskKind):
+    """Block-diffusion training's mask over ``[noisy ; clean]``: two
+    copies of one sequence of L = S / 2 positions laid end to end, cut
+    into blocks of ``block`` tokens, n(i) = (i mod L) // block. Query u
+    sees key w iff
+
+    - both noisy and n(u) == n(w): a block sees itself, both ways;
+    - u noisy, w clean and n(w) < n(u): the clean past, strictly;
+    - both clean and n(w) <= n(u): block-causal;
+
+    and a clean query sees no noisy key. Of the 2L square's tiles the
+    upper right quarter and all of the upper left but its diagonal are
+    empty, the noisy diagonal and the two clean diagonals are partial,
+    and what lies under the clean diagonals is full."""
+
+    block: int = 4
+    name = "block_diffusion"
+
+    def span(self, s):
+        if s % 2 or (s // 2) % self.block:
+            raise ValueError(
+                f"a block-diffusion mask lies over two copies of a "
+                f"sequence in blocks of {self.block}; got S = {s}")
+        return s // 2
+
+    def dense(self, s):
+        half = self.span(s)
+        at = np.arange(s)
+        noisy, n = at < half, (at % half) // self.block
+        un, wn, nu, nw = noisy[:, None], noisy[None], n[:, None], n[None]
+        return (un & wn & (nu == nw)) | (un & ~wn & (nw < nu)) \
+            | (~un & ~wn & (nw <= nu))
+
+    def _local(self, s, at):
+        """``(in the noisy copy, position within its copy)`` of a tile's
+        first row or column: a tile lies within one copy."""
+        half = s // 2
+        noisy = at < half
+        return noisy, at - jnp.where(noisy, 0, half)
+
+    def keep(self, s, row0, col0, shape, rows_dim):
+        # one predicate for the three partial kinds of tile, told apart
+        # by two scalars: n(w) <= n(u) - a and n(w) >= n(u) - c, with
+        # (a, c) = (0, 0) noisy on noisy, (1, all) noisy on clean, (0,
+        # all) clean on clean
+        rn, r0 = self._local(s, row0)
+        cn, c0 = self._local(s, col0)
+        nu = _block_of(r0 + jax.lax.broadcasted_iota(
+            jnp.int32, shape, rows_dim), self.block)
+        nw = _block_of(c0 + jax.lax.broadcasted_iota(
+            jnp.int32, shape, 1 - rows_dim), self.block)
+        a = jnp.where(jnp.logical_and(rn, jnp.logical_not(cn)), 1, 0)
+        c = jnp.where(cn, 0, s)
+        return (nw <= nu - a) & (nw >= nu - c)
+
+    def key_segments(self, s, qi, block_q, block_k, nk):
+        # the noisy copy's keys of the q block's own rows (a noisy q block
+        # alone: no clean one sees them), then the clean copy's keys up to
+        # them, the last of those partial
+        noisy, r0 = self._local(s, qi * block_q)
+        lo = jax.lax.div(r0, block_k)
+        hi = jax.lax.div(r0 + block_q + block_k - 1, block_k)
+        clean = nk // 2
+        return ((lo, jnp.where(noisy, hi, lo), True),
+                (clean, clean + lo, False),
+                (clean + lo, clean + hi, True))
+
+    def tile(self, s, qi, ki, block_q, block_k):
+        rn, r0 = self._local(s, qi * block_q)
+        cn, c0 = self._local(s, ki * block_k)
+        overlap = jnp.logical_and(c0 < r0 + block_q, r0 < c0 + block_k)
+        before = c0 + block_k <= r0
+        clean_key = jnp.logical_not(cn)
+        bare = jnp.logical_and(clean_key, before)
+        visible = jnp.logical_or(
+            bare, jnp.logical_and(overlap, jnp.logical_or(clean_key, rn)))
+        return bare, visible
+
+    def first_query_block(self, s, j, i, block_q, block_k):
+        half = s // 2
+        noisy_key, c0 = self._local(s, j * block_k)
+        lo = jax.lax.div(c0, block_q)
+        hi = jax.lax.div(c0 + block_k + block_q - 1, block_q)
+        clean = half // block_q             # the first clean q block
+        return jnp.where(
+            noisy_key, jnp.clip(i, lo, hi - 1),
+            jnp.where(i < clean, jnp.maximum(i, lo),
+                      jnp.maximum(i, clean + lo)))
+
+
+NO_MASK = MaskKind()
+CAUSAL = _Causal()
+
+
+def _as_kind(kind) -> MaskKind:
+    """``causal``'s two values as the kinds they name."""
+    if isinstance(kind, MaskKind):
+        return kind
+    return CAUSAL if kind else NO_MASK
+
+
+@functools.lru_cache(maxsize=None)
+def _forward_tiles(kind: MaskKind, s: int, block_q: int, block_k: int):
+    """``(visited, square)``: the (q block, k block) pairs of one S x S
+    square the forward's loops run over, and all there are: the kernel's
+    own arithmetic (``key_segments``) asked with numbers."""
+    nq, nk = s // block_q, s // block_k
+    with jax.ensure_compile_time_eval():
+        visited = sum(
+            max(0, int(end) - int(first)) for qi in range(nq)
+            for first, end, _ in kind.key_segments(
+                s, jnp.int32(qi), block_q, block_k, nk))
+    return visited, nq * nk
+
+
+def tiles_visited(kind: MaskKind, s: int, block_q: int, block_k: int):
+    """``(forward, backward, square)``: :func:`_forward_tiles` with, between
+    them, the pairs the backward's grid steps work on (``tile`` asked
+    with numbers, a step at a time: the tests' reading, not a trace's)."""
+    forward, square = _forward_tiles(kind, s, block_q, block_k)
+    with jax.ensure_compile_time_eval():
+        backward = sum(bool(kind.tile(
+            s, jnp.int32(qi), jnp.int32(ki), block_q, block_k)[1])
+            for qi in range(s // block_q) for ki in range(s // block_k))
+    return forward, backward, square
+
+
+def _loop_key_blocks(step, init, qi, block_q, block_k, nk, kind):
+    """Run ``step(j, carry, partial)`` over the k blocks q block ``qi``
+    sees under ``kind`` (a mask kind, or ``causal``'s boolean): range by
+    range, the full ones bare and the partial ones with the select;
+    blocks in no range hold nothing visible and are skipped."""
+    carry = init
+    for first, end, partial in _as_kind(kind).key_segments(
+            nk * block_k, qi, block_q, block_k, nk):
+        carry = jax.lax.fori_loop(
+            first, end, lambda j, c, p=partial: step(j, c, p), carry)
+    return carry
+
+
+def _key_block(k_ref, v_ref, m_ref, qi, j, block_q, block_k, partial,
+               kind=True):
     """What a step of the forward's loop reads for k block j: the K
-    and V tiles (bk, lanes), the key mask (1, bk) or None, and the causal
-    select (bq, bk) or None."""
+    and V tiles (bk, lanes), the key mask (1, bk) or None, and the mask
+    kind's select (bq, bk) or None."""
     ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
     kmask = None if m_ref is None else m_ref[:, ks]
-    keep = _below_diagonal(qi * block_q, j * block_k, (block_q, block_k),
-                           0) if on_diagonal else None
+    keep = _as_kind(kind).keep(
+        k_ref.shape[0], qi * block_q, j * block_k, (block_q, block_k),
+        0) if partial else None
     return k_ref[ks, :], v_ref[ks, :], kmask, keep
 
 
-def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
+def _fwd_kernel(*refs, block_k, kind, scale, has_mask):
     q_ref, k_ref, v_ref = refs[:3]
     m_ref = refs[3] if has_mask else None
     o_ref, lse_ref = refs[-2:]
@@ -298,7 +555,7 @@ def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
 
     def step(j, carry, on_diagonal):
         k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
-                                       block_k, on_diagonal)
+                                       block_k, on_diagonal, kind)
         out = []
         for g, (m, l, acc) in enumerate(carry):
             s = _masked(_scores(q, _head(k, g, heads), on_scores), kmask,
@@ -316,7 +573,7 @@ def _fwd_kernel(*refs, block_k, causal, scale, has_mask):
             jnp.zeros((block_q, 1), jnp.float32),
             jnp.zeros((block_q, lanes), jnp.float32))
     carry = _loop_key_blocks(step, (init,) * heads, qi, block_q, block_k,
-                             nk, causal)
+                             nk, kind)
     outs = []
     for g, (m, l, acc) in enumerate(carry):
         l = jnp.maximum(l, 1e-30)
@@ -336,7 +593,7 @@ def _pt_dst(qg, dog, k, v, lse, dd, on_scores, kmask, keep):
     return pt, pt * (_dot(v, dog, _NT) - dd)
 
 
-def _bwd_kernel(*refs, causal, scale, has_mask):
+def _bwd_kernel(*refs, kind, scale, has_mask):
     """dq, dk and dv of one (q block, k block) of a head group: the
     scores are built once (transposed, k·qᵀ) and feed all three."""
     q_ref, k_ref, v_ref = refs[:3]
@@ -346,6 +603,7 @@ def _bwd_kernel(*refs, causal, scale, has_mask):
     block_q = q_ref.shape[0]
     block_k = k_ref.shape[0]
     heads = lse_ref.shape[0]
+    s = dq_acc.shape[0]
     ki, qi = pl.program_id(2), pl.program_id(3)
     # This q block's rows of dq, which VMEM holds for the whole sequence:
     # zeroed where the first k block meets them, added to at every visible
@@ -367,9 +625,8 @@ def _bwd_kernel(*refs, causal, scale, has_mask):
         k = k_ref[...]                                      # (bk, lanes)
         v = v_ref[...]
         kmask = m_ref[0, :][:, None] if has_mask else None  # (bk, 1)
-        keep = _below_diagonal(qi * block_q, ki * block_k,
-                               (block_k, block_q), 1) if on_diagonal \
-            else None
+        keep = kind.keep(s, qi * block_q, ki * block_k,
+                         (block_k, block_q), 1) if on_diagonal else None
         for g in range(heads):
             qg, dog = _head(q, g, heads), _head(do, g, heads)
             pt, dst = _pt_dst(qg, dog, k, v, lse_ref[g], dd_ref[g],
@@ -383,18 +640,15 @@ def _bwd_kernel(*refs, causal, scale, has_mask):
             # already cast for the MXU (half the vregs through the XLU)
             dq_acc[rows, :] += _dot(dst.T, _head(k, g, heads), _NN)
 
-    if causal:
-        bare = qi * block_q >= (ki + 1) * block_k - 1
-        visible = (qi + 1) * block_q > ki * block_k
+    bare, visible = kind.tile(s, qi, ki, block_q, block_k)
+    if bare is True:
+        block(False)
+    else:
         pl.when(bare)(lambda: block(False))
         pl.when(jnp.logical_and(visible, jnp.logical_not(bare)))(
             lambda: block(True))
-        # the next k block starts past this q block's last row
-        last_k = jnp.logical_and(
-            visible, (ki + 1) * block_k >= (qi + 1) * block_q)
-    else:
-        block(False)
-        last_k = ki == pl.num_programs(2) - 1
+    last_k = kind.last_key_block(s, qi, ki, block_q, block_k,
+                                 pl.num_programs(2), visible)
 
     @pl.when(last_k)
     def _():
@@ -490,19 +744,22 @@ def _q_major_specs(layout, s, bq, group=1):
     return q_spec, kv_spec, m_spec, row_spec
 
 
-def _k_major_specs(layout, s, bq, bk, causal, group=1):
+def _k_major_specs(layout, s, bq, bk, kind, group=1):
     """Block specs of the backward call, grid (B, H/G, S/bk, S/bq), q
     blocks innermost: a q-side tile, a k-side tile, a block of the key
-    mask, a row slice, and dq's tile. Under ``causal`` the q blocks
-    above the diagonal are skipped; their index is clamped to the first
-    visible one so that a skipped step fetches nothing new. dq's tile is
+    mask, a row slice, and dq's tile. ``kind`` is the mask kind (or
+    ``causal``'s boolean): the q blocks of a k block's empty pairs are
+    skipped; their index is clamped to a visited one's
+    (``MaskKind.first_query_block``) so that a skipped step fetches
+    nothing new. dq's tile is
     the whole sequence of a (batch, head group): it stays in VMEM over
     both block dimensions and goes to HBM once. With ``group`` > 1 the
     k-side tile is the group's one K/V head's."""
     kv = _kv_head(group)
+    kind = _as_kind(kind)
 
     def qi(j, i):
-        return jnp.maximum(i, jax.lax.div(j * bk, bq)) if causal else i
+        return kind.first_query_block(s, j, i, bq, bk)
 
     q_spec = layout.tile(bq, lambda b, g, j, i: (b, g, qi(j, i)))
     kv_spec = layout.tile(bk, lambda b, g, j, i: (b, kv(g), j))
@@ -524,11 +781,13 @@ def _compiler_params(s, d, itemsize, bq, bk, backward=False):
         vmem_limit_bytes=min(max(2 * plan, _VMEM_FLOOR), _VMEM_CEIL))
 
 
-def _forward(q, k, v, mask, causal, bq, bk, interpret):
+def _forward(q, k, v, mask, kind, bq, bk, interpret):
     """(o, lse, residuals): o (B, S, H, D) in q's dtype, lse (B, H, S)
     fp32. The residuals are kept in the kernels' layout so the backward
-    moves nothing twice."""
+    moves nothing twice. ``kind``: the mask kind (or ``causal``'s
+    boolean)."""
     b, s, h, d = q.shape
+    kind = _as_kind(kind)
     layout = _Layout(h, d)
     group = h // k.shape[2]
     has_mask = mask is not None
@@ -536,7 +795,7 @@ def _forward(q, k, v, mask, causal, bq, bk, interpret):
     qt, kt, vt = (layout.to_kernel(x) for x in (q, k, v))
     mask3 = mask.astype(jnp.float32)[:, None, :] if has_mask else None
     ot, lse = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_k=bk, causal=causal,
+        functools.partial(_fwd_kernel, block_k=bk, kind=kind,
                           scale=1.0 / np.sqrt(d), has_mask=has_mask),
         grid=(b, layout.groups, s // bq),
         in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask,
@@ -551,11 +810,12 @@ def _forward(q, k, v, mask, causal, bq, bk, interpret):
             (qt, kt, vt, mask3, ot, lse))
 
 
-def _backward(causal, bq, bk, interpret, res, do, dlse):
+def _backward(kind, bq, bk, interpret, res, do, dlse):
     """(dq, dk, dv, None) on (B, S, H, D). ``dlse`` None: the caller has
     no lse output (flash_attention), so no cotangent of it exists."""
     qt, kt, vt, mask3, ot, lse = res
     b, h, _, s = lse.shape
+    kind = _as_kind(kind)
     # Packed operands are (B, S, H·D), per-head ones (B, H, S, D).
     d = qt.shape[-1] // h if qt.ndim == 3 else qt.shape[-1]
     layout = _Layout(h, d)
@@ -572,12 +832,12 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
     dd = dd[:, :, None, :]
     masks = [mask3] * has_mask
     q_spec, kv_spec, m_spec, row_spec, dq_spec = _k_major_specs(
-        layout, s, bq, bk, causal, group)
+        layout, s, bq, bk, kind, group)
     # dk and dv leave a query head at a time: the k-side tile of a call
     # with no grouping, whatever this one's is
-    dkv_spec = _k_major_specs(layout, s, bq, bk, causal)[1]
+    dkv_spec = _k_major_specs(layout, s, bq, bk, kind)[1]
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_kernel, causal=causal,
+        functools.partial(_bwd_kernel, kind=kind,
                           scale=1.0 / np.sqrt(d), has_mask=has_mask),
         grid=(b, layout.groups, s // bk, s // bq),
         in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
@@ -609,41 +869,41 @@ def _backward(causal, bq, bk, interpret, res, do, dlse):
 
 
 # Two interfaces over the same kernels: the need differs by caller (is
-# the logsumexp an output?), not by a knob.
+# the logsumexp an output?), not by a knob. ``kind`` is the mask kind.
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash(q, k, v, mask, causal, bq, bk, interpret):
+def _flash(q, k, v, mask, kind, bq, bk, interpret):
     """o alone: the backward has no lse cotangent to carry."""
-    return _forward(q, k, v, mask, causal, bq, bk, interpret)[0]
+    return _forward(q, k, v, mask, kind, bq, bk, interpret)[0]
 
 
-def _flash_fwd(q, k, v, mask, causal, bq, bk, interpret):
-    o, _, res = _forward(q, k, v, mask, causal, bq, bk, interpret)
+def _flash_fwd(q, k, v, mask, kind, bq, bk, interpret):
+    o, _, res = _forward(q, k, v, mask, kind, bq, bk, interpret)
     return o, res
 
 
-def _flash_bwd(causal, bq, bk, interpret, res, do):
-    return _backward(causal, bq, bk, interpret, res, do, None)
+def _flash_bwd(kind, bq, bk, interpret, res, do):
+    return _backward(kind, bq, bk, interpret, res, do, None)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_lse(q, k, v, mask, causal, bq, bk, interpret):
+def _flash_lse(q, k, v, mask, kind, bq, bk, interpret):
     """(o, lse). lse (B, H, S) is a first-class differentiable output so
     blockwise callers (ring attention) can combine partial results; its
     cotangent folds into the backward kernels' ds."""
-    return _forward(q, k, v, mask, causal, bq, bk, interpret)[:2]
+    return _forward(q, k, v, mask, kind, bq, bk, interpret)[:2]
 
 
-def _flash_lse_fwd(q, k, v, mask, causal, bq, bk, interpret):
-    o, lse, res = _forward(q, k, v, mask, causal, bq, bk, interpret)
+def _flash_lse_fwd(q, k, v, mask, kind, bq, bk, interpret):
+    o, lse, res = _forward(q, k, v, mask, kind, bq, bk, interpret)
     return (o, lse), res
 
 
-def _flash_lse_bwd(causal, bq, bk, interpret, res, cotangents):
-    return _backward(causal, bq, bk, interpret, res, *cotangents)
+def _flash_lse_bwd(kind, bq, bk, interpret, res, cotangents):
+    return _backward(kind, bq, bk, interpret, res, *cotangents)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
@@ -653,7 +913,8 @@ _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def flash_available(seq_len: int, use_pallas: Optional[bool] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None) -> bool:
+                    block_k: Optional[int] = None,
+                    mask_kind: Optional[MaskKind] = None) -> bool:
     """THE availability predicate — single source of truth for every
     reason the kernel path can decline (off-TPU without forcing,
     HVD_TPU_FLASH_ATTENTION=0 escape hatch, un-tileable sequence).
@@ -664,9 +925,10 @@ def flash_available(seq_len: int, use_pallas: Optional[bool] = None,
         return False
     # Whether SOME block tiles seq_len does not depend on D or the dtype
     # (they only shrink the choice, never below one lane tile).
+    span = None if mask_kind is None else mask_kind.span(seq_len)
     return bool(use) and _resolve_blocks(
         seq_len, _LANE, jnp.float32, block_q, block_k,
-        interpret) is not None
+        interpret, span) is not None
 
 
 @functools.lru_cache(maxsize=None)  # once per shape, not per trace
@@ -680,11 +942,13 @@ def _warn_untileable(shape, block_q, block_k):
 
 
 @functools.lru_cache(maxsize=None)  # once per distinct call shape
-def _say_path(shape, dtype, bq, bk, has_mask, causal, dlse):
+def _say_path(shape, dtype, bq, bk, has_mask, kind, dlse, tiles):
     logger.info(
         "flash_attention: q%s %s runs the Pallas kernels with "
-        "block_q=%d block_k=%d has_mask=%s causal=%s dlse_operand=%s",
-        tuple(shape), dtype, bq, bk, has_mask, causal, dlse)
+        "block_q=%d block_k=%d has_mask=%s causal=%s dlse_operand=%s "
+        "mask_kind=%s (%d of %d tiles visited)",
+        tuple(shape), dtype, bq, bk, has_mask, kind == CAUSAL, dlse,
+        kind.name, *tiles)
 
 
 def _kv_heads_for_kernels(q, k, v):
@@ -699,62 +963,77 @@ def _kv_heads_for_kernels(q, k, v):
     return k, v
 
 
-def _engage(q, mask, causal, use_pallas, block_q, block_k, dlse):
-    """(bq, bk, interpret) of the kernel path for this call, or None
-    where flash_available declines; says which path engaged."""
+def _engage(q, mask, kind, use_pallas, block_q, block_k, dlse):
+    """(kind, bq, bk, interpret) of the kernel path for this call, or
+    None where flash_available declines; says which path engaged."""
     _, s, _, d = q.shape
     use, interpret = _decide(use_pallas)
-    if not flash_available(s, use_pallas, block_q, block_k):
+    if not flash_available(s, use_pallas, block_q, block_k, kind):
         if use and not interpret \
                 and runtime_env("FLASH_ATTENTION", "1") != "0":
             _warn_untileable(q.shape, block_q, block_k)
         return None
-    bq, bk = _resolve_blocks(s, d, q.dtype, block_q, block_k, interpret)
+    bq, bk = _resolve_blocks(s, d, q.dtype, block_q, block_k, interpret,
+                             kind.span(s))
     has_mask = mask is not None
-    _say_path(q.shape, q.dtype.name, bq, bk, has_mask, causal, dlse)
+    forward, square = _forward_tiles(kind, s, bq, bk)
+    _say_path(q.shape, q.dtype.name, bq, bk, has_mask, kind, dlse,
+              (forward, square))
     _M_PATHS.labels(seq_len=str(s), head_dim=str(d), dtype=q.dtype.name,
                     block_q=str(bq), block_k=str(bk),
                     has_mask=str(has_mask).lower(),
-                    causal=str(bool(causal)).lower(),
-                    dlse=str(dlse).lower()).inc()
-    return bq, bk, interpret
+                    causal=str(kind == CAUSAL).lower(),
+                    dlse=str(dlse).lower(), mask_kind=kind.name).inc()
+    for tiles, n in (("visited", forward), ("square", square)):
+        _M_TILES.labels(mask_kind=kind.name, seq_len=str(s),
+                        block_q=str(bq), block_k=str(bk),
+                        tiles=tiles).set(n)
+    return kind, bq, bk, interpret
 
 
 def flash_attention_with_lse(q, k, v, mask=None, causal: bool = False,
                              use_pallas: Optional[bool] = None,
                              block_q: Optional[int] = None,
-                             block_k: Optional[int] = None):
+                             block_k: Optional[int] = None,
+                             mask_kind: Optional[MaskKind] = None):
     """Like :func:`flash_attention` but also returns the per-row
     logsumexp (B, H, S) — the blockwise-combination interface ring
     attention stitches partial results with. Both outputs are
     differentiable (the lse cotangent folds into the backward kernels).
     Returns None when :func:`flash_available` declines, so callers use
     their own reference path."""
-    path = _engage(q, mask, causal, use_pallas, block_q, block_k, True)
+    path = _engage(q, mask, _as_kind(mask_kind or causal), use_pallas,
+                   block_q, block_k, True)
     if path is None:
         return None
-    return _flash_lse(q, *_kv_heads_for_kernels(q, k, v), mask, causal,
-                      *path)
+    return _flash_lse(q, *_kv_heads_for_kernels(q, k, v), mask, *path)
 
 
 def flash_attention(q, k, v, mask=None, causal: bool = False,
                     use_pallas: Optional[bool] = None,
                     block_q: Optional[int] = None,
-                    block_k: Optional[int] = None):
+                    block_k: Optional[int] = None,
+                    mask_kind: Optional[MaskKind] = None):
     """Blockwise online-softmax attention on (B, S, H, D), returned in
     q's dtype. k and v may hold fewer heads, (B, S, Hkv, D) with H a
     multiple of Hkv (grouped-query attention).
 
-    ``mask``: optional (B, S) key mask (1 = attend). ``use_pallas=None``
+    ``mask``: optional (B, S) key mask (1 = attend). ``mask_kind``: which
+    (query, key) pairs exist, a static ``MaskKind``: ``NO_MASK`` and
+    ``CAUSAL`` are what ``causal=False`` / ``True`` say (and what None
+    leaves to ``causal``), ``BlockDiffusionMask(block)`` the mask of
+    block-diffusion training over ``[noisy ; clean]``; tiles the kind
+    leaves empty are not visited. ``use_pallas=None``
     auto-selects the Pallas kernel on TPU with a jnp fallback elsewhere;
     ``True`` forces the kernel (interpret mode off-TPU — the test path).
     ``block_q`` / ``block_k``: None lets the code choose from the shape;
     a number caps the block. Differentiable via the flash backward
     kernels."""
-    path = _engage(q, mask, causal, use_pallas, block_q, block_k, False)
+    kind = _as_kind(mask_kind or causal)
+    path = _engage(q, mask, kind, use_pallas, block_q, block_k, False)
     if path is None:
-        return reference_attention(q, k, v, mask, causal)
-    return _flash(q, *_kv_heads_for_kernels(q, k, v), mask, causal, *path)
+        return reference_attention(q, k, v, mask, mask_kind=kind)
+    return _flash(q, *_kv_heads_for_kernels(q, k, v), mask, *path)
 
 
 def attend(q, k, v, mask=None):

@@ -506,9 +506,9 @@ def _expert_block(xg, weights, valid, w_gate, w_up, w_down, sizes):
     return jnp.where(valid[:, None], y * weights[:, None], 0.0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 8))
 def _grouped_experts(rungs, x, w_gate, w_up, w_down, weights, tokens,
-                     group_sizes):
+                     group_sizes, loop_rows=None):
     """Sum over the routes of weight * expert(x[token]), as (T, D) fp32.
     ``tokens`` / ``weights``: the routes sorted by expert (a multiple of
     ``block_rows = rungs[-1]`` long; only the first ``group_sizes.sum()``
@@ -517,14 +517,25 @@ def _grouped_experts(rungs, x, w_gate, w_up, w_down, weights, tokens,
     outside it: the usual step fills one block and pays for no second.
     That first block works on the smallest of the static row counts
     ``rungs`` that holds the routes (:func:`first_block_rungs`): the rows
-    past them are rows no route fills."""
+    past them are rows no route fills. With ``loop_rows`` the loop's
+    blocks are that long and not ``block_rows`` (:func:`_worked_whole`:
+    ``tokens`` is then ``block_rows`` and a multiple of ``loop_rows``
+    long)."""
     return _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights,
-                                tokens, group_sizes)[0]
+                                tokens, group_sizes, loop_rows)[0]
 
 
-def _route_block(rows, tokens, weights, group_sizes, block):
-    start = block * rows
-    sizes = _block_group_sizes(group_sizes, block, rows)
+def _route_block(rows, tokens, weights, group_sizes, block, offset=0):
+    """Block ``block`` of ``rows`` rows, ``offset`` rows further on where
+    the blocks before it were not all ``rows`` long."""
+    if offset:
+        start = block * rows + offset
+        ends = jnp.cumsum(group_sizes)
+        sizes = jnp.clip(jnp.minimum(ends, start + rows)
+                         - jnp.maximum(ends - group_sizes, start), 0)
+    else:
+        start = block * rows
+        sizes = _block_group_sizes(group_sizes, block, rows)
     valid = start + jnp.arange(rows) < group_sizes.sum()
     return (lax.dynamic_slice_in_dim(tokens, start, rows),
             lax.dynamic_slice_in_dim(weights, start, rows),
@@ -546,16 +557,28 @@ def _first_block(rungs, rung, add_block, carry):
                              for rows in rungs], carry)
 
 
-def _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights, tokens,
-                         group_sizes):
+def _loop_blocks(rungs, loop_rows, routes):
+    """``(rows of a block of the loop, how far its blocks are offset,
+    blocks in all)``: the loop's blocks follow a first block of
+    ``rungs[-1]`` rows, which is their own length unless ``loop_rows``
+    says another."""
     block_rows = rungs[-1]
+    if loop_rows is None:
+        return block_rows, 0, -(-routes // block_rows)
+    return loop_rows, block_rows - loop_rows, \
+        1 + -(-jnp.maximum(routes - block_rows, 0) // loop_rows)
+
+
+def _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights, tokens,
+                         group_sizes, loop_rows=None):
     banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
-    blocks = -(-group_sizes.sum() // block_rows)
+    block_rows, offset, blocks = _loop_blocks(rungs, loop_rows,
+                                              group_sizes.sum())
     rung = _rung_taken(rungs, group_sizes.sum())
 
-    def add_block(rows, block, out):
+    def add_block(rows, block, out, offset=0):
         idx, wts, valid, sizes = _route_block(
-            rows, tokens, weights, group_sizes, block)
+            rows, tokens, weights, group_sizes, block, offset)
         return out.at[idx].add(
             _expert_block(x[idx], wts, valid, *banks, sizes))
 
@@ -563,25 +586,26 @@ def _grouped_experts_fwd(rungs, x, w_gate, w_up, w_down, weights, tokens,
                        jnp.zeros(x.shape, jnp.float32))
     out = lax.while_loop(
         lambda c: c[0] < blocks,
-        lambda c: (c[0] + 1, add_block(block_rows, *c)), (1, out))[1]
+        lambda c: (c[0] + 1, add_block(block_rows, *c, offset)),
+        (1, out))[1]
     return out, (x, w_gate, w_up, w_down, weights, tokens, group_sizes,
                  rung)
 
 
-def _grouped_experts_bwd(rungs, residuals, dout):
+def _grouped_experts_bwd(rungs, loop_rows, residuals, dout):
     """A block at a time, as the forward went (the first at the forward's
     rung): the block's forward again and its transpose (JAX's, of the
     three grouped matmuls); dx scattered back to the tokens, the banks'
     gradients summed in fp32."""
     x, w_gate, w_up, w_down, weights, tokens, group_sizes, rung = residuals
-    block_rows = rungs[-1]
     banks = tuple(w.astype(x.dtype) for w in (w_gate, w_up, w_down))
-    blocks = -(-group_sizes.sum() // block_rows)
+    block_rows, offset, blocks = _loop_blocks(rungs, loop_rows,
+                                              group_sizes.sum())
 
-    def add_block(rows, block, carry):
+    def add_block(rows, block, carry, offset=0):
         dx, dweights, dbanks = carry
         idx, wts, valid, sizes = _route_block(
-            rows, tokens, weights, group_sizes, block)
+            rows, tokens, weights, group_sizes, block, offset)
         _, vjp = jax.vjp(
             lambda xg, w, *b: _expert_block(xg, w, valid, *b, sizes),
             x[idx], wts, *banks)
@@ -589,7 +613,8 @@ def _grouped_experts_bwd(rungs, residuals, dout):
         db = tuple(b.astype(jnp.float32) for b in db)
         return (dx.at[idx].add(dxg.astype(jnp.float32)),
                 lax.dynamic_update_slice_in_dim(
-                    dweights, dwts, block * rows, 0),
+                    dweights, dwts,
+                    block * rows + offset if offset else block * rows, 0),
                 # the first block's are the sum so far: nothing to add to
                 db if dbanks is None else tuple(
                     a + b for a, b in zip(dbanks, db)))
@@ -599,7 +624,8 @@ def _grouped_experts_bwd(rungs, residuals, dout):
                           jnp.zeros_like(weights), None))
     dx, dweights, dbanks = lax.while_loop(
         lambda c: c[0] < blocks,
-        lambda c: (c[0] + 1, add_block(block_rows, *c)), (1, carry))[1]
+        lambda c: (c[0] + 1, add_block(block_rows, *c, offset)),
+        (1, carry))[1]
     return (dx.astype(x.dtype),
             *(g.astype(w.dtype) for g, w in
               zip(dbanks, (w_gate, w_up, w_down))),
@@ -656,11 +682,40 @@ def first_block_rungs(expected: float, block_rows: int) -> Tuple[int, ...]:
         rungs = (rows, *rungs)
 
 
+def _spill_rows(block_rows: int) -> int:
+    """Rows of the blocks that take the routes past a whole first block:
+    a fifth of it (half a balanced share where the block is the default
+    two and a half), a multiple of the grouped matmul's row tile."""
+    tile = 512 if block_rows >= 5 * 512 else 8
+    return -(-block_rows // (5 * tile)) * tile
+
+
+def _worked_whole(block_rows, x, w_gate, w_up, w_down, weights, tokens,
+                  group_sizes):
+    """:func:`_grouped_experts` at a cost that does not follow the routes:
+    the first ``block_rows`` rows of the sorted routes are ONE block, and
+    the routes past it, where a step has any, go through the loop in
+    blocks of :func:`_spill_rows`; the groups are made to fill every block
+    that runs: the rows past the routes are the last group's, at weight 0
+    (their products are exact zeros in the result, in dx and in the banks'
+    gradients). Returns ``(y, rows worked on)``."""
+    small = _spill_rows(block_rows)
+    routes = group_sizes.sum()
+    worked = block_rows \
+        + -(-jnp.maximum(routes - block_rows, 0) // small) * small
+    weights = jnp.where(jnp.arange(tokens.size) < routes, weights, 0.0)
+    y = _grouped_experts((block_rows,), x, w_gate, w_up, w_down, weights,
+                         tokens, group_sizes.at[-1].add(worked - routes),
+                         small)
+    return y, worked
+
+
 def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
                        held: Tuple[int, int], top_k: int,
                        scale: float = 1.0,
                        block_rows: Optional[int] = None,
-                       score: str = "softmax", select_bias=None):
+                       score: str = "softmax", select_bias=None,
+                       whole_blocks: bool = False):
     """The routed experts' part of an MoE layer that the experts held on
     this rank give: ``held = (first, count)`` of ``num_experts``, their
     SwiGLU banks ``w_gate``, ``w_up`` (count, D, F) and ``w_down``
@@ -674,7 +729,14 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
     run through the grouped matmuls ``block_rows`` at a time
     (:func:`default_block_rows`), in as many blocks as they fill, the
     first on the rows its routes fill (:func:`first_block_rungs`; an
-    explicit ``block_rows`` is one rung). Summed
+    explicit ``block_rows`` is one rung). With ``whole_blocks`` the first
+    ``block_rows`` routes go through one block worked whole: the rows no
+    route fills are handed to the last held expert at weight 0 and add
+    exact zeros to the result and to every gradient, so a step costs the
+    same whatever its routes are while they fit the block; routes past
+    it, where there are any, take blocks a fifth that size, whole too
+    (:func:`_worked_whole`). The price is the grouped matmuls on the rows
+    a balanced router would not have filled. Summed
     over the ranks that hold all the experts, the results are the whole
     routed layer; over an ep axis it is what the exchange would feed
     (``first = ep_index * count``), and it adds no exchange.
@@ -701,21 +763,32 @@ def held_experts_layer(x, router_w, w_gate, w_up, w_down, num_experts: int,
         key = jnp.where(is_held, local, count)      # the others sort last
         order = jnp.argsort(key, stable=True)
         pad = -order.size % block_rows
+        if whole_blocks:
+            spill = -(-max(order.size - block_rows, 0)
+                      // _spill_rows(block_rows)) * _spill_rows(block_rows)
+            pad = block_rows + spill - order.size
         tokens = jnp.pad(order // top_k, (0, pad)).astype(jnp.int32)
         sorted_weights = jnp.pad(weights.reshape(-1)[order], (0, pad))
         group_sizes = jnp.bincount(key, length=count + 1)[:count] \
             .astype(jnp.int32)
     with jax.named_scope(scopes.MOE_EXPERTS):
-        y = _grouped_experts(rungs, x, w_gate, w_up, w_down,
-                             sorted_weights, tokens, group_sizes)
+        if whole_blocks:
+            y, worked = _worked_whole(block_rows, x, w_gate, w_up, w_down,
+                                      sorted_weights, tokens, group_sizes)
+        else:
+            y = _grouped_experts(rungs, x, w_gate, w_up, w_down,
+                                 sorted_weights, tokens, group_sizes)
     routes = group_sizes.sum()
-    n_blocks = tokens.size // block_rows
-    ran = -(-routes // block_rows)
-    done = _block_group_sizes(group_sizes, jnp.arange(n_blocks),
-                              block_rows).sum(-1)
-    computed = jnp.where(jnp.arange(n_blocks) < ran, done, 0).sum()
-    worked = jnp.asarray(rungs)[_rung_taken(rungs, routes)] \
-        + block_rows * (jnp.maximum(ran, 1) - 1)
+    if whole_blocks:
+        computed = routes           # every group is handed to a block
+    else:
+        n_blocks = tokens.size // block_rows
+        ran = -(-routes // block_rows)
+        done = _block_group_sizes(group_sizes, jnp.arange(n_blocks),
+                                  block_rows).sum(-1)
+        computed = jnp.where(jnp.arange(n_blocks) < ran, done, 0).sum()
+        worked = jnp.asarray(rungs)[_rung_taken(rungs, routes)] \
+            + block_rows * (jnp.maximum(ran, 1) - 1)
     stats = {"expert_load": group_sizes.astype(jnp.float32),
              "local_routes": routes.astype(jnp.float32),
              "dropped_tokens": (routes - computed).astype(jnp.float32),

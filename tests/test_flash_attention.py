@@ -660,3 +660,165 @@ def test_flash_fuzz_matches_reference(seed):
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=3e-4, atol=3e-4)
+
+
+# -- mask kinds ---------------------------------------------------------------
+
+# [noisy ; clean] of L = 128 in blocks of 4; the pairs of tile sizes put
+# empty, full and partial tiles in one call (S256: 8 x 8 tiles of 32, 4 x 16
+# of (64, 16), ...), and a tile as long as L itself.
+BD = fa.BlockDiffusionMask(4)
+BD_BLOCKS = [(32, 32), (64, 16), (16, 64), (128, 8), (8, 128)]
+
+
+def _explicit(dense):
+    """Plain softmax attention under an explicit (S, S) boolean matrix."""
+    def attend(q, k, v):
+        k, v = (fa._repeat_heads(x, q.shape[2] // x.shape[2])
+                for x in (k, v))
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        logits = jnp.where(jnp.asarray(dense)[None, None], logits, -jnp.inf)
+        return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), v)
+    return attend
+
+
+def test_the_block_diffusion_mask_is_the_four_rules():
+    """Written out for L = 8, block 4: rows the queries, [noisy ; clean]."""
+    own = np.kron(np.eye(2, dtype=bool), np.ones((4, 4), bool))
+    past = np.kron(np.tril(np.ones((2, 2), bool), -1), np.ones((4, 4), bool))
+    want = np.block([[own, past], [np.zeros((8, 8), bool), own | past]])
+    assert np.array_equal(BD.dense(16), want)
+    assert np.array_equal(fa.CAUSAL.dense(5), np.tril(np.ones((5, 5), bool)))
+    assert fa.NO_MASK.dense(3).all()
+    with pytest.raises(ValueError, match="two copies"):
+        BD.span(2 * 6)
+    assert fa.BlockDiffusionMask(4) == BD != fa.BlockDiffusionMask(8)
+    assert len({fa.NO_MASK, fa.CAUSAL, BD, fa.BlockDiffusionMask(4)}) == 3
+
+
+@pytest.mark.parametrize("kind", [fa.NO_MASK, fa.CAUSAL, BD],
+                         ids=lambda k: k.name)
+def test_reference_attention_takes_the_mask_kind(rng, kind):
+    q, k, v = _qkv(rng, d=16)
+    got = reference_attention(q, k, v, mask_kind=kind)
+    np.testing.assert_allclose(got, _explicit(kind.dense(S))(q, k, v),
+                               rtol=1e-5, atol=1e-6)
+    if kind != BD:      # the two kinds ``causal`` names
+        assert np.array_equal(got, reference_attention(
+            q, k, v, causal=kind == fa.CAUSAL))
+
+
+@pytest.mark.parametrize("kv_heads", [2, 1], ids=["mha", "gqa"])
+@pytest.mark.parametrize("blocks", BD_BLOCKS,
+                         ids=[f"bq{a}_bk{b}" for a, b in BD_BLOCKS])
+def test_the_kernels_under_the_block_diffusion_mask(rng, blocks, kv_heads):
+    """Forward and every gradient, the kernels' bodies in interpret mode
+    against an explicit boolean mask."""
+    q, k, v = _qkv(rng, d=16)
+    k, v = k[:, :, :kv_heads], v[:, :, :kv_heads]
+    explicit = _explicit(BD.dense(S))
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, mask_kind=BD, use_pallas=True,
+                               **_kw(blocks))
+
+    np.testing.assert_allclose(kernels(q, k, v), explicit(q, k, v),
+                               rtol=2e-5, atol=2e-5)
+    for got, want in zip(_grads(kernels, q, k, v),
+                         _grads(explicit, q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_the_block_diffusion_mask_with_a_key_mask_and_an_lse(rng):
+    """The kind composes with the (B, S) key mask, and the lse interface
+    carries it (a live lse cotangent)."""
+    q, k, v = _qkv(rng, d=16)
+    mask = _mask(rng, "keys")
+    mask[:, S // 2:] = 1.0      # every row keeps a visible key
+    dense = BD.dense(S)[None] & (mask[:, None, :] > 0)
+    w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
+
+    def explicit(q, k, v):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(16)
+        logits = jnp.where(jnp.asarray(dense)[:, None], logits, -jnp.inf)
+        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1),
+                           v), jax.nn.logsumexp(logits, -1))
+
+    def kernels(q, k, v):
+        return flash_attention_with_lse(q, k, v, mask=mask, mask_kind=BD,
+                                        use_pallas=True, block_q=32,
+                                        block_k=64)
+
+    for got, want in zip(kernels(q, k, v), explicit(q, k, v)):
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    got = jax.grad(_lse_loss(kernels, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_lse_loss(explicit, w), argnums=(0, 1, 2))(q, k, v)
+    for g, e in zip(got, want):
+        np.testing.assert_allclose(g, e, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("blocks", BD_BLOCKS + [(8, 8)],
+                         ids=lambda b: f"bq{b[0]}_bk{b[1]}")
+@pytest.mark.parametrize("kind", [fa.NO_MASK, fa.CAUSAL, BD],
+                         ids=lambda k: k.name)
+def test_empty_tiles_are_not_visited(kind, blocks):
+    """The kernels' own arithmetic asked with numbers (``tiles_visited``):
+    the forward's loops and the backward's grid steps work on exactly the
+    tiles that hold a visible pair, and know a tile that holds nothing
+    but visible pairs from one that needs the select."""
+    bq, bk = blocks
+    dense = kind.dense(S)
+    tiles = dense.reshape(S // bq, bq, S // bk, bk)
+    some, every = tiles.any((1, 3)), tiles.all((1, 3))
+    forward, backward, square = fa.tiles_visited(kind, S, bq, bk)
+    assert square == some.size
+    assert forward == backward == some.sum()
+    with jax.ensure_compile_time_eval():
+        for qi in range(S // bq):
+            seen = np.zeros(S // bk, bool)
+            for first, end, partial in kind.key_segments(
+                    S, jnp.int32(qi), bq, bk, S // bk):
+                ks = np.arange(int(first), int(end))
+                assert not seen[ks].any()       # no tile twice
+                seen[ks] = True
+                # a bare tile holds visible pairs alone
+                assert partial or every[qi, ks].all()
+            assert np.array_equal(seen, some[qi])
+            for ki in range(S // bk):
+                bare, visible = kind.tile(S, jnp.int32(qi), jnp.int32(ki),
+                                          bq, bk)
+                assert bool(visible) == some[qi, ki]
+                assert not bool(bare) or every[qi, ki]
+                # a skipped step fetches a visited pair's tiles
+                at = int(kind.first_query_block(S, jnp.int32(ki),
+                                                jnp.int32(qi), bq, bk))
+                assert some[at, ki] and (at == qi or not some[qi, ki])
+    if kind == BD and bq == bk == 32:
+        assert (forward, square) == (24, 64)    # 4 + 2 x (4 + 3 + 2 + 1)
+
+
+def test_the_mask_kind_is_said_and_counted(rng, caplog):
+    import logging
+
+    from horovod_tpu.common import metrics
+
+    fa._say_path.cache_clear()
+    q, k, v = _qkv(rng, s=64, d=16)
+    with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+        flash_attention(q, k, v, mask_kind=BD, use_pallas=True, block_q=16,
+                        block_k=16)
+    said = [r.getMessage() for r in caplog.records
+            if "flash_attention" in r.getMessage()]
+    assert len(said) == 1 and "mask_kind=block_diffusion" in said[0] \
+        and "causal=False" in said[0] and "(8 of 16 tiles visited)" in said[0]
+    snapshot = metrics.snapshot()
+    assert any(s["labels"].get("mask_kind") == "block_diffusion"
+               and s["labels"]["seq_len"] == "64"
+               for s in snapshot["hvd_tpu_flash_attention_traces_total"][
+                   "samples"])
+    tiles = {s["labels"]["tiles"]: s["value"]
+             for s in snapshot["hvd_tpu_flash_attention_tiles"]["samples"]
+             if s["labels"]["mask_kind"] == "block_diffusion"
+             and s["labels"]["seq_len"] == "64"
+             and s["labels"]["block_q"] == "16"}
+    assert tiles == {"visited": 8, "square": 16}   # 2 + 2 x (1 + 2)

@@ -81,6 +81,88 @@ def test_the_held_experts_part_and_its_gradients(layer, held, block_rows):
     assert float(stats["dropped_tokens"]) == 0.0
 
 
+# held experts, rows a block: the default block with room to spare; blocks
+# so small that the routes spill past the first, into one further block
+# and into several; a share at the end of the router's columns.
+@pytest.mark.parametrize("held, block_rows", [
+    ((0, 16), None), ((4, 4), 40), ((4, 4), 8), ((4, 4), None),
+    ((12, 4), 16)])
+def test_whole_blocks_give_what_the_ladder_gives(layer, held, block_rows):
+    """``whole_blocks``: the first ``block_rows`` routes are one block
+    whose groups fill it (the rows no route fills are the last held
+    expert's, at weight 0), the routes past it go through the loop in
+    blocks a fifth that size, filled the same way. The layer's result, every gradient
+    and the routes' stats are those of the ladder."""
+    a, b = held[0], sum(held)
+    keys = ("x", "router", "gate", "up", "down")
+
+    def run(whole, *args):
+        p = dict(zip(keys, args))
+        return moe.held_experts_layer(
+            p["x"], p["router"], p["gate"][a:b], p["up"][a:b],
+            p["down"][a:b], E, held, K, block_rows=block_rows,
+            whole_blocks=whole)
+
+    args = [layer[k] for k in keys]
+    seen = []
+    grouped = moe._grouped_experts
+
+    def spy(rungs, *rest):
+        seen.append((rungs, int(rest[-2].sum()), rest[-1]))
+        return grouped(rungs, *rest)
+
+    with jax.default_matmul_precision("highest"):
+        want, want_stats = run(False, *args)
+        moe._grouped_experts = spy
+        try:
+            got, got_stats = run(True, *args)
+        finally:
+            moe._grouped_experts = grouped
+        grads = [jax.grad(lambda *a: (run(whole, *a)[0] ** 2).sum(),
+                          argnums=range(5))(*args) for whole in (True, False)]
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    for ours, theirs in zip(*grads):
+        np.testing.assert_allclose(ours, theirs, atol=1e-5)
+    for k in ("expert_load", "local_routes", "dropped_tokens"):
+        assert got_stats[k].tolist() == want_stats[k].tolist()
+    block = block_rows or moe.default_block_rows(T, K, held[1], E)
+    small = moe._spill_rows(block)
+    routes = int(want_stats["local_routes"])
+    spilled = -(-max(routes - block, 0) // small) * small
+    # one rung: no ladder; the groups fill every block that runs, the
+    # first of ``block`` rows and the loop's of ``small``
+    assert seen == [((block,), block + spilled, small)]
+    assert float(got_stats["worked_rows"]) == block + spilled
+
+
+def test_a_whole_block_works_on_the_same_rows_whatever_the_routes(layer):
+    """What ``whole_blocks`` is for. A router that sends the held experts
+    next to nothing and a balanced one hand the grouped matmuls the same
+    40,960 rows (the ladder takes its lower rung, 19,456, for both); a
+    router that sends them nearly every token's four routes (59,648, past
+    the block) pays three further blocks of 8,192 rows, where the
+    ladder's loop pays a second block of 40,960."""
+    x = jnp.abs(jnp.tile(layer["x"], (256, 1)))       # 16,384 rows
+    shifted = {name: layer["router"].at[:, 4:8].add(by)
+               for name, by in (("none", -1.0), ("fair", 0.0), ("all", 1.0))}
+    stats = {(whole, name): moe.held_experts_layer(
+        x, router, layer["gate"][4:8], layer["up"][4:8], layer["down"][4:8],
+        E, (4, 4), K, whole_blocks=whole)[1]
+        for whole in (False, True) for name, router in shifted.items()}
+    routes = {k: float(v["local_routes"]) for k, v in stats.items()}
+    worked = {k: float(v["worked_rows"]) for k, v in stats.items()}
+    block = moe.default_block_rows(256 * T, K, 4, E)
+    assert block == 40960 and moe._spill_rows(block) == 8192
+    assert routes[True, "none"] < 1000 < routes[True, "fair"] < block
+    assert block + 2 * 8192 < routes[True, "all"] <= block + 3 * 8192
+    assert routes == {(w, n): routes[True, n] for w, n in routes}
+    assert worked[True, "none"] == worked[True, "fair"] == block
+    assert worked[False, "none"] == worked[False, "fair"] == 19456
+    assert worked[True, "all"] == block + 3 * 8192
+    assert worked[False, "all"] == 2 * block
+    assert all(float(v["dropped_tokens"]) == 0 for v in stats.values())
+
+
 def test_the_shares_add_up_to_the_whole_routed_layer(layer):
     """16 experts in 4 shares of 4: the parts the four ranks compute add
     up to the part of a rank that holds every expert."""

@@ -361,14 +361,15 @@ def _constants():
 
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES
-             + scopes.BLOCK_SCOPES + scopes.FLASH_KERNELS
+             + scopes.BLOCK_SCOPES + scopes.BLOCK_DIFFUSION_SCOPES
+             + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
              + scopes.ROPE_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 32
+    assert len(values) == len(set(values)) == 33
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
@@ -376,6 +377,7 @@ def test_each_name_is_written_once():
                                  "hvd_moe_shared")
     assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
     assert scopes.SHORT_CONV_SCOPES == ("hvd_short_conv",)
+    assert scopes.BLOCK_DIFFUSION_SCOPES == ("hvd_bd_noise",)
     assert scopes.BLOCK_SCOPES == ("hvd_mixer_proj", "hvd_rope", "hvd_mlp",
                                    "hvd_norm", "hvd_embed", "hvd_loss")
     assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES

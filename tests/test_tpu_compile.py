@@ -196,6 +196,34 @@ def test_grouped_query_flash_compiles_for_v5e(v5e, on_tpu):
 
 
 
+def test_the_block_diffusion_mask_compiles_for_v5e(v5e, on_tpu):
+    """The block-diffusion cell's attention call: 32 query heads of 128
+    on 4 K/V heads over [noisy ; clean] of 2 x 4096 positions under
+    ``BlockDiffusionMask(4)``. One forward and one backward Mosaic call,
+    K and V as they lie (the group is the index maps'), blocks of 512
+    that tile the 4096 of one copy; the select of the partial tiles (a
+    shift, two compares against scalars) and the clamped index maps are
+    what interpret mode cannot vouch for."""
+    b, s, h, hkv, d = 2, 8192, 32, 4, 128
+    kind = fa.BlockDiffusionMask(4)
+    assert fa._resolve_blocks(s, d, jnp.bfloat16, None, None, False,
+                              kind.span(s)) == (512, 512)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, mask_kind=kind) \
+            .astype(jnp.float32).sum()
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)), v5e,
+                   ((b, s, h, d), jnp.bfloat16),
+                   *[((b, s, hkv, d), jnp.bfloat16)] * 2)
+    assert _mosaic_calls(hlo) == 2
+    wide, narrow = f"bf16[{b},{s},{h * d}]", f"bf16[{b},{s},{hkv * d}]"
+    fwd, = re.findall(r"%[\w.]*hvd_flash_fwd[\w.]* = [^\n]*", hlo)
+    operands = fwd.split("operand_layout_constraints=")[1]
+    assert operands.count(wide) == 1 and operands.count(narrow) == 2
+    assert len(re.findall(r"%[\w.]*hvd_flash_dkv[\w.]* = ", hlo)) == 1
+
+
 # (B, S, H, D), dtype: q of the three GPT cells' kind, of the looped cell
 # (one head a tile), of the gated-convolution cell and its eight K/V
 # heads, and the narrow and wide widths no cell runs.
