@@ -18,9 +18,10 @@ out, and HBM holds no lane padding. A head's matmuls run on the whole
 128-lane tile with the other heads' lanes zeroed in one operand: the MXU
 does for 128 lanes what it did for 64, the products are exact, and each
 result lands in its own head's lanes, so the heads' results add up to
-the tile. Other shapes (an odd head count, a width like 80) go one head a
-block on the transposed (B, H, S, D), D being that array's own minor
-size. Mosaic wants a block's last two dims (8k, 128k) or equal to the
+the tile (the forward's second product alone works on a head's own D
+rows of the transposed tile instead). Other shapes (an odd head count, a
+width like 80) go one head a block on the transposed (B, H, S, D), D
+being that array's own minor size. Mosaic wants a block's last two dims (8k, 128k) or equal to the
 array's; both layouts give it that. Operands cross at the caller's own
 dtype, and o, dq, dk, dv come back in it. The MXU is fed that dtype
 (bf16 tiles straight from the refs; probabilities and ds cast to it
@@ -30,14 +31,27 @@ sum, ``exp`` and the saved logsumexp are fp32 whatever the input.
 Per-row vectors (logsumexp, and ``delta - dlse`` of the backward) cross
 HBM as (B, H, 1, S) rows and the key mask as (B, 1, S): a block of them
 is a (1, block) lane slice, which is why a compiled block is a multiple
-of 128 or the whole sequence. ``mask=None`` builds no mask operand and
-no ``where``; under ``causal`` only the blocks the diagonal crosses pay
-for the iota / compare / select, blocks under it run bare and blocks
-above it are skipped.
+of 128 or the whole sequence. Both kernels read a block of the key mask
+as a (block_k, 1) column of their key-major scores. ``mask=None`` builds
+no mask operand and no ``where``; under ``causal`` only the blocks the
+diagonal crosses pay for the iota / compare / select, blocks under it
+run bare and blocks above it are skipped.
 
 Two kernels, named in common/scopes.py. Forward: grid (B, H/G,
 S/block_q), K/V whole in VMEM per (batch, head group), an in-kernel loop
-over their blocks with carried fp32 state. Backward, one call for dq, dk
+over their blocks with carried fp32 state. It computes the scores of a
+block transposed (k·qᵀ: keys down the sublanes, queries along the
+lanes), which makes the online softmax's per-query vectors (running max,
+sum, rescale) (1, block_q) lane rows that broadcast along sublanes, and
+the max and the sum over keys element-wise over the tile's sublane
+groups with one 8-sublane fold: no reduction across lanes, no one-lane
+column broadcast back across them, and the logsumexp is already the row
+it is stored as. A head's accumulator is held transposed, (D, block_q) =
+its own rows of vᵀ times the probabilities (v's tile is turned once a
+block, an eighth of the score tile; packed heads each stream their own D
+rows through the MXU, not the tile's 128), and the heads' accumulators,
+laid under one another, are turned back to (block_q, lanes) once a q
+block, after the cast. Backward, one call for dq, dk
 and dv: grid (B, H/G, S/block_k, S/block_q) with the q blocks innermost.
 It computes the scores of a block once, transposed (k·qᵀ), which makes
 dv = pᵀ·dO and dk = dsᵀ·q plain matmuls and lets the row vectors
@@ -236,7 +250,9 @@ def reference_attention(q, k, v, mask=None, causal=False, mask_kind=None):
 # heads' lanes zeroed in ONE operand: the contraction (or the output)
 # over 128 lanes costs the MXU what 64 did, the products are exact, and a
 # result lands in its own head's lanes, so the heads' partial results
-# add up to the tile. The row vectors come as (G, 1, rows).
+# add up to the tile. (The forward's p·v is the exception: on the
+# transposed v tile a head is D whole sublane rows, sliced, not zeroed.)
+# The row vectors come as (G, 1, rows).
 
 def _dot(a, b, dims):
     return jax.lax.dot_general(a, b, dims,
@@ -533,53 +549,63 @@ def _loop_key_blocks(step, init, qi, block_q, block_k, nk, kind):
 def _key_block(k_ref, v_ref, m_ref, qi, j, block_q, block_k, partial,
                kind=True):
     """What a step of the forward's loop reads for k block j: the K
-    and V tiles (bk, lanes), the key mask (1, bk) or None, and the mask
-    kind's select (bq, bk) or None."""
+    and V tiles (bk, lanes), the key mask as a (bk, 1) column or None,
+    and the mask kind's select (bk, bq), key-major, or None."""
     ks = pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
-    kmask = None if m_ref is None else m_ref[:, ks]
+    kmask = None if m_ref is None else m_ref[0, ks][:, None]
     keep = _as_kind(kind).keep(
-        k_ref.shape[0], qi * block_q, j * block_k, (block_q, block_k),
-        0) if partial else None
+        k_ref.shape[0], qi * block_q, j * block_k, (block_k, block_q),
+        1) if partial else None
     return k_ref[ks, :], v_ref[ks, :], kmask, keep
 
 
 def _fwd_kernel(*refs, block_k, kind, scale, has_mask):
+    """o and lse of one q block of a head group. The scores of a block
+    are built key-major (k·qᵀ), as the backward builds them: a query's
+    running max, sum and rescale are (1, bq) lane rows, the max and the
+    sum over the keys run down the sublanes, and the rows broadcast
+    along them. A head's accumulator is held transposed, (D, bq) = its
+    own rows of vᵀ times the probabilities, so packed heads do not pay
+    for each other's lanes; the heads' accumulators are laid under one
+    another and turned back to (bq, lanes) once a q block."""
     q_ref, k_ref, v_ref = refs[:3]
     m_ref = refs[3] if has_mask else None
     o_ref, lse_ref = refs[-2:]
     block_q, lanes = q_ref.shape
     heads = lse_ref.shape[0]
+    width = lanes // heads
     nk = k_ref.shape[0] // block_k
     qi = pl.program_id(2)
     q, on_scores = _scaled(q_ref, scale)                    # (bq, lanes)
 
-    def step(j, carry, on_diagonal):
+    def step(j, carry, partial):
         k, v, kmask, keep = _key_block(k_ref, v_ref, m_ref, qi, j, block_q,
-                                       block_k, on_diagonal, kind)
+                                       block_k, partial, kind)
+        vt = v.T                                            # (lanes, bk)
         out = []
         for g, (m, l, acc) in enumerate(carry):
-            s = _masked(_scores(q, _head(k, g, heads), on_scores), kmask,
-                        keep)
-            m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
-            p = jnp.exp(s - m_new)                          # (bq, bk)
+            st = _masked(_scores(_head(k, g, heads), q, on_scores), kmask,
+                         keep)                              # (bk, bq)
+            m_new = jnp.maximum(m, st.max(axis=0, keepdims=True))
+            pt = jnp.exp(st - m_new)
             alpha = jnp.exp(m - m_new)
-            l = l * alpha + p.sum(axis=-1, keepdims=True)
-            acc = acc * alpha + _dot(p.astype(v.dtype),
-                                     _head(v, g, heads), _NN)
+            l = l * alpha + pt.sum(axis=0, keepdims=True)
+            acc = acc * alpha + _dot(vt[g * width:(g + 1) * width],
+                                     pt.astype(v.dtype), _NN)
             out.append((m_new, l, acc))
         return tuple(out)
 
-    init = (jnp.full((block_q, 1), _NEG, jnp.float32),
-            jnp.zeros((block_q, 1), jnp.float32),
-            jnp.zeros((block_q, lanes), jnp.float32))
+    init = (jnp.full((1, block_q), _NEG, jnp.float32),
+            jnp.zeros((1, block_q), jnp.float32),
+            jnp.zeros((width, block_q), jnp.float32))
     carry = _loop_key_blocks(step, (init,) * heads, qi, block_q, block_k,
                              nk, kind)
     outs = []
     for g, (m, l, acc) in enumerate(carry):
         l = jnp.maximum(l, 1e-30)
-        outs.append(acc / l)            # head g's lanes, 0 in the others
-        lse_ref[g, 0, :] = (m + jnp.log(l))[:, 0]
-    o_ref[...] = sum(outs[1:], outs[0]).astype(o_ref.dtype)
+        outs.append(acc / l)                                # (D, bq)
+        lse_ref[g] = m + jnp.log(l)
+    o_ref[...] = jnp.concatenate(outs, axis=0).astype(o_ref.dtype).T
 
 
 def _pt_dst(qg, dog, k, v, lse, dd, on_scores, kmask, keep):

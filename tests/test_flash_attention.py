@@ -290,7 +290,9 @@ def _twin_dq_kernel(*refs, block_k, causal, scale, has_mask, transposed):
     ascending k order. What the backward was before dq moved into the
     dk/dv call, built from that call's helpers; ``transposed`` takes ds
     from the same ``_pt_dst`` as the fused call, else the scores are
-    built untransposed (q·kᵀ), the other way round the same products."""
+    built untransposed (q·kᵀ), the other way round the same products
+    (``_key_block`` hands the key mask and the select key-major, as the
+    forward and ``_pt_dst`` take them)."""
     q_ref, k_ref, v_ref = refs[:3]
     m_ref = refs[3] if has_mask else None
     do_ref, lse_ref, dd_ref, dq_ref = refs[-4:]
@@ -308,13 +310,14 @@ def _twin_dq_kernel(*refs, block_k, causal, scale, has_mask, transposed):
             if transposed:
                 _, dst = fa._pt_dst(
                     fa._head(q, g, heads), fa._head(do, g, heads), k, v,
-                    lse_ref[g], dd_ref[g], on_scores,
-                    None if kmask is None else kmask.T,
-                    None if keep is None else keep.T)
+                    lse_ref[g], dd_ref[g], on_scores, kmask, keep)
                 ds = dst.T
             else:
-                p = jnp.exp(fa._masked(fa._scores(q, kg, on_scores), kmask,
-                                       keep) - lse_ref[g, 0, :][:, None])
+                p = jnp.exp(fa._masked(
+                    fa._scores(q, kg, on_scores),
+                    None if kmask is None else kmask.T,
+                    None if keep is None else keep.T)
+                    - lse_ref[g, 0, :][:, None])
                 ds = p * (fa._dot(do, fa._head(v, g, heads), fa._NT)
                           - dd_ref[g, 0, :][:, None])
             dq = dq + fa._dot(ds.astype(k.dtype), kg, fa._NN)
@@ -671,6 +674,19 @@ BD = fa.BlockDiffusionMask(4)
 BD_BLOCKS = [(32, 32), (64, 16), (16, 64), (128, 8), (8, 128)]
 
 
+def _dense_reference(q, k, v, mask, kind):
+    """(o, lse) from the kind's (S, S) boolean matrix and the key mask,
+    written out: the oracle of the forward's two outputs."""
+    d, s = q.shape[-1], q.shape[1]
+    dense = jnp.asarray(kind.dense(s))[None]
+    if mask is not None:
+        dense = dense & (jnp.asarray(mask)[:, None, :] > 0)
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+    logits = jnp.where(dense[:, None], logits, -jnp.inf)
+    return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), v),
+            jax.nn.logsumexp(logits, -1))
+
+
 def _explicit(dense):
     """Plain softmax attention under an explicit (S, S) boolean matrix."""
     def attend(q, k, v):
@@ -735,14 +751,10 @@ def test_the_block_diffusion_mask_with_a_key_mask_and_an_lse(rng):
     q, k, v = _qkv(rng, d=16)
     mask = _mask(rng, "keys")
     mask[:, S // 2:] = 1.0      # every row keeps a visible key
-    dense = BD.dense(S)[None] & (mask[:, None, :] > 0)
     w = jnp.asarray(rng.standard_normal((B, H, S)), jnp.float32)
 
     def explicit(q, k, v):
-        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(16)
-        logits = jnp.where(jnp.asarray(dense)[:, None], logits, -jnp.inf)
-        return (jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1),
-                           v), jax.nn.logsumexp(logits, -1))
+        return _dense_reference(q, k, v, mask, BD)
 
     def kernels(q, k, v):
         return flash_attention_with_lse(q, k, v, mask=mask, mask_kind=BD,
@@ -822,3 +834,97 @@ def test_the_mask_kind_is_said_and_counted(rng, caplog):
              and s["labels"]["seq_len"] == "64"
              and s["labels"]["block_q"] == "16"}
     assert tiles == {"visited": 8, "square": 16}   # 2 + 2 x (1 + 2)
+
+
+# -- the forward's orientation ------------------------------------------------
+#
+# The forward builds a block's scores key-major (k·qᵀ, as the backward
+# does): the running max / sum are lane rows reduced down the sublanes,
+# the accumulator is held transposed and turned back once a q block. What
+# that is new in: the three layouts' accumulators (two packed heads summed
+# into one tile, one head of 128, one head a block on (B, H, S, D)), the
+# three mask kinds' selects asked key-major, the key mask as a column, and
+# a q block that meets 1, 2 or 4 k blocks of its own rows.
+
+KEY_MAJOR_LAYOUTS = [(2, 64), (2, 128), (3, 64)]
+KEY_MAJOR_IDS = ["packed2x64", "d128", "perhead_h3"]
+KEY_MAJOR_KINDS = [fa.NO_MASK, fa.CAUSAL, BD]
+
+
+def _keys_every_row_sees(rng, s):
+    """A key mask that keeps the first key of every block of 4: under
+    each of the three kinds every query keeps a visible key."""
+    mask = _mask(rng, "keys", s=s)
+    mask[:, ::4] = 1.0
+    return mask
+
+
+@pytest.mark.parametrize("k_blocks", [1, 2, 4])
+@pytest.mark.parametrize("masking", ["none", "keys"])
+@pytest.mark.parametrize("kind", KEY_MAJOR_KINDS, ids=lambda k: k.name)
+@pytest.mark.parametrize("h, d", KEY_MAJOR_LAYOUTS, ids=KEY_MAJOR_IDS)
+def test_forward_and_lse_key_major(rng, h, d, kind, masking, k_blocks):
+    """o and lse of the forward kernel against the written-out softmax:
+    every layout under every mask kind, with and without a key mask, a q
+    block of 64 rows over k blocks of 64, 32 and 16."""
+    s, block_q = 128, 64
+    q, k, v = _qkv(rng, d=d, s=s, h=h)
+    mask = None if masking == "none" else _keys_every_row_sees(rng, s)
+    o, lse = flash_attention_with_lse(
+        q, k, v, mask=mask, mask_kind=kind, use_pallas=True,
+        block_q=block_q, block_k=block_q // k_blocks)
+    want_o, want_lse = _dense_reference(q, k, v, mask, kind)
+    assert o.shape == (B, s, h, d) and lse.shape == (B, h, s)
+    assert lse.dtype == jnp.float32
+    np.testing.assert_allclose(o, want_o, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse, want_lse, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kind", KEY_MAJOR_KINDS, ids=lambda k: k.name)
+@pytest.mark.parametrize("h, d", KEY_MAJOR_LAYOUTS, ids=KEY_MAJOR_IDS)
+def test_no_mask_is_a_mask_of_ones_in_every_layout(rng, h, d, kind):
+    """``mask=None`` reads no mask column and builds no select in the
+    key-major body either: o and lse are a mask of ones' to the bit."""
+    s = 128
+    q, k, v = _qkv(rng, d=d, s=s, h=h)
+
+    def run(mask):
+        return flash_attention_with_lse(q, k, v, mask=mask, mask_kind=kind,
+                                        use_pallas=True, block_q=64,
+                                        block_k=32)
+
+    for bare, masked in zip(run(None), run(np.ones((B, s), np.float32))):
+        np.testing.assert_array_equal(np.asarray(bare), np.asarray(masked))
+
+
+@pytest.mark.parametrize("kind", [fa.NO_MASK, fa.CAUSAL],
+                         ids=lambda k: k.name)
+@pytest.mark.parametrize("h, d", KEY_MAJOR_LAYOUTS, ids=KEY_MAJOR_IDS)
+def test_a_row_with_every_key_masked_stays_finite(rng, h, d, kind):
+    """A batch row whose key mask is all zeros: every score is ``_NEG``
+    (not -inf), so the running max is ``_NEG``, every probability exp(0)
+    and the row the plain mean of the values its tiles visit: all S of
+    them with no mask kind, what ``reference_attention`` gives too; the
+    lse is ``_NEG`` + log(count), finite. Nothing is NaN or inf, and the
+    other batch row is untouched by it."""
+    s = 128
+    q, k, v = _qkv(rng, d=d, s=s, h=h)
+    mask = np.ones((B, s), np.float32)
+    mask[0] = 0.0
+    o, lse = flash_attention_with_lse(q, k, v, mask=mask, mask_kind=kind,
+                                      use_pallas=True, block_q=64,
+                                      block_k=32)
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.isfinite(np.asarray(lse)).all()
+    np.testing.assert_array_equal(np.asarray(lse[0]),
+                                  np.full((h, s), np.float32(fa._NEG)))
+    if kind == fa.NO_MASK:
+        np.testing.assert_allclose(
+            o[0], jnp.broadcast_to(v[0].mean(0), (s, h, d)), rtol=1e-5,
+            atol=1e-6)
+        np.testing.assert_allclose(
+            o, reference_attention(q, k, v, mask=mask), rtol=2e-5,
+            atol=2e-5)
+    want_o, want_lse = _dense_reference(q[1:], k[1:], v[1:], None, kind)
+    np.testing.assert_allclose(o[1:], want_o, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(lse[1:], want_lse, rtol=2e-5, atol=2e-5)

@@ -20,9 +20,10 @@ from horovod_tpu.ops import flash_attention as fa
 from horovod_tpu.ops import pallas_kernels as pk
 
 BUCKET = 25_000_000  # one 100 MB fp32 gradient bucket
-# (b, S, h, d), causal: gpt_small at three lengths (S4096 is what the
-# dk/dv kernel could not hold in VMEM before it was tiled on its q side),
-# bert_large's unmasked attention, the looped model's heads of 128.
+# (b, S, h, d), causal or the mask kind: gpt_small at three lengths (S4096
+# is what the dk/dv kernel could not hold in VMEM before it was tiled on
+# its q side), bert_large's unmasked attention, the looped model's heads
+# of 128.
 FLASH_SHAPES = {"b8_s512": ((8, 512, 12, 64), True),
                 "b4_s2048": ((4, 2048, 12, 64), True),
                 "b4_s4096": ((4, 4096, 12, 64), True),
@@ -31,7 +32,16 @@ FLASH_SHAPES = {"b8_s512": ((8, 512, 12, 64), True),
                 "b2_s512_h3": ((2, 512, 3, 64), True),
                 # Ouro-2.6B: one head of 128 fills a 128-lane block, and
                 # the softmax scale (no power of two) rides on the scores
-                "ouro_b2_s2048_d128": ((2, 2048, 16, 128), True)}
+                "ouro_b2_s2048_d128": ((2, 2048, 16, 128), True),
+                # the gated-convolution cell's attention: S 8192, two
+                # packed heads of 64 a tile, K and V of 8 heads repeated
+                "lfm2_b2_s8192_kv8": ((2, 8192, 32, 64), True),
+                # the block-diffusion cell's: [noisy ; clean] of 2 x 4096,
+                # heads of 128 on 4 K/V heads through the index maps
+                "sdar_b2_s8192_d128_kv4": ((2, 8192, 32, 128),
+                                           fa.BlockDiffusionMask(4))}
+# K/V heads where they are fewer than q's
+FLASH_KV_HEADS = {"lfm2_b2_s8192_kv8": 8, "sdar_b2_s8192_d128_kv4": 4}
 
 
 @pytest.fixture(scope="module")
@@ -135,17 +145,18 @@ def test_bucket_kernel_compiles_for_v5e(v5e, on_tpu, kernel, dtype):
 @pytest.mark.parametrize("direction", ["fwd", "bwd"])
 def test_flash_attention_compiles_for_v5e(v5e, on_tpu, direction, shape,
                                           dtype):
-    (b, s, h, d), causal = FLASH_SHAPES[shape]
+    (b, s, h, d), mask = FLASH_SHAPES[shape]
+    hkv = FLASH_KV_HEADS.get(shape, h)
 
     def fwd(q, k, v):
-        return fa.flash_attention(q, k, v, causal=causal)
+        return fa.flash_attention(q, k, v, mask_kind=fa._as_kind(mask))
 
     def bwd(q, k, v):
         return jax.grad(lambda *a: fwd(*a).astype(jnp.float32).sum(),
                         argnums=(0, 1, 2))(q, k, v)
 
     hlo = _compile(fwd if direction == "fwd" else bwd, v5e,
-                   *[((b, s, h, d), dtype)] * 3)
+                   ((b, s, h, d), dtype), *[((b, s, hkv, d), dtype)] * 2)
     # bwd recomputes nothing: the fwd kernel for the residuals, then ONE
     # Mosaic call that gives dq, dk and dv, under the dk/dv call's name;
     # no call carries the dq kernel's name since the two were fused.
@@ -170,6 +181,55 @@ def test_flash_attention_compiles_for_v5e(v5e, on_tpu, direction, shape,
                          rf"operand_layout_constraints=\{{\w+\[{b},{s},"
                          rf"{h * d}\]", hlo)
         assert not re.search(rf"\[{b},{h},{s},{d}\]", hlo)
+
+
+def _reductions(jaxpr):
+    """``(primitive, operand shape, axes)`` of every reduction in a jaxpr
+    and in the jaxprs its equations carry (loop bodies, branches)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith(("reduce_", "argm")):
+            yield (eqn.primitive.name, eqn.invars[0].aval.shape,
+                   tuple(eqn.params["axes"]))
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _reductions(sub)
+
+
+@pytest.mark.parametrize("with_mask", [False, True], ids=["bare", "keys"])
+@pytest.mark.parametrize("kind", [fa.NO_MASK, fa.CAUSAL,
+                                  fa.BlockDiffusionMask(4)],
+                         ids=lambda k: k.name)
+@pytest.mark.parametrize("h, d", [(4, 64), (2, 128), (3, 64)],
+                         ids=["packed2x64", "d128", "perhead_h3"])
+def test_the_forward_reduces_over_keys_along_sublanes(h, d, kind, with_mask):
+    """The forward kernel's jaxpr: every reduction over a score tile runs
+    down the tile's first axis, the sublanes (the scores are key-major,
+    (block_k, block_q): the max and the sum over keys are element-wise
+    over the tile's sublane groups and one 8-sublane fold), and none
+    along its minor axis, the lanes (128 rotate-and-combine reductions a
+    block, with the per-query vectors as one-lane columns broadcast back
+    across lanes: what the query-major form paid). Traced, not compiled:
+    the orientation cannot drift back unnoticed."""
+    b, s, bq, bk = 2, 1024, 256, 512
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    mask = jax.ShapeDtypeStruct((b, s), jnp.float32) if with_mask else None
+    jaxpr = jax.make_jaxpr(
+        lambda q, k, v, m: fa._forward(q, k, v, m, kind, bq, bk, False)[:2]
+    )(q, q, q, mask)
+    kernel, = [eqn for eqn in jaxpr.jaxpr.eqns
+               if eqn.primitive.name == "pallas_call"]
+    assert kernel.params["name"] == "hvd_flash_fwd"
+    found = list(_reductions(kernel.params["jaxpr"]))
+    over_scores = [r for r in found if r[1] == (bk, bq)]
+    # a max and a sum a head of the tile, in every range of k blocks the
+    # kind's loop runs over
+    assert {r[0] for r in over_scores} == {"reduce_max", "reduce_sum"}
+    assert len(over_scores) >= 2 * fa._Layout(h, d).heads
+    for name, shape, axes in found:
+        if len(shape) == 2 and min(shape) >= 128:
+            assert axes == (0,), (name, shape, axes)
 
 
 def test_grouped_query_flash_compiles_for_v5e(v5e, on_tpu):
