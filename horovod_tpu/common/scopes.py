@@ -55,6 +55,11 @@ BD_NOISE = "hvd_bd_noise"
 FLASH_FWD = "hvd_flash_fwd"
 FLASH_DQ = "hvd_flash_dq"
 FLASH_DKV = "hvd_flash_dkv"
+# ops/flash_attention.py under SlidingWindowMask: the same two bodies
+# over a window's band, named apart so that a trace tells a window call
+# from a full one (none of them holds a FLASH_KERNELS name)
+SWA_FWD = "hvd_swa_fwd"
+SWA_BWD = "hvd_swa_bwd"
 SCALE = "hvd_scale"
 ADASUM_DOT_NORMS = "hvd_adasum_dot_norms"
 ADASUM_COMBINE = "hvd_adasum_combine"
@@ -82,6 +87,7 @@ BLOCK_DIFFUSION_SCOPES = (BD_NOISE,)    # a block-diffusion loss's
 # cross-entropy is the model's own
 BLOCK_SCOPES = (MIXER_PROJ, ROPE, MLP, NORM, EMBED, LOSS)
 FLASH_KERNELS = (FLASH_FWD, FLASH_DQ, FLASH_DKV)
+SWA_KERNELS = (SWA_FWD, SWA_BWD)
 BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)
 KDA_KERNELS = (KDA_FWD, KDA_BWD)

@@ -52,7 +52,7 @@ import jax.numpy as jnp
 
 from ..common import scopes
 from ..ops.flash_attention import CAUSAL, flash_attention
-from ..ops.rope import rotate_heads
+from ..ops.rope import Rotation, rotate, rotate_heads
 from ..ops.short_conv import gated_short_conv
 from .looplm import RMSNorm, head_losses
 from .solar import SparseExperts, _dense, solar_loss
@@ -85,9 +85,22 @@ class ShortConv(nn.Module):
 
 class RotaryGQA(nn.Module):
     """Softmax attention, ``num_heads`` query heads on ``num_kv_heads``
-    K/V heads, q and k normed a head and rotated by ``positions`` (1 or
-    B, S; None: 0 … S-1), under ``mask_kind`` (``ops/flash_attention.py``
-    ``MaskKind``; causal unless told)."""
+    K/V heads, q and k rotated by ``positions`` (1 or B, S; None: 0 …
+    S-1), under ``mask_kind`` (``ops/flash_attention.py`` ``MaskKind``;
+    causal unless told). The one rotary attention body of the model
+    files; what differs between the families is data:
+
+    - ``qk_norm``: q and k through an RMSNorm over the head's channels
+      before the rotation (one scale vector each, shared by the heads);
+    - ``rotation``: an ``ops/rope.py`` ``Rotation`` (a rotary width under
+      the head's, YaRN's frequencies, a scale); None is the plain one at
+      ``rope_base``;
+    - ``packed_rotation``: the rotation on the packed rows the flash
+      kernels read (``rotate``) in place of a head at a time
+      (``rotate_heads``, which XLA fuses into the per-head norm before
+      it: PERF.md PR 39);
+    - ``head_gate``: the output of head h times ``sigmoid(u W_g)_h``, one
+      scalar a query head from the layer's input, before ``W_o``."""
 
     num_heads: int
     num_kv_heads: int
@@ -96,24 +109,33 @@ class RotaryGQA(nn.Module):
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
     mask_kind: Any = CAUSAL
+    rotation: Any = None
+    qk_norm: bool = True
+    packed_rotation: bool = False
+    head_gate: bool = False
 
     @nn.compact
     def __call__(self, u, positions=None):
         b, s, hidden = u.shape
         dense = _dense(self.dtype)
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
+        turn = functools.partial(
+            rotate if self.packed_rotation else rotate_heads,
+            positions=positions,
+            rotation=self.rotation or Rotation(self.rope_base))
         wide, narrow = (n * self.head_dim
                         for n in (self.num_heads, self.num_kv_heads))
         with jax.named_scope(scopes.MIXER_PROJ):
             q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
             k, v = (dense(narrow, name=n)(u).reshape(
                 b, s, self.num_kv_heads, -1) for n in ("k", "v"))
-            q = rotate_heads(norm(name="q_norm")(q), positions,
-                             self.rope_base)
-            k = rotate_heads(norm(name="k_norm")(k), positions,
-                             self.rope_base)
+            q, k = (turn(norm(name=n)(x) if self.qk_norm else x)
+                    for n, x in (("q_norm", q), ("k_norm", k)))
         o = flash_attention(q, k, v, mask_kind=self.mask_kind)
         with jax.named_scope(scopes.MIXER_PROJ):
+            if self.head_gate:
+                gate = nn.sigmoid(dense(self.num_heads, name="gate")(u))
+                o = o * gate[..., None]
             return dense(hidden, name="o")(o.reshape(b, s, wide))
 
 

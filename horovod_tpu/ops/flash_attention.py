@@ -88,7 +88,14 @@ of that 2L square are empty tiles, which neither kernel visits: the
 forward's loop runs over the two key ranges a q block can see, the
 backward's grid steps of an empty pair do nothing and fetch nothing new
 (their block indices are clamped to the nearest visited pair's). The
-blocks tile L, so that no tile straddles the two copies. How many tiles
+blocks tile L, so that no tile straddles the two copies. The fourth is
+``SlidingWindowMask(window)``, causal attention over the last ``window``
+keys: the visible pairs are a band under the diagonal, the forward loops
+over the band's k blocks (partial tiles at the window's far edge, bare
+tiles, partial tiles on the diagonal) and the backward's grid is the band
+itself, a k block's visible q blocks and no more, with dq's rows zeroed
+at the first k block that sees them; its two calls carry names of their
+own (``scopes.SWA_KERNELS``). How many tiles
 a call visits of how many is counted where the call is traced
 (``tiles_visited``, the gauge ``hvd_tpu_flash_attention_tiles``).
 
@@ -360,9 +367,31 @@ class MaskKind:
 
     def first_query_block(self, s, j, i, block_q, block_k):
         """The q block whose tiles the backward's grid step (k block j,
-        q block i) fetches: i itself where the pair is visited, else a
-        neighbour that is, so that a skipped step fetches nothing new."""
+        step i) fetches: the step's own (``query_block``) where the pair
+        is visited, else a neighbour that is, so that a skipped step
+        fetches nothing new."""
         return i
+
+    # What follows is asked by a kind whose visible pairs lie in a band:
+    # the three standing kinds keep the answers below, and their kernels
+    # the jaxprs they had.
+
+    kernel_names = (scopes.FLASH_FWD, scopes.FLASH_DKV)
+
+    def query_steps(self, s, block_q, block_k):
+        """The innermost extent of the backward's grid: how many q blocks
+        a k block is given steps for."""
+        return s // block_q
+
+    def query_block(self, s, j, i, block_q, block_k):
+        """The q block of the backward's grid step (k block j, step i);
+        past the last one where the step has none."""
+        return i
+
+    def first_key_block(self, s, qi, ki, block_q, block_k):
+        """Whether q block ``qi`` meets no k block before ``ki``: dq's
+        rows start from zero then."""
+        return ki == 0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -494,6 +523,104 @@ class BlockDiffusionMask(MaskKind):
             noisy_key, jnp.clip(i, lo, hi - 1),
             jnp.where(i < clean, jnp.maximum(i, lo),
                       jnp.maximum(i, clean + lo)))
+
+
+@dataclasses.dataclass(frozen=True)
+class SlidingWindowMask(MaskKind):
+    """Causal attention over the last ``window`` keys: query u sees key w
+    iff 0 <= u - w < window. The visible pairs are a band under the
+    diagonal, ``window`` wide: a q block's key range has two partial ends
+    (the window's far edge and the diagonal; one tile may be both) and,
+    where the window is wider than a block, bare tiles between them.
+    Nothing outside the band is visited. The forward loops over the
+    band's k blocks; the backward's grid is the band itself, (k block,
+    step) with ``query_steps`` steps a k block and q block =
+    ``query_block`` = the k block's first visible q block + step, so that
+    a call costs what its band costs and not its square's grid steps (at S
+    8192, blocks of 256 and a window of 512 a head has 32 x 3 steps, not
+    32 x 32). ``window >= S`` is ``CAUSAL``: the same tiles in the same
+    order, every select's second half true.
+
+    The blocks are ``_choose_blocks``'s own. A q block of b rows works on
+    b + window columns for b x window visible pairs, window / (window + b)
+    of what it computes: half at b = window = 512, two thirds at 256, four
+    fifths at 128; but on the v5e at S 8192, 72 heads on 8, a forward and
+    a backward took 13.2 ms at 512 x 512, 16.4 at 256 x 256 and 28.8 at
+    128 x 128 (14.6 / 15.9 with one side 256): a tile's fixed cost
+    outweighs what a thinner rim saves (PERF.md section 6, PR 42). The
+    two calls carry names of their own, so that a trace tells a window
+    call from a full one."""
+
+    window: int = 512
+    name = "sliding_window"
+    kernel_names = scopes.SWA_KERNELS
+
+    def __post_init__(self):
+        if self.window < 1:
+            raise ValueError(f"a window holds at least the query's own "
+                             f"key; got {self.window}")
+
+    def dense(self, s):
+        gap = np.arange(s)[:, None] - np.arange(s)[None]
+        return (gap >= 0) & (gap < self.window)
+
+    def keep(self, s, row0, col0, shape, rows_dim):
+        gap = (row0 - col0) \
+            + jax.lax.broadcasted_iota(jnp.int32, shape, rows_dim) \
+            - jax.lax.broadcasted_iota(jnp.int32, shape, 1 - rows_dim)
+        return (gap >= 0) & (gap < self.window)
+
+    def _first_key_block(self, r0, block_k):
+        """The first k block the q rows from ``r0`` on see."""
+        return jax.lax.div(jnp.maximum(r0 - (self.window - 1), 0), block_k)
+
+    def key_segments(self, s, qi, block_q, block_k, nk):
+        # far-edge partial tiles, bare tiles, diagonal partial tiles; a
+        # tile that is both (a window narrower than a block) falls to the
+        # last range
+        r0 = qi * block_q
+        hi = jnp.minimum(
+            jax.lax.div(r0 + block_q + block_k - 1, block_k), nk)
+        bare_end = jax.lax.div(r0 + 1, block_k)
+        bare_first = jnp.minimum(bare_end, jax.lax.div(
+            jnp.maximum(r0 + block_q - self.window, 0) + block_k - 1,
+            block_k))
+        return ((self._first_key_block(r0, block_k), bare_first, True),
+                (bare_first, bare_end, False), (bare_end, hi, True))
+
+    def tile(self, s, qi, ki, block_q, block_k):
+        # the gaps row - col over a tile are the whole range below
+        r0, c0 = qi * block_q, ki * block_k
+        least, most = r0 - c0 - (block_k - 1), r0 - c0 + (block_q - 1)
+        visible = jnp.logical_and(
+            jnp.logical_and(most >= 0, least < self.window), r0 < s)
+        bare = jnp.logical_and(
+            jnp.logical_and(least >= 0, most < self.window), r0 < s)
+        return bare, visible
+
+    def last_key_block(self, s, qi, ki, block_q, block_k, nk, visible):
+        return jnp.logical_and(
+            visible, (ki + 1) * block_k >= (qi + 1) * block_q)
+
+    def first_key_block(self, s, qi, ki, block_q, block_k):
+        r0 = qi * block_q
+        return jnp.logical_and(
+            r0 < s, ki == self._first_key_block(r0, block_k))
+
+    def query_steps(self, s, block_q, block_k):
+        # the most q blocks one k block's rows and their window reach
+        return min(s // block_q, max(
+            (j * block_k + block_k + self.window - 2) // block_q
+            - j * block_k // block_q + 1 for j in range(s // block_k)))
+
+    def query_block(self, s, j, i, block_q, block_k):
+        return jax.lax.div(j * block_k, block_q) + i
+
+    def first_query_block(self, s, j, i, block_q, block_k):
+        # clamped to the last q block k block j's last row is visible to
+        last = jax.lax.div(j * block_k + block_k + self.window - 2, block_q)
+        return jnp.minimum(self.query_block(s, j, i, block_q, block_k),
+                           jnp.minimum(last, s // block_q - 1))
 
 
 NO_MASK = MaskKind()
@@ -630,17 +757,19 @@ def _bwd_kernel(*refs, kind, scale, has_mask):
     block_k = k_ref.shape[0]
     heads = lse_ref.shape[0]
     s = dq_acc.shape[0]
-    ki, qi = pl.program_id(2), pl.program_id(3)
+    ki, step = pl.program_id(2), pl.program_id(3)
+    # the step's q block: the step itself, but in a banded grid
+    qi = kind.query_block(s, ki, step, block_q, block_k)
     # This q block's rows of dq, which VMEM holds for the whole sequence:
     # zeroed where the first k block meets them, added to at every visible
     # block (so in ascending k order, in fp32), written out after the last.
     rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    @pl.when(ki == 0)
+    @pl.when(kind.first_key_block(s, qi, ki, block_q, block_k))
     def _():
         dq_acc[rows, :] = jnp.zeros((block_q, dq_acc.shape[1]), jnp.float32)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -680,7 +809,7 @@ def _bwd_kernel(*refs, kind, scale, has_mask):
     def _():
         dq_ref[rows, :] = (dq_acc[rows, :] * scale).astype(dq_ref.dtype)
 
-    @pl.when(qi == pl.num_programs(3) - 1)
+    @pl.when(step == pl.num_programs(3) - 1)
     def _():
         dk = dk_acc[...]
         if not _scale_folds_into_q(scale):
@@ -755,7 +884,16 @@ class _Layout:
 
 
 def _kv_head(group):
-    """The K/V head (group) a q head (group) reads."""
+    """The K/V head (group) a q head (group) reads. The grids run the
+    heads in order, the block dimensions inside them, so consecutive grid
+    steps stay in a group until its last head is done and the K/V block
+    index changes once a group: the forward, which holds K and V whole a
+    (batch, K/V head), fetches them once a GROUP and not once a head
+    (Pallas skips the copy of a block whose index did not change). Read
+    on the v5e at S 8192 under a window of 512, 72 query heads: on 8 K/V
+    heads (groups of 9) the forward took 4.38 ms, on 72 (every head its
+    own K/V) 5.53: the 64 fetches of 4 MiB the grouping saves (PERF.md
+    section 6, PR 42)."""
     return (lambda g: g) if group == 1 else (lambda g: g // group)
 
 
@@ -830,7 +968,7 @@ def _forward(q, k, v, mask, kind, bq, bk, interpret):
                    jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
         compiler_params=_compiler_params(s, d, q.dtype.itemsize, bq, bk),
         interpret=interpret,
-        name=scopes.FLASH_FWD,
+        name=kind.kernel_names[0],
     )(qt, kt, vt, *([mask3] * has_mask))
     return (layout.from_kernel(ot), lse[:, :, 0, :],
             (qt, kt, vt, mask3, ot, lse))
@@ -865,7 +1003,7 @@ def _backward(kind, bq, bk, interpret, res, do, dlse):
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_kernel, kind=kind,
                           scale=1.0 / np.sqrt(d), has_mask=has_mask),
-        grid=(b, layout.groups, s // bk, s // bq),
+        grid=(b, layout.groups, s // bk, kind.query_steps(s, bq, bk)),
         in_specs=[q_spec, kv_spec, kv_spec] + [m_spec] * has_mask
         + [q_spec, row_spec, row_spec],
         out_specs=[dq_spec, dkv_spec, dkv_spec],
@@ -876,7 +1014,7 @@ def _backward(kind, bq, bk, interpret, res, do, dlse):
         compiler_params=_compiler_params(s, d, qt.dtype.itemsize, bq, bk,
                                          backward=True),
         interpret=interpret,
-        name=scopes.FLASH_DKV,
+        name=kind.kernel_names[1],
     )(qt, kt, vt, *masks, dot, lse, dd)
     # Where a model joins the three straight back into the gradient of a
     # fused qkv projection (BERT's), XLA:TPU sees one call's outputs

@@ -928,3 +928,238 @@ def test_a_row_with_every_key_masked_stays_finite(rng, h, d, kind):
     want_o, want_lse = _dense_reference(q[1:], k[1:], v[1:], None, kind)
     np.testing.assert_allclose(o[1:], want_o, rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(lse[1:], want_lse, rtol=2e-5, atol=2e-5)
+
+
+# -- the sliding window -------------------------------------------------------
+#
+# ``SlidingWindowMask(w)``: query u sees key v iff 0 <= u - v < w. What is
+# new in it: a q block's key range has two partial ends and bare tiles
+# between them; a tile can be both ends at once (a window narrower than a
+# block); the backward's grid is the band itself, so a grid step's q block
+# is the k block's first visible one plus the step, dq's rows start at
+# the first k block that sees them (not block 0) and skipped steps lie at
+# the band's end; groups of 6 and 9 query heads share a K/V head of 128.
+
+SWA_S = 256
+# (window, block_q, block_k): a multiple of the block, not one, narrower
+# than a block, unequal blocks both ways, and one past S
+SWA_CASES = [(64, 32, 32), (96, 32, 32), (50, 32, 32), (7, 32, 32),
+             (100, 32, 64), (100, 64, 32), (33, 64, 16), (1, 32, 32),
+             (200, 128, 32), (SWA_S, 32, 32), (SWA_S + 44, 64, 32)]
+SWA_IDS = [f"w{w}_bq{a}_bk{b}" for w, a, b in SWA_CASES]
+
+
+def test_the_window_is_the_band_under_the_diagonal():
+    want = np.array([[u - v in (0, 1, 2) for v in range(6)]
+                     for u in range(6)])
+    assert np.array_equal(fa.SlidingWindowMask(3).dense(6), want)
+    assert np.array_equal(fa.SlidingWindowMask(6).dense(6),
+                          fa.CAUSAL.dense(6))
+    assert np.array_equal(fa.SlidingWindowMask(1).dense(4), np.eye(4, dtype=bool))
+    assert fa.SlidingWindowMask(3) == fa.SlidingWindowMask(3) \
+        != fa.SlidingWindowMask(4)
+    assert isinstance(fa.SlidingWindowMask(3), fa.MaskKind)
+    assert fa.SlidingWindowMask(3).name == "sliding_window"
+    with pytest.raises(ValueError, match="at least the query's own"):
+        fa.SlidingWindowMask(0)
+    # the pairs a window allows: w S - w (w - 1) / 2
+    assert fa.SlidingWindowMask(512).dense(2048).sum() \
+        == 512 * 2048 - 512 * 511 // 2
+
+
+@pytest.mark.parametrize("window, bq, bk", SWA_CASES, ids=SWA_IDS)
+def test_the_kernels_under_the_window(rng, window, bq, bk):
+    """Outputs, lse and all three gradients (a live lse cotangent), the
+    kernels' bodies in interpret mode against ``reference_attention``
+    under ``dense()``, two query heads on one K/V head."""
+    kind = fa.SlidingWindowMask(window)
+    q, k, v = _qkv(rng, d=16, s=SWA_S)
+    k, v = k[:, :, :1], v[:, :, :1]
+    w = jnp.asarray(rng.standard_normal((B, H, SWA_S)), jnp.float32)
+
+    def kernels(q, k, v):
+        return flash_attention_with_lse(q, k, v, mask_kind=kind,
+                                        use_pallas=True, block_q=bq,
+                                        block_k=bk)
+
+    def explicit(q, k, v):
+        k, v = (fa._repeat_heads(x, H) for x in (k, v))
+        return _dense_reference(q, k, v, None, kind)
+
+    o, lse = kernels(q, k, v)
+    np.testing.assert_allclose(
+        o, reference_attention(q, k, v, mask_kind=kind), rtol=2e-5,
+        atol=2e-5)
+    np.testing.assert_allclose(lse, explicit(q, k, v)[1], rtol=2e-5,
+                               atol=2e-5)
+    got = jax.grad(_lse_loss(kernels, w), argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(_lse_loss(explicit, w), argnums=(0, 1, 2))(q, k, v)
+    for g, e in zip(got, want):
+        np.testing.assert_allclose(g, e, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("h, hkv, d", [(2, 2, 128), (6, 1, 128),
+                                       (9, 1, 128), (18, 2, 128),
+                                       (4, 2, 64)],
+                         ids=["group1_d128", "group6_d128", "group9_d128",
+                              "two_groups_of_9", "packed_d64_group2"])
+def test_the_window_at_the_models_groups(rng, h, hkv, d):
+    """Groups of 1, 6 and 9 query heads on a K/V head of 128 (the K/V
+    index maps), and one packed width-64 case (K/V repeated): forward and
+    every gradient, a window that is no multiple of the block."""
+    kind = fa.SlidingWindowMask(72)
+    s = 128
+    q = jnp.asarray(rng.standard_normal((1, s, h, d)), jnp.float32)
+    k, v = (jnp.asarray(rng.standard_normal((1, s, hkv, d)), jnp.float32)
+            for _ in range(2))
+
+    def kernels(q, k, v):
+        return flash_attention(q, k, v, mask_kind=kind, use_pallas=True,
+                               block_q=32, block_k=32)
+
+    def plain(q, k, v):
+        return reference_attention(q, k, v, mask_kind=kind)
+
+    np.testing.assert_allclose(kernels(q, k, v), plain(q, k, v), rtol=2e-5,
+                               atol=2e-5)
+    for got, want in zip(_grads(kernels, q, k, v), _grads(plain, q, k, v)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=3e-4, atol=3e-4)
+
+
+@pytest.mark.parametrize("blocks", [(32, 32), (64, 32), (32, 128)],
+                         ids=lambda b: f"bq{b[0]}_bk{b[1]}")
+@pytest.mark.parametrize("window", [SWA_S, SWA_S + 1, 4 * SWA_S])
+def test_a_window_past_the_sequence_is_causal_to_the_bit(rng, window, blocks):
+    """The same tiles in the same order: outputs, lse and gradients equal
+    ``CAUSAL``'s bit for bit."""
+    q, k, v = _qkv(rng, d=16, s=SWA_S)
+    w = jnp.asarray(rng.standard_normal((B, H, SWA_S)), jnp.float32)
+
+    def under(kind):
+        def kernels(q, k, v):
+            return flash_attention_with_lse(q, k, v, mask_kind=kind,
+                                            use_pallas=True, **_kw(blocks))
+        return kernels(q, k, v) + jax.grad(
+            _lse_loss(kernels, w), argnums=(0, 1, 2))(q, k, v)
+
+    for got, want in zip(under(fa.SlidingWindowMask(window)),
+                         under(fa.CAUSAL)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert fa.tiles_visited(fa.SlidingWindowMask(window), SWA_S, *blocks) \
+        == fa.tiles_visited(fa.CAUSAL, SWA_S, *blocks)
+
+
+@pytest.mark.parametrize("window, bq, bk", SWA_CASES, ids=SWA_IDS)
+def test_the_window_visits_its_band_and_nothing_else(window, bq, bk):
+    """``tiles_visited``'s three counts against a count made from
+    ``dense()``; the forward's ranges (far-edge partial, bare, diagonal
+    partial: no tile twice, a bare tile holds visible pairs alone); the
+    backward's banded grid: every visible pair of a k block is one of its
+    steps, in ascending q order, a step past the band fetches a visited
+    pair's tiles, and every q block's dq rows start from zero at the
+    first k block that sees them and are written out exactly once."""
+    s = SWA_S
+    kind = fa.SlidingWindowMask(window)
+    nq, nk = s // bq, s // bk
+    tiles = kind.dense(s).reshape(nq, bq, nk, bk)
+    some, every = tiles.any((1, 3)), tiles.all((1, 3))
+    forward, backward, square = fa.tiles_visited(kind, s, bq, bk)
+    assert (forward, backward, square) == (some.sum(), some.sum(), nq * nk)
+    steps = kind.query_steps(s, bq, bk)
+    assert steps == min(nq, some.sum(0).max())      # the band, not the square
+    with jax.ensure_compile_time_eval():
+        for qi in range(nq):
+            seen = np.zeros(nk, bool)
+            for first, end, partial in kind.key_segments(
+                    s, jnp.int32(qi), bq, bk, nk):
+                ks = np.arange(int(first), int(end))
+                assert not seen[ks].any()
+                seen[ks] = True
+                assert partial or every[qi, ks].all()
+            assert np.array_equal(seen, some[qi])
+        zeroed, written = np.zeros(nq, int), np.zeros(nq, int)
+        for ki in range(nk):
+            worked = []
+            for step in range(steps):
+                qi = int(kind.query_block(s, jnp.int32(ki), jnp.int32(step),
+                                          bq, bk))
+                at = int(kind.first_query_block(
+                    s, jnp.int32(ki), jnp.int32(step), bq, bk))
+                bare, visible = kind.tile(s, jnp.int32(qi), jnp.int32(ki),
+                                          bq, bk)
+                inside = qi < nq and some[qi, ki]
+                assert bool(visible) == inside
+                assert some[at, ki] and (at == qi or not inside)
+                assert not bool(bare) or every[qi, ki]
+                first = bool(kind.first_key_block(
+                    s, jnp.int32(qi), jnp.int32(ki), bq, bk))
+                last = bool(kind.last_key_block(
+                    s, jnp.int32(qi), jnp.int32(ki), bq, bk, nk, visible))
+                assert not (first or last) or inside
+                if inside:
+                    worked.append(qi)
+                    # zeroed before anything is added, written after all is
+                    assert first == (not some[qi, :ki].any())
+                    assert last == (not some[qi, ki + 1:].any())
+                    zeroed[qi] += first
+                    written[qi] += last
+            assert worked == list(np.flatnonzero(some[:, ki]))
+        assert (zeroed == 1).all() and (written == 1).all()
+    if window < s and bk < s:
+        # the case the issue names: a first visible k block that is not 0
+        assert some[-1, 0] == (window + bq > s)
+
+
+def test_the_cells_window_visits_31_of_256_tiles():
+    """At the cell's own shape and the kernels' own blocks: 16 x 16 tiles
+    of 512, two a q block but the first; and the blocks are the chooser's
+    own, the window asks for none (PERF.md section 6, PR 42)."""
+    kind = fa.SlidingWindowMask(512)
+    blocks = fa._resolve_blocks(8192, 128, jnp.bfloat16, None, None, False,
+                                kind.span(8192))
+    assert blocks == (512, 512)
+    assert fa.tiles_visited(kind, 8192, *blocks) == (31, 31, 256)
+    assert kind.query_steps(8192, *blocks) == 2
+    assert fa.tiles_visited(fa.CAUSAL, 8192, *blocks)[0] == 136
+
+
+def test_the_windows_calls_carry_names_of_their_own(rng, caplog):
+    """A trace tells a window call from a full one: the two ``pallas_call``
+    names hold none of the flash kernels' names; the standing kinds keep
+    theirs; the mask kind is said and counted."""
+    import logging
+
+    from horovod_tpu.common import metrics, scopes
+
+    assert not any(flash in swa for swa in scopes.SWA_KERNELS
+                   for flash in scopes.FLASH_KERNELS)
+    q, k, v = _qkv(rng, s=64, d=16)
+
+    def names(kind):
+        text = str(jax.make_jaxpr(jax.grad(lambda q: flash_attention(
+            q, k, v, mask_kind=kind, use_pallas=True, block_q=16,
+            block_k=16).sum()))(q))
+        return {n for n in scopes.SWA_KERNELS + scopes.FLASH_KERNELS
+                if f"name={n}" in text or n in text}
+
+    fa._say_path.cache_clear()
+    with caplog.at_level(logging.INFO, logger="horovod_tpu"):
+        assert names(fa.SlidingWindowMask(24)) == set(scopes.SWA_KERNELS)
+    assert names(fa.CAUSAL) == {scopes.FLASH_FWD, scopes.FLASH_DKV}
+    assert names(BD) == {scopes.FLASH_FWD, scopes.FLASH_DKV}
+    said = [r.getMessage() for r in caplog.records
+            if "flash_attention" in r.getMessage()]
+    assert len(said) == 1 and "mask_kind=sliding_window" in said[0] \
+        and "causal=False" in said[0] and "(9 of 16 tiles visited)" in said[0]
+    snapshot = metrics.snapshot()
+    assert any(s["labels"].get("mask_kind") == "sliding_window"
+               and s["labels"]["seq_len"] == "64"
+               for s in snapshot["hvd_tpu_flash_attention_traces_total"][
+                   "samples"])
+    tiles = {s["labels"]["tiles"]: s["value"]
+             for s in snapshot["hvd_tpu_flash_attention_tiles"]["samples"]
+             if s["labels"]["mask_kind"] == "sliding_window"
+             and s["labels"]["seq_len"] == "64"
+             and s["labels"]["block_q"] == "16"}
+    assert tiles == {"visited": 9, "square": 16}    # 1 + 2 + 3 + 3

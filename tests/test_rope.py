@@ -4,6 +4,7 @@ the plain reference: forward and gradient, every width that packs and two
 that do not, the Pallas kernels' bodies in interpret mode against their
 jnp twin, and the counter that says which layout a traced call took."""
 
+import functools
 import logging
 
 import jax
@@ -263,3 +264,158 @@ def test_a_models_step_counts_its_layout_and_says_it_once(caplog):
              vocab_size=64), tokens)
     assert _count("per_head", 96) == before["per_head", 96] + 2 * 2
     assert _count("packed", 96) == before["packed", 96]
+
+
+# -- one description of a rotation --------------------------------------------
+#
+# ``Rotation``: the rotary width (the first ``width`` channels of a head,
+# half-split pairs inside them, the rest pass through), the inverse
+# frequencies (a base's, or YaRN's blend) and a scale on cos and sin. The
+# plain one (``base=`` alone) is every test above; here the others, each
+# and together, packed against a head at a time against the formulas
+# written out below in numpy.
+
+YARN = dict(factor=8.0, original_length=16, beta_fast=4.0, beta_slow=1.0)
+ROTATIONS = {
+    "partial": rope_lib.Rotation(base=10000.0, width=None),     # set by d
+    "yarn": rope_lib.Rotation(base=100.0, scale=1.25, **YARN),
+    "partial_yarn": rope_lib.Rotation(base=100.0, scale=1.25, **YARN),
+    "scaled": rope_lib.Rotation(base=1e4, scale=0.5),
+}
+
+
+def _rotation(which, d):
+    rotation = ROTATIONS[which]
+    if which.startswith("partial"):
+        rotation = rope_lib.Rotation(**{**rotation.__dict__, "width": d // 2})
+    return rotation
+
+
+def _inv_freq(rotation, rot):
+    """The formulas of the issue, in float64."""
+    i = np.arange(rot // 2, dtype=np.float64)
+    plain = rotation.base ** (-2.0 * i / rot)
+    if rotation.factor == 1.0:
+        return plain
+
+    def corr(turns):
+        return rot * np.log(rotation.original_length / (2 * np.pi * turns)) \
+            / (2 * np.log(rotation.base))
+
+    low = max(np.floor(corr(rotation.beta_fast)), 0)
+    high = min(np.ceil(corr(rotation.beta_slow)), rot - 1)
+    ramp = np.clip((i - low) / (high - low), 0.0, 1.0)
+    return plain * (1 - ramp) + plain / rotation.factor * ramp
+
+
+def described(x, positions, rotation):
+    """The rotation a ``Rotation`` describes, a head at a time in fp32."""
+    b, s, h, d = x.shape
+    rot = d if rotation.width is None else rotation.width
+    half = rot // 2
+    if positions is None:
+        positions = jnp.arange(s)[None, :]
+    angles = positions.astype(jnp.float32)[:, :, None] \
+        * jnp.asarray(_inv_freq(rotation, rot), jnp.float32)
+    cos = (jnp.cos(angles) * rotation.scale)[:, :, None, :]
+    sin = (jnp.sin(angles) * rotation.scale)[:, :, None, :]
+    x = x.astype(jnp.float32)
+    x1, x2 = x[..., :half], x[..., half:rot]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos,
+                            x[..., rot:]], -1)
+
+
+def _near(got, want, dtype, ulps=4):
+    assert got.shape == want.shape
+    gap = np.abs(np.asarray(got, np.float32) - np.asarray(want, np.float32))
+    bound = ulps * _ulp(dtype) * (np.abs(np.asarray(want, np.float32)) + 1.0)
+    assert (gap <= bound).all(), gap.max()
+
+
+@pytest.mark.parametrize("positions", [False, True],
+                         ids=["arange", "positions"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("h, d", [(2, 128), (9, 128), (4, 64), (2, 32),
+                                  (3, 64)],
+                         ids=["d128", "h9_d128", "packed_d64", "packed_d32",
+                              "perhead_h3_d64"])
+@pytest.mark.parametrize("which", sorted(ROTATIONS))
+def test_a_described_rotation_is_its_formulas(which, h, d, dtype, positions):
+    """Partial and YaRN each and together, and a bare scale: the packed
+    pass (or a head at a time where the widths do not pack), the kernels'
+    bodies in interpret mode and ``rotate_heads`` against the formulas,
+    values and gradients."""
+    rotation = _rotation(which, d)
+    x, w, pos = _operands(h, d, dtype, positions)
+    want = described(x, pos, rotation)
+    want_grad = jax.grad(lambda x: (described(x, pos, rotation)
+                                    * w.astype(jnp.float32)).sum())(x)
+    forms = {
+        "rotate": lambda x, p: rope_lib.rotate(x, p, rotation=rotation),
+        "kernels": lambda x, p: rope_lib.rotate(x, p, rotation=rotation,
+                                                use_pallas=True),
+        "heads": lambda x, p: rope_lib.rotate_heads(x, p, rotation=rotation),
+    }
+    layout = "packed" if _packs(h, d) else "per_head"
+    for name, form in forms.items():
+        got = form(x, pos)
+        assert got.dtype == x.dtype
+        _near(got, want, dtype)
+        _near(_grad(form, x, w, pos), want_grad, dtype)
+    if _packs(h, d):
+        assert _pallas_names(lambda x: _grad(forms["kernels"], x, w, pos),
+                             x) == [scopes.ROPE_FWD, scopes.ROPE_BWD]
+    samples = metrics_lib.snapshot()["hvd_tpu_rope_paths"]["samples"]
+    assert any(s["labels"] == {"head_dim": str(d), "layout": layout,
+                               "rotation": rotation.name} and s["value"] > 0
+               for s in samples)
+    if which.startswith("partial"):
+        # the channels past the rotary width pass through to the bit
+        assert (forms["rotate"](x, pos)[..., d // 2:] == x[..., d // 2:]).all()
+        assert (forms["kernels"](x, pos)[..., d // 2:]
+                == x[..., d // 2:]).all()
+
+
+@pytest.mark.parametrize("form", ["rotate", "kernels", "heads"])
+@pytest.mark.parametrize("h, d", [(2, 128), (4, 64), (3, 64)])
+def test_the_plain_rotation_is_unchanged_to_the_bit(h, d, form):
+    """``base=`` is the short way to say ``Rotation(base)``: the same
+    values and gradients bit for bit, and the formula's as before."""
+    x, w, pos = _operands(h, d, jnp.bfloat16, True)
+    rotate = {"rotate": rope_lib.rotate,
+              "kernels": functools.partial(rope_lib.rotate, use_pallas=True),
+              "heads": rope_lib.rotate_heads}[form]
+
+    def short(x, p):
+        return rotate(x, p, base=1e6)
+
+    def long(x, p):
+        return rotate(x, p, rotation=rope_lib.Rotation(base=1e6))
+
+    assert (short(x, pos) == long(x, pos)).all()
+    assert (_grad(short, x, w, pos) == _grad(long, x, w, pos)).all()
+    _close(short(x, pos), reference(x, pos, 1e6), x, 1)
+    assert rope_lib.Rotation(1e6).name == "plain"
+    assert rope_lib.Rotation(1e6, width=32).name == "partial"
+    assert rope_lib.Rotation(1e6, factor=2.0, original_length=64).name \
+        == "yarn"
+
+
+def test_the_published_yarn_table():
+    """The full layers' rotation of the window-and-full cell, from its
+    published numbers: low 9, high 18; the first nine frequencies are the
+    plain ones, those from the eighteenth on the plain ones over 128."""
+    rotation = rope_lib.Rotation(base=500000.0, width=64, factor=128.0,
+                                 original_length=8192, beta_fast=32.0,
+                                 beta_slow=1.0, scale=1.4852030263919618)
+    table = np.asarray(rotation.inv_freq(
+        jnp.arange(32, dtype=jnp.float32), 64), np.float64)
+    plain = 500000.0 ** (-np.arange(32) / 32)
+    np.testing.assert_allclose(table[:10], plain[:10], rtol=1e-6)
+    np.testing.assert_allclose(table[18:], plain[18:] / 128, rtol=1e-6)
+    assert (table[10:18] < plain[10:18]).all() \
+        and (table[10:18] > plain[10:18] / 128).all()
+    np.testing.assert_allclose(table, _inv_freq(rotation, 64), rtol=1e-6)
+    with pytest.raises(ValueError, match="rotary width"):
+        rope_lib.Rotation(width=130).rotary(128)

@@ -364,12 +364,12 @@ ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.BLOCK_SCOPES + scopes.BLOCK_DIFFUSION_SCOPES
              + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
-             + scopes.ROPE_KERNELS)
+             + scopes.ROPE_KERNELS + scopes.SWA_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 33
+    assert len(values) == len(set(values)) == 35
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
@@ -451,6 +451,64 @@ def test_a_gated_convolution_models_scopes_are_on_its_step(lfm2_op_names,
     # this model has no shared expert
     assert not any(_under(scopes.MOE_SHARED).search(n)
                    for n in lfm2_op_names)
+
+
+@pytest.fixture(scope="module")
+def laguna_op_names():
+    """Every ``op_name`` of a tiny window-and-full model's differentiated
+    step, as lowered: layers F W W, the first dense."""
+    from horovod_tpu.models import LagunaLM, laguna_loss
+    from horovod_tpu.ops.rope import Rotation
+
+    model = LagunaLM(
+        vocab_size=64, num_layers=3, hidden=32,
+        layer_types=("full_attention", "sliding_attention",
+                     "sliding_attention"),
+        heads_per_layer=(2, 3, 3), num_kv_heads=1, head_dim=16, window=8,
+        full_rotation=Rotation(base=100.0, width=8, factor=8.0,
+                               original_length=16, beta_fast=4.0,
+                               scale=1.2),
+        mlp_layer_types=("dense", "sparse", "sparse"), mlp_dim=48,
+        num_experts=8, held_experts=(2, 4), top_k=2, expert_dim=16,
+        shared_dim=16)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    text = jax.jit(jax.value_and_grad(
+        lambda p: laguna_loss(model, p, tokens))).lower(params).as_text(
+            debug_info=True)
+    return set(re.findall(r'"(jit\([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", scopes.BLOCK_SCOPES + scopes.MOE_SCOPES
+                         + (scopes.LM_HEAD,))
+def test_a_window_and_full_models_scopes_are_on_its_step(laguna_op_names,
+                                                         scope):
+    """The gate and the projections under ``hvd_mixer_proj`` with the
+    rotation inside it, the dense layer under ``hvd_mlp``, the expert
+    layers under the three of ``MOE_SCOPES``, the norms, the embedding and
+    the loss under theirs: forward and backward, and only in the layers
+    that have them, so that ``tools/block_parts.py`` prints this family's
+    parts as it prints the others'."""
+    under = [n for n in laguna_op_names if _under(scope).search(n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    layers = {m for n in under for m in re.findall(r"layer\d", n)}
+    every, sparse = {"layer0", "layer1", "layer2"}, {"layer1", "layer2"}
+    assert layers == {
+        scopes.MIXER_PROJ: every, scopes.ROPE: every, scopes.NORM: every,
+        scopes.MLP: {"layer0"}, scopes.MOE_ROUTE: sparse,
+        scopes.MOE_EXPERTS: sparse, scopes.MOE_SHARED: sparse,
+        scopes.EMBED: set(), scopes.LOSS: set(),
+        scopes.LM_HEAD: set()}[scope]
+    if scope == scopes.ROPE:    # nested in the projections, and only there
+        assert all(_under(scopes.MIXER_PROJ).search(n) for n in under)
+    if scope == scopes.MIXER_PROJ:      # the gate's map is among them
+        assert any("gate" in n for n in under)
+    siblings = (scopes.MIXER_PROJ, scopes.MLP, scopes.NORM, scopes.EMBED,
+                scopes.LOSS, scopes.LM_HEAD) + scopes.MOE_SCOPES
+    if scope != scopes.ROPE:
+        for n in under:     # no instruction under two of them
+            assert sum(bool(_under(s).search(n)) for s in siblings) == 1, n
 
 
 @pytest.fixture(scope="module")

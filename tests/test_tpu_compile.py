@@ -284,6 +284,96 @@ def test_the_block_diffusion_mask_compiles_for_v5e(v5e, on_tpu):
     assert len(re.findall(r"%[\w.]*hvd_flash_dkv[\w.]* = ", hlo)) == 1
 
 
+def test_the_window_compiles_for_v5e(v5e, on_tpu):
+    """The window-and-full cell's window layers' attention call, forward
+    and backward: 72 query heads of 128 on 8 K/V heads (groups of 9
+    through the index maps: K and V cross as they lie), S 8192, bf16,
+    under ``SlidingWindowMask(512)``. The two Mosaic calls carry the
+    window's own names and none of the flash kernels'; the backward's
+    grid is the band (two q blocks a k block at blocks of 512), which
+    interpret mode cannot vouch for the index maps of; and a window that
+    is no multiple of the block, narrower than one, or past S compiles
+    too."""
+    b, s, h, hkv, d = 1, 8192, 72, 8, 128
+    kind = fa.SlidingWindowMask(512)
+    assert fa._resolve_blocks(s, d, jnp.bfloat16, None, None, False,
+                              kind.span(s)) == (512, 512)
+    assert kind.query_steps(s, 512, 512) == 2
+
+    def loss(kind):
+        def f(q, k, v):
+            return fa.flash_attention(q, k, v, mask_kind=kind) \
+                .astype(jnp.float32).sum()
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    specs = (((b, s, h, d), jnp.bfloat16),
+             *[((b, s, hkv, d), jnp.bfloat16)] * 2)
+    hlo = _compile(loss(kind), v5e, *specs)
+    assert _mosaic_calls(hlo) == 2
+    assert "hvd_flash" not in hlo
+    wide, narrow = f"bf16[{b},{s},{h * d}]", f"bf16[{b},{s},{hkv * d}]"
+    fwd, = re.findall(r"%[\w.]*hvd_swa_fwd[\w.]* = [^\n]*", hlo)
+    operands = fwd.split("operand_layout_constraints=")[1]
+    assert operands.count(wide) == 1 and operands.count(narrow) == 2
+    bwd, = re.findall(r"%[\w.]*hvd_swa_bwd[\w.]* = [^\n]*", hlo)
+    assert bwd.split(" custom-call(")[0].count(wide) == 3
+    for window in (500, 100, 1300, 3 * s):
+        hlo = _compile(loss(fa.SlidingWindowMask(window)), v5e, *specs)
+        assert _mosaic_calls(hlo) == 2 and "hvd_swa_bwd" in hlo
+
+
+def test_the_window_and_full_cells_step_fits(v5e, on_tpu):
+    """The whole training step of the window-and-full cell (``LagunaLM``'s
+    defaults: layers 0-4 of the published stack, 48 and 72 query heads on
+    8, 8 of 256 experts, 1 x S8192, AdamW with bf16 first moments,
+    donated) compiled for a described v5e: 9.94 GiB, on the chip to the
+    digit (PERF.md, PR 42), under the 15.75 the issue set, so the heads
+    held did not have to be halved. Its flash calls are two kinds under
+    two names: two full layers (forward, forward again, one backward
+    each) and three window layers; the rotation is on packed rows (two
+    kernels a layer each way); one block of routes a layer and no
+    ``conditional``."""
+    import optax
+
+    from horovod_tpu.models import laguna
+
+    model = laguna.LagunaLM()
+    tokens = jax.ShapeDtypeStruct((1, 8193), jnp.int32, sharding=v5e)
+    tx = optax.adamw(1e-4, mu_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 811_017_216
+    state = jax.eval_shape(tx.init, params)
+
+    def step(params, state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: laguna.laguna_loss(model, p, tokens))(params)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, loss
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 9.5 < held / 2 ** 30 < 10.4
+    hlo = compiled.as_text()
+    assert len(re.findall(r" conditional\(", hlo)) == 0
+    calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    ours = sorted(re.sub(r"[.\d]+$", "", c) for c in calls
+                  if c.startswith("hvd_"))
+    assert ours == (["hvd_flash_dkv"] * 2 + ["hvd_flash_fwd"] * 4
+                    + ["hvd_rope_bwd"] * 10 + ["hvd_rope_fwd"] * 20
+                    + ["hvd_swa_bwd"] * 3 + ["hvd_swa_fwd"] * 6)
+
+
 # (B, S, H, D), dtype: q of the three GPT cells' kind, of the looped cell
 # (one head a tile), of the gated-convolution cell and its eight K/V
 # heads, and the narrow and wide widths no cell runs.
