@@ -139,6 +139,61 @@ def _bucket_kernels(dtype, n):
     out["quantize_int8_stochastic.scale_mismatches"] = int(
         jnp.sum(ss != ss_twin))
 
+    def with_residual(use, x, r):
+        q, s, _, res = pk.quantize_int8_stochastic(
+            x, ku, use_pallas=use, plus=r, return_residual=True)
+        return res, q, s
+
+    # The error-feedback form: (x + r) quantised and its residual written
+    # by the same kernel. On a TPU the residual is held to the twin's
+    # bits (no fused multiply-add on a v5e: kernel and XLA's fusion both
+    # round q * scale before they subtract); XLA:CPU contracts the two in
+    # some programs, so elsewhere to one rounding of the product.
+    (res, qr, sr), (res_twin, qr_twin, sr_twin) = _kernel_and_twin(
+        with_residual, a, b.astype(jnp.float32) * 0.01)
+    out["quantize_int8_stochastic.residual_q_mismatches"] = int(
+        jnp.sum(qr != qr_twin) + jnp.sum(sr != sr_twin))
+    out["quantize_int8_stochastic.residual"] = _excess(
+        res, res_twin, 0.0,
+        atol=0.0 if jax.default_backend() == "tpu" else 2 ** -23 * scale)
+
+    # The same against the forms these kernels took the place of
+    # (collectives.quantized_allreduce before it handed the residual and
+    # the gathered result to them), written out here in plain jnp and
+    # sharing no line with the kernels' body: g + r formed outside, the
+    # residual by dequantising the whole buffer on the (blocks, 4096)
+    # view, and a four-rank mean divided after the dequantise. Counted,
+    # not tolerated: the reduction is to be the same result on the chip.
+    ranks = 4
+    m = n // (ranks * 4096) * ranks * 4096      # the reduction's own grid
+    r32 = b[:m].astype(jnp.float32) * 0.01
+
+    def deq_before(qq, sc):
+        blocks = qq.reshape(-1, 4096).astype(jnp.float32) * sc[:, None]
+        return blocks.reshape(-1)
+
+    def residual_before(x, r):
+        flat = x.astype(jnp.float32) + r
+        q, s, _ = pk.quantize_int8_stochastic(flat, ku)
+        return flat - deq_before(q, s), q, s
+
+    res_was, q_was, s_was = jax.jit(residual_before)(a[:m], r32)
+    res_now, q_now, s_now = jax.jit(
+        functools.partial(with_residual, True))(a[:m], r32)
+    out["residual_as_it_was.q_and_scale_mismatches"] = int(
+        jnp.sum(q_now != q_was) + jnp.sum(s_now != s_was))
+    out["residual_as_it_was.mismatches"] = int(jnp.sum(res_now != res_was))
+
+    def mean_now(qq, sc):
+        return pk.dequantize_int8(qq, sc * jnp.float32(1.0 / ranks), m, (m,),
+                                  use_pallas=True)
+
+    def mean_before(qq, sc):
+        return deq_before(qq, sc) / jnp.float32(ranks)
+
+    out["mean_as_it_was.mismatches"] = int(jnp.sum(
+        jax.jit(mean_now)(q_was, s_was) != jax.jit(mean_before)(q_was, s_was)))
+
     def dequantize(use, qq, sc):
         return pk.dequantize_int8(qq, sc, n, (n,), dtype, use_pallas=use)
 

@@ -222,14 +222,24 @@ def assign_alltoall_wire(nbytes: int,
     return small_wire or WIRE_NONE
 
 
-def fuse(tree, plan: FusionPlan) -> List[jnp.ndarray]:
+def fuse(tree, plan: FusionPlan,
+         lengths: Optional[Sequence[int]] = None) -> List[jnp.ndarray]:
     """Concatenate each bucket's leaves into one flat array
-    (the MemcpyInFusionBuffer analog, collective_operations.h:97-110)."""
+    (the MemcpyInFusionBuffer analog, collective_operations.h:97-110).
+
+    ``lengths[i]``, where it is more than bucket ``i`` holds, is the
+    length its flat array is made: zeros join the same concatenate, so a
+    reduction on a block grid (the int8 wire's n x 4096) finds its buffer
+    aligned and pads nothing itself, which would be one more pass over
+    it. :func:`unfuse` does not look at the tail."""
     leaves = jax.tree.leaves(tree)
     flats = []
     with jax.named_scope(scopes.PACK):
-        for b in plan.buckets:
-            parts = [jnp.ravel(leaves[i]) for i in b.leaf_indices]
+        for i, b in enumerate(plan.buckets):
+            parts = [jnp.ravel(leaves[j]) for j in b.leaf_indices]
+            tail = lengths[i] - b.total_elems if lengths else 0
+            if tail > 0:
+                parts.append(jnp.zeros((tail,), parts[0].dtype))
             flats.append(parts[0] if len(parts) == 1
                          else jnp.concatenate(parts))
     return flats
@@ -237,7 +247,8 @@ def fuse(tree, plan: FusionPlan) -> List[jnp.ndarray]:
 
 def unfuse(flats: Sequence[jnp.ndarray], plan: FusionPlan):
     """Split flat buffers back into the original pytree
-    (the MemcpyOutFusionBuffer analog)."""
+    (the MemcpyOutFusionBuffer analog). A flat buffer may be longer than
+    its bucket (:func:`fuse`'s ``lengths``): the tail is left behind."""
     leaves: List[Any] = [None] * plan.num_leaves
     with jax.named_scope(scopes.UNPACK):
         for flat, b in zip(flats, plan.buckets):

@@ -583,26 +583,34 @@ def quantized_hierarchical_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
 _Q_BLOCK = 32 * 128
 
 
-def _int8_chunks(flat_pad, n, key, use_pallas):
+def _int8_chunks(flat_pad, n, key, use_pallas, **residual_args):
     """Quantize a (n*chunk,) fp32 buffer, chunk%4096==0, into per-rank
-    stacks: q (n, rows, 128) int8 + scales (n, nblocks) fp32."""
+    stacks: q (n, rows, 128) int8 + scales (n, nblocks) fp32, and after
+    them whatever ``residual_args`` (``plus``, ``prescale``,
+    ``return_residual`` of pallas_kernels.quantize_int8) ask for."""
     from .pallas_kernels import quantize_int8, quantize_int8_stochastic
 
     if key is None:
-        q, s, _ = quantize_int8(flat_pad, use_pallas=use_pallas)
+        q, s, _, *rest = quantize_int8(flat_pad, use_pallas=use_pallas,
+                                       **residual_args)
     else:
-        q, s, _ = quantize_int8_stochastic(flat_pad, key,
-                                           use_pallas=use_pallas)
+        q, s, _, *rest = quantize_int8_stochastic(
+            flat_pad, key, use_pallas=use_pallas, **residual_args)
     chunk = flat_pad.shape[0] // n
     return (q.reshape(n, chunk // 128, 128),
-            s.reshape(n, chunk // _Q_BLOCK))
+            s.reshape(n, chunk // _Q_BLOCK), *rest)
 
 
 def _deq(q, s):
     """Dequantize a stacked (…, rows, 128) int8 + (…, nblocks) scale pair
     to fp32 of shape (…, nblocks*4096) — the vectorized inverse of
-    :func:`_int8_chunks` (XLA fuses this into the surrounding consumer;
-    the standalone Pallas dequant kernel serves the host-staged paths)."""
+    :func:`_int8_chunks`, for a consumer XLA fuses it into: the sum over
+    ranks after the all-to-all is one fusion with it. A whole buffer
+    dequantised this way and then kept is not: XLA:TPU materialises the
+    convert, the scales' broadcast and the reshape to the flat order, each
+    a pass over HBM (PERF.md, PR 45), so the gathered result goes through
+    the Pallas dequantise kernel and the residual comes out of the
+    quantise kernel."""
     nb = s.shape[-1]
     lead = q.shape[:-2]
     blocks = q.reshape(lead + (nb, _Q_BLOCK)).astype(jnp.float32)
@@ -612,6 +620,28 @@ def _deq(q, s):
 # hvdlint: disable=ste-vjp -- reduction path: the int8_ef allreduce
 # building block runs on already-computed gradients with error
 # feedback; autodiff never crosses it (docs/compression.md).
+def _int8_reducescatter(x, n, axis_name, key, use_pallas, return_residual,
+                        plus=None, prescale=None):
+    """The int8 hop both reductions below share: quantise ``(x + plus) *
+    prescale`` (``x`` 1-D on the n x 4096 grid, any float dtype), send
+    chunk ``j``'s int8 and scales to rank ``j``, sum them there. Returns
+    the owned chunk's SUM **in fp32 whatever ``x``'s dtype** (what is
+    requantised for the second hop must not pass through bf16 on its
+    way) and a list holding the kernel's fp32 residual where wanted."""
+    # The residual is the quantise kernel's own output: no dequantise
+    # of the whole buffer beside it.
+    q, s, *residual = _int8_chunks(x, n, key, use_pallas, plus=plus,
+                                   prescale=prescale,
+                                   return_residual=return_residual)
+    if n == 1:
+        return _deq(q[0], s[0]), residual
+    # int8 reduce-scatter: rank j receives chunk j from every rank
+    # (the scales ride alongside their blocks), then dequant-sums.
+    qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0)
+    sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0)
+    return jnp.sum(_deq(qx, sx), axis=0), residual
+
+
 def quantized_reducescatter(x, op: ReduceOp = ReduceOp.SUM,
                             axis_name: str = "hvd", key=None,
                             use_pallas=None, return_residual: bool = False):
@@ -639,28 +669,20 @@ def quantized_reducescatter(x, op: ReduceOp = ReduceOp.SUM,
             f"quantized_reducescatter needs a 1-D buffer with length "
             f"divisible by n*4096 = {n * _Q_BLOCK}; got {x.shape} "
             "(zero-pad — pads quantize to exact 0)")
-    flat = x.astype(jnp.float32)
-    q, s = _int8_chunks(flat, n, key, use_pallas)
-    if n == 1:
-        own = _deq(q[0], s[0])
-    else:
-        # int8 reduce-scatter: rank j receives chunk j from every rank
-        # (the scales ride alongside their blocks), then dequant-sums.
-        qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0)
-        sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0)
-        own = jnp.sum(_deq(qx, sx), axis=0)
+    own, residual = _int8_reducescatter(x, n, axis_name, key, use_pallas,
+                                        return_residual)
     if op == ReduceOp.AVERAGE:
         own = own / jnp.asarray(n, own.dtype)
     if not return_residual:
         return own.astype(x.dtype)
-    residual = flat - _deq(q, s).reshape(flat.shape)
-    return own.astype(x.dtype), residual
+    return own.astype(x.dtype), residual[0]
 
 
 def quantized_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
                         axis_name: str = "hvd", wire: str = "int8",
                         key=None, use_pallas=None,
-                        return_residual: bool = False):
+                        return_residual: bool = False, _hop_keys=None,
+                        _plus=None, _prescale=None):
     """Reduce-safe quantized allreduce: block-scaled int8 on every hop.
 
     Decomposition (any shape/dtype ``x``; works on a flat 1-D mesh axis):
@@ -695,6 +717,13 @@ def quantized_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
     linear reductions); ``wire`` names the payload dtype — only
     ``"int8"`` exists today (tiny buckets ride bf16 via the fusion
     planner's ``wire_dtypes``, common/fusion.py, not through here).
+
+    Private, for optim._reduce_tree_ef, which reduces many buckets a
+    step: ``_hop_keys`` are ``fold_in(key, 0)`` and ``fold_in(key, 1)``
+    derived by the caller for all its buckets at once (a threefry on
+    scalars is some 120 instructions of a compiled step, a bucket and a
+    hop), and what is reduced is ``(x + _plus) * _prescale``, formed in
+    the quantise kernel (``_plus``: the fp32 residual, of ``x``'s shape).
     """
     if wire != "int8":
         raise ValueError(f"unsupported wire format {wire!r}; only 'int8'")
@@ -703,35 +732,62 @@ def quantized_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
                          "(per-block scales only compose with linear "
                          "reductions)")
     n = lax.axis_size(axis_name)
-    orig_dtype = x.dtype
+    # The result is in the dtype of what was reduced: x's own, or the
+    # sum's where the fp32 residual joins it.
+    orig_dtype = (x.dtype if _plus is None
+                  else jnp.result_type(x.dtype, _plus.dtype))
     size = int(x.size)
     if n == 1:
         # No wire at all — quantizing would add pure rounding loss.
+        if _plus is not None:
+            x = x.astype(jnp.float32) + _plus
+        x = _apply_scale(x, _prescale)
         y = x if op == ReduceOp.SUM else x / jnp.asarray(1, x.dtype)
         if return_residual:
             return y, jnp.zeros(x.shape, jnp.float32)
         return y
-    flat = x.astype(jnp.float32).reshape(-1)
     # Per-rank chunks of whole 32x128 blocks: pad to a multiple of
-    # n*_Q_BLOCK (== ceil-align of the per-rank chunk).
+    # n*_Q_BLOCK (== ceil-align of the per-rank chunk). A caller that
+    # packs its own buffers hands them in aligned (fusion.fuse) and
+    # nothing is padded or sliced here: each would be a pass over HBM.
     chunk = -(-size // (n * _Q_BLOCK)) * _Q_BLOCK
-    flat = jnp.pad(flat, (0, n * chunk - size))
 
-    kc = None if key is None else jax.random.fold_in(key, 0)
-    rs = quantized_reducescatter(flat, ReduceOp.SUM, axis_name, key=kc,
-                                 use_pallas=use_pallas,
-                                 return_residual=return_residual)
-    own, residual = rs if return_residual else (rs, None)
-    own = own.astype(jnp.float32)
+    def aligned(a):
+        a = a.reshape(-1)
+        return a if a.size == n * chunk else jnp.pad(
+            a.astype(jnp.float32), (0, n * chunk - size))
 
-    # Requantize the reduced chunk and all-gather it back (hop 2).
-    kr = None if key is None else jax.random.fold_in(key, 1)
-    qr, sr = _int8_chunks(own, 1, kr, use_pallas)
+    if key is None:
+        kc = kr = None
+    elif _hop_keys is None:
+        kc, kr = jax.random.fold_in(key, 0), jax.random.fold_in(key, 1)
+    else:
+        kc, kr = _hop_keys
+    # The owned chunk's sum stays fp32 from here to the requantise,
+    # whatever x's dtype: a bf16 bucket on the grid is not padded, so
+    # nothing above has cast it.
+    own, residual = _int8_reducescatter(
+        aligned(x), n, axis_name, kc, use_pallas, return_residual,
+        plus=None if _plus is None else aligned(_plus), prescale=_prescale)
+
+    # Requantize the reduced chunk and all-gather it back (hop 2). Its
+    # error, where the residual is wanted, leaves the kernel with it.
+    qr, sr, *err_own = _int8_chunks(own, 1, kr, use_pallas,
+                                    return_residual=return_residual)
     qg = lax.all_gather(qr[0], axis_name)           # (n, rows, 128)
     sg = lax.all_gather(sr[0], axis_name)           # (n, nblocks)
-    red = _deq(qg, sg).reshape(-1)[:size]
-    y = red.reshape(x.shape)
-    if op == ReduceOp.AVERAGE:
+    # The gathered int8 is dequantised once, in the kernel, on the
+    # (n * blocks, 32, 128) view that is the flat result's own order. The
+    # mean's 1/n rides on the scales where that is exact (n a power of
+    # two: the same bits as dividing the products), else it follows.
+    from .pallas_kernels import dequantize_int8
+
+    mean_on_scales = op == ReduceOp.AVERAGE and n & (n - 1) == 0
+    if mean_on_scales:
+        sg = sg * jnp.asarray(1.0 / n, sg.dtype)
+    y = dequantize_int8(qg.reshape(-1, 128), sg.reshape(-1), size, x.shape,
+                        use_pallas=use_pallas)
+    if op == ReduceOp.AVERAGE and not mean_on_scales:
         y = y / jnp.asarray(n, y.dtype)
     y = y.astype(orig_dtype)
     if not return_residual:
@@ -741,12 +797,13 @@ def quantized_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
     # across ranks through next step's reduction, so the owner carrying
     # it corrects the global value just the same.
     me = lax.axis_index(axis_name)
-    err_own = own - _deq(qr[0], sr[0])
+    residual = residual[0]
     cur = lax.dynamic_slice_in_dim(residual, me * chunk, chunk)
     residual = lax.dynamic_update_slice_in_dim(
-        residual, cur + err_own, me * chunk, 0)
-    residual = residual[:size].reshape(x.shape)
-    return y, residual
+        residual, cur + err_own[0], me * chunk, 0)
+    if residual.size != size:
+        residual = residual[:size]
+    return y, residual.reshape(x.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -1134,7 +1191,7 @@ def mesh_allgather(x, plan: Optional[WirePlan] = None, key=None,
 def mesh_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
                    plan: Optional[WirePlan] = None, key=None,
                    use_pallas=None, return_residual: bool = False,
-                   adasum_scalar_dtype=None):
+                   adasum_scalar_dtype=None, _hop_keys=None):
     """Topology-routed allreduce: per-axis RS descent -> final-axis
     reduction -> per-axis AG ascent, with PER-AXIS WIRE DTYPES.
 
@@ -1173,6 +1230,10 @@ def mesh_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
     the linear fast-axis sum — the Adasum recursion then consumes
     corrected local sums). Ascent-hop errors are carried once (owner-
     masked on the already-reduced axes).
+
+    ``_hop_keys`` (private, optim._reduce_tree_ef's): ``fold_in(key, k)``
+    for every hop ``k`` of the plan, 2 x phases - 1 of them, derived by
+    the caller for all its buckets at once.
     """
     plan = WirePlan.resolve(plan)
     if plan is None:
@@ -1193,7 +1254,8 @@ def mesh_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
     align = _Q_BLOCK if any_int8 else 1
     grid = N * align
     L = -(-size // grid) * grid
-    flat = jnp.pad(flat, (0, L - size))
+    if L != size:       # a caller that packs its own buffers aligns them
+        flat = jnp.pad(flat, (0, L - size))
     # Byte accounting over the PADDED length — the wire carries the
     # whole block-aligned buffer, not the caller's element count.
     _count_mesh_bytes(plan, L, ns, op)
@@ -1205,7 +1267,10 @@ def mesh_allreduce(x, op: ReduceOp = ReduceOp.AVERAGE,
     kidx = 0
 
     def fold(k):
-        return None if key is None else jax.random.fold_in(key, k)
+        if key is None:
+            return None
+        return (jax.random.fold_in(key, k) if _hop_keys is None
+                else _hop_keys[k])
 
     # -- descent: RS over the fast axes, each in its wire ------------------
     for p, n in zip(phases[:-1], ns[:-1]):

@@ -249,21 +249,51 @@ def _block_scale(xf):
                              axis=1, keepdims=True))
 
 
-def _quant_kernel(x_ref, q_ref, s_ref):
-    xf = x_ref[...].astype(jnp.float32)
-    scale = _block_scale(xf)
-    q_ref[...] = jnp.clip(jnp.round(xf / scale), -127, 127).astype(jnp.int8)
-    s_ref[...] = scale
-
-
-def _quant_sr_kernel(x_ref, u_ref, q_ref, s_ref):
-    xf = x_ref[...].astype(jnp.float32)
+def _quantize_blocks(xf, u, want_residual):
+    """The quantisation of fp32 blocks ``xf`` (blocks, 32, 128), the body
+    of the kernels and of their jnp twin alike, so the two are equal to
+    the bit: one absmax scale a block, round to nearest, or up where the
+    threshold ``u`` lies under the fractional part. Returns ``(q, scale,
+    residual)``: q as fp32 whole numbers in [-127, 127], the scales
+    (blocks, 1, 1) and ``xf - q * scale`` (None unless wanted)."""
     scale = _block_scale(xf)
     scaled = xf / scale
-    fl = jnp.floor(scaled)
-    q = fl + (u_ref[...] < (scaled - fl)).astype(jnp.float32)
-    q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    s_ref[...] = scale
+    if u is None:
+        q = jnp.round(scaled)
+    else:
+        fl = jnp.floor(scaled)
+        q = fl + (u < (scaled - fl)).astype(jnp.float32)
+    q = jnp.clip(q, -127, 127)
+    return q, scale, (xf - q * scale) if want_residual else None
+
+
+def _corrected(x, plus, prescale):
+    """``(x + plus) * prescale`` in fp32, either of the two left out where
+    it is None: what the quantisers quantise."""
+    xf = x.astype(jnp.float32)
+    if plus is not None:
+        xf = xf + plus
+    if prescale is not None:
+        xf = xf * prescale
+    return xf
+
+
+def _quant_kernel(*refs, plus, stochastic, prescale, want_residual):
+    """Operands ``x[, plus][, u]``, results ``q, scales[, residual]``. The
+    block quantised is ``(x + plus) * prescale`` in fp32: the error-
+    feedback path's corrected gradient, formed here so that neither the
+    sum nor the residual ``x - q * scale`` is a pass over HBM of its own
+    (optim._reduce_tree_ef). No PRNG in here: the thresholds are an
+    operand."""
+    refs = list(refs)
+    x = refs.pop(0)[...]
+    xf = _corrected(x, refs.pop(0)[...] if plus else None, prescale)
+    u = refs.pop(0)[...] if stochastic else None
+    q, scale, residual = _quantize_blocks(xf, u, want_residual)
+    refs[0][...] = q.astype(jnp.int8)
+    refs[1][...] = scale
+    if want_residual:
+        refs[2][...] = residual
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
@@ -282,29 +312,52 @@ def _q_specs(nblocks):
     return (pl.cdiv(nblocks, group),), data, scale
 
 
-def _quantize_call(kernel, operands, interpret, name):
-    """Run a quantize kernel over (rows, 128) operands; returns q as
-    (rows, 128) int8 and one fp32 scale per 32-row block. ``name`` is
-    the kernel's name in a device trace (common/scopes.py)."""
-    rows = operands[0].shape[0]
-    nblocks = rows // _Q_ROWS
-    grid, data, scale = _q_specs(nblocks)
-    q, scales = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[data] * len(operands),
-        out_specs=[data, scale],
-        out_shape=[
-            jax.ShapeDtypeStruct((nblocks, _Q_ROWS, _LANES), jnp.int8),
-            jax.ShapeDtypeStruct((nblocks, 1, 1), jnp.float32),
-        ],
-        interpret=interpret,
-        name=name,
-    )(*(x.reshape(nblocks, _Q_ROWS, _LANES) for x in operands))
-    return q.reshape(rows, _LANES), scales.reshape(nblocks)
+def _quantize(x, key, use_pallas, plus, prescale, return_residual):
+    """Both quantisers: ``(x + plus) * prescale`` to int8 by 4,096-element
+    blocks, by the kernel or by its twin. See :func:`quantize_int8`."""
+    use, interpret = _decide(use_pallas)
+    x2, n = _to_rows(x, sublane=_Q_ROWS)
+    nblocks = x2.shape[0] // _Q_ROWS
+    view = (nblocks, _Q_ROWS, _LANES)   # the flat buffer's own order
+    operands = [x2.reshape(view)]
+    if plus is not None:
+        operands.append(_to_rows(plus.astype(jnp.float32),
+                                 sublane=_Q_ROWS)[0].reshape(view))
+    if key is not None:
+        operands.append(jax.random.uniform(key, x2.shape,
+                                           jnp.float32).reshape(view))
+    if not use:
+        q, scales, residual = _quantize_blocks(
+            _corrected(operands[0],
+                       operands[1] if plus is not None else None, prescale),
+            operands[-1] if key is not None else None, return_residual)
+        q = q.astype(jnp.int8)
+    else:
+        grid, data, scale = _q_specs(nblocks)
+        results = pl.pallas_call(
+            functools.partial(_quant_kernel, plus=plus is not None,
+                              stochastic=key is not None, prescale=prescale,
+                              want_residual=return_residual),
+            grid=grid,
+            in_specs=[data] * len(operands),
+            out_specs=[data, scale] + [data] * return_residual,
+            out_shape=[jax.ShapeDtypeStruct(view, jnp.int8),
+                       jax.ShapeDtypeStruct((nblocks, 1, 1), jnp.float32)]
+            + [jax.ShapeDtypeStruct(view, jnp.float32)] * return_residual,
+            interpret=interpret,
+            name=(scopes.INT8_QUANTIZE if key is None
+                  else scopes.INT8_QUANTIZE_SR),
+        )(*operands)
+        q, scales = results[:2]
+        residual = results[2] if return_residual else None
+    out = (q.reshape(x2.shape), scales.reshape(nblocks), n)
+    if return_residual:
+        out += (residual.ravel()[:n].reshape(x.shape),)
+    return out
 
 
-def quantize_int8(x, use_pallas: Optional[bool] = None):
+def quantize_int8(x, use_pallas: Optional[bool] = None, *, plus=None,
+                  prescale=None, return_residual: bool = False):
     """Block-scaled int8 quantization: 4x wire compression over fp32.
 
     Returns ``(q, scales, n)`` where ``q`` is (rows, 128) int8, ``scales``
@@ -313,21 +366,21 @@ def quantize_int8(x, use_pallas: Optional[bool] = None):
     cast-only ``Compression.fp16`` (compression.py) for DCN-bound traffic,
     built as a Pallas quantization kernel (pallas_guide: quantization
     pattern).
+
+    What is quantised is ``(x + plus) * prescale`` in fp32, both formed
+    inside the kernel (``plus``: an fp32 array of ``x``'s shape, the
+    error-feedback residual; ``prescale``: a Python number). With
+    ``return_residual=True`` a fourth result is that value less its
+    dequantised int8, fp32 in ``x``'s shape, written by the same kernel:
+    the error-feedback path asks for it, and no dequantise of the whole
+    buffer stands beside the quantise (docs/compression.md).
     """
-    use, interpret = _decide(use_pallas)
-    x2, n = _to_rows(x, sublane=_Q_ROWS)
-    nblocks = x2.shape[0] // _Q_ROWS
-    if not use:
-        blocks = x2.reshape(nblocks, _Q_ROWS * _LANES).astype(jnp.float32)
-        scales = _scale_of(jnp.max(jnp.abs(blocks), axis=1))
-        q = jnp.clip(jnp.round(blocks / scales[:, None]), -127, 127)
-        return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = _quantize_call(_quant_kernel, (x2,), interpret,
-                               scopes.INT8_QUANTIZE)
-    return q, scales, n
+    return _quantize(x, None, use_pallas, plus, prescale, return_residual)
 
 
-def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None):
+def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None, *,
+                             plus=None, prescale=None,
+                             return_residual: bool = False):
     """Block-scaled int8 quantization with UNBIASED stochastic rounding —
     the reduce-path variant of :func:`quantize_int8`.
 
@@ -348,25 +401,10 @@ def quantize_int8_stochastic(x, key, use_pallas: Optional[bool] = None):
     determinism (optim.py does).
 
     Returns ``(q, scales, n)`` — same contract as :func:`quantize_int8`
-    (one fp32 absmax scale per 32x128 block); invert with
-    :func:`dequantize_int8`.
+    (one fp32 absmax scale per 32x128 block; ``plus``, ``prescale`` and
+    ``return_residual`` as there); invert with :func:`dequantize_int8`.
     """
-    use, interpret = _decide(use_pallas)
-    x2, n = _to_rows(x, sublane=_Q_ROWS)
-    nblocks = x2.shape[0] // _Q_ROWS
-    u = jax.random.uniform(key, x2.shape, jnp.float32)
-    if not use:
-        blocks = x2.reshape(nblocks, _Q_ROWS * _LANES).astype(jnp.float32)
-        scales = _scale_of(jnp.max(jnp.abs(blocks), axis=1))
-        scaled = blocks / scales[:, None]
-        fl = jnp.floor(scaled)
-        ub = u.reshape(nblocks, _Q_ROWS * _LANES)
-        q = fl + (ub < (scaled - fl)).astype(jnp.float32)
-        q = jnp.clip(q, -127, 127)
-        return q.astype(jnp.int8).reshape(x2.shape), scales, n
-    q, scales = _quantize_call(_quant_sr_kernel, (x2, u), interpret,
-                               scopes.INT8_QUANTIZE_SR)
-    return q, scales, n
+    return _quantize(x, key, use_pallas, plus, prescale, return_residual)
 
 
 def dequantize_int8(q, scales, n, shape, dtype=jnp.float32,

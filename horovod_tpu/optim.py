@@ -462,6 +462,21 @@ def _ef_key(step, bucket_index: int):
         bucket_index)
 
 
+def _ef_keys(step, buckets: int, hops: int):
+    """``_ef_key(step, i)`` for every bucket ``i`` of a step, (buckets, 2),
+    and ``fold_in`` of each with every hop number below ``hops``,
+    (buckets, hops, 2): the keys the reductions would derive themselves,
+    to the bit, in three vectorised threefries instead of one on scalars
+    for every bucket and hop (some 120 instructions of the compiled step
+    each: 8,951 against 124 for 25 buckets, PERF.md PR 45)."""
+    at_step = jax.random.fold_in(jax.random.PRNGKey(_EF_SEED), step)
+    keys = jax.vmap(lambda i: jax.random.fold_in(at_step, i))(
+        jnp.arange(buckets))
+    hop_keys = jax.vmap(lambda key: jax.vmap(
+        lambda hop: jax.random.fold_in(key, hop))(jnp.arange(hops)))(keys)
+    return keys, hop_keys
+
+
 def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
                     fusion_threshold: int, prescale: float = 1.0,
                     postscale: float = 1.0,
@@ -502,10 +517,26 @@ def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
                           else (axis_name,)))
     plan = fusion_lib.assign_wire_dtypes(
         fusion_lib.plan_fusion(grads, fusion_threshold), qmin)
-    g_flats = fusion_lib.fuse(grads, plan)
-    r_flats = fusion_lib.fuse(residual, plan)
     reducible = (C.ReduceOp.SUM, C.ReduceOp.AVERAGE, C.ReduceOp.ADASUM)
     adasum = op == C.ReduceOp.ADASUM
+    lengths = None
+    if bound and op in reducible:
+        # The keys of every bucket and hop are derived at once, and an
+        # int8 bucket is packed onto the grid its reduction works on (a
+        # whole 4,096-element block a rank), so nothing pads or slices it
+        # there. Flat Adasum keeps its buffers as they were.
+        keys, hop_keys = _ef_keys(
+            step, len(plan.buckets),
+            2 if route is None else 2 * len(route.phases) - 1)
+        if route is not None or not adasum:
+            ranks = (jax.lax.axis_size(axis_name) if route is None
+                     else _route_total(route))
+            lengths = [_qpad_len(b.total_elems, ranks)
+                       if w == fusion_lib.WIRE_INT8 else 0
+                       for b, w in zip(plan.buckets, plan.wire_dtypes)]
+    g_flats = fusion_lib.fuse(grads, plan, lengths)
+    r_flats = fusion_lib.fuse(residual, plan, lengths)
+    scaled = None if adasum or prescale in (None, 1.0) else prescale
 
     def one(i, g, r):
         wire = plan.wire_dtypes[i]
@@ -513,13 +544,13 @@ def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
             w = C._apply_scale(g, prescale)
             return C._apply_scale(w, postscale), r
         if wire == fusion_lib.WIRE_INT8 and op in reducible:
-            corrected = g.astype(jnp.float32) + r
-            if not adasum and prescale not in (None, 1.0):
-                corrected = corrected * prescale
             if route is not None:
+                corrected = g.astype(jnp.float32) + r
+                if scaled is not None:
+                    corrected = corrected * scaled
                 y, res = C.mesh_allreduce(
-                    corrected, op, route, key=_ef_key(step, i),
-                    return_residual=True)
+                    corrected, op, route, key=keys[i],
+                    return_residual=True, _hop_keys=hop_keys[i])
             elif adasum:
                 # Flat-axis Adasum: quantized distance-doubling exchange
                 # (unbiased with the stochastic key); no linear phase, so
@@ -527,14 +558,16 @@ def _reduce_tree_ef(grads, residual, step, op: C.ReduceOp, axis_name: str,
                 from .ops import adasum as adasum_lib
 
                 y = adasum_lib.adasum_allreduce(
-                    corrected, axis_name, wire="int8",
-                    key=_ef_key(step, i))
+                    g.astype(jnp.float32) + r, axis_name, wire="int8",
+                    key=keys[i])
                 res = jnp.zeros_like(r)
             else:
+                # The corrected gradient (g + r) * prescale is formed in
+                # the quantise kernel, which writes the new residual too.
                 y, res = C.quantized_allreduce(
-                    corrected, op, axis_name, key=_ef_key(step, i),
-                    return_residual=True)
-            if not adasum and prescale not in (None, 1.0):
+                    g, op, axis_name, key=keys[i], return_residual=True,
+                    _hop_keys=hop_keys[i], _plus=r, _prescale=scaled)
+            if scaled is not None:
                 # Residual lives in UNSCALED gradient units (it is added
                 # to raw grads next step, before this prescale reapplies).
                 res = res / prescale

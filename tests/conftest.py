@@ -34,7 +34,8 @@ def rng():
     return np.random.default_rng(42)
 
 
-# Four test functions (five cases) of ``tests/benchmark/test_benchmark_sdar.py``
+# Five test functions of ``tests/benchmark/test_benchmark_sdar.py`` and one of
+# ``test_benchmark_laguna.py`` (eleven cases)
 # say where ``BENCHMARK.json``'s lists END, how long one is, or that no other
 # cell lists a file of names. The builders' contract has every later entry
 # put at the end of its list, so the first PR that appends a cell, a reader
@@ -45,8 +46,10 @@ def rng():
 # from this file, which lies outside the paths; and
 # ``tests/benchmark/test_benchmark_laguna.py`` runs every one of them whole
 # on the lists and the cells' files as they stood before PR 42
-# (``test_the_marked_tests_hold_whole_before_this_pr``), so that no assertion
-# of theirs goes unexecuted. The ``benchmark`` PR that makes them say "in
+# (``test_the_marked_tests_hold_whole_before_this_pr``; since PR 45, which
+# appended two readers, ``tests/benchmark/test_benchmark_int8ef.py`` runs
+# that runner and one more whole in turn), so that no assertion of theirs
+# goes unexecuted. The ``benchmark`` PR that makes them say "in
 # this order, before whatever came later" takes this away (PERF.md section 7).
 APPENDED_TO_SINCE_PR_40 = {
     "test_benchmark_sdar.py::"
@@ -66,6 +69,16 @@ APPENDED_TO_SINCE_PR_40 = {
     "test_the_cells_file_of_names_adds_the_scope_for_this_cell_alone":
         "asserts that no other cell lists a file of names; the window "
         "cell lists its kernels' names",
+    # since PR 45 (two readers appended for the int8 cell;
+    # tests/benchmark/test_benchmark_int8ef.py runs these whole on the
+    # per-layer list as it stood before them)
+    "test_benchmark_sdar.py::test_the_cells_report_their_readings":
+        "asserts that the int8 cell reports the common readings and none "
+        "of its own; quantize_ms and dequantize_ms were appended for it",
+    "test_benchmark_laguna.py::"
+    "test_the_marked_tests_hold_whole_before_this_pr":
+        "asserts that PR 42's three readers are the last of the per-layer "
+        "list; two were appended after them (all five cases)",
 }
 
 

@@ -196,3 +196,262 @@ def test_zero1_compression_state_mismatch_raises(hvd):
 
     with pytest.raises(ValueError, match="must match the sharded_init"):
         go(jnp.zeros((8, 1), jnp.float32))
+
+
+# -- the bucket moves its bytes once (ISSUE 45) ------------------------------
+#
+# The residual leaves the quantise kernel, the gathered int8 goes through
+# the dequantise kernel with the mean on its scales, the buckets are packed
+# onto the block grid and every bucket's keys are derived at once: the wire,
+# the rounding and the state are what they were. The plain reference is the
+# reduction as it stood before, kept here.
+
+def _reduction_before(x, op, axis_name, key):
+    """``collectives.quantized_allreduce(x, op, axis_name, key=key,
+    return_residual=True)`` as it stood before PR 45: the pad and the
+    slices its own, a ``fold_in`` a hop, the residual and the result by
+    dequantising whole buffers in jnp, the mean divided after."""
+    from jax import lax
+
+    from horovod_tpu.ops import collectives as C
+    from horovod_tpu.ops import pallas_kernels as pk
+
+    def chunks(flat, n, hop):
+        if key is None:
+            q, s, _ = pk.quantize_int8(flat)
+        else:
+            q, s, _ = pk.quantize_int8_stochastic(
+                flat, jax.random.fold_in(key, hop))
+        chunk = flat.shape[0] // n
+        return q.reshape(n, chunk // 128, 128), s.reshape(n, chunk // 4096)
+
+    n = lax.axis_size(axis_name)
+    size = int(x.size)
+    chunk = -(-size // (n * 4096)) * 4096
+    flat = jnp.pad(x.astype(jnp.float32).reshape(-1), (0, n * chunk - size))
+    q, s = chunks(flat, n, 0)
+    qx = lax.all_to_all(q, axis_name, split_axis=0, concat_axis=0)
+    sx = lax.all_to_all(s, axis_name, split_axis=0, concat_axis=0)
+    own = jnp.sum(C._deq(qx, sx), axis=0)
+    residual = flat - C._deq(q, s).reshape(flat.shape)
+    qr, sr = chunks(own, 1, 1)
+    qg = lax.all_gather(qr[0], axis_name)
+    sg = lax.all_gather(sr[0], axis_name)
+    y = C._deq(qg, sg).reshape(-1)[:size].reshape(x.shape)
+    if op == C.ReduceOp.AVERAGE:
+        y = y / jnp.asarray(n, y.dtype)
+    me = lax.axis_index(axis_name)
+    cur = lax.dynamic_slice_in_dim(residual, me * chunk, chunk)
+    residual = lax.dynamic_update_slice_in_dim(
+        residual, cur + (own - C._deq(qr[0], sr[0])), me * chunk, 0)
+    return y.astype(x.dtype), residual[:size].reshape(x.shape)
+
+
+def _one_rounding(x):
+    """``x - q * scale`` is one fused multiply-add in some of the programs
+    XLA:CPU compiles and two roundings in others
+    (tests/test_pallas_kernels.py), so a residual is held to the
+    reference's within one rounding of the product, not to its bits."""
+    return 2.0 ** -22 * float(np.abs(np.asarray(x)).max())
+
+
+def _per_rank(fn, ranks, *stacked):
+    """``fn`` on each rank's row of the ``(ranks, ...)`` operands under a
+    flat ``hvd`` mesh of ``ranks`` CPU devices; results stacked alike."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:ranks]), ("hvd",))
+
+    def body(*rows):
+        out = fn(*jax.tree.map(lambda v: v[0], rows))
+        return jax.tree.map(lambda v: v[None], out)
+
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("hvd"),
+                                 out_specs=P("hvd"), check_vma=False))(
+        *stacked)
+
+
+def _check_against_the_reduction_before(ranks, op, keyed, size, operands,
+                                        dtype=np.float32):
+    from horovod_tpu.ops import collectives as C
+
+    op = {"average": C.ReduceOp.AVERAGE, "sum": C.ReduceOp.SUM}[op]
+    rng = np.random.default_rng(size + ranks)
+    x = jnp.asarray(rng.standard_normal((ranks, size))
+                    * np.exp(rng.standard_normal((ranks, size))), dtype)
+    r = (rng.standard_normal((ranks, size)) * 0.02).astype(np.float32)
+    key = jax.random.PRNGKey(7) if keyed else None
+
+    def corrected(x, r):
+        # One operand: the buffer in its own dtype, as any caller hands
+        # it over; two: the fp32 sum the parent's optimizer formed.
+        return x if operands == 1 else x.astype(jnp.float32) + r
+
+    def now(x, r):
+        if operands == 1:
+            return C.quantized_allreduce(x, op, "hvd", key=key,
+                                         return_residual=True)
+        hops = None if key is None else (jax.random.fold_in(key, 0),
+                                         jax.random.fold_in(key, 1))
+        return C.quantized_allreduce(x, op, "hvd", key=key, _plus=r,
+                                     _hop_keys=hops, return_residual=True)
+
+    y, res = _per_rank(now, ranks, x, r)
+    y_was, res_was = _per_rank(
+        lambda x, r: _reduction_before(corrected(x, r), op, "hvd", key),
+        ranks, x, r)
+    assert y.dtype == y_was.dtype and res.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(y), np.asarray(y_was))
+    np.testing.assert_allclose(np.asarray(res), np.asarray(res_was), rtol=0,
+                               atol=_one_rounding(x))
+    # every rank holds the same result
+    assert (np.asarray(y) == np.asarray(y)[0]).all()
+
+
+_SIZES = pytest.mark.parametrize("size", [50_000, 3 * 8 * 4096],
+                                 ids=["ragged", "on_the_grid"])
+_OPERANDS = pytest.mark.parametrize("operands", [1, 2],
+                                    ids=["x", "x_plus_residual"])
+_ROUNDINGS = pytest.mark.parametrize("keyed", [False, True],
+                                     ids=["nearest", "stochastic"])
+_OPS = pytest.mark.parametrize("op", ["average", "sum"])
+
+
+@_OPERANDS
+@_SIZES
+@_ROUNDINGS
+@_OPS
+@pytest.mark.parametrize("ranks", [4, 8])
+def test_quantized_allreduce_is_the_reduction_it_was(ranks, op, keyed, size,
+                                                     operands):
+    """``y`` to the bit (so q and the scales of both hops, the thresholds
+    and the mean folded into the gathered scales), the residual to one
+    rounding; with the corrected gradient handed in whole and as its two
+    operands, formed in the kernel, under the hop keys derived outside."""
+    _check_against_the_reduction_before(ranks, op, keyed, size, operands)
+
+
+@_OPERANDS
+@_SIZES
+@_ROUNDINGS
+@_OPS
+def test_quantized_allreduce_of_bf16_is_the_reduction_it_was(op, keyed, size,
+                                                             operands):
+    """A bf16 buffer: what each hop quantises is fp32 all the way, as it
+    was when the buffer was cast on entry. On the grid nothing pads the
+    buffer, so nothing casts it either: the owned chunk's sum must not
+    pass through bf16 before the second hop rounds it (it did, in this
+    PR's first form; the case on the grid is the one that showed it)."""
+    _check_against_the_reduction_before(4, op, keyed, size, operands,
+                                        jnp.bfloat16)
+
+
+@_OPERANDS
+@_SIZES
+@_ROUNDINGS
+@_OPS
+@pytest.mark.parametrize("ranks", [3, 6])
+def test_quantized_allreduce_over_ranks_no_power_of_two(ranks, op, keyed,
+                                                        size, operands):
+    """Where 1/ranks is not exact the mean is divided after the
+    dequantise, as it was, and not folded into the gathered scales."""
+    _check_against_the_reduction_before(ranks, op, keyed, size, operands)
+
+
+@pytest.mark.parametrize("step", [0, 7, 2 ** 31 - 5])
+def test_a_steps_keys_derived_at_once_are_the_keys(step):
+    """``_ef_keys``: every bucket's ``_ef_key(step, i)`` and the two hop
+    keys ``quantized_allreduce`` folds from it, to the bit, for every
+    bucket index of a 25-bucket step (and the router's five hops)."""
+    from horovod_tpu import optim
+
+    step = jnp.asarray(step, jnp.int32)
+    keys, hop_keys = jax.jit(lambda s: optim._ef_keys(s, 25, 5))(step)
+    assert keys.shape == (25, 2) and hop_keys.shape == (25, 5, 2)
+    for i in range(25):
+        key = optim._ef_key(step, i)
+        np.testing.assert_array_equal(np.asarray(keys[i]), np.asarray(key))
+        for hop in range(5):
+            np.testing.assert_array_equal(
+                np.asarray(hop_keys[i, hop]),
+                np.asarray(jax.random.fold_in(key, hop)))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("prescale", [1.0, 0.5], ids=["plain", "prescaled"])
+@pytest.mark.parametrize("ranks", [4, 8])
+def test_three_int8_ef_steps_leave_the_state_they_left(ranks, prescale,
+                                                       dtype):
+    """``DistributedOptimizer(compression="int8_ef")`` over three steps
+    against the reduction as it stood (each int8 bucket: ``(g + r) *
+    prescale`` whole through :func:`_reduction_before` under
+    ``_ef_key(step, i)``, the small bucket on bf16): the first step's
+    updates to the bit, every later one and the ``_EFState`` residual but
+    for what one rounding of a residual can move (an element whose
+    threshold falls inside that rounding takes the other neighbour)."""
+    from horovod_tpu import optim
+    from horovod_tpu.common import fusion
+    from horovod_tpu.ops import collectives as C
+
+    rng = np.random.default_rng(ranks)
+    params = {"a": jnp.zeros((300, 257), dtype),
+              "b": jnp.zeros((1000, 130), dtype), "c": jnp.zeros((7,), dtype)}
+    # bf16 gradients: the fp32 sum g + r is what is reduced, and the
+    # result is rounded to the gradient's dtype once, after the postscale.
+    threshold = 400_000 if dtype == "float32" else 200_000
+    postscale = 1.0 if dtype == "float32" else 1.0 / 3.0
+    tx = optim.DistributedOptimizer(
+        optax.sgd(1.0), compression="int8_ef", axis_name="hvd",
+        quantize_min_bucket_bytes=1024, fusion_threshold_bytes=threshold,
+        prescale_factor=prescale, postscale_factor=postscale)
+
+    def was(grads, residual, step):
+        plan = fusion.assign_wire_dtypes(
+            fusion.plan_fusion(grads, threshold), 1024)
+        assert plan.wire_dtypes.count(fusion.WIRE_INT8) == 2
+        ys, rs = [], []
+        for i, (g, r) in enumerate(zip(fusion.fuse(grads, plan),
+                                       fusion.fuse(residual, plan))):
+            if plan.wire_dtypes[i] != fusion.WIRE_INT8:
+                ys.append(C.allreduce(g.astype(jnp.bfloat16),
+                                      C.ReduceOp.AVERAGE, "hvd", prescale,
+                                      postscale).astype(g.dtype))
+                rs.append(r)
+                continue
+            y, res = _reduction_before(
+                (g.astype(jnp.float32) + r) * prescale, C.ReduceOp.AVERAGE,
+                "hvd", optim._ef_key(step, i))
+            ys.append((y * postscale).astype(g.dtype))
+            rs.append(res / prescale)
+        return fusion.unfuse(ys, plan), fusion.unfuse(rs, plan)
+
+    def now(grads, state):
+        updates, state = tx.update(grads, state, params)
+        return updates, state
+
+    state = jax.tree.map(
+        lambda v: jnp.broadcast_to(v, (ranks,) + jnp.shape(v)),
+        tx.init(params))
+    residual_was = state.residual
+    for step in range(3):
+        grads = jax.tree.map(
+            lambda p: jnp.asarray(rng.standard_normal((ranks,) + p.shape)
+                                  * np.exp(rng.standard_normal(p.shape)),
+                                  dtype), params)
+        updates, state = _per_rank(now, ranks, grads, state)
+        reduced_was, residual_was = _per_rank(
+            lambda g, r: was(g, r, jnp.asarray(step, jnp.int32)), ranks,
+            grads, residual_was)
+        for name in params:
+            got = -np.asarray(updates[name], np.float32)
+            want = np.asarray(reduced_was[name], np.float32)
+            if step == 0:
+                np.testing.assert_array_equal(got, want)
+            for got, want, within in (
+                    (got, want, 1e-6 * np.abs(want).max()),
+                    (np.asarray(state.residual[name]),
+                     np.asarray(residual_was[name]),
+                     _one_rounding(grads[name]))):
+                assert (np.abs(got - want) > within).mean() < 1e-4, (
+                    step, name)
+    assert (np.asarray(state.step) == 3).all()

@@ -134,6 +134,148 @@ def test_stochastic_quantize_pallas_matches_fallback(rng):
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
 
 
+@pytest.mark.parametrize("ragged", [False, True],
+                         ids=["whole_groups", "ragged_last_group"])
+@pytest.mark.parametrize("operands", [1, 2],
+                         ids=["one_operand", "two_operands"])
+@pytest.mark.parametrize("keyed", [False, True],
+                         ids=["nearest", "stochastic"])
+def test_quantize_residual_matches_twin(rng, keyed, operands, ragged):
+    """The residual a caller asks the quantise kernels for, ``(x + plus)
+    * prescale`` less its dequantised int8, formed and written inside the
+    kernel, against the jnp twin: with and without a key, one operand
+    and two, and where the last grid step reads past the end. q and the
+    scales are bitwise the twin's and the ones the same call gives
+    without the residual. The residual is the twin's to one rounding of
+    ``q * scale``: XLA:CPU contracts ``x - q * scale`` into a fused
+    multiply-add in some of the programs it compiles and not in others
+    (as for ``adasum_combine`` below), so two CPU programs cannot be held
+    to the same bits; a TPU v5e has no such instruction, and there
+    ``chip_smoke.py`` counts the kernel's mismatches with XLA's fusion."""
+    import jax
+
+    blocks = pk._Q_GROUP + 5 if ragged else 2 * pk._Q_GROUP
+    n = blocks * pk._Q_ROWS * pk._LANES - (7 if ragged else 0)
+    x = jnp.asarray(rng.standard_normal(n) * 5, jnp.float32)
+    extra = {} if operands == 1 else {
+        "plus": jnp.asarray(rng.standard_normal(n) * 0.05, jnp.float32),
+        "prescale": 0.25}
+    key = jax.random.PRNGKey(3)
+
+    def quantize(use_pallas, **kwargs):
+        if keyed:
+            return pk.quantize_int8_stochastic(x, key, use_pallas=use_pallas,
+                                               **extra, **kwargs)
+        return pk.quantize_int8(x, use_pallas=use_pallas, **extra, **kwargs)
+
+    q1, s1, _, r1 = quantize(True, return_residual=True)
+    q0, s0, _, r0 = quantize(False, return_residual=True)
+    assert r1.shape == x.shape and r1.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(q1), np.asarray(q0))
+    np.testing.assert_array_equal(np.asarray(s1), np.asarray(s0))
+    for use_pallas in (True, False):
+        q, s, _ = quantize(use_pallas)
+        np.testing.assert_array_equal(np.asarray(q), np.asarray(q0))
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(s0))
+    # Op by op the twin rounds the product before it subtracts, as the
+    # same arithmetic in numpy does: those two are the same bits.
+    xf = np.asarray(x)
+    if operands == 2:
+        xf = (xf + np.asarray(extra["plus"])) * np.float32(0.25)
+    deq = np.asarray(pk.dequantize_int8(q0, s0, n, x.shape,
+                                        use_pallas=False))
+    np.testing.assert_array_equal(np.asarray(r0), xf - deq)
+    one_rounding = 2.0 ** -23 * np.abs(xf).max()
+    np.testing.assert_allclose(np.asarray(r1), np.asarray(r0), rtol=0,
+                               atol=one_rounding)
+    # The error of this quantisation: within a step of the block's grid
+    # (half a step, rounded to nearest).
+    bound = np.repeat(np.asarray(s0), pk._Q_ROWS * pk._LANES)[:n]
+    assert (np.abs(np.asarray(r1))
+            <= bound * (1.0 if keyed else 0.5) + one_rounding).all()
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "twin"])
+@pytest.mark.parametrize("operands", [1, 2],
+                         ids=["one_operand", "two_operands"])
+@pytest.mark.parametrize("keyed", [False, True],
+                         ids=["nearest", "stochastic"])
+def test_quantize_is_the_plain_arithmetic(rng, keyed, operands, use_pallas):
+    """q, the scales and the residual against the quantisation written
+    out in numpy on the (blocks, 4096) view, sharing no line with the
+    kernel's body: the kernel and its twin are one function
+    (``_quantize_blocks``), so their agreeing says nothing of a slip in
+    it. One scale a block, absmax / 127 (as a product, see
+    ``_scale_of``); nearest rounds half to even; with a key, up where
+    the threshold ``jax.random.uniform(key, (rows, 128))`` lies under
+    the fractional part; clipped to +-127."""
+    import jax
+
+    f32 = np.float32
+    blocks = pk._Q_GROUP + 5
+    n = blocks * 4096 - 7
+    x = (rng.standard_normal(n) * np.exp(rng.standard_normal(n))).astype(f32)
+    plus = (rng.standard_normal(n) * 0.05).astype(f32)
+    x[4096:2 * 4096] = 0                    # a block of zeros: scale 1e-30/127
+    plus[4096:2 * 4096] = 0
+    extra = {} if operands == 1 else {"plus": jnp.asarray(plus),
+                                      "prescale": 0.25}
+    key = jax.random.PRNGKey(11)
+    if keyed:
+        q, s, _, res = pk.quantize_int8_stochastic(
+            jnp.asarray(x), key, use_pallas=use_pallas, return_residual=True,
+            **extra)
+    else:
+        q, s, _, res = pk.quantize_int8(
+            jnp.asarray(x), use_pallas=use_pallas, return_residual=True,
+            **extra)
+
+    xf = x if operands == 1 else (x + plus) * f32(0.25)
+    xb = np.concatenate([xf, np.zeros(7, f32)]).reshape(blocks, 4096)
+    scale = np.maximum(np.abs(xb).max(axis=1), f32(1e-30)) * f32(1.0 / 127.0)
+    scaled = xb / scale[:, None]
+    if keyed:
+        u = np.asarray(jax.random.uniform(
+            key, (blocks * 32, 128), jnp.float32)).reshape(blocks, 4096)
+        low = np.floor(scaled)
+        want = low + (u < scaled - low).astype(f32)
+    else:
+        want = np.round(scaled)
+    want = np.clip(want, -127, 127)
+    assert scale.dtype == f32 and want.dtype == f32
+    np.testing.assert_array_equal(np.asarray(s), scale)
+    np.testing.assert_array_equal(np.asarray(q).reshape(blocks, 4096),
+                                  want.astype(np.int8))
+    np.testing.assert_allclose(
+        np.asarray(res), (xb - want * scale[:, None]).reshape(-1)[:n],
+        rtol=0, atol=2.0 ** -23 * np.abs(xf).max())
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "twin"])
+@pytest.mark.parametrize("ranks", [2, 4, 8])
+def test_dequantize_stacked_view_folds_the_mean_into_the_scales(
+        rng, ranks, use_pallas):
+    """The gathered result of the quantised allreduce, (ranks, rows, 128)
+    int8 with (ranks, blocks) scales, dequantised in ONE call on the
+    (ranks * blocks, 32, 128) view with 1/ranks on the scales: bitwise
+    ``_deq(q, s) / ranks`` for a power of two, the flat buffer's order."""
+    from horovod_tpu.ops.collectives import _deq
+
+    blocks = pk._Q_GROUP + 3
+    q = jnp.asarray(rng.integers(-127, 128, (ranks, blocks * pk._Q_ROWS,
+                                             pk._LANES)), jnp.int8)
+    s = jnp.asarray(np.exp(rng.standard_normal((ranks, blocks)) * 4),
+                    jnp.float32)
+    size = ranks * blocks * pk._Q_ROWS * pk._LANES
+    got = pk.dequantize_int8(
+        q.reshape(-1, pk._LANES), (s * jnp.float32(1.0 / ranks)).reshape(-1),
+        size, (size,), use_pallas=use_pallas)
+    want = _deq(q, s).reshape(-1) / jnp.float32(ranks)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_stochastic_quantize_deterministic_per_key(rng):
     import jax
 

@@ -14,6 +14,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from horovod_tpu.ops import flash_attention as fa
@@ -45,8 +46,8 @@ FLASH_KV_HEADS = {"lfm2_b2_s8192_kv8": 8, "sdar_b2_s8192_d128_kv4": 4}
 
 
 @pytest.fixture(scope="module")
-def v5e():
-    """SingleDeviceSharding on one chip of a described v5e:2x2."""
+def v5e_2x2():
+    """A described v5e:2x2: four chips, none attached."""
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
 
@@ -62,9 +63,15 @@ def v5e():
     was_enabled = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield jax.sharding.SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_enabled)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def v5e(v5e_2x2):
+    """SingleDeviceSharding on one chip of the described v5e:2x2."""
+    return jax.sharding.SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.fixture()
@@ -115,6 +122,28 @@ def _dequantize(dtype, q, s):
     return pk.dequantize_int8(q, s, BUCKET, (BUCKET,), dtype)
 
 
+def _with_residual(x, r, key=None):
+    """The error-feedback form: (x + r) / 2 quantised, its residual the
+    kernel's third result."""
+    quantize = pk.quantize_int8 if key is None else functools.partial(
+        pk.quantize_int8_stochastic, key=key)
+    q, s, _, residual = quantize(x, plus=r, prescale=0.5,
+                                 return_residual=True)
+    return q, s, residual
+
+
+def _stacked(ranks):
+    """Four ranks' gathered chunks as one (ranks x rows, 128) view."""
+    ((rows, lanes), _), ((blocks,), _) = _quantized()
+    return (((ranks * rows, lanes), jnp.int8),
+            ((ranks * blocks,), jnp.float32))
+
+
+def _dequantize_stacked(dtype, q, s):
+    n = q.shape[0] * q.shape[1]
+    return pk.dequantize_int8(q, s * 0.25, n, (n,), dtype)
+
+
 KERNELS = {
     "scale_buffer": lambda dt: (lambda x: pk.scale_buffer(x, 0.5),
                                 [_flat(dt)]),
@@ -128,6 +157,13 @@ KERNELS = {
     "quantize_int8_stochastic": lambda dt: (_stochastic, [_flat(dt)]),
     "dequantize_int8": lambda dt: (functools.partial(_dequantize, dt),
                                    list(_quantized())),
+    "quantize_int8_residual": lambda dt: (
+        _with_residual, [_flat(dt), _flat(jnp.float32)]),
+    "quantize_int8_stochastic_residual": lambda dt: (
+        functools.partial(_with_residual, key=jax.random.PRNGKey(0)),
+        [_flat(dt), _flat(jnp.float32)]),
+    "dequantize_int8_stacked": lambda dt: (
+        functools.partial(_dequantize_stacked, dt), list(_stacked(4))),
 }
 
 
@@ -662,6 +698,85 @@ def test_flash_with_lse_backward_compiles_for_v5e(v5e, on_tpu):
                    ((b, s), jnp.float32))
     assert _mosaic_calls(hlo) == 2 and "hvd_flash_dq" not in hlo
     assert not re.search(rf"\[{b},{h},{s},128\]", hlo)
+
+
+EF_BUCKET = 3272 * 4096   # 13.4 M elements on four chips' block grid
+
+
+def _entry_instructions(hlo):
+    """``(name, result type, opcode)`` of the entry computation's
+    instructions."""
+    found = []
+    for line in hlo[hlo.index("ENTRY"):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
+        if m:
+            found.append(m.groups())
+    return found
+
+
+def _elements(result_type):
+    return max([1] + [int(np.prod([int(d) for d in dims.split(",")]))
+                      for dims in re.findall(r"\[([\d,]+)\]", result_type)])
+
+
+def test_an_error_feedback_bucket_moves_its_bytes_once(v5e_2x2, on_tpu):
+    """One int8 error-feedback bucket as ``optim._reduce_tree_ef`` hands
+    it to ``quantized_allreduce`` (13.4 M fp32 on the block grid, the
+    residual a second operand, the hop keys derived outside), compiled
+    for the four described chips. Three Mosaic calls: the bucket's
+    quantise, the owned chunk's, the gathered result's dequantise.
+    Between them nothing the size of the bucket or of a rank's chunk is
+    converted, broadcast, reshaped other than as a bitcast, padded,
+    sliced or copied: before PR 45 the residual and the result were
+    dequantised by XLA (a convert, a broadcast of the scales and a
+    relayout to the flat order each, all materialised: 1.613 GB accessed
+    for this 53.6 MB bucket by ``cost_analysis``, which counts a Mosaic
+    call's operands at nothing, against 0.229). And a step's keys for 25
+    buckets are a hundred-odd instructions, not 8,951 (PERF.md, PR 45)."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from horovod_tpu import optim
+    from horovod_tpu.ops import collectives as C
+
+    mesh = Mesh(np.array(v5e_2x2.devices), ("hvd",))
+    rows, whole = NamedSharding(mesh, P("hvd")), NamedSharding(mesh, P())
+
+    def bucket(g, r, step):
+        keys, hop_keys = optim._ef_keys(step[0], 25, 2)
+        y, residual = C.quantized_allreduce(
+            g[0], C.ReduceOp.AVERAGE, "hvd", key=keys[3],
+            return_residual=True, _hop_keys=hop_keys[3], _plus=r[0])
+        return y[None], residual[None]
+
+    compiled = jax.jit(jax.shard_map(
+        bucket, mesh=mesh, in_specs=(P("hvd"), P("hvd"), P()),
+        out_specs=(P("hvd"), P("hvd")), check_vma=False)).lower(
+        *[jax.ShapeDtypeStruct((4, EF_BUCKET), jnp.float32,
+                               sharding=rows)] * 2,
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=whole)).compile()
+    assert compiled.cost_analysis()["bytes accessed"] < 0.6e9
+    entry = _entry_instructions(compiled.as_text())
+    calls = sorted(re.sub(r"[.\d]+$", "", name) for name, _, opcode in entry
+                   if opcode == "custom-call" and name.startswith("hvd_"))
+    assert calls == ["hvd_int8_dequantize"] + ["hvd_int8_quantize_sr"] * 2
+    moved = {opcode for _, result, opcode in entry
+             if _elements(result) >= EF_BUCKET // 4}
+    assert not moved & {"convert", "broadcast", "reshape", "pad", "slice",
+                        "copy", "transpose", "concatenate"}, moved
+    # the thresholds, the sum over ranks, the owned chunk's error added in
+    big_fusions = sorted(re.sub(r"[.\d]+$", "", name)
+                         for name, result, opcode in entry
+                         if opcode == "fusion"
+                         and _elements(result) >= EF_BUCKET // 4)
+    assert big_fusions == ["add_maximum_fusion", "add_maximum_fusion",
+                           "dynamic-slice_add_fusion",
+                           "multiply_reduce_fusion"], big_fusions
+    assert len(entry) < 300     # 599 with a threefry a bucket and a hop
+
+    keys = jax.jit(lambda step: optim._ef_keys(step[0], 25, 2)).lower(
+        jax.ShapeDtypeStruct((1,), jnp.int32, sharding=whole)).compile()
+    assert len(_entry_instructions(keys.as_text())) < 200
 
 
 def test_a_fused_qkv_projections_gradient_is_not_joined_by_copies(v5e,
