@@ -16,7 +16,7 @@ it. ``shard_batch`` lays a global batch out rank-major for
 placement (``DeviceInfeed(shard=True)``) so only this rank's slice is
 ever transferred. Consumer starvation is measurable:
 ``hvd_tpu_infeed_wait_seconds`` (how long the step blocked on the next
-batch) + ``hvd_tpu_infeed_queue_depth`` feed ``analyze_trace.py
+batch) + ``hvd_tpu_infeed_queue_depth`` feed ``analyze_metrics.py
 --metrics`` (docs/metrics.md).
 """
 

@@ -1409,7 +1409,7 @@ def alltoall_wire_cost(plan: WirePlan, nelems: int,
                        axis_sizes: Sequence[int],
                        itemsize: int = 4) -> dict:
     """Static per-axis bytes-per-device model of a mesh-routed alltoall
-    (the analytic half of ``tpu_microbench alltoall``). Every phase
+    (``tests/test_moe_dispatch.py`` holds its counts). Every phase
     exchanges the FULL buffer over its axis — a permutation has nothing
     to shrink — keeping ``(n-1)/n`` of it on the wire in that phase's
     format. Compare against the flat exchange's

@@ -46,7 +46,7 @@ def build_env_for_slot(base_env: Dict[str, str], coordinator: str,
     if num_proc > 1 and env.get("HVD_TPU_METRICS_FILE"):
         # One JSON-lines dump per worker: N processes appending
         # snapshots to one file would interleave rank states. The
-        # .rank<k> suffix is what analyze_trace.py --metrics globs to
+        # .rank<k> suffix is what analyze_metrics.py --metrics globs to
         # build its per-rank + merged report (docs/podmon.md).
         env["HVD_TPU_METRICS_FILE"] = \
             f"{env['HVD_TPU_METRICS_FILE']}.rank{proc_id}"

@@ -467,7 +467,7 @@ def _assert_chunked_matches_oracle(out, counts, splits, datas, tag=""):
 def test_alltoallv_chunked_skewed_oracle(hvd, rng):
     """Chunked (per-hop padded) uneven all-to-all vs a numpy oracle on a
     heavily skewed split table — the bounded-wire-bytes variant
-    (VERDICT r3 weak #4); wire accounting in perf_evidence."""
+    (VERDICT r3 weak #4)."""
     import jax
     from jax.sharding import PartitionSpec as P
 

@@ -210,7 +210,7 @@ def test_device_infeed_shard_fuses_rank_slice(hvd):
 def test_infeed_pipeline_modes_and_metrics(hvd):
     """All three modes deliver identical content in order; the wait
     histogram and batch counter move (the starvation signal
-    analyze_trace --metrics reads)."""
+    ``tools/analyze_metrics.py --metrics`` reads)."""
     import horovod_tpu as hvd_mod
 
     def snap():

@@ -95,8 +95,9 @@ def test_gpt_long_context_zero1_example():
 
 
 def test_parity_doc_references_resolve():
-    """docs/parity.md is the judge-facing component map — every file and
-    test module it cites must exist (tools/check_parity.py)."""
+    """tools/check_parity.py whole, once: its surface checks (docs
+    against code) pass. The reference resolver's own cases, one a
+    document, are tier-1 in tests/test_check_parity.py."""
     out = _run(["tools/check_parity.py"], timeout=60)
     assert "all file/test/module references resolve" in out
 

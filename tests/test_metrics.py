@@ -629,7 +629,7 @@ def test_hvd_metrics_snapshot_surface(hvd):
     json.dumps(snap)
 
 
-# -- tools/analyze_trace.py merge + graceful degrade ------------------------
+# -- tools/analyze_metrics.py merge + graceful degrade ----------------------
 
 def _write_metrics_jsonl(path):
     snap = {
@@ -648,56 +648,33 @@ def _write_metrics_jsonl(path):
 
 
 def _run_analyze(*args):
-    import os
     import subprocess
 
-    tool = __file__.rsplit("/", 2)[0] + "/tools/analyze_trace.py"
+    tool = __file__.rsplit("/", 2)[0] + "/tools/analyze_metrics.py"
     proc = subprocess.run([sys.executable, tool, *args],
                           capture_output=True, text=True, timeout=120)
     return proc.returncode, (json.loads(proc.stdout)
                              if proc.stdout.strip() else None)
 
 
-def test_analyze_trace_merges_metrics_dump(tmp_path):
-    import gzip
-
-    d = tmp_path / "plugins" / "profile" / "x"
-    d.mkdir(parents=True)
-    events = [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "thread_name", "pid": 1, "tid": 10,
-         "args": {"name": "Steps"}},
-        {"ph": "X", "pid": 1, "tid": 10, "name": "1", "ts": 0.0,
-         "dur": 4000.0},
-    ]
-    with gzip.open(d / "vm.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
+def test_analyze_metrics_merges_metrics_dump(tmp_path):
     mpath = tmp_path / "metrics.jsonl"
     _write_metrics_jsonl(mpath)
-    rc, out = _run_analyze(str(tmp_path), "--metrics", str(mpath))
+    rc, out = _run_analyze("--metrics", str(mpath))
     assert rc == 0
     assert out["metrics"]["allreduce_bytes_on_wire"]["int8"] == 12345.0
-    # Merged per-step report: device Steps track vs host histogram.
-    assert out["per_step"]["trace_mean_ms"] == 4.0
-    assert out["per_step"]["metrics_mean_ms"] == 5.0
-    assert out["per_step"]["host_overhead_ms"] == 1.0
-    # No XLA Ops track: flagged, not assumed.
-    assert "no XLA Ops track" in out["note"]
+    assert out["metrics"]["step_seconds"] == {"count": 10, "mean_ms": 5.0}
+    assert out["metrics"]["snapshot_unix"] == 1.0
 
 
-def test_analyze_trace_degrades_without_trace(tmp_path):
-    """Missing ops track / missing trace: message + rc 0, never a
-    crash (the satellite contract)."""
-    mpath = tmp_path / "metrics.jsonl"
-    _write_metrics_jsonl(mpath)
-    rc, out = _run_analyze(str(tmp_path / "empty"), "--metrics",
-                           str(mpath))
+def test_analyze_metrics_degrades_without_a_dump(tmp_path):
+    """A missing dump: message + rc 0, never a crash (the satellite
+    contract). Nothing to read at all is a usage error."""
+    rc, out = _run_analyze("--metrics", str(tmp_path / "none.jsonl"))
     assert rc == 0
-    assert "metrics-only report" in out["note"]
-    assert out["metrics"]["step_seconds"]["mean_ms"] == 5.0
-    rc2, out2 = _run_analyze(str(tmp_path / "empty"))
-    assert rc2 == 0 and "no *.trace.json.gz" in out2["note"]
+    assert "no metrics snapshot" in out["note"] and "metrics" not in out
+    rc2, out2 = _run_analyze()
+    assert rc2 == 2 and out2 is None
 
 
 # -- bench.py integration ---------------------------------------------------

@@ -4,7 +4,7 @@ step time/count, the PodMonitor scrape/merge/attribution pipeline, the
 pass-through), endpoint discovery (KV advertisement + static list),
 the autoscale scrape-path bridge (the engine reaches the same decision
 from a scrape as from the KV), the per-rank /debug capture endpoints,
-and analyze_trace's multi-rank metrics-dump globbing."""
+and analyze_metrics' multi-rank metrics-dump globbing."""
 
 import json
 import os
@@ -474,7 +474,7 @@ def test_debug_profile_ms_is_capped():
         srv.stop()
 
 
-# -- analyze_trace multi-rank globbing ---------------------------------------
+# -- analyze_metrics multi-rank globbing -------------------------------------
 
 def _write_dump(path, rank, mean_ms, wire_bytes):
     snap = {
@@ -496,19 +496,18 @@ def _write_dump(path, rank, mean_ms, wire_bytes):
 
 def _run_analyze(*args):
     tool = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "tools", "analyze_trace.py")
+        os.path.abspath(__file__))), "tools", "analyze_metrics.py")
     proc = subprocess.run([sys.executable, tool, *args],
                           capture_output=True, text=True, timeout=120)
     return proc.returncode, (json.loads(proc.stdout)
                              if proc.stdout.strip() else None)
 
 
-def test_analyze_trace_globs_rank_suffixed_dumps(tmp_path):
+def test_analyze_metrics_globs_rank_suffixed_dumps(tmp_path):
     base = tmp_path / "metrics.jsonl"
     _write_dump(str(base) + ".rank0", 0, 5.0, 1000.0)
     _write_dump(str(base) + ".rank1", 1, 9.0, 3000.0)
-    rc, out = _run_analyze(str(tmp_path / "notrace"), "--metrics",
-                           str(base))
+    rc, out = _run_analyze("--metrics", str(base))
     assert rc == 0
     # Per-rank view for both ranks, not silently rank 0 only.
     assert set(out["metrics_per_rank"]) == {"0", "1"}
@@ -522,25 +521,23 @@ def test_analyze_trace_globs_rank_suffixed_dumps(tmp_path):
     assert merged["step_seconds"]["count"] == 20
 
 
-def test_analyze_trace_legacy_bare_suffix_and_single_file(tmp_path):
+def test_analyze_metrics_legacy_bare_suffix_and_single_file(tmp_path):
     base = tmp_path / "metrics.jsonl"
     # Legacy `.0` suffix from pre-PR-9 launches still globs.
     _write_dump(str(base) + ".0", 0, 5.0, 100.0)
     _write_dump(str(base) + ".1", 1, 7.0, 100.0)
-    rc, out = _run_analyze(str(tmp_path / "notrace"), "--metrics",
-                           str(base))
+    rc, out = _run_analyze("--metrics", str(base))
     assert rc == 0 and out["metrics"]["ranks"] == [0, 1]
     # A bare single dump keeps the historical single-rank report shape.
     single = tmp_path / "solo.jsonl"
     _write_dump(str(single), 0, 5.0, 100.0)
-    rc, out = _run_analyze(str(tmp_path / "notrace"), "--metrics",
-                           str(single))
+    rc, out = _run_analyze("--metrics", str(single))
     assert rc == 0
     assert "metrics_per_rank" not in out
     assert out["metrics"]["step_seconds"]["mean_ms"] == 5.0
 
 
-def test_analyze_trace_flight_overlay(tmp_path):
+def test_analyze_metrics_flight_overlay(tmp_path):
     boxdir = tmp_path / "blackbox"
     boxdir.mkdir()
     ev = {"seq": 1, "op": "allreduce", "name": "allreduce.grad",
@@ -553,14 +550,12 @@ def test_analyze_trace_flight_overlay(tmp_path):
             "trigger": "sigusr2", "reason": "", "t_unix": 0.0,
             "step": 2, "seq_head": 1, "events": events, "stacks": {},
             "stall_inflight": {}, "recovery": {}}))
-    rc, out = _run_analyze(str(tmp_path / "notrace"), "--flight",
-                           str(boxdir))
+    rc, out = _run_analyze("--flight", str(boxdir))
     assert rc == 0
     assert out["flight"]["ranks"] == [0, 1]
     assert out["flight"]["laggard_rank"] == 1
     assert any("rank 1 never completed allreduce.grad" in v
                for v in out["flight"]["verdicts"])
     # Missing dir: a note, not a crash.
-    rc, out = _run_analyze(str(tmp_path / "notrace"), "--flight",
-                           str(tmp_path / "nothing"))
+    rc, out = _run_analyze("--flight", str(tmp_path / "nothing"))
     assert rc == 0 and "no blackbox" in out["flight"]["note"]

@@ -188,7 +188,7 @@ def analyze(boxes: Dict[int, Dict[str, Any]]) -> Dict[str, Any]:
 def duration_skew(boxes: Dict[int, Dict[str, Any]]) -> Dict[str, Any]:
     """Per-seq submit→complete duration spread across ranks (monotonic
     clocks are per-host, so absolute timestamps never cross ranks —
-    durations do). Consumed by ``analyze_trace.py --flight``."""
+    durations do). Consumed by ``analyze_metrics.py --flight``."""
     by_seq: Dict[int, Dict[int, float]] = {}
     meta: Dict[int, Dict[str, Any]] = {}
     for rank, box in boxes.items():

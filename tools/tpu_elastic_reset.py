@@ -31,8 +31,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)  # workers run with sys.path[0] = tools/
 
-from tools.round_dirs import CURRENT as _ROUND  # noqa: E402
-
 
 def _log(msg):
     print(f"elastic_reset: {msg}", file=sys.stderr, flush=True)
@@ -264,8 +262,7 @@ def main():
                     help=argparse.SUPPRESS)
     ap.add_argument("--phase", type=int, default=1)
     ap.add_argument("--ckpt-dir",
-                    default=os.path.join(REPO, "results", _ROUND,
-                                         "elastic_ckpt"))
+                    default=os.path.join(REPO, "results", "elastic_ckpt"))
     ap.add_argument("--cache-dir",
                     default=os.environ.get("JAX_COMPILATION_CACHE_DIR")
                     or os.path.join(REPO, ".jax_cache"))
