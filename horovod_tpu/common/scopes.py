@@ -32,6 +32,11 @@ KDA = "hvd_kda"
 # ops/short_conv.py: the gated short convolution's two gates and its taps,
 # not the two projections around them
 SHORT_CONV = "hvd_short_conv"
+# ops/ssd.py: the selective state-space recurrence with a scalar decay a
+# head (Mamba-2's SSD), chunked: the step size, the decays, the chunks'
+# pair matrices and states, the carry across chunks and the D skip; not
+# the convolution, the gated norm and the projections around it
+SSD = "hvd_ssd"
 # models/*.py, the parts of a block, one vocabulary for every family
 # (docs/timeline.md). A token mixer's kernel (flash attention, the KDA
 # recurrence, the gated short convolution) lies outside MIXER_PROJ, and
@@ -82,6 +87,7 @@ LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 MOE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)   # an expert layer's
 LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
 SHORT_CONV_SCOPES = (SHORT_CONV,)   # a gated-convolution layer's
+STATE_SPACE_SCOPES = (SSD,)  # a state-space layer's
 BLOCK_DIFFUSION_SCOPES = (BD_NOISE,)    # a block-diffusion loss's
 # a block's parts: ROPE where positions are rotary, LOSS where the
 # cross-entropy is the model's own

@@ -13,6 +13,7 @@ from .solar import SolarLM, solar_loss  # noqa: F401
 from .lfm2 import Lfm2LM, lfm2_loss  # noqa: F401
 from .sdar import SdarLM, block_noise, sdar_loss  # noqa: F401
 from .laguna import LagunaLM, laguna_loss  # noqa: F401
+from .granite import GraniteHybridLM, Mamba2Mixer, granite_loss  # noqa: F401
 from .mlp import MLP, ConvNet  # noqa: F401
 from .resnet import ResNet, ResNet50, ResNet101, ResNet152  # noqa: F401
 from .vgg import VGG, VGG11, VGG13, VGG16, VGG19  # noqa: F401

@@ -58,6 +58,7 @@ from .looplm import RMSNorm, head_losses
 from .solar import SparseExperts, _dense, solar_loss
 
 ATTENTION = "full_attention"    # a ``layer_types`` entry; any other: conv
+NO_ROTATION = "nope"    # a ``RotaryGQA.rotation``: q and k as projected
 # The published pattern of LFM2-8B-A1B's 24 layers: attention in layers 2,
 # 6, 10, 14, 18 and 21.
 _PATTERN = tuple(ATTENTION if i in (2, 6, 10, 14, 18, 21) else "conv"
@@ -94,13 +95,17 @@ class RotaryGQA(nn.Module):
       before the rotation (one scale vector each, shared by the heads);
     - ``rotation``: an ``ops/rope.py`` ``Rotation`` (a rotary width under
       the head's, YaRN's frequencies, a scale); None is the plain one at
-      ``rope_base``;
+      ``rope_base``; ``NO_ROTATION``: no positions at all;
     - ``packed_rotation``: the rotation on the packed rows the flash
       kernels read (``rotate``) in place of a head at a time
       (``rotate_heads``, which XLA fuses into the per-head norm before
       it: PERF.md PR 39);
     - ``head_gate``: the output of head h times ``sigmoid(u W_g)_h``, one
-      scalar a query head from the layer's input, before ``W_o``."""
+      scalar a query head from the layer's input, before ``W_o``;
+    - ``scale``: the softmax scale where it is not ``head_dim ** -0.5``
+      (None). The kernels keep theirs; q takes the ratio of the two, in
+      fp32 and back, which is exact where the ratio is a power of two
+      (1/64 on heads of 64: 1/8)."""
 
     num_heads: int
     num_kv_heads: int
@@ -113,16 +118,21 @@ class RotaryGQA(nn.Module):
     qk_norm: bool = True
     packed_rotation: bool = False
     head_gate: bool = False
+    scale: Any = None
 
     @nn.compact
     def __call__(self, u, positions=None):
         b, s, hidden = u.shape
         dense = _dense(self.dtype)
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
-        turn = functools.partial(
-            rotate if self.packed_rotation else rotate_heads,
-            positions=positions,
-            rotation=self.rotation or Rotation(self.rope_base))
+        if self.rotation == NO_ROTATION:
+            def turn(x):
+                return x
+        else:
+            turn = functools.partial(
+                rotate if self.packed_rotation else rotate_heads,
+                positions=positions,
+                rotation=self.rotation or Rotation(self.rope_base))
         wide, narrow = (n * self.head_dim
                         for n in (self.num_heads, self.num_kv_heads))
         with jax.named_scope(scopes.MIXER_PROJ):
@@ -131,6 +141,9 @@ class RotaryGQA(nn.Module):
                 b, s, self.num_kv_heads, -1) for n in ("k", "v"))
             q, k = (turn(norm(name=n)(x) if self.qk_norm else x)
                     for n, x in (("q_norm", q), ("k_norm", k)))
+            if self.scale is not None:
+                q = (q.astype(jnp.float32)
+                     * (self.scale * self.head_dim ** 0.5)).astype(q.dtype)
         o = flash_attention(q, k, v, mask_kind=self.mask_kind)
         with jax.named_scope(scopes.MIXER_PROJ):
             if self.head_gate:
@@ -156,11 +169,12 @@ class DenseFFN(nn.Module):
 
 
 class Lfm2Layer(nn.Module):
-    """``x + Mix(norm(x))`` then ``x + FFN(norm(x))``; the FFN's stats
-    are left behind. ``mixer`` / ``ffn`` are the two classes and
-    ``mixer_args`` / ``ffn_args`` their constructor arguments; what the
-    layer is called with beside ``x`` (an attention mixer's positions)
-    goes to the mixer."""
+    """``x + r Mix(norm(x))`` then ``x + r FFN(norm(x))``, r =
+    ``residual_scale`` (1: the plain sum); the FFN's stats are left
+    behind. ``mixer`` / ``ffn`` are the two classes and ``mixer_args`` /
+    ``ffn_args`` their constructor arguments; what the layer is called
+    with beside ``x`` (an attention mixer's positions) goes to the
+    mixer."""
 
     mixer: Any
     mixer_args: Tuple
@@ -168,17 +182,24 @@ class Lfm2Layer(nn.Module):
     ffn_args: Tuple
     norm_eps: float = 1e-5
     dtype: Any = jnp.bfloat16
+    residual_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, *mixer_inputs):
         norm = functools.partial(RMSNorm, self.norm_eps, self.dtype)
+
+        def branch(y):
+            return y if self.residual_scale == 1.0 \
+                else y * self.residual_scale
+
         with jax.named_scope(scopes.NORM):
             y = norm(name="op_norm")(x)
-        x = x + self.mixer(*self.mixer_args, name="mixer")(y, *mixer_inputs)
+        x = x + branch(self.mixer(*self.mixer_args, name="mixer")(
+            y, *mixer_inputs))
         with jax.named_scope(scopes.NORM):
             y = norm(name="ffn_norm")(x)
         y, _ = self.ffn(*self.ffn_args, name="ffn")(y)
-        return x + y
+        return x + branch(y)
 
 
 class Lfm2LM(nn.Module):

@@ -103,18 +103,22 @@ class LoopLayer(nn.Module):
         return x + y
 
 
-def head_losses(z, kernel, labels=None, dtype=jnp.bfloat16, tied=False):
+def head_losses(z, kernel, labels=None, dtype=jnp.bfloat16, tied=False,
+                logit_scale=1.0):
     """fp32 logits of a state over the vocabulary (bf16 operands, fp32
     accumulation, under ``hvd_lm_head``), or, given the labels, the
     cross-entropy of each position (the log-sum-exp and the pick of the
     label under ``hvd_loss``, beside the matmul's scope and not inside
     it). ``kernel`` is (hidden, vocab), or with ``tied`` an embedding's
-    table (vocab, hidden)."""
+    table (vocab, hidden); the logits are the product times
+    ``logit_scale`` (1: the product itself)."""
     with jax.named_scope(scopes.LM_HEAD):
         logits = jax.lax.dot_general(
             z.astype(dtype), kernel.astype(dtype),
             (((z.ndim - 1,), (1 if tied else 0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if logit_scale != 1.0:
+            logits = logits * logit_scale
     if labels is None:
         return logits
     with jax.named_scope(scopes.LOSS):

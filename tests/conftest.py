@@ -48,7 +48,11 @@ def rng():
 # on the lists and the cells' files as they stood before PR 42
 # (``test_the_marked_tests_hold_whole_before_this_pr``; since PR 45, which
 # appended two readers, ``tests/benchmark/test_benchmark_int8ef.py`` runs
-# that runner and one more whole in turn), so that no assertion of theirs
+# that runner and one more whole in turn; since PR 47, which appended a
+# configuration, two cells and two readers and bound the cells to seven
+# accepted readers, ``tests/benchmark/test_benchmark_granite.py`` runs PR
+# 45's runner whole in its turn, and the two tests of those readers' lists:
+# four runners deep), so that no assertion of theirs
 # goes unexecuted. The ``benchmark`` PR that makes them say "in
 # this order, before whatever came later" takes this away (PERF.md section 7).
 APPENDED_TO_SINCE_PR_40 = {
@@ -79,6 +83,26 @@ APPENDED_TO_SINCE_PR_40 = {
     "test_the_marked_tests_hold_whole_before_this_pr":
         "asserts that PR 42's three readers are the last of the per-layer "
         "list; two were appended after them (all five cases)",
+    # since PR 47 (a configuration, two cells and two readers appended
+    # for the state-space cell and the data-parallel GPT cell;
+    # tests/benchmark/test_benchmark_granite.py runs these whole on the
+    # lists as they stood before them)
+    "test_benchmark_int8ef.py::"
+    "test_the_marked_tests_hold_whole_before_this_pr":
+        "asserts that PR 45's two readers are the last of the per-layer "
+        "list; two were appended after them (all six cases)",
+    # since PR 47 too (its two cells appended to the lists of the accepted
+    # readers whose scopes their steps hold, where the review of PR 47 had
+    # them bound in place of a second name for one reader)
+    "test_benchmark_block_parts.py::"
+    "test_a_reading_has_its_entry_its_file_and_its_cells":
+        "asserts that a block reader's list of cells is the one PR 38 gave "
+        "it; the state-space cell and the data-parallel GPT cell were "
+        "appended to it (all six cases)",
+    "test_benchmark_lfm2.py::test_the_convolutions_cost_by_hand":
+        "asserts that short_conv_ms is reported by cells of one "
+        "configuration; the state-space cell, whose convolution lies under "
+        "the same scope, was appended to its list",
 }
 
 

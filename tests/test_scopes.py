@@ -361,6 +361,7 @@ def _constants():
 
 ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.LINEAR_ATTN_SCOPES + scopes.SHORT_CONV_SCOPES
+             + scopes.STATE_SPACE_SCOPES
              + scopes.BLOCK_SCOPES + scopes.BLOCK_DIFFUSION_SCOPES
              + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
@@ -369,7 +370,7 @@ ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 35
+    assert len(values) == len(set(values)) == 36
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
@@ -377,11 +378,13 @@ def test_each_name_is_written_once():
                                  "hvd_moe_shared")
     assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
     assert scopes.SHORT_CONV_SCOPES == ("hvd_short_conv",)
+    assert scopes.STATE_SPACE_SCOPES == ("hvd_ssd",)
     assert scopes.BLOCK_DIFFUSION_SCOPES == ("hvd_bd_noise",)
     assert scopes.BLOCK_SCOPES == ("hvd_mixer_proj", "hvd_rope", "hvd_mlp",
                                    "hvd_norm", "hvd_embed", "hvd_loss")
     assert not set(scopes.MOE_SCOPES + scopes.LINEAR_ATTN_SCOPES
-                   + scopes.SHORT_CONV_SCOPES + scopes.BLOCK_SCOPES) \
+                   + scopes.SHORT_CONV_SCOPES + scopes.STATE_SPACE_SCOPES
+                   + scopes.BLOCK_SCOPES) \
         & set(scopes.STEP_SCOPES + scopes.LOOP_SCOPES)
 
 
@@ -509,6 +512,59 @@ def test_a_window_and_full_models_scopes_are_on_its_step(laguna_op_names,
     if scope != scopes.ROPE:
         for n in under:     # no instruction under two of them
             assert sum(bool(_under(s).search(n)) for s in siblings) == 1, n
+
+
+@pytest.fixture(scope="module")
+def granite_op_names():
+    """Every ``op_name`` of a tiny state-space / attention model's
+    differentiated step, as lowered: layers m A m."""
+    from horovod_tpu.models import GraniteHybridLM, granite_loss
+
+    model = GraniteHybridLM(
+        vocab_size=64, num_layers=3, hidden=32,
+        layer_types=("mamba", "attention", "mamba"), num_heads=2,
+        num_kv_heads=1, head_dim=16, mlp_dim=48, ssm_heads=4,
+        ssm_head_dim=16, ssm_state=8, chunk=8)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    text = jax.jit(jax.value_and_grad(
+        lambda p: granite_loss(model, p, tokens))).lower(params).as_text(
+            debug_info=True)
+    assert " while" not in text     # the scan is no loop
+    return set(re.findall(r'"(jit\([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", scopes.STATE_SPACE_SCOPES
+                         + scopes.SHORT_CONV_SCOPES
+                         + tuple(s for s in scopes.BLOCK_SCOPES
+                                 if s != scopes.ROPE) + (scopes.LM_HEAD,))
+def test_a_state_space_models_scopes_are_on_its_step(granite_op_names,
+                                                     scope):
+    """The scan under ``hvd_ssd`` and the convolution with its bias and
+    SiLU under ``hvd_short_conv`` in the two state-space layers and not
+    in the attention layer, the projections, the gate and the gated norm
+    under ``hvd_mixer_proj`` in all three, the feed-forward, the norms,
+    the embedding, the loss and the tied head under theirs: forward and
+    backward, no instruction under two of them, and no rotation
+    anywhere."""
+    under = [n for n in granite_op_names if _under(scope).search(n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    layers = {m for n in under for m in re.findall(r"layer\d", n)}
+    every, ssm = {"layer0", "layer1", "layer2"}, {"layer0", "layer2"}
+    assert layers == {
+        scopes.SSD: ssm, scopes.SHORT_CONV: ssm, scopes.MIXER_PROJ: every,
+        scopes.MLP: every, scopes.NORM: every, scopes.EMBED: set(),
+        scopes.LOSS: set(), scopes.LM_HEAD: set()}[scope]
+    if scope == scopes.SSD:     # the step size and the decays are inside
+        assert any("softplus" in n for n in under)
+        assert any("exp" in n for n in under)
+    siblings = scopes.STATE_SPACE_SCOPES + scopes.SHORT_CONV_SCOPES + (
+        scopes.MIXER_PROJ, scopes.MLP, scopes.NORM, scopes.EMBED,
+        scopes.LOSS, scopes.LM_HEAD)
+    for n in under:
+        assert sum(bool(_under(s).search(n)) for s in siblings) == 1, n
+    assert not any(_under(scopes.ROPE).search(n) for n in granite_op_names)
 
 
 @pytest.fixture(scope="module")

@@ -410,6 +410,60 @@ def test_the_window_and_full_cells_step_fits(v5e, on_tpu):
                     + ["hvd_swa_bwd"] * 3 + ["hvd_swa_fwd"] * 6)
 
 
+def test_the_state_space_cells_step_fits(v5e, on_tpu):
+    """The whole training step of the state-space cell
+    (``GraniteHybridLM``'s defaults: layers 0-9 of the published stack,
+    nine Mamba-2 layers and one attention layer, a quarter of the
+    vocabulary, 1 x S8192, AdamW with bf16 first moments, donated)
+    compiled for a described v5e: 9.71 GiB, under the 15.75 of the chip
+    and over its quarter. The scan is XLA code under ``hvd_ssd`` with no
+    ``while`` (its 32 chunks are carried by one decay-matrix product a
+    head); the one attention layer's three flash calls are the program's
+    only Mosaic calls (no rotation: no rope kernel)."""
+    import optax
+
+    from horovod_tpu.models import granite
+
+    model = granite.GraniteHybridLM()
+    tokens = jax.ShapeDtypeStruct((1, 8193), jnp.int32, sharding=v5e)
+    tx = optax.adamw(1e-4, mu_dtype=jnp.bfloat16)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=v5e), tree)
+
+    params = jax.eval_shape(
+        lambda key: model.init(key, jnp.zeros((1, 8), jnp.int32))["params"],
+        jax.random.PRNGKey(0))
+    assert sum(x.size for x in jax.tree.leaves(params)) == 797_850_560
+    state = jax.eval_shape(tx.init, params)
+
+    def step(params, state, tokens):
+        loss, grads = jax.value_and_grad(
+            lambda p: granite.granite_loss(model, p, tokens))(params)
+        updates, state = tx.update(grads, state, params)
+        return optax.apply_updates(params, updates), state, loss
+
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(state), tokens).compile()
+    memory = compiled.memory_analysis()
+    held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + memory.output_size_in_bytes - memory.alias_size_in_bytes)
+    assert 9.3 < held / 2 ** 30 < 10.2
+    hlo = compiled.as_text()
+    assert len(re.findall(r" while\(", hlo)) == 0
+    calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
+                       r'custom_call_target="tpu_custom_call"', hlo)
+    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == [
+        "hvd_flash_dkv", "hvd_flash_fwd", "hvd_flash_fwd"]
+    # the scan's names survive the fusion, forward and backward
+    under = re.findall(r'op_name="([^"]*hvd_ssd[^"]*)"',
+                       hlo[hlo.index("ENTRY"):])
+    assert any("transpose(" in n for n in under)
+    assert any("transpose(" not in n for n in under)
+    assert len({m for n in under for m in re.findall(r"layer\d", n)}) == 9
+
+
 # (B, S, H, D), dtype: q of the three GPT cells' kind, of the looped cell
 # (one head a tile), of the gated-convolution cell and its eight K/V
 # heads, and the narrow and wide widths no cell runs.
