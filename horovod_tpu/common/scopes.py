@@ -81,6 +81,10 @@ KDA_BWD = KDA + "_bwd"
 # the scope ROPE and named with it as their prefix, as KDA's are
 ROPE_FWD = ROPE + "_fwd"
 ROPE_BWD = ROPE + "_bwd"
+# ops/ssd.py: the state-space scan's two kernels, made under the scope SSD
+# and named with it as their prefix, as KDA's are
+SSD_FWD = SSD + "_fwd"
+SSD_BWD = SSD + "_bwd"
 
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
@@ -98,3 +102,4 @@ BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
                   INT8_QUANTIZE_SR, INT8_DEQUANTIZE)
 KDA_KERNELS = (KDA_FWD, KDA_BWD)
 ROPE_KERNELS = (ROPE_FWD, ROPE_BWD)
+SSD_KERNELS = (SSD_FWD, SSD_BWD)

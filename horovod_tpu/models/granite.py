@@ -25,7 +25,11 @@ and the attention's softmax scale is ``attention_multiplier``, not
   The projections, the gate and the gated norm lie under
   ``hvd_mixer_proj``, the convolution with its bias and SiLU under
   ``hvd_short_conv`` (``ops/short_conv.py`` ``causal_conv``), the
-  recurrence under ``hvd_ssd``.
+  recurrence under ``hvd_ssd``: on a TPU, at the published widths (heads
+  of 64, a state of 128, one group, chunks of 256), the two Pallas kernels
+  ``hvd_ssd_fwd`` / ``hvd_ssd_bwd``; at any other shape (the tiny preset)
+  and off a TPU the chunked XLA code, picked by ``ssd_scan`` from what it
+  sees in its operands.
 - **Attention**: ``models/lfm2.py`` ``RotaryGQA`` with no rotation, no QK
   norm and ``scale = attention_multiplier``; causal flash attention.
 

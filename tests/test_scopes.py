@@ -297,6 +297,74 @@ def test_the_benchmarks_readers_count_the_rope_kernels_as_the_rotation():
         assert read(record) == pytest.approx(8e-3)
 
 
+def test_ssd_kernels_carry_their_names():
+    """The state-space scan's kernels meet the same contract (PERF.md,
+    the ``ssd_ms`` row): made under ``hvd_ssd`` and named with it as their
+    prefix, no other kernel's name."""
+    from horovod_tpu.ops import ssd
+
+    x = jnp.ones((1, 256, 8, 64), jnp.bfloat16)
+    heads = jnp.ones((8,), jnp.float32)
+    shared = jnp.ones((1, 256, 1, 128), jnp.bfloat16)
+    args = (x, x[..., 0], -heads, shared, shared, heads, heads)
+
+    def loss(*a):
+        return ssd.ssd_scan(*a, use_pallas=True).astype(jnp.float32).sum()
+
+    forward = _pallas_names(jax.make_jaxpr(loss)(*args).jaxpr, [])
+    assert forward == [scopes.SSD_FWD]
+    names = _pallas_names(jax.make_jaxpr(
+        jax.grad(loss, argnums=tuple(range(7))))(*args).jaxpr, [])
+    assert names == [scopes.SSD_FWD, scopes.SSD_BWD] \
+        == list(scopes.SSD_KERNELS)
+    # made under the scope: a path component of the compiled op_names
+    # before the kernel's own, through the call's own ``jit``, which the
+    # layers of a model share and XLA inlines (as the rotation's)
+    with _compiled_here():
+        text = jax.jit(loss).lower(*args).compile().as_text()
+    assert [name for name in re.findall(r'op_name="([^"]*)"', text)
+            if re.search(rf"/{scopes.SSD}/(jit\(\w+\)/)?{scopes.SSD_FWD}/",
+                         name)]
+    for name in names:
+        assert name.startswith(scopes.SSD + "_")
+    assert not set(names) & set(scopes.FLASH_KERNELS + scopes.BUCKET_KERNELS
+                                + scopes.KDA_KERNELS + scopes.ROPE_KERNELS)
+
+
+def test_the_benchmarks_readers_count_the_ssd_kernels_as_the_scan():
+    """On a hand-made trace of the calls as the chip names them (the
+    backward's ``op_name`` loses the scope under ``transpose(``; its own
+    instruction name keeps it), the benchmark's readers (not this PR's to
+    edit) give ``ssd_ms`` all three calls' time and the flash kernels,
+    the convolution and the projections none of it."""
+    from benchmark import of_which
+    from benchmark.catalog import Catalog
+
+    call = ('%{}.{} = bf16[8]{{0}} custom-call(%p.1), '
+            'custom_call_target="tpu_custom_call"')
+    under = f"layer0/mixer/{scopes.SSD}/"
+    events = [[call.format(scopes.SSD_FWD, 4), 0.0, 3e3, "",
+               f"jit(step)/jvp(G)/{under}{scopes.SSD_FWD}/pallas_call", 1],
+              [call.format(scopes.SSD_FWD, 5), 4e3, 3e3, "",
+               "jit(step)/transpose(jvp(G))/jvp(G)/checkpoint/"
+               f"rematted_computation/{under}{scopes.SSD_FWD}/pallas_call",
+               1],
+              [call.format(scopes.SSD_BWD, 6), 8e3, 7e3, "",
+               "jit(step)/transpose(jvp(G))/jvp(G)/checkpoint/layer0/mixer/"
+               f"transpose(jvp({scopes.SSD}))/{scopes.SSD_BWD}/pallas_call",
+               1]]
+    trace = {"devices": {"/device:TPU:0": events}, "hlo": {}}
+    record = {"trace": {"steps": 1},
+              "of_which_trace": of_which._without_loops(trace)}
+
+    def read(metric):
+        return Catalog().module("layer_metrics", metric).read(record)
+
+    assert read("ssd_ms") == pytest.approx(13e-3)
+    for metric in ("short_conv_ms", "mixer_proj_ms", "kda_ms"):
+        assert not read(metric)
+
+
 def test_the_flash_backward_call_has_three_outputs():
     q = jnp.ones((1, 128, 2, 64), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(jax.grad(_flash_loss(False), argnums=(0, 1, 2)))(
@@ -365,12 +433,13 @@ ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.BLOCK_SCOPES + scopes.BLOCK_DIFFUSION_SCOPES
              + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
-             + scopes.ROPE_KERNELS + scopes.SWA_KERNELS)
+             + scopes.ROPE_KERNELS + scopes.SWA_KERNELS
+             + scopes.SSD_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 36
+    assert len(values) == len(set(values)) == 38
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries

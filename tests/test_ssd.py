@@ -2,7 +2,9 @@
 the recurrence run token by token, values and every gradient, at chunks
 that do and do not divide the sequence, one chunk and several, one group
 and several, under a decay strong enough that a factored ``exp(g_t) *
-exp(-g_s)`` overflows, and with bf16 operands against fp32."""
+exp(-g_s)`` overflows, and with bf16 operands against fp32; and the two
+Pallas kernels that run the chunked form on a TPU (interpret mode here),
+at the state-space cell's shape cut in length, against both."""
 
 import re
 
@@ -11,21 +13,29 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.common import metrics as metrics_lib
+from horovod_tpu.common import scopes
 from horovod_tpu.ops import ssd
 
 B, S, H, P, N = 2, 50, 4, 8, 16
 OPERANDS = ("x", "dt", "a", "b", "c", "d", "dt_bias")
+# the state-space cell's scan (64 heads of 64 over a state of 128, one
+# group, chunks of 256) cut to two chunks, and to two blocks of 8 heads
+# where the recurrence token by token is differentiated as well
+CELL = dict(batch=1, length=512, heads=64, width=64, state=128)
+TWO_BLOCKS = dict(CELL, heads=16)
 
 
-def _operands(groups=1, seed=0, dtype=jnp.float32, decay=1.0):
+def _operands(groups=1, seed=0, dtype=jnp.float32, decay=1.0, batch=B,
+              length=S, heads=H, width=P, state=N):
     k = jax.random.split(jax.random.PRNGKey(seed), 7)
-    x = jax.random.normal(k[0], (B, S, H, P))
-    dt = jax.random.normal(k[1], (B, S, H))
-    a = -jnp.exp(jax.random.uniform(k[2], (H,), minval=0.0, maxval=2.7))
-    b = jax.random.normal(k[3], (B, S, groups, N))
-    c = jax.random.normal(k[4], (B, S, groups, N))
-    d = jax.random.normal(k[5], (H,))
-    dt_bias = jax.random.normal(k[6], (H,)) - 2.0
+    x = jax.random.normal(k[0], (batch, length, heads, width))
+    dt = jax.random.normal(k[1], (batch, length, heads))
+    a = -jnp.exp(jax.random.uniform(k[2], (heads,), minval=0.0, maxval=2.7))
+    b = jax.random.normal(k[3], (batch, length, groups, state))
+    c = jax.random.normal(k[4], (batch, length, groups, state))
+    d = jax.random.normal(k[5], (heads,))
+    dt_bias = jax.random.normal(k[6], (heads,)) - 2.0
     return (x.astype(dtype), dt.astype(dtype), decay * a, b.astype(dtype),
             c.astype(dtype), d, dt_bias)
 
@@ -143,3 +153,153 @@ def test_the_scan_is_under_its_scope_and_has_no_loop():
     assert any("transpose(" in n for n in under)
     assert any("transpose(" not in n for n in under)
     assert "while" not in text
+
+
+# -- the Pallas kernels (interpret mode off a TPU) ---------------------------
+
+def _kernels(*ops):
+    return ssd.ssd_scan(*ops, use_pallas=True)
+
+
+def _xla(*ops):
+    return ssd.ssd_scan(*ops, use_pallas=False)
+
+
+def _gradients(fn, args, weights):
+    return jax.jit(jax.grad(
+        lambda *ops: (fn(*ops).astype(jnp.float32) * weights).sum(),
+        argnums=tuple(range(len(OPERANDS)))))(*args)
+
+
+# fp32 operands: the kernels are the recurrence to fp32's rounding (the
+# sums over a head's tokens, dA and d dt_bias, to a few 1e-5: the XLA
+# code's own read 1.4e-5 and 3.0e-5 there). bf16 operands: to the
+# products' rounding, as the XLA code is.
+@pytest.mark.parametrize("dtype, length, tol, sums_tol", [
+    (jnp.float32, 512, 1e-5, 1e-4), (jnp.float32, 300, 1e-5, 1e-4),
+    (jnp.bfloat16, 512, 1e-2, 1e-2), (jnp.bfloat16, 300, 1e-2, 1e-2)])
+def test_the_kernels_are_the_recurrence_values_and_every_gradient(
+        dtype, length, tol, sums_tol):
+    """Two blocks of 8 heads over two chunks (300: the second one padded),
+    against the recurrence token by token on the same operands."""
+    shape = dict(TWO_BLOCKS, length=length)
+    args = _operands(dtype=dtype, **shape)
+    got = jax.jit(_kernels)(*args)
+    assert got.dtype == dtype and got.shape == args[0].shape
+    assert _close(got, jax.jit(ssd.ssd_reference)(*args), tol)
+    weights = jnp.cos(jnp.arange(length * 64, dtype=jnp.float32)).reshape(
+        length, 1, 64)
+    want = _gradients(ssd.ssd_reference, args, weights)
+    grads = _gradients(_kernels, args, weights)
+    for name, g, w, operand in zip(OPERANDS, grads, want, args):
+        assert g.dtype == operand.dtype and g.shape == operand.shape, name
+        assert float(jnp.abs(w).max()) > 0, name
+        assert _close(g, w, sums_tol if name in ("a", "dt_bias") else tol), \
+            name
+
+
+@pytest.mark.parametrize("dtype, length, tol", [
+    (jnp.float32, 512, 1e-4), (jnp.bfloat16, 512, 1e-2),
+    (jnp.bfloat16, 300, 1e-2)])
+def test_the_kernels_and_the_xla_code_agree_at_the_cells_shape(dtype, length,
+                                                                tol):
+    """All 64 heads (eight blocks) of the cell's scan, values and every
+    gradient against the chunked XLA code on the same operands; the
+    values against the recurrence too."""
+    args = _operands(dtype=dtype, **dict(CELL, length=length))
+    got = jax.jit(_kernels)(*args)
+    assert _close(got, jax.jit(_xla)(*args), tol)
+    assert _close(got, jax.jit(ssd.ssd_reference)(*args), tol)
+    weights = jnp.sin(jnp.arange(length * 64, dtype=jnp.float32)).reshape(
+        length, 1, 64)
+    want = _gradients(_xla, args, weights)
+    for name, g, w in zip(OPERANDS, _gradients(_kernels, args, weights),
+                          want):
+        assert bool(jnp.isfinite(g.astype(jnp.float32)).all()), name
+        assert _close(g, w, tol), name
+
+
+def test_a_strong_decay_does_not_overflow_in_the_kernels():
+    """``test_a_strong_decay_does_not_overflow...``'s operands (``delta
+    A`` of some -90 a token, g past -200 within 16 tokens and past -20,000
+    within a chunk) through the kernels: the exponent of a pair is
+    clamped at 0 before ``exp``, the state is carried by factors <= 1."""
+    x, dt, a, b, c, d, dt_bias = _operands(decay=40.0, **TWO_BLOCKS)
+    dt = dt + 8.0
+    g = jnp.cumsum(jax.nn.softplus(dt + dt_bias) * a, 1)
+    assert float(-g[:, 15].max()) > 200
+    want = ssd.ssd_reference(x, dt, a, b, c, d, dt_bias)
+    got = _kernels(x, dt, a, b, c, d, dt_bias)
+    assert bool(jnp.isfinite(got).all()) and _close(got, want, 1e-5)
+    grads = jax.grad(lambda *ops: _kernels(*ops).sum(),
+                     argnums=tuple(range(7)))(x, dt, a, b, c, d, dt_bias)
+    assert all(bool(jnp.isfinite(v).all()) for v in grads)
+
+
+def test_without_a_bias_the_kernels_read_dt_alone():
+    x, dt, a, b, c, d, dt_bias = _operands(**dict(TWO_BLOCKS, length=256))
+    want = _xla(x, dt + dt_bias, a, b, c, d)
+    assert _close(_kernels(x, dt + dt_bias, a, b, c, d), want, 1e-5)
+    assert _close(_kernels(x, dt, a, b, c, d, dt_bias), want, 1e-5)
+
+
+def _equations(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) \
+                    else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+def _pallas_names(fn, *args):
+    return [eqn.params["name"]
+            for eqn in _equations(jax.make_jaxpr(fn)(*args).jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+def _calls(path):
+    samples = metrics_lib.snapshot()["hvd_tpu_ssd_calls_total"]["samples"]
+    return sum(s["value"] for s in samples
+               if s["labels"].get("path") == path)
+
+
+@pytest.mark.parametrize("shape, groups, chunk, use_pallas, path", [
+    (TWO_BLOCKS, 1, 256, True, "pallas"),   # the cell's kind, forced here
+    (TWO_BLOCKS, 1, 256, None, "xla"),      # a CPU picks the XLA code
+    (TWO_BLOCKS, 1, 256, False, "xla"),
+    (TWO_BLOCKS, 1, 128, True, "xla"),      # a chunk never run on the chip
+    (TWO_BLOCKS, 2, 256, True, "xla"),      # two groups of B and C
+    (dict(TWO_BLOCKS, heads=12), 1, 256, True, "xla"),  # no whole blocks
+    (dict(TWO_BLOCKS, width=32), 1, 256, True, "xla"),
+    (dict(TWO_BLOCKS, state=64), 1, 256, True, "xla"),  # half a lane tile
+    (dict(batch=B, length=S, heads=H, width=P, state=N), 1, 8, True,
+     "xla"),                                # the tiny preset's kind
+])
+def test_the_path_follows_what_the_scan_sees_and_is_counted(
+        shape, groups, chunk, use_pallas, path):
+    args = _operands(groups, **dict(shape, length=64))
+    before = {p: _calls(p) for p in ("pallas", "xla")}
+
+    def loss(*ops):
+        return ssd.ssd_scan(*ops, chunk=chunk, use_pallas=use_pallas).sum()
+
+    assert _pallas_names(loss, *args) == (
+        [scopes.SSD_FWD] if path == "pallas" else [])
+    other = "xla" if path == "pallas" else "pallas"
+    assert _calls(path) == before[path] + 1
+    assert _calls(other) == before[other]
+    # differentiated: the forward that keeps the states, and the backward
+    assert _pallas_names(jax.grad(loss), *args) == (
+        list(scopes.SSD_KERNELS) if path == "pallas" else [])
+
+
+def test_the_kernels_carry_the_state_across_chunks():
+    """A token of the second chunk reads what the first chunk wrote."""
+    args = _operands(**TWO_BLOCKS)
+    out = _kernels(*args)
+    cut = _kernels(args[0].at[:, :256].set(0.0), *args[1:])
+    assert float(jnp.abs(out[:, 256:] - cut[:, 256:]).max()) > 1e-4
+    assert float(jnp.abs(out[:, :256] - cut[:, :256]).max()) > 1e-4

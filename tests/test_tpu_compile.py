@@ -415,13 +415,20 @@ def test_the_state_space_cells_step_fits(v5e, on_tpu):
     (``GraniteHybridLM``'s defaults: layers 0-9 of the published stack,
     nine Mamba-2 layers and one attention layer, a quarter of the
     vocabulary, 1 x S8192, AdamW with bf16 first moments, donated)
-    compiled for a described v5e: 9.71 GiB, under the 15.75 of the chip
-    and over its quarter. The scan is XLA code under ``hvd_ssd`` with no
-    ``while`` (its 32 chunks are carried by one decay-matrix product a
-    head); the one attention layer's three flash calls are the program's
-    only Mosaic calls (no rotation: no rope kernel)."""
+    compiled for a described v5e: 9.45 GiB (9.71 while the scan was XLA
+    code: its fp32 pair matrices are gone), under the 15.75 of the chip,
+    over its quarter and inside the 1% the benchmark allows over the
+    accepted 9.7066. Every layer's scan is Mosaic calls (``ops/ssd.py``:
+    the forward, the forward run again under rematerialisation, the
+    backward: 18 + 9) beside the one attention layer's three flash calls
+    (no rotation: no rope kernel), on every compile: the path is picked
+    from the platform and the shapes as the step is traced. No ``while``;
+    what XLA is left under ``hvd_ssd`` is A's sign, the stack of the
+    heads' parameters, dt's cast and transposition (and back) and the
+    sums' last additions."""
     import optax
 
+    from horovod_tpu.common import scopes
     from horovod_tpu.models import granite
 
     model = granite.GraniteHybridLM()
@@ -449,19 +456,67 @@ def test_the_state_space_cells_step_fits(v5e, on_tpu):
     memory = compiled.memory_analysis()
     held = (memory.argument_size_in_bytes + memory.temp_size_in_bytes
             + memory.output_size_in_bytes - memory.alias_size_in_bytes)
-    assert 9.3 < held / 2 ** 30 < 10.2
+    assert 9.40 < held / 2 ** 30 < 9.50 < 9.7066 * 1.01
     hlo = compiled.as_text()
     assert len(re.findall(r" while\(", hlo)) == 0
     calls = re.findall(r"%([\w.\-]+) = [^\n]* custom-call\([^\n]*"
                        r'custom_call_target="tpu_custom_call"', hlo)
-    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == [
-        "hvd_flash_dkv", "hvd_flash_fwd", "hvd_flash_fwd"]
-    # the scan's names survive the fusion, forward and backward
+    assert sorted(re.sub(r"[.\d]+$", "", c) for c in calls) == (
+        ["hvd_flash_dkv"] + ["hvd_flash_fwd"] * 2
+        + [scopes.SSD_BWD] * 9 + [scopes.SSD_FWD] * 18)
+    # the scope's name survives in every layer, forward and backward, and
+    # no pair matrix (1 x 32 chunks x 64 heads x 256 x 256) is XLA's
     under = re.findall(r'op_name="([^"]*hvd_ssd[^"]*)"',
                        hlo[hlo.index("ENTRY"):])
     assert any("transpose(" in n for n in under)
     assert any("transpose(" not in n for n in under)
     assert len({m for n in under for m in re.findall(r"layer\d", n)}) == 9
+    assert not re.search(r"\[1,32,(64|1,64),256,256\]", hlo)
+    # and the kernels read x, B and C as the layer has them: what XLA lays
+    # out anew around the calls is dt and its gradient (a row a head, 2
+    # MiB) and the heads' parameters
+    moved = {re.match(r"\s*%[\w.\-]+ = (\w+\[[\d,]*\])", line).group(1)
+             for line in hlo[hlo.index("ENTRY"):].splitlines()
+             if "hvd_ssd" in line
+             and re.search(r" (copy|transpose)\(", line)}
+    assert moved <= {"f32[64,3]", "f32[1,64,8192]", "bf16[1,64,8192]"}
+
+
+def test_the_state_space_kernels_compile_alone_for_v5e(v5e, on_tpu):
+    """``hvd_ssd_fwd`` and ``hvd_ssd_bwd`` at the cell's shape (1 x S8192,
+    64 heads of 64, a state of 128, one group, chunks of 256), alone: the
+    Mosaic compile fits VMEM under the default scoped limit (the calls
+    set none), the forward keeps the chunk-start states for the backward
+    (32 chunks x 128 x 4096 fp32) and the backward gives dx, d dt (a row
+    a head), dB, dC and the chunks' sums for d dt_bias, dA and dD."""
+    from horovod_tpu.common import scopes
+    from horovod_tpu.ops import ssd
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+
+    heads = spec((64,), jnp.float32)
+    args = (spec((1, 8192, 64, 64)), spec((1, 8192, 64)), heads,
+            spec((1, 8192, 1, 128)), spec((1, 8192, 1, 128)), heads, heads)
+    assert ssd._kernels_take(args[0], args[3], ssd.CHUNK)
+
+    def loss(*ops):
+        return ssd.ssd_scan(*ops).astype(jnp.float32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=tuple(range(7)))).lower(
+        *args).compile().as_text()
+    assert "vmem_limit_bytes" not in hlo
+    calls = {re.sub(r"[.\d]+$", "", name): line for name, line in re.findall(
+        r"%([\w.\-]+) = ([^\n]*) custom-call\([^\n]*"
+        r'custom_call_target="tpu_custom_call"', hlo)}
+    assert sorted(calls) == [scopes.SSD_BWD, scopes.SSD_FWD]
+    shapes = {name: re.findall(r"(?:bf16|f32)\[[\d,]+\]", line)
+              for name, line in calls.items()}
+    assert shapes[scopes.SSD_FWD] == ["bf16[1,8192,4096]",
+                                      "f32[1,32,128,4096]"]
+    assert shapes[scopes.SSD_BWD] == [
+        "bf16[1,8192,4096]", "f32[1,64,8192]", "f32[1,8192,128]",
+        "f32[1,8192,128]", "f32[1,32,64,3]"]
 
 
 # (B, S, H, D), dtype: q of the three GPT cells' kind, of the looped cell
