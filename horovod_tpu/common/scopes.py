@@ -85,6 +85,11 @@ ROPE_BWD = ROPE + "_bwd"
 # and named with it as their prefix, as KDA's are
 SSD_FWD = SSD + "_fwd"
 SSD_BWD = SSD + "_bwd"
+# ops/short_conv.py: ``conv_act``'s two kernels (the convolution with its
+# bias and SiLU before a scan), made under the scope SHORT_CONV and named
+# with it as their prefix, as KDA's are
+SHORT_CONV_FWD = SHORT_CONV + "_fwd"
+SHORT_CONV_BWD = SHORT_CONV + "_bwd"
 
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
@@ -103,3 +108,4 @@ BUCKET_KERNELS = (SCALE, ADASUM_DOT_NORMS, ADASUM_COMBINE, INT8_QUANTIZE,
 KDA_KERNELS = (KDA_FWD, KDA_BWD)
 ROPE_KERNELS = (ROPE_FWD, ROPE_BWD)
 SSD_KERNELS = (SSD_FWD, SSD_BWD)
+SHORT_CONV_KERNELS = (SHORT_CONV_FWD, SHORT_CONV_BWD)

@@ -52,7 +52,8 @@ import jax.numpy as jnp
 
 from ..common import scopes
 from ..ops.flash_attention import CAUSAL
-from ..ops.short_conv import causal_conv
+# (causal_conv: the benchmark's fault tests reach it through this module)
+from ..ops.short_conv import causal_conv, conv_act  # noqa: F401
 from ..ops.ssd import CHUNK, ssd_scan
 from .lfm2 import NO_ROTATION, DenseFFN, Lfm2Layer, RotaryGQA
 from .looplm import RMSNorm, head_losses
@@ -89,9 +90,9 @@ def _conv_bias_init(taps):
 
 
 def _conv_act(xbc, taps, bias):
-    """``silu(conv(xbc) + bias)`` in xbc's dtype, the arithmetic in fp32."""
-    with jax.named_scope(scopes.SHORT_CONV):
-        return nn.silu(causal_conv(xbc, taps) + bias).astype(xbc.dtype)
+    """``silu(conv(xbc) + bias)`` in xbc's dtype, the arithmetic in fp32:
+    ``ops/short_conv.py`` ``conv_act``, which picks its path."""
+    return conv_act(xbc, taps, bias)
 
 
 def _gated_norm(y, z, scale, eps, groups):

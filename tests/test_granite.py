@@ -14,7 +14,7 @@ import pytest
 
 from horovod_tpu.models import granite, lfm2, looplm
 from horovod_tpu.ops import flash_attention as fa
-from horovod_tpu.ops import ssd
+from horovod_tpu.ops import short_conv, ssd
 
 
 def _close(got, want, tol):
@@ -201,3 +201,59 @@ def test_the_model_is_causal_and_its_multipliers_are_where_they_belong():
     text = str(jax.make_jaxpr(lambda p: granite.granite_loss(
         model, p["params"], tokens))(params))
     assert text.count("remat2[") == 4
+
+
+def _conv_calls(path):
+    from horovod_tpu.common import metrics as metrics_lib
+
+    samples = metrics_lib.snapshot()[
+        "hvd_tpu_short_conv_calls_total"]["samples"]
+    return sum(s["value"] for s in samples
+               if s["labels"].get("path") == path)
+
+
+@pytest.mark.parametrize("dtype, loss_rtol, scale_rtol, leaf_rtol", [
+    (jnp.float32, 1e-6, 1e-5, 1e-4),
+    # the state-space cell's own limits on the loss and on sqrt(sum g^2)
+    # (benchmark/workloads/granite-4.0-h-micro-l10-s8192.json)
+    (jnp.bfloat16, 2e-5, 7.5e-4, 3e-2),
+], ids=["float32", "bfloat16"])
+def test_the_model_through_the_convolutions_kernels_is_the_model(
+        monkeypatch, dtype, loss_rtol, scale_rtol, leaf_rtol):
+    """Loss and gradients with ``conv_act``'s kernels forced (interpret
+    mode) against the XLA code, at the smallest widths the kernels take:
+    256 channels of x, B and C over one tile of tokens, two Mamba-2
+    layers."""
+    model = granite.GraniteHybridLM(
+        vocab_size=64, num_layers=2, hidden=32,
+        layer_types=("mamba", "mamba"), num_heads=2, num_kv_heads=1,
+        head_dim=16, mlp_dim=48, ssm_heads=8, ssm_head_dim=16,
+        ssm_state=64, dtype=dtype)
+    tokens = jax.random.randint(jax.random.PRNGKey(0),
+                                (1, short_conv._ROWS + 1), 0, 64)
+    params = model.init(jax.random.PRNGKey(1), tokens[:, :8])["params"]
+
+    def step(params):
+        return jax.value_and_grad(
+            lambda p: granite.granite_loss(model, p, tokens))(params)
+
+    want_loss, want = step(params)
+    before = _conv_calls("pallas"), _conv_calls("xla")
+    monkeypatch.setattr(granite, "conv_act", functools.partial(
+        short_conv.conv_act, use_pallas=True))
+    got_loss, got = step(params)
+    assert _conv_calls("pallas") > before[0]
+    assert _conv_calls("xla") == before[1]
+
+    def scale(tree):
+        return float(jnp.sqrt(sum(
+            (g.astype(jnp.float32) ** 2).sum()
+            for g in jax.tree.leaves(tree))))
+
+    assert abs(float(got_loss) - float(want_loss)) \
+        <= loss_rtol * abs(float(want_loss))
+    assert abs(scale(got) - scale(want)) <= scale_rtol * scale(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.isfinite(np.asarray(g, np.float32)).all()
+        assert scale(jax.tree.map(jnp.subtract, g, w)) \
+            <= leaf_rtol * scale(w) + 1e-8

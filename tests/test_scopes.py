@@ -297,6 +297,38 @@ def test_the_benchmarks_readers_count_the_rope_kernels_as_the_rotation():
         assert read(record) == pytest.approx(8e-3)
 
 
+def test_short_conv_kernels_carry_their_names():
+    """``conv_act``'s kernels meet the contract of PERF.md's
+    ``short_conv_ms`` row: made under ``hvd_short_conv`` and named with it
+    as their prefix (the backward's ``op_name`` loses the scope under
+    ``transpose(``; the instruction's own name keeps it), no other
+    kernel's name."""
+    from horovod_tpu.ops import short_conv
+
+    args = (jnp.ones((1, short_conv._ROWS, 128), jnp.bfloat16),
+            jnp.ones((4, 128)), jnp.ones((128,)))
+
+    def loss(*a):
+        return short_conv.conv_act(*a, use_pallas=True).astype(
+            jnp.float32).sum()
+
+    assert _pallas_names(jax.make_jaxpr(loss)(*args).jaxpr, []) \
+        == [scopes.SHORT_CONV_FWD]
+    names = _pallas_names(jax.make_jaxpr(
+        jax.grad(loss, argnums=(0, 1, 2)))(*args).jaxpr, [])
+    assert names == list(scopes.SHORT_CONV_KERNELS)
+    with _compiled_here():
+        text = jax.jit(loss).lower(*args).compile().as_text()
+    assert [name for name in re.findall(r'op_name="([^"]*)"', text)
+            if re.search(rf"/{scopes.SHORT_CONV}/(jit\(\w+\)/)?"
+                         rf"{scopes.SHORT_CONV_FWD}/", name)]
+    for name in names:
+        assert name.startswith(scopes.SHORT_CONV + "_")
+    assert not set(names) & set(
+        scopes.FLASH_KERNELS + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
+        + scopes.ROPE_KERNELS + scopes.SWA_KERNELS + scopes.SSD_KERNELS)
+
+
 def test_ssd_kernels_carry_their_names():
     """The state-space scan's kernels meet the same contract (PERF.md,
     the ``ssd_ms`` row): made under ``hvd_ssd`` and named with it as their
@@ -434,12 +466,12 @@ ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
              + scopes.FLASH_KERNELS
              + scopes.BUCKET_KERNELS + scopes.KDA_KERNELS
              + scopes.ROPE_KERNELS + scopes.SWA_KERNELS
-             + scopes.SSD_KERNELS)
+             + scopes.SSD_KERNELS + scopes.SHORT_CONV_KERNELS)
 
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 38
+    assert len(values) == len(set(values)) == 40
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
