@@ -29,6 +29,10 @@ MOE_EXPERTS = "hvd_moe_experts"
 MOE_SHARED = "hvd_moe_shared"
 # ops/linear_attention.py: the gated delta-rule recurrence, chunked
 KDA = "hvd_kda"
+# ops/linear_attention.py gated_delta_attention: the same recurrence with
+# one decay scalar a head (Gated DeltaNet), chunked. "hvd_kda" is no part
+# of the string: a reader of one recurrence does not find the other
+GDN = "hvd_gdn"
 # ops/short_conv.py: the gated short convolution's two gates and its taps,
 # not the two projections around them
 SHORT_CONV = "hvd_short_conv"
@@ -94,7 +98,7 @@ SHORT_CONV_BWD = SHORT_CONV + "_bwd"
 STEP_SCOPES = (REDUCE, REDUCE_PACK, REDUCE_UNPACK, UPDATE, LM_HEAD)
 LOOP_SCOPES = (LOOP_EXIT,)   # a looped model's step only
 MOE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED)   # an expert layer's
-LINEAR_ATTN_SCOPES = (KDA,)  # a linear-attention layer's
+LINEAR_ATTN_SCOPES = (KDA, GDN)     # a linear-attention layer's, either
 SHORT_CONV_SCOPES = (SHORT_CONV,)   # a gated-convolution layer's
 STATE_SPACE_SCOPES = (SSD,)  # a state-space layer's
 BLOCK_DIFFUSION_SCOPES = (BD_NOISE,)    # a block-diffusion loss's

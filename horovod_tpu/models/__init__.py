@@ -14,6 +14,8 @@ from .lfm2 import Lfm2LM, lfm2_loss  # noqa: F401
 from .sdar import SdarLM, block_noise, sdar_loss  # noqa: F401
 from .laguna import LagunaLM, laguna_loss  # noqa: F401
 from .granite import GraniteHybridLM, Mamba2Mixer, granite_loss  # noqa: F401
+from .olmo_hybrid import (GatedDeltaNet, OlmoHybridLM,  # noqa: F401
+                          olmo_hybrid_loss)
 from .mlp import MLP, ConvNet  # noqa: F401
 from .resnet import ResNet, ResNet50, ResNet101, ResNet152  # noqa: F401
 from .vgg import VGG, VGG11, VGG13, VGG16, VGG19  # noqa: F401

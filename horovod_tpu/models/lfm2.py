@@ -59,6 +59,9 @@ from .solar import SparseExperts, _dense, solar_loss
 
 ATTENTION = "full_attention"    # a ``layer_types`` entry; any other: conv
 NO_ROTATION = "nope"    # a ``RotaryGQA.rotation``: q and k as projected
+# a ``RotaryGQA.qk_norm``: one RMSNorm over the whole projection of q and
+# of k (a scale vector as wide as the projection), before the heads are cut
+WHOLE_PROJECTION = "projection"
 # The published pattern of LFM2-8B-A1B's 24 layers: attention in layers 2,
 # 6, 10, 14, 18 and 21.
 _PATTERN = tuple(ATTENTION if i in (2, 6, 10, 14, 18, 21) else "conv"
@@ -93,6 +96,7 @@ class RotaryGQA(nn.Module):
 
     - ``qk_norm``: q and k through an RMSNorm over the head's channels
       before the rotation (one scale vector each, shared by the heads);
+      ``WHOLE_PROJECTION``: over all the heads' channels at once (Olmo's);
     - ``rotation``: an ``ops/rope.py`` ``Rotation`` (a rotary width under
       the head's, YaRN's frequencies, a scale); None is the plain one at
       ``rope_base``; ``NO_ROTATION``: no positions at all;
@@ -115,7 +119,7 @@ class RotaryGQA(nn.Module):
     dtype: Any = jnp.bfloat16
     mask_kind: Any = CAUSAL
     rotation: Any = None
-    qk_norm: bool = True
+    qk_norm: Any = True
     packed_rotation: bool = False
     head_gate: bool = False
     scale: Any = None
@@ -135,11 +139,20 @@ class RotaryGQA(nn.Module):
                 rotation=self.rotation or Rotation(self.rope_base))
         wide, narrow = (n * self.head_dim
                         for n in (self.num_heads, self.num_kv_heads))
+        whole = self.qk_norm == WHOLE_PROJECTION
+
+        def projected(name, width, heads, normed=False):
+            x = dense(width, name=name)(u)
+            if normed:
+                x = norm(name=name + "_norm")(x)
+            return x.reshape(b, s, heads, -1)
+
         with jax.named_scope(scopes.MIXER_PROJ):
-            q = dense(wide, name="q")(u).reshape(b, s, self.num_heads, -1)
-            k, v = (dense(narrow, name=n)(u).reshape(
-                b, s, self.num_kv_heads, -1) for n in ("k", "v"))
-            q, k = (turn(norm(name=n)(x) if self.qk_norm else x)
+            q = projected("q", wide, self.num_heads, whole)
+            k = projected("k", narrow, self.num_kv_heads, whole)
+            v = projected("v", narrow, self.num_kv_heads)
+            q, k = (turn(norm(name=n)(x) if self.qk_norm and not whole
+                         else x)
                     for n, x in (("q_norm", q), ("k_norm", k)))
             if self.scale is not None:
                 q = (q.astype(jnp.float32)

@@ -47,7 +47,6 @@ from typing import Any, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from jax.experimental import pallas as pl
 
@@ -57,6 +56,7 @@ from ..ops.linear_attention import kda_attention
 from ..ops.pallas_kernels import _decide
 from ..ops.short_conv import causal_conv
 from ..parallel import moe
+from .delta_rule import decay_bias_init, decay_rate_init, unit
 from .looplm import RMSNorm, _Head
 
 
@@ -89,17 +89,6 @@ class GatedGQA(nn.Module):
             o = o.reshape(b, s, wide) * nn.sigmoid(
                 dense(wide, name="gate")(x))
             return dense(hidden, name="o")(o)
-
-
-def _decay_bias_init(key, shape, dtype=jnp.float32):
-    """softplus^-1 of a step drawn log-uniformly from [1e-3, 1e-1]."""
-    step = jnp.exp(jax.random.uniform(
-        key, shape, dtype, np.log(1e-3), np.log(1e-1)))
-    return step + jnp.log(-jnp.expm1(-step))
-
-
-def _decay_rate_init(key, shape, dtype=jnp.float32):
-    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
 
 
 # -- what the step tells XLA:TPU's scheduler ---------------------------------
@@ -194,9 +183,6 @@ class KDA(nn.Module):
             y = nn.silu(causal_conv(dense(heads * width, name=name)(x), taps))
             return y.reshape(b, s, heads, width)
 
-        def unit(y):
-            return y * jax.lax.rsqrt((y * y).sum(-1, keepdims=True) + 1e-6)
-
         # everything around the recurrence under the one name; the
         # recurrence itself under its own (``hvd_kda``), outside it
         with jax.named_scope(scopes.MIXER_PROJ):
@@ -204,9 +190,9 @@ class KDA(nn.Module):
             k = unit(mixed("k")).astype(self.dtype)
             v = mixed("v").astype(self.dtype)
 
-            rate = self.param("A_log", _decay_rate_init, (heads,),
+            rate = self.param("A_log", decay_rate_init, (heads,),
                               jnp.float32)
-            bias = self.param("dt_bias", _decay_bias_init, (heads * width,),
+            bias = self.param("dt_bias", decay_bias_init, (heads * width,),
                               jnp.float32)
             f = dense(heads * width, name="f_up")(
                 dense(self.gate_rank, name="f_down")(x))

@@ -64,6 +64,16 @@ no option:
   chunk-start states the forward kept (S / C states a head, not S) and
   carries dS. ``use_pallas=True`` runs the kernels' bodies in interpret
   mode there (the tests' twin check).
+
+``gated_delta_attention`` is the recurrence with ONE decay scalar a head
+(Gated DeltaNet: ``Diag(alpha_t) = alpha_t I``), keys and values of
+widths of their own, under the scope ``hvd_gdn``. With a scalar,
+``exp(g_t - g_s)`` leaves the sums over the channels above: A and P are
+``K K^T`` and ``Q K^T``, one matmul a chunk each, times the chunk's (C, C)
+decay mask ``exp(g_t - g_s)`` (s <= t, every exponent <= 0 as it stands:
+no sub-chunks). The solve (``_solve``), the scan over chunks and its
+hand-written backward are the ones above, gamma one value a head. XLA
+code on every platform; no kernel is written for it yet.
 """
 
 from __future__ import annotations
@@ -157,15 +167,44 @@ def _within_chunks(q, k, v, log_alpha, beta, sub):
     g = jnp.cumsum(log_alpha, -2)
     c = k.shape[-2]
     pairs = _pair_matrices(jnp.stack([q, k]), k, g, sub)
-    strict = jnp.arange(c)[:, None] > jnp.arange(c)[None, :]
-    a = jnp.where(strict, pairs[1], 0.0) * beta[..., None]
     decayed = jnp.exp(g)
+    w, u0 = _solve(pairs[1], beta, k.astype(jnp.float32) * decayed, v)
+    k_to_end = (k * jnp.exp(g[..., -1:, :] - g)).astype(dtype)
+    return ((q * decayed).astype(dtype), pairs[0], w, u0, k_to_end,
+            decayed[..., -1, :])
+
+
+def _solve(pairs, beta, k_decayed, v):
+    """``(I + A) [W | U0] = beta [k e^g | v]`` with ``A = beta pairs``
+    under the diagonal (unit lower triangular), fp32, for all chunks at
+    once: ``pairs`` (..., C, C) the keys' pair matrix, ``k_decayed``
+    (..., C, Dk) fp32, ``v`` (..., C, Dv)."""
+    c = pairs.shape[-1]
+    strict = jnp.arange(c)[:, None] > jnp.arange(c)[None, :]
+    a = jnp.where(strict, pairs, 0.0) * beta[..., None]
     rhs = beta[..., None] * jnp.concatenate(
-        [k.astype(jnp.float32) * decayed, v.astype(jnp.float32)], -1)
+        [k_decayed, v.astype(jnp.float32)], -1)
     solved = solve_triangular(a + jnp.eye(c), rhs, lower=True,
                               unit_diagonal=True)
-    w, u0 = solved[..., :k.shape[-1]], solved[..., k.shape[-1]:]
-    k_to_end = (k * jnp.exp(g[..., -1:, :] - g)).astype(dtype)
+    return solved[..., :k_decayed.shape[-1]], solved[..., k_decayed.shape[-1]:]
+
+
+def _within_chunks_scalar(q, k, v, log_decay, beta):
+    """``_within_chunks`` where the decay is one scalar a head
+    (``log_decay`` (..., C)): ``exp(g_t - g_s)`` leaves the sum over the
+    channels, so a pair matrix is one ``rows K^T`` matmul a chunk times
+    the (C, C) decay mask, every exponent <= 0 with no sub-chunks. gamma
+    comes back (..., 1)."""
+    dtype = k.dtype
+    g = jnp.cumsum(log_decay, -1)
+    c = k.shape[-2]
+    low = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
+    decay = jnp.exp(jnp.where(low, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))
+    pairs = _mm("r...tc,...sc->r...ts", jnp.stack([q, k]), k, dtype) * decay
+    decayed = jnp.exp(g)[..., None]
+    w, u0 = _solve(pairs[1], beta, k.astype(jnp.float32) * decayed, v)
+    k_to_end = (k * jnp.exp(g[..., -1:] - g)[..., None]).astype(dtype)
     return ((q * decayed).astype(dtype), pairs[0], w, u0, k_to_end,
             decayed[..., -1, :])
 
@@ -182,8 +221,9 @@ def _chunk_forward(dtype, state, xs):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _across_chunks(dtype, qd, p, w, u0, kbar, gamma):
     """The scan over chunks (leading axis), from a zero state: per chunk
-    U = U0 - W S, O = Q~ S + P U, S <- Diag(gamma) S + K-^T U. Returns O
-    (N, B, H, C, Dv) fp32."""
+    U = U0 - W S, O = Q~ S + P U, S <- Diag(gamma) S + K-^T U; ``gamma``
+    (N, B, H, Dk), or (N, B, H, 1) where the decay is one scalar a head.
+    Returns O (N, B, H, C, Dv) fp32."""
     return _across_chunks_fwd(dtype, qd, p, w, u0, kbar, gamma)[0]
 
 
@@ -203,6 +243,9 @@ def _across_chunks_bwd(dtype, residuals, do):
 
     def step(ds, inputs):
         (qd, p, w, u0, kbar, gamma), state, do_n = inputs
+        d_gamma = (ds * state).sum(-1)
+        if gamma.shape[-1] == 1:    # one decay a head: over the keys too
+            d_gamma = d_gamma.sum(-1, keepdims=True)
         u = u0 - _mm("bhck,bhkv->bhcv", w, state, dtype)
         du = _mm("bhcs,bhcv->bhsv", p, do_n, dtype) \
             + _mm("bhck,bhkv->bhcv", kbar, ds, dtype)
@@ -212,7 +255,7 @@ def _across_chunks_bwd(dtype, residuals, do):
             -_mm("bhcv,bhkv->bhck", du, state, dtype),
             du,
             _mm("bhcv,bhkv->bhck", u, ds, dtype).astype(kbar.dtype),
-            (ds * state).sum(-1))
+            d_gamma)
         ds = gamma[..., None] * ds \
             + _mm("bhck,bhcv->bhkv", qd, do_n, dtype) \
             - _mm("bhck,bhcv->bhkv", w, du, dtype)
@@ -734,13 +777,50 @@ def kda_attention(q, k, v, log_alpha, beta, chunk: int = CHUNK,
                              by_head, SUB_CHUNK, interpret)
             return o.reshape(b, n * chunk, h, -1)[:, :s]
 
-        def chunks(x):              # (B, S, H, ...) -> (B, H, N, C, ...)
-            x = padded(x).reshape((b, n, chunk) + x.shape[2:])
-            return jnp.moveaxis(x, 3, 1)
+        return _chunked_xla(
+            functools.partial(_within_chunks, sub=min(SUB_CHUNK, chunk)),
+            q, k, v, log_alpha, beta, chunk)
 
-        parts = _within_chunks(chunks(q), chunks(k), chunks(v),
-                               chunks(log_alpha), chunks(beta),
-                               min(SUB_CHUNK, chunk))
-        o = _across_chunks(k.dtype, *(jnp.moveaxis(x, 2, 0) for x in parts))
-        o = jnp.moveaxis(o, (0, 3), (1, 2))     # (B, N, C, H, Dv)
-        return o.reshape(b, n * chunk, h, -1)[:, :s].astype(v.dtype)
+
+def _chunked_xla(within, q, k, v, log_decay, beta, chunk):
+    """The XLA code of either recurrence on (B, S, H, ...) operands: the
+    tail padded to whole chunks, ``within`` over all chunks at once, the
+    scan across them."""
+    b, s, h, _ = k.shape
+    pad = -s % chunk
+    n = (s + pad) // chunk
+
+    def chunks(x):              # (B, S, H, ...) -> (B, H, N, C, ...)
+        x = jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
+        return jnp.moveaxis(x.reshape((b, n, chunk) + x.shape[2:]), 3, 1)
+
+    parts = within(*(chunks(x) for x in (q, k, v, log_decay, beta)))
+    o = _across_chunks(k.dtype, *(jnp.moveaxis(x, 2, 0) for x in parts))
+    o = jnp.moveaxis(o, (0, 3), (1, 2))     # (B, N, C, H, Dv)
+    return o.reshape(b, n * chunk, h, -1)[:, :s].astype(v.dtype)
+
+
+_M_GDN_CALLS = metrics_lib.counter(
+    "hvd_tpu_gdn_calls_total",
+    "gated_delta_attention calls traced, by the path picked for them: "
+    "the chunked XLA code (no kernel is written for the scalar-decay "
+    "form yet)",
+    labels=("path",))
+
+
+def gated_delta_attention(q, k, v, log_decay, beta, chunk: int = CHUNK):
+    """The gated delta rule with ONE decay scalar a head (Gated DeltaNet),
+    ``kda_reference``'s recurrence with ``Diag(alpha_t) = alpha_t I``:
+    ``q``, ``k`` (B, S, H, Dk), ``v`` (B, S, H, Dv) with any Dv,
+    ``log_decay`` (B, S, H) (fp32, <= 0), ``beta`` (B, S, H). Returns o
+    like v. Any S: the tail is padded with tokens that write nothing.
+
+    Chunked as ``kda_attention`` is, under the scope ``hvd_gdn``: the
+    solve, the scan across chunks and its hand-written backward are
+    KDA's; the pair matrices are one matmul a chunk times the chunk's
+    decay mask (``_within_chunks_scalar``). XLA code on every platform."""
+    _M_GDN_CALLS.labels(path="xla").inc()
+    with jax.named_scope(scopes.GDN):
+        return _chunked_xla(_within_chunks_scalar, q, k, v,
+                            log_decay.astype(jnp.float32),
+                            beta.astype(jnp.float32), chunk)

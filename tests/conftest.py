@@ -52,7 +52,10 @@ def rng():
 # configuration, two cells and two readers and bound the cells to seven
 # accepted readers, ``tests/benchmark/test_benchmark_granite.py`` runs PR
 # 45's runner whole in its turn, and the two tests of those readers' lists:
-# four runners deep), so that no assertion of theirs
+# four runners deep; since PR 51, which appended a configuration, a cell
+# and two readers and bound the cell to six accepted readers,
+# ``tests/benchmark/test_benchmark_olmo_hybrid.py`` runs PR 47's runner
+# whole in its turn: five), so that no assertion of theirs
 # goes unexecuted. The ``benchmark`` PR that makes them say "in
 # this order, before whatever came later" takes this away (PERF.md section 7).
 APPENDED_TO_SINCE_PR_40 = {
@@ -103,6 +106,20 @@ APPENDED_TO_SINCE_PR_40 = {
         "asserts that short_conv_ms is reported by cells of one "
         "configuration; the state-space cell, whose convolution lies under "
         "the same scope, was appended to its list",
+    # since PR 51 (a configuration, a cell and two readers appended for
+    # the scalar-gated delta-rule cell, and the cell to six accepted
+    # readers' lists; tests/benchmark/test_benchmark_olmo_hybrid.py runs
+    # these whole on the lists as they stood before them)
+    "test_benchmark_granite.py::"
+    "test_the_marked_tests_hold_whole_before_this_pr":
+        "asserts that PR 47's configuration, two cells and two readers are "
+        "the last of their lists; a configuration, a cell and two readers "
+        "were appended after them (all thirteen cases)",
+    "test_benchmark_granite.py::"
+    "test_the_cells_report_the_common_readings_their_own_and_the_bound":
+        "asserts that PR 47's cells end the lists of the accepted readers "
+        "they were bound to; the scalar-gated delta-rule cell was appended "
+        "to six of them",
 }
 
 

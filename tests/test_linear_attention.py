@@ -4,6 +4,8 @@ token by token, on the CPU: the XLA code at a small width, and the Pallas
 kernels' bodies in interpret mode at the width the kernels take (128
 lanes a head)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,27 +48,39 @@ def _value_and_grads(fn, args, weight):
         argnums=(0, 1, 2, 3, 4))(*args)
 
 
-# S a multiple of the chunk, not a multiple, shorter than one chunk, and
-# a chunk that is a single sub-chunk.
+# One compile a call and a shape, shared by the cases that read it (the
+# mild and the strong decays, the seeds): run op by op, every case paid
+# for its own few hundred small programs.
+@functools.partial(jax.jit, static_argnames=("chunk", "use_pallas"))
+def _attention(args, weight, chunk=la.CHUNK, use_pallas=None):
+    """``(o, (sum, five gradients))`` of ``kda_attention``."""
+    def fn(*a):
+        return la.kda_attention(*a, chunk=chunk, use_pallas=use_pallas)
+    return fn(*args), _value_and_grads(fn, args, weight)
+
+
+@jax.jit
+def _recurrence(args, weight):
+    """The same of the recurrence token by token."""
+    return la.kda_reference(*args), _value_and_grads(la.kda_reference, args,
+                                                     weight)
+
+
+# S two whole chunks (of two sub-chunks each), a tail that is no whole
+# chunk, shorter than one chunk, and a chunk that is a single sub-chunk.
 @pytest.mark.parametrize("strong", [False, True], ids=["mild", "strong"])
-@pytest.mark.parametrize("s, chunk", [(128, 32), (100, 32), (37, 64),
-                                      (48, 16)])
+@pytest.mark.parametrize("s, chunk", [(64, 32), (40, 32), (37, 64),
+                                      (32, 16)])
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_chunked_forward_and_backward_equal_the_recurrence(path, s, chunk,
                                                            strong):
     use_pallas, width = PATHS[path]
     args = _operands(s, 2 if path == "xla" else 1, s, 2, width, strong)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
-
-    def attention(*a):
-        return la.kda_attention(*a, chunk=chunk, use_pallas=use_pallas)
-
     with jax.default_matmul_precision("highest"):
-        out = attention(*args)
-        want = la.kda_reference(*args)
-        (_, got_grads), (_, want_grads) = (
-            _value_and_grads(attention, args, weight),
-            _value_and_grads(la.kda_reference, args, weight))
+        out, (_, got_grads) = _attention(args, weight, chunk=chunk,
+                                         use_pallas=use_pallas)
+        want, (_, want_grads) = _recurrence(args, weight)
     assert out.shape == want.shape == args[2].shape
     assert _close(out, want, 1e-5)
     for got, wanted in zip(got_grads, want_grads):
@@ -76,10 +90,11 @@ def test_chunked_forward_and_backward_equal_the_recurrence(path, s, chunk,
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_bf16_operands_stay_near_the_fp32_recurrence(path):
     use_pallas, width = PATHS[path]
-    args = _operands(3, 1, 192, 2, max(width, 64), False, jnp.bfloat16)
-    out = la.kda_attention(*args, use_pallas=use_pallas)
+    args = _operands(3, 1, 96, 2, max(width, 64), False, jnp.bfloat16)
+    out = jax.jit(lambda *a: la.kda_attention(
+        *a, use_pallas=use_pallas))(*args)
     assert out.dtype == jnp.bfloat16
-    assert _close(out, la.kda_reference(*args), 2e-2)
+    assert _close(out, jax.jit(la.kda_reference)(*args), 2e-2)
 
 
 @pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
@@ -87,13 +102,12 @@ def test_bf16_operands_stay_near_the_fp32_recurrence(path):
                          ids=["fp32", "bf16"])
 def test_the_kernels_and_the_xla_code_agree_on_the_same_operands(dtype, tol):
     """One algorithm, two compilers: the cell's chunk of 64 at its width,
-    three heads, forward and the five gradients."""
-    args = _operands(11, 1, 160, 3, 128, True, dtype)
+    two heads, a chunk and a half, forward and the five gradients."""
+    args = _operands(11, 1, 96, 2, 128, True, dtype)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     with jax.default_matmul_precision("highest"):
-        (got, got_grads), (want, want_grads) = (
-            _value_and_grads(
-                lambda *a: la.kda_attention(*a, use_pallas=use), args, weight)
+        (got, (_, got_grads)), (want, (_, want_grads)) = (
+            _attention(args, weight, use_pallas=use)
             for use in (True, False))
     assert _close(got, want, tol)
     for g, w, like in zip(got_grads, want_grads, args):
@@ -104,7 +118,7 @@ def test_the_kernels_and_the_xla_code_agree_on_the_same_operands(dtype, tol):
 # d beta of the kernels against the XLA code's, both on bf16 operands. The
 # two round the same matmul operands to bf16, so they differ by what a
 # rounding flips: read 6.4e-7 and 3.3e-6 (strong decays), 2.3e-5 and
-# 3.7e-5 (mild) over two seeds each. With the solve's gradient
+# 3.7e-5 (mild) over two seeds each at S 160. With the solve's gradient
 # dA = -dR [W | U0]^T taken on bf16 operands (the XLA code's comes from
 # JAX's rule for the triangular solve, fp32 at the highest precision) the
 # same four read 2.1e-5, 2.8e-5, 5.0e-4 and 5.2e-4: each limit lies between.
@@ -113,13 +127,11 @@ def test_the_kernels_and_the_xla_code_agree_on_the_same_operands(dtype, tol):
                          ids=["strong", "mild"])
 def test_the_solves_gradient_keeps_fp32_under_bf16_operands(seed, strong,
                                                             tol):
-    args = _operands(seed, 1, 160, 2, 128, strong, jnp.bfloat16)
+    args = _operands(seed, 1, 96, 2, 128, strong, jnp.bfloat16)
     weight = jax.random.normal(jax.random.PRNGKey(9), args[2].shape)
     with jax.default_matmul_precision("highest"):
-        got, want = (
-            jax.grad(lambda *a: (la.kda_attention(*a, use_pallas=use).astype(
-                jnp.float32) * weight).sum(), argnums=4)(*args)
-            for use in (True, False))
+        got, want = (_attention(args, weight, use_pallas=use)[1][1][4]
+                     for use in (True, False))
     assert got.dtype == jnp.float32
     assert _close(got, want, tol)
 
@@ -128,10 +140,9 @@ def test_a_head_of_two_lane_tiles_runs_the_kernels():
     args = _operands(13, 1, 48, 1, 256, False)
     weight = jnp.ones(args[2].shape)
     with jax.default_matmul_precision("highest"):
-        (got, got_grads), (want, want_grads) = (
-            _value_and_grads(fn, args, weight) for fn in (
-                lambda *a: la.kda_attention(*a, chunk=32, use_pallas=True),
-                la.kda_reference))
+        got, (_, got_grads) = _attention(args, weight, chunk=32,
+                                         use_pallas=True)
+        want, (_, want_grads) = _recurrence(args, weight)
     assert _close(got, want, 1e-5)
     for g, w in zip(got_grads, want_grads):
         assert _close(g, w, 1e-4)
@@ -188,12 +199,12 @@ def test_the_state_crosses_chunks(path):
     """A token in the last chunk reads what the first chunk wrote: with
     the first chunk's values zeroed the last outputs change."""
     use_pallas, width = PATHS[path]
-    q, k, v, log_alpha, beta = _operands(5, 1, 128, 1, width, False)
-    out = la.kda_attention(q, k, v, log_alpha, beta, chunk=32,
-                           use_pallas=use_pallas)
-    cut = la.kda_attention(q, k, v.at[:, :32].set(0.0), log_alpha, beta,
-                           chunk=32, use_pallas=use_pallas)
-    assert float(jnp.abs(out[:, 96:] - cut[:, 96:]).max()) > 1e-4
+    q, k, v, log_alpha, beta = _operands(5, 1, 96, 1, width, False)
+    attention = jax.jit(lambda *a: la.kda_attention(
+        *a, chunk=32, use_pallas=use_pallas))
+    out = attention(q, k, v, log_alpha, beta)
+    cut = attention(q, k, v.at[:, :32].set(0.0), log_alpha, beta)
+    assert float(jnp.abs(out[:, 64:] - cut[:, 64:]).max()) > 1e-4
 
 
 def test_the_backward_is_a_scan_over_chunks_not_over_tokens():

@@ -471,13 +471,13 @@ ALL_NAMES = (scopes.STEP_SCOPES + scopes.LOOP_SCOPES + scopes.MOE_SCOPES
 
 def test_each_name_is_written_once():
     values = list(_constants().values())
-    assert len(values) == len(set(values)) == 40
+    assert len(values) == len(set(values)) == 41
     assert set(ALL_NAMES) <= set(values)
     # tuples of their own: a scope of one model's step is not one every
     # family carries
     assert scopes.MOE_SCOPES == ("hvd_moe_route", "hvd_moe_experts",
                                  "hvd_moe_shared")
-    assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda",)
+    assert scopes.LINEAR_ATTN_SCOPES == ("hvd_kda", "hvd_gdn")
     assert scopes.SHORT_CONV_SCOPES == ("hvd_short_conv",)
     assert scopes.STATE_SPACE_SCOPES == ("hvd_ssd",)
     assert scopes.BLOCK_DIFFUSION_SCOPES == ("hvd_bd_noise",)
@@ -503,8 +503,7 @@ def solar_op_names():
     return set(re.findall(r'"(jit\([^"]*)"', text))
 
 
-@pytest.mark.parametrize("scope", scopes.MOE_SCOPES
-                         + scopes.LINEAR_ATTN_SCOPES)
+@pytest.mark.parametrize("scope", scopes.MOE_SCOPES + (scopes.KDA,))
 def test_an_expert_linear_attention_models_scopes_are_on_its_step(
         solar_op_names, scope):
     """Forward and backward, and only under the layers that have them:
@@ -666,6 +665,83 @@ def test_a_state_space_models_scopes_are_on_its_step(granite_op_names,
     for n in under:
         assert sum(bool(_under(s).search(n)) for s in siblings) == 1, n
     assert not any(_under(scopes.ROPE).search(n) for n in granite_op_names)
+
+
+@pytest.fixture(scope="module")
+def olmo_op_names():
+    """Every ``op_name`` of a tiny scalar-gated delta-rule / attention
+    model's differentiated step, as lowered: layers L F L."""
+    from horovod_tpu.models import OlmoHybridLM, olmo_hybrid_loss
+
+    model = OlmoHybridLM(
+        vocab_size=64, num_layers=3, hidden=32,
+        layer_types=("linear_attention", "full_attention",
+                     "linear_attention"), num_heads=2, head_dim=16,
+        mlp_dim=48, linear_heads=2, linear_key_dim=8, linear_value_dim=16,
+        chunk=8)
+    tokens = jnp.zeros((2, 33), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:, :-1])["params"]
+    text = jax.jit(jax.value_and_grad(
+        lambda p: olmo_hybrid_loss(model, p, tokens))).lower(params).as_text(
+            debug_info=True)
+    return set(re.findall(r'"(jit\([^"]*)"', text))
+
+
+@pytest.mark.parametrize("scope", (scopes.GDN,) + scopes.SHORT_CONV_SCOPES
+                         + tuple(s for s in scopes.BLOCK_SCOPES
+                                 if s != scopes.ROPE) + (scopes.LM_HEAD,))
+def test_a_scalar_gated_delta_rule_models_scopes_are_on_its_step(
+        olmo_op_names, scope):
+    """The recurrence under ``hvd_gdn`` and the convolution with its SiLU
+    under ``hvd_short_conv`` in the two linear layers and not in the
+    attention layer; the projections, the norms, the decays and the gate
+    under ``hvd_mixer_proj`` in all three; the feed-forward, the
+    post-branch norms, the embedding, the loss and the head under theirs:
+    forward and backward, no instruction under two of them, no rotation
+    and nothing of KDA's anywhere."""
+    under = [n for n in olmo_op_names if _under(scope).search(n)]
+    assert any("transpose(" not in n for n in under)
+    assert any("transpose(" in n for n in under)
+    layers = {m for n in under for m in re.findall(r"layer\d", n)}
+    every, linear = {"layer0", "layer1", "layer2"}, {"layer0", "layer2"}
+    assert layers == {
+        scopes.GDN: linear, scopes.SHORT_CONV: linear,
+        scopes.MIXER_PROJ: every, scopes.MLP: every, scopes.NORM: every,
+        scopes.EMBED: set(), scopes.LOSS: set(),
+        scopes.LM_HEAD: set()}[scope]
+    siblings = (scopes.GDN,) + scopes.SHORT_CONV_SCOPES + (
+        scopes.MIXER_PROJ, scopes.MLP, scopes.NORM, scopes.EMBED,
+        scopes.LOSS, scopes.LM_HEAD)
+    for n in under:
+        assert sum(bool(_under(s).search(n)) for s in siblings) == 1, n
+    assert not any(_under(scopes.ROPE).search(n) for n in olmo_op_names)
+
+
+def test_every_event_of_the_recurrence_carries_its_scope_and_not_kdas(
+        olmo_op_names):
+    """What ``gdn_ms`` reads and what ``kda_ms`` must not: the solve, the
+    scan over chunks and its hand-written reverse scan lie under
+    ``hvd_gdn`` wherever they are in the step, and no ``op_name`` of the
+    step holds ``kda_ms``'s marker, which is no part of ``hvd_gdn``."""
+    from benchmark.layer_metrics import gdn_ms, kda_ms
+
+    assert gdn_ms.MARKER == scopes.GDN and kda_ms.MARKER == scopes.KDA
+    assert kda_ms.MARKER not in gdn_ms.MARKER
+    assert not any(kda_ms.MARKER in n for n in olmo_op_names)
+    recurrence = [n for n in olmo_op_names
+                  if re.search(r"triangular_solve|/while|cumsum", n)]
+    assert len(recurrence) >= 3
+    assert all(_under(scopes.GDN).search(n) for n in recurrence)
+    # a step of three events: the recurrence's is gdn_ms's alone
+    events = [["%fusion.1", 0.0, 2e6, "",
+               "jit(step)/layer0/mixer/hvd_gdn/triangular_solve", 1],
+              ["%fusion.2", 2e6, 1e6, "",
+               "jit(step)/layer0/mixer/hvd_mixer_proj/dot_general", 1]]
+    record = {"trace": {"steps": 1}, "cell": {},
+              "of_which_trace": {"devices": {"/device:TPU:0": events},
+                                 "hlo": {}}}
+    assert gdn_ms.read(dict(record)) == pytest.approx(2.0)
+    assert kda_ms.read(dict(record)) is None
 
 
 @pytest.fixture(scope="module")
